@@ -10,8 +10,9 @@ into tier-1) statically rejects any other blocking conversion
 ``block_until_ready``) inside the serving package, so a future edit
 cannot quietly reintroduce a per-token sync.
 
-Two measured facts about this environment's backend (JAX 0.4.37 CPU,
-and the same holds for TPU streams) dictate the shape of ``fetch_lanes``:
+Two facts about a device's in-order execution stream (re-measured on
+JAX 0.9.0's CPU backend in PR 22; not measured on the TPU) dictate the
+shape of ``fetch_lanes``:
 
 - fetching program N's OUTPUT buffer does NOT wait on program N+1
   dispatched after it — the transfer only waits for N's completion

@@ -43,7 +43,7 @@ def width_bucket(width: int, buckets) -> int:
 
     XLA compiles one executable per SHAPE, so a width search that builds
     models at every sampled width pays a full compile per fresh width
-    (~12s on the dev chip, parity_results.jsonl). Building instead at
+    (~12s on a v5e chip, parity_results.jsonl, July 2026). Building instead at
     ``width_bucket(w, buckets)`` with the true width masked
     (``models.mlp.MaskedMLP``, or any model taking a bucket+active
     pair) means only bucket boundaries ever compile; combined with an

@@ -12,7 +12,13 @@ suite (``tests/test_lint_blocking.py``) keep working; running it as a
 script behaves exactly as before.
 """
 
-from elephas_tpu.analysis.legacy import (  # noqa: F401
+import os
+import sys
+
+# runnable as ``python scripts/lint_blocking.py`` from a bare checkout
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from elephas_tpu.analysis.legacy import (  # noqa: E402,F401
     CLOCK_PRAGMA,
     KIND_PRAGMA,
     METRIC_PRAGMA,

@@ -45,7 +45,10 @@ artifact (``--out BENCH_DECODE.json``):
   rollback with zero non-canary exposure to the poisoned version).
 
 Importable (and runnable with tiny defaults) without a TPU — tier-1
-collects it; real numbers come from the dev chip.
+collects it. Only the meta row names the backend: every other row
+carries whatever it ran on under device-metric names, and every
+committed artifact it wrote is a CPU run. Not an on-chip measurement
+until ROADMAP S0 rebuilds it; ``chip_smoke.py`` is what runs on the chip.
 
 Usage: python scripts/lm_bench.py [--batches 1 8 32] [--new 64]
        [--out BENCH_DECODE.json]
@@ -1924,4 +1927,7 @@ def main(argv=None) -> list:
 
 
 if __name__ == "__main__":
+    from elephas_tpu.utils.compiler import configure_compile_cache
+
+    configure_compile_cache()
     main()

@@ -1,0 +1,135 @@
+"""The main path's kernels and train step, compiled for the real chip.
+
+The TPU compiler is installed here and compiles for a chip that is
+described, not attached (``v5e:2x2``): it refuses what interpret mode
+lets through — unaligned tiles, too much VMEM, a program that does not
+fit HBM. Nothing runs, so these say nothing about results or times;
+``chip_smoke.py`` is the run. The persistent compilation cache is off
+around the module: such a compile is written to it but cannot be read
+back without a chip.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from elephas_tpu.ops.attention_pallas import (
+    default_blocks,
+    pallas_flash_attention,
+    pallas_flash_attention_bwd,
+)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as exc:  # no libtpu here: nothing to compile against
+        pytest.skip(f"cannot describe a v5e topology: {exc}")
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_enabled)
+        compilation_cache.reset_cache()
+
+
+kernel_shapes = pytest.mark.parametrize(
+    "seq,head_dim,dtype",
+    [
+        (4096, 64, jnp.bfloat16),
+        (2048, 128, jnp.bfloat16),
+        (8192, 64, jnp.float32),
+        (8192, 128, jnp.bfloat16),
+    ],
+)
+
+
+def _qkv_shape(seq, head_dim, dtype, sharding):
+    return jax.ShapeDtypeStruct((1, 8, seq, head_dim), dtype, sharding=sharding)
+
+
+@kernel_shapes
+def test_pallas_forward_compiles_for_v5e(one_chip, seq, head_dim, dtype):
+    block_q, block_k = default_blocks(seq)
+    x = _qkv_shape(seq, head_dim, dtype, one_chip)
+    compiled = pallas_flash_attention.lower(
+        x, x, x, causal=True, block_q=block_q, block_k=block_k,
+        return_lse=True,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@kernel_shapes
+def test_pallas_backward_compiles_for_v5e(one_chip, seq, head_dim, dtype):
+    block_q, block_k = default_blocks(seq)
+    x = _qkv_shape(seq, head_dim, dtype, one_chip)
+    lse = jax.ShapeDtypeStruct((1, 8, seq), jnp.float32, sharding=one_chip)
+    compiled = pallas_flash_attention_bwd.lower(
+        x, x, x, x, lse, x, causal=True, block_q=block_q, block_k=block_k,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.slow  # a 43 s compile here; tier-1 is cut by its clock (ROADMAP D0)
+def test_resnet18_train_step_compiles_for_v5e(one_chip):
+    """The jitted ResNet-18 step at batch 512 bf16 — ``chip_smoke.py``'s
+    train phase and ``bench.py``'s model — from ``eval_shape``d state."""
+    from chip_smoke import REAL, resnet18
+    from elephas_tpu.engine.step import init_train_state, make_train_step
+
+    compiled = resnet18(REAL)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            tree,
+        )
+
+    state = described(jax.eval_shape(lambda: init_train_state(compiled)))
+    batch = REAL["batch"]
+    x = jax.ShapeDtypeStruct((batch, 32, 32, 3), jnp.float32, sharding=one_chip)
+    y = jax.ShapeDtypeStruct((batch, 10), jnp.float32, sharding=one_chip)
+    step = jax.jit(make_train_step(compiled), donate_argnums=(0,))
+    program = step.lower(state, x, y).compile()
+    memory = program.memory_analysis()
+    print("resnet18 step, batch 512 bf16, v5e:", memory)
+    # one chip holds 16 GB; the program's own arguments, outputs and
+    # temporaries must fit with room for what else the process keeps
+    assert memory.temp_size_in_bytes + memory.argument_size_in_bytes < 8 << 30
+
+
+def test_configure_compile_cache_respects_the_environment(monkeypatch):
+    """``$JAX_COMPILATION_CACHE_DIR`` set: nothing is configured in code.
+    Unset: the fixed in-checkout path — no tempfile, pid or time in it,
+    and no directory made by asking."""
+    from elephas_tpu.utils.compiler import configure_compile_cache
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        assert configure_compile_cache() == "/some/dir"
+        assert jax.config.jax_compilation_cache_dir == before
+        assert not os.path.exists("/some/dir")
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        expected = os.path.join(repo, ".jax_cache")
+        existed = os.path.exists(expected)
+        assert configure_compile_cache() == expected
+        assert configure_compile_cache() == expected  # fixed, not per-call
+        assert jax.config.jax_compilation_cache_dir == expected
+        assert os.path.exists(expected) == existed
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
