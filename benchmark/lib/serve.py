@@ -1,0 +1,333 @@
+"""What the two serving drivers share: the engine built from a cell's
+files, its warm-up, the counters of the counting rule, and `correct`.
+"""
+
+from __future__ import annotations
+
+import gc
+import os
+import time
+from dataclasses import dataclass, field
+from typing import Dict, List, Optional
+
+import numpy as np
+
+from . import traffic as traffic_lib
+from . import weights
+from .cells import CHECKOUT, Cell, device_report, log, process_age_s
+from .stats import check
+from .window import ChunkCounter, StepSink
+from .xplane import TraceCapture
+
+
+class DecodeCounter:
+    """Wraps `scheduler.decode_fn`; remembers which lanes each step ran."""
+
+    def __init__(self, fn, clock):
+        self.fn, self.clock, self.calls = fn, clock, []
+
+    def __call__(self, cache, prev, vals, mask, active_mask, pad):
+        self.calls.append((self.clock(), np.flatnonzero(active_mask)))
+        return self.fn(cache, prev, vals, mask, active_mask, pad)
+
+
+@dataclass
+class Sent:
+    index: int
+    req_id: int
+    due: Optional[float]      # monotonic; None in a closed backlog
+    submitted: float
+    prompt: List[int]
+    result: object = None
+
+
+@dataclass
+class Serving:
+    """One run's state: what the readers read."""
+    cell: Cell
+    cfg: dict
+    serving: dict
+    engine: object
+    sink: StepSink
+    chunks: ChunkCounter
+    decodes: DecodeCounter
+    schedule: Optional[traffic_lib.Schedule] = None
+    sent: List[Sent] = field(default_factory=list)
+    by_id: Dict[int, Sent] = field(default_factory=dict)
+    window: object = None
+    setup_s: float = 0.0
+    lateness_s: List[float] = field(default_factory=list)
+    capture: Optional[TraceCapture] = None
+    trace: object = None
+    spans: list = field(default_factory=list)
+    peak: dict = None
+    extra: dict = field(default_factory=dict)
+
+    def submit(self, i: int, due: Optional[float]) -> None:
+        prompt = self.schedule.prompt(i)
+        now = time.monotonic()
+        rid = self.engine.submit(prompt, max_new_tokens=self.schedule.output_lens[i],
+                                 stop_token=None)
+        s = Sent(i, rid, due, now, prompt)
+        self.sent.append(s)
+        self.by_id[rid] = s
+        if due is not None:
+            self.lateness_s.append(now - due)
+
+    def step(self) -> None:
+        for r in self.engine.step():
+            if r.req_id in self.by_id:
+                self.by_id[r.req_id].result = r
+
+    def drain_device(self) -> None:
+        import jax
+
+        jax.block_until_ready(self.engine.pool.cache)
+
+
+def model_cfg(config: dict) -> dict:
+    return {k: config[k] for k in ("n_layer", "n_embd", "n_head", "n_positions", "vocab_size")}
+
+
+def build(cell: Cell, seed: int, rehearse: bool, trace: bool) -> Serving:
+    import jax.numpy as jnp
+
+    from elephas_tpu import InferenceEngine, compile_model, obs
+    from elephas_tpu.models import get_model
+
+    config = cell.sized(cell.config, rehearse)
+    tr = cell.sized(cell.traffic, rehearse)
+    cfg, serving = model_cfg(config), {**config["serving"], **tr["engine"]}
+    dtype = jnp.dtype(config["dtype"])
+    tracer = obs.enable_tracing(capacity=1 << 20) if trace else None
+    params = weights.gpt2_params(seed, cfg, dtype)
+    module = get_model("transformer_lm", dtype=config["dtype"], vocab_size=cfg["vocab_size"],
+                       d_model=cfg["n_embd"], num_heads=cfg["n_head"],
+                       num_layers=cfg["n_layer"], max_seq_len=cfg["n_positions"])
+    compiled = compile_model(module, params=params, optimizer="sgd",
+                             loss="sparse_categorical_crossentropy", metrics=[],
+                             input_shape=(serving["max_prompt_len"],), input_dtype=jnp.int32)
+    sink = StepSink(time.monotonic)
+    engine = InferenceEngine(
+        compiled, max_slots=serving["max_slots"], max_prompt_len=serving["max_prompt_len"],
+        max_len=serving["max_len"], queue_depth=serving["queue_depth"],
+        kv_block_size=serving["kv_block_size"], prefill_chunk=serving["prefill_chunk"],
+        prefill_chunks_per_step=serving.get("prefill_chunks_per_step"),
+        sink=sink, tracer=tracer)
+    chunks = ChunkCounter(engine.scheduler.chunk_prefill_fn, time.monotonic)
+    engine.scheduler.chunk_prefill_fn = chunks
+    decodes = DecodeCounter(engine.scheduler.decode_fn, time.monotonic)
+    engine.scheduler.decode_fn = decodes
+    run = Serving(cell, cfg, serving, engine, sink, chunks, decodes)
+    run.extra["traffic"] = tr
+    run.extra["rehearse"] = rehearse
+    run.extra["config"] = config
+    return run
+
+
+def plan_requests(run: Serving, seed: int, requests: int) -> None:
+    """The run's schedule: at least `requests` requests, in whole cycles."""
+    run.schedule = traffic_lib.make_schedule(run.extra["traffic"], seed,
+                                             run.cfg["vocab_size"], requests)
+
+
+def warm_up(run: Serving, seed: int) -> None:
+    """Both programs, at the one shape each has: a prompt of a chunk and a
+    bit, a few tokens, on every slot at once so that the lane fetch sees
+    its widest case."""
+    rng = np.random.default_rng([seed, 2])
+    plen = min(run.serving["prefill_chunk"] + 3, run.serving["max_prompt_len"])
+    ids = [run.engine.submit(rng.integers(0, run.cfg["vocab_size"], plen).tolist(),
+                             max_new_tokens=4, stop_token=None)
+           for _ in range(run.serving["max_slots"])]
+    for rid in ids:
+        run.engine.result(rid, timeout_s=1100)
+    run.drain_device()
+    stats = run.engine.stats()
+    if stats["prefill_traces"] != 1 or stats["decode_traces"] != 1:
+        raise RuntimeError(f"warm-up traced {stats['prefill_traces']} prefill and "
+                           f"{stats['decode_traces']} decode programs, not one each")
+    run.sink.steps.clear()
+    run.sink.requests.clear()
+
+
+def open_window(run: Serving) -> float:
+    run.setup_s = process_age_s()
+    return time.monotonic()
+
+
+def trace_length(run: Serving, trace: bool) -> Optional[float]:
+    """Makes the capture of a traced run and returns the length of its
+    traced part, a few seconds of the window. The driver says where they
+    begin; the profiler is stopped after the window has closed, because
+    stopping stalls the host for many seconds."""
+    if not trace:
+        return None
+    run.capture = TraceCapture(os.path.join(CHECKOUT, ".bench_trace", run.cell.name))
+    return run.extra["traffic"]["trace"]["seconds"]
+
+
+def close_trace(run: Serving) -> None:
+    """As the window closes: the traced part ends here."""
+    if run.capture is not None:
+        if not run.capture.active:
+            raise RuntimeError("the window ended before the traced part began")
+        run.capture.mark_close()
+
+
+def finish_trace(run: Serving) -> None:
+    if run.capture is None:
+        return
+    run.capture.stop()
+    from elephas_tpu import obs
+
+    run.spans = [(e.name, e.begin_s, e.end_s) for e in obs.default_tracer().events()]
+    try:
+        run.trace = run.capture.result()
+    except ValueError:
+        if not run.extra["rehearse"]:  # a CPU rehearsal has no device plane
+            raise
+
+
+def lengths_before_each_step(run: Serving, t0: float, t1: float):
+    """For each decode step in [t0, t1]: the cache columns each of its
+    lanes held before it, rebuilt from the chunk and decode calls alone;
+    and each chunk call's (start, valid)."""
+    events = [(t, 0, (int(slot), int(start), int(valid)))
+              for t, slot, start, valid in run.chunks.detail] + \
+             [(t, 1, lanes) for t, lanes in run.decodes.calls]
+    length, steps, chunk_calls = {}, [], []
+    for t, kind, what in sorted(events, key=lambda e: (e[0], e[1])):
+        if kind == 0:
+            slot, start, valid = what
+            length[slot] = start + valid
+            if t0 <= t <= t1:
+                chunk_calls.append((start, valid))
+        else:
+            if t0 <= t <= t1:
+                steps.append([length.get(int(s), 0) for s in what])
+            for s in what:
+                length[int(s)] = length.get(int(s), 0) + 1
+    return steps, chunk_calls
+
+
+# -- correct ---------------------------------------------------------------
+
+
+def sample_finished(run: Serving, seed: int, count: int) -> List[Sent]:
+    done = [s for s in run.sent if s.result is not None and s.result.status == "completed"
+            and s.result.tokens]
+    if not done:
+        return []
+    longest = max(done, key=lambda s: len(s.prompt) + len(s.result.tokens))
+    rest = [s for s in done if s is not longest]
+    rng = np.random.default_rng([seed, 3])
+    picks = [rest[i] for i in rng.permutation(len(rest))[: max(0, count - 1)]]
+    return [longest] + picks
+
+
+def reference_gaps(run: Serving, seed: int, sample: List[Sent], control: bool = False) -> dict:
+    """Widest gap by which a served token's reference logit lies below the
+    reference's best, and the mean of those gaps, over the sampled
+    requests; with `control`, the same for the token that the reference
+    computed in float8 puts first."""
+    import jax
+    import jax.numpy as jnp
+
+    ref = run.cell.module("references", run.extra["config"]["reference"])
+    cfg, T = run.cfg, run.serving["max_len"]
+    width = max(len(s.result.tokens) for s in sample)
+    tokens = np.zeros((len(sample), T), np.int32)
+    rows = np.zeros((len(sample), width), np.int32)
+    served = np.zeros((len(sample), width), np.int32)
+    mask = np.zeros((len(sample), width), bool)
+    for i, s in enumerate(sample):
+        out, plen = s.result.tokens, len(s.prompt)
+        seq = (s.prompt + out[:-1])[:T]
+        tokens[i, : len(seq)] = seq
+        rows[i, : len(out)] = np.arange(plen - 1, plen - 1 + len(out))
+        served[i, : len(out)], mask[i, : len(out)] = out, True
+    dtype = jnp.dtype(run.extra["config"]["dtype"])
+    top = weights.gpt2_top_at(seed, cfg, dtype)
+
+    def block_at(layer):
+        return weights.gpt2_block_at(seed, layer, cfg, dtype)
+
+    logits = ref.logits_at(jnp.asarray(tokens), jnp.asarray(rows), top, block_at, cfg["n_layer"])
+    best = logits.max(-1)
+
+    def gap_of(tok):
+        at = jnp.take_along_axis(logits, jnp.asarray(tok)[:, :, None], axis=-1)[..., 0]
+        return np.where(mask, np.asarray(best - at), 0.0)
+
+    gaps = gap_of(served)
+    out = {"logit_gap_max": float(gaps.max()),
+           "logit_gap_mean": float(gaps.sum() / mask.sum()), "tokens_compared": int(mask.sum()),
+           "requests_compared": len(sample),
+           "tokens_off_best": int((gaps > 0).sum())}
+    if control:
+        low = ref.logits_at(jnp.asarray(tokens), jnp.asarray(rows), top, block_at,
+                            cfg["n_layer"], quant=ref.fp8)
+        low_gaps = gap_of(np.asarray(low.argmax(-1)))
+        out["control_logit_gap_max"] = float(low_gaps.max())
+        out["control_logit_gap_mean"] = float(low_gaps.sum() / mask.sum())
+    jax.clear_caches()
+    return out
+
+
+def free_program(run: Serving) -> None:
+    """Drop the engine, its pool and its weights before the reference runs."""
+    run.engine.scheduler.chunk_prefill_fn = None
+    run.engine.scheduler.decode_fn = None
+    run.engine = None
+    run.chunks.fn = run.decodes.fn = None
+    gc.collect()
+
+
+def conclude(run: Serving, args, attempted: int, failed: int, end_to_end: dict,
+             checks: dict) -> dict:
+    """What both drivers do once the window is closed: read the memory
+    peak, free the program, run the reference over a sample of what was
+    served, and hand the readers their run."""
+    import jax
+
+    devices = jax.devices()[: run.cell.chips]
+    run.peak = device_report(devices, run.trace)
+    count = run.extra["traffic"]["check"]["requests"]
+    sample = sample_finished(run, args.seed, count)
+    run.chunks.detail  # read the chunk calls' scalars while the engine lives
+    free_program(run)
+    t, controls = time.monotonic(), {}
+    if sample:
+        gaps = reference_gaps(run, args.seed, sample, control=bool(args.control))
+        # the mean over the compared tokens: the widest gap (in the notes)
+        # swings too much to keep the program and the control apart
+        limit = run.extra["traffic"]["check"]["logit_gap_mean_limit"]
+        checks["logit_gap_mean"] = check(gaps["logit_gap_mean"], limit, "at_most")
+        if "control_logit_gap_mean" in gaps:
+            # the float8 reference's tokens in the served ones' place, held
+            # to the same limit: `run.py` fails a run whose control passes
+            controls["float8_reference"] = {"logit_gap_mean": check(
+                gaps["control_logit_gap_mean"], limit, "at_most")}
+        run.extra["reference"] = gaps
+    checks["requests_sampled"] = check(len(sample), 1, "at_least")
+    run.extra["reference_s"] = time.monotonic() - t
+    log(f"reference over {len(sample)} requests took {run.extra['reference_s']:.1f} s")
+    notes = {"setup_s": run.setup_s, "reference_s": run.extra["reference_s"],
+             "window_s": run.window.seconds, "prompt_tokens": run.window.prompt_tokens,
+             "output_tokens": run.window.output_tokens, "steps": len(run.window.steps),
+             "chunk_calls": run.window.chunk_calls,
+             "reference": run.extra.get("reference")}
+    for key in ("backlog_at_close", "generator_late_ms_p95", "generator_late_ms_max"):
+        if key in run.extra:
+            notes[key] = run.extra[key]
+    if "tpot_ms" in run.extra:
+        notes["tpot_ms_p50"] = end_to_end.get("tpot_ms_p50")
+        notes["tpot_ms_p95"] = end_to_end.get("tpot_ms_p95")
+    steps = run.window.steps
+    if steps:
+        notes["occupancy"] = sum(s["active_slots"] for s in steps) / (
+            len(steps) * run.serving["max_slots"])
+        notes["queue_depth_max"] = max(s["queue_depth"] for s in steps)
+    return {"run": run, "attempted": attempted, "failed": failed, "end_to_end": end_to_end,
+            "checks": checks, "controls": controls, "device": run.peak, "notes": notes}
