@@ -850,6 +850,12 @@ class AsyncTrainer:
         checkpointers (which no-op on an already-saved step) keep saving
         after a resume."""
         compiled = self.compiled
+        # True training cadence: `time.monotonic` stamp when the SLOWEST
+        # worker finishes each epoch, with or without callbacks (the fire
+        # timestamps lag by the in-flight fire, so throughput harnesses
+        # should read these). The elastic ledger has no epoch barrier
+        # and leaves the list empty.
+        self.epoch_end_times: List[float] = []
         if self.elastic:
             return self._fit_elastic(
                 dataset, epochs, batch_size, validation_data, verbose,
@@ -968,10 +974,6 @@ class AsyncTrainer:
 
         per_worker_metrics: List[List[Dict[str, float]]] = [None] * self.n_workers
         errors: List[BaseException] = []
-        # True training cadence: wall timestamp when the SLOWEST worker
-        # finishes each epoch (the fire timestamps lag by the in-flight
-        # fire, so throughput harnesses should read these).
-        self.epoch_end_times: List[float] = []
         # Epoch-barrier bookkeeping: once the *slowest* worker has finished
         # epoch e (workers never block on each other — the barrier is
         # observational only), fire callbacks and evaluate validation on a
@@ -1067,9 +1069,8 @@ class AsyncTrainer:
 
         def on_epoch_done(epoch: int) -> None:
             nonlocal epochs_fired
-            if not run_callbacks and not do_val:
-                return
-            if fire_errors:
+            barrier_work = bool(run_callbacks) or do_val
+            if barrier_work and fire_errors:
                 # Surface a failed fire (checkpoint/eval) at the next
                 # epoch boundary instead of training to completion first.
                 raise RuntimeError(
@@ -1081,6 +1082,13 @@ class AsyncTrainer:
                     epochs_fired < epochs
                     and epoch_done_counts[epochs_fired] == self.n_workers
                 ):
+                    # The stamp needs no callback: count the barrier and
+                    # stamp first; without barrier work nothing else
+                    # happens here (no snapshot pulled, no fire queued).
+                    self.epoch_end_times.append(time.monotonic())
+                    if not barrier_work:
+                        epochs_fired += 1
+                        continue
                     # Snapshot AT THE EPOCH BOUNDARY (a device-to-device
                     # copy, ~10ms) so per-epoch validation samples the PS
                     # as of this epoch even though the eval itself runs
@@ -1103,7 +1111,6 @@ class AsyncTrainer:
                         )
                     snapshot = None if saturated else pull_snapshot()
                     fire_queue.append((epochs_fired, snapshot))
-                    self.epoch_end_times.append(time.perf_counter())
                     epochs_fired += 1
                 fire_cond.notify_all()
 

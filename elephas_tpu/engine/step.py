@@ -55,21 +55,32 @@ def make_train_step(compiled, pmean_axis: Optional[str] = None) -> Callable:
     data+seq reduction in sequence-parallel training.
     """
     loss_fn = make_loss_fn(compiled)
-    grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
     def train_step(state: TrainState, x, y) -> Tuple[TrainState, Dict]:
         rng, step_rng = jax.random.split(state.rng)
-        (loss, (new_stats, outputs)), grads = grad_fn(
-            state.params, state.batch_stats, x, y, step_rng
-        )
-        if pmean_axis is not None:
-            grads = jax.lax.pmean(grads, pmean_axis)
-        updates, new_opt_state = compiled.optimizer.update(
-            grads, state.opt_state, state.params
-        )
-        new_params = jax.tree_util.tree_map(
-            lambda p, u: p + u.astype(p.dtype), state.params, updates
-        )
+
+        def forward(params):
+            return loss_fn(params, state.batch_stats, x, y, step_rng)
+
+        # `value_and_grad` spelled out as its two halves, so that each
+        # carries its own name in the device trace (`forward/jvp(forward)`,
+        # `backward/transpose(jvp(forward))`, `update`): HLO metadata
+        # only, the program is the same.
+        with jax.named_scope("forward"):
+            loss, vjp_fn, (new_stats, outputs) = jax.vjp(
+                forward, state.params, has_aux=True
+            )
+        with jax.named_scope("backward"):
+            (grads,) = vjp_fn(jnp.ones_like(loss))
+            if pmean_axis is not None:
+                grads = jax.lax.pmean(grads, pmean_axis)
+        with jax.named_scope("update"):
+            updates, new_opt_state = compiled.optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            new_params = jax.tree_util.tree_map(
+                lambda p, u: p + u.astype(p.dtype), state.params, updates
+            )
         new_state = state.replace(
             step=state.step + 1,
             params=new_params,

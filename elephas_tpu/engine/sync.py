@@ -109,6 +109,22 @@ def stack_epoch(features, labels, n_shards: int, batch_size: int):
     return lay_out(np.asarray(features)), lay_out(np.asarray(labels)), nb
 
 
+@jax.jit
+def _dynamics_norms(prev_params, params):
+    """(delta_norm, param_norm) of one epoch as two device scalars: the
+    global L2 norms of ``prev_params - params`` and of ``prev_params``,
+    accumulated in float32. Fetched with the epoch's metrics, so the
+    dynamics gauges move no parameter to the host."""
+    def sq(tree):
+        return sum(
+            jnp.sum(jnp.square(leaf.astype(jnp.float32)))
+            for leaf in jax.tree_util.tree_leaves(tree)
+        )
+
+    delta = jax.tree_util.tree_map(lambda a, b: a - b, prev_params, params)
+    return jnp.sqrt(sq(delta)), jnp.sqrt(sq(prev_params))
+
+
 class SyncTrainer:
     def __init__(
         self, compiled, mesh, frequency: str = _PER_EPOCH,
@@ -148,6 +164,9 @@ class SyncTrainer:
             self._predict_step, out_shardings=replicated_sharding(mesh),
             compiler_options=opts,
         )
+        # Monotonic stamp of each epoch's end (metrics on the host, before
+        # validation and callbacks), filled by every ``fit``.
+        self.epoch_end_times: List[float] = []
 
     # -- observability ---------------------------------------------------------
 
@@ -338,16 +357,26 @@ class SyncTrainer:
                 validation_data, verbose, initial_state, rng, callbacks,
             )
         mesh = self.mesh
-        state = initial_state or init_train_state(
-            self.compiled, rng=rng if rng is not None else jax.random.PRNGKey(0)
-        )
-        state = jax.device_put(state, replicated_sharding(mesh))
+        tracer = obs.default_tracer()
+        self.epoch_end_times = []
+        with tracer.span("fit/state"):
+            state = initial_state or init_train_state(
+                self.compiled, rng=rng if rng is not None else jax.random.PRNGKey(0)
+            )
+            state = jax.device_put(state, replicated_sharding(mesh))
 
-        xs, ys, nb = stack_epoch(
-            dataset.features, dataset.labels, self.n_shards, batch_size
-        )
-        xs = jax.device_put(xs, NamedSharding(mesh, P(None, DATA_AXIS, *([None] * (xs.ndim - 2)))))
-        ys = jax.device_put(ys, NamedSharding(mesh, P(None, DATA_AXIS, *([None] * (ys.ndim - 2)))))
+        with tracer.span("fit/stack"):
+            xs, ys, nb = stack_epoch(
+                dataset.features, dataset.labels, self.n_shards, batch_size
+            )
+        with tracer.span("fit/upload"):
+            xs = jax.device_put(xs, NamedSharding(mesh, P(None, DATA_AXIS, *([None] * (xs.ndim - 2)))))
+            ys = jax.device_put(ys, NamedSharding(mesh, P(None, DATA_AXIS, *([None] * (ys.ndim - 2)))))
+            if tracer.enabled:
+                # Only a traced run waits here, so that the span is the
+                # upload's own time; untraced, the transfer overlaps the
+                # first epoch's trace and lowering as it always did.
+                jax.block_until_ready((xs, ys))
 
         if self.autotune and self.autotune_choice is None:
             if self.frequency == _PER_FIT:
@@ -366,7 +395,6 @@ class SyncTrainer:
         if self.frequency == _PER_FIT:
             return self._fit_parity(state, xs, ys, epochs, validation_data, verbose)
 
-        tracer = obs.default_tracer()
         epoch_hist = obs.default_registry().histogram(
             "train_epoch_seconds",
             help="wall seconds per dispatched training epoch",
@@ -375,42 +403,49 @@ class SyncTrainer:
         for epoch in range(epochs):
             t_ep = time.perf_counter()
             # The span covers dispatch AND the metrics fetch — the fetch
-            # is where the host actually blocks on the epoch program.
+            # (`train/epoch/wait`) is where the host actually blocks on
+            # the epoch program; validation and callbacks follow it
+            # inside the span.
             with tracer.span("train/epoch", mode="sync", epoch=epoch) as esp:
-                prev_params = state.params
-                state, metrics = self._epoch_fn(state, xs, ys, jnp.int32(epoch))
-                metrics = {
-                    k: float(v) for k, v in jax.device_get(metrics).items()
-                }
-                # Epoch dynamics: the metrics fetch above already forced
-                # the epoch program, so the delta norm costs one host
-                # transfer. Sync mode has one logical worker → the
-                # "driver" gauge row.
-                delta = jax.tree_util.tree_map(
-                    lambda a, b: a - b, prev_params, state.params
-                )
-                obs.record_unit_dynamics(
-                    obs.default_registry(),
-                    loss=metrics.get("loss"),
-                    delta_norm=obs.tree_norm(jax.device_get(delta)),
-                    param_norm=obs.tree_norm(jax.device_get(prev_params)),
-                    span=esp,
-                )
-            epoch_hist.observe(time.perf_counter() - t_ep)
-            if validation_data is not None:
-                # Eval in chunks of >=512 regardless of the (often tiny)
-                # training batch: each chunk is a host->device round-trip,
-                # and 64 tiny chunks cost more in round-trips than in eval
-                # compute. Weighted mean is exact either way.
-                with tracer.span("train/eval", epoch=epoch):
-                    val = self.evaluate_state(
-                        state, *validation_data, batch_size=max(batch_size, 512)
+                with tracer.span("train/epoch/dispatch"):
+                    prev_params = state.params
+                    state, metrics = self._epoch_fn(state, xs, ys, jnp.int32(epoch))
+                    # Epoch dynamics as two device scalars, queued behind
+                    # the epoch program and fetched with its metrics: no
+                    # parameter goes to the host for a gauge.
+                    norms = _dynamics_norms(prev_params, state.params)
+                with tracer.span("train/epoch/wait"):
+                    metrics, (delta_norm, param_norm) = jax.device_get(
+                        (metrics, norms)
                     )
-                metrics.update({f"val_{k}": v for k, v in val.items()})
-            for key, value in metrics.items():
-                history.setdefault(key, []).append(value)
-            for cb in callbacks:
-                cb(epoch, state, metrics)
+                    metrics = {k: float(v) for k, v in metrics.items()}
+                self.epoch_end_times.append(time.monotonic())
+                with tracer.span("train/epoch/dynamics"):
+                    # Sync mode has one logical worker → the "driver"
+                    # gauge row.
+                    obs.record_unit_dynamics(
+                        obs.default_registry(),
+                        loss=metrics.get("loss"),
+                        delta_norm=float(delta_norm),
+                        param_norm=float(param_norm),
+                        span=esp,
+                    )
+                epoch_hist.observe(time.perf_counter() - t_ep)
+                if validation_data is not None:
+                    # Eval in chunks of >=512 regardless of the (often tiny)
+                    # training batch: each chunk is a host->device round-trip,
+                    # and 64 tiny chunks cost more in round-trips than in eval
+                    # compute. Weighted mean is exact either way.
+                    with tracer.span("train/eval", epoch=epoch):
+                        val = self.evaluate_state(
+                            state, *validation_data, batch_size=max(batch_size, 512)
+                        )
+                    metrics.update({f"val_{k}": v for k, v in val.items()})
+                for key, value in metrics.items():
+                    history.setdefault(key, []).append(value)
+                with tracer.span("train/epoch/callbacks"):
+                    for cb in callbacks:
+                        cb(epoch, state, metrics)
             if verbose:
                 desc = " ".join(f"{k}={v:.4f}" for k, v in metrics.items())
                 print(f"[sync] epoch {epoch + 1}/{epochs} {desc}")
@@ -577,43 +612,63 @@ class SyncTrainer:
             )
 
         tracer = obs.default_tracer()
+        self.epoch_end_times = []
+        # Every shard's slice of the stacked block is the same tree at an
+        # epoch boundary, so the block's norm is sqrt(n_shards) times it.
+        per_shard = 1.0 / np.sqrt(n_shards)
         history: Dict[str, List[float]] = {}
         for epoch in range(epochs):
             perms = [host_rng.permutation(rows_per_shard) for _ in range(n_shards)]
             bounds = list(range(0, nb, stream_batches)) + [nb]
             spans = list(zip(bounds[:-1], bounds[1:]))
-            with tracer.span("train/epoch", mode="sync-stream", epoch=epoch):
-                nxt = assemble(*spans[0], perms)
-                chunk_metrics = []
-                for i, (b0, b1) in enumerate(spans):
-                    cur = nxt
-                    state_block, metrics = chunk_fn(state_block, *cur)  # async dispatch
-                    if i + 1 < len(spans):  # overlap host assembly with device compute
-                        nxt = assemble(*spans[i + 1], perms)
-                    chunk_metrics.append((b1 - b0, metrics))
-                state_block = epoch_end_fn(state_block)
+            with tracer.span("train/epoch", mode="sync-stream", epoch=epoch) as esp:
+                with tracer.span("train/epoch/dispatch"):
+                    prev_params = state_block.params
+                    nxt = assemble(*spans[0], perms)
+                    chunk_metrics = []
+                    for i, (b0, b1) in enumerate(spans):
+                        cur = nxt
+                        state_block, metrics = chunk_fn(state_block, *cur)  # async dispatch
+                        if i + 1 < len(spans):  # overlap host assembly with device compute
+                            nxt = assemble(*spans[i + 1], perms)
+                        chunk_metrics.append((b1 - b0, metrics))
+                    state_block = epoch_end_fn(state_block)
+                    norms = _dynamics_norms(prev_params, state_block.params)
 
-                total = sum(w for w, _ in chunk_metrics)
-                fetched = jax.device_get([m for _, m in chunk_metrics])
-            metrics = {
-                k: float(sum(w * d[k] for (w, _), d in zip(chunk_metrics, fetched)) / total)
-                for k in fetched[0]
-            }
-            snap = (
-                extract_fn(state_block)
-                if (validation_data is not None or callbacks)
-                else None
-            )
-            if validation_data is not None:
-                val = self.evaluate_state(
-                    snap, *validation_data, batch_size=max(batch_size, 512)
+                with tracer.span("train/epoch/wait"):
+                    total = sum(w for w, _ in chunk_metrics)
+                    fetched, (delta_norm, param_norm) = jax.device_get(
+                        ([m for _, m in chunk_metrics], norms)
+                    )
+                metrics = {
+                    k: float(sum(w * d[k] for (w, _), d in zip(chunk_metrics, fetched)) / total)
+                    for k in fetched[0]
+                }
+                self.epoch_end_times.append(time.monotonic())
+                with tracer.span("train/epoch/dynamics"):
+                    obs.record_unit_dynamics(
+                        obs.default_registry(),
+                        loss=metrics.get("loss"),
+                        delta_norm=float(delta_norm) * per_shard,
+                        param_norm=float(param_norm) * per_shard,
+                        span=esp,
+                    )
+                snap = (
+                    extract_fn(state_block)
+                    if (validation_data is not None or callbacks)
+                    else None
                 )
-                metrics.update({f"val_{k}": v for k, v in val.items()})
-            for key, value in metrics.items():
-                history.setdefault(key, []).append(value)
-            if callbacks:
-                for cb in callbacks:
-                    cb(epoch, snap, metrics)
+                if validation_data is not None:
+                    with tracer.span("train/eval", epoch=epoch):
+                        val = self.evaluate_state(
+                            snap, *validation_data, batch_size=max(batch_size, 512)
+                        )
+                    metrics.update({f"val_{k}": v for k, v in val.items()})
+                for key, value in metrics.items():
+                    history.setdefault(key, []).append(value)
+                with tracer.span("train/epoch/callbacks"):
+                    for cb in callbacks:
+                        cb(epoch, snap, metrics)
             if verbose:
                 desc = " ".join(f"{k}={v:.4f}" for k, v in metrics.items())
                 print(f"[sync/stream] epoch {epoch + 1}/{epochs} {desc}")

@@ -213,15 +213,18 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, pad_offset=None, active=None):
         d_model = x.shape[-1]
-        y = nn.LayerNorm(dtype=jnp.float32)(x)
-        x = x + SelfAttention(self.num_heads, dtype=self.dtype,
-                              attention=self.attention,
-                              decode=self.decode)(y, pad_offset=pad_offset,
-                                                  active=active)
-        y = nn.LayerNorm(dtype=jnp.float32)(x)
-        h = nn.Dense(d_model * self.mlp_ratio, dtype=self.dtype)(y)
-        h = nn.gelu(h)
-        return x + nn.Dense(d_model, dtype=self.dtype)(h)
+        # Model-part names for the device trace (HLO metadata only).
+        with jax.named_scope("attention"):
+            y = nn.LayerNorm(dtype=jnp.float32)(x)
+            x = x + SelfAttention(self.num_heads, dtype=self.dtype,
+                                  attention=self.attention,
+                                  decode=self.decode)(y, pad_offset=pad_offset,
+                                                      active=active)
+        with jax.named_scope("mlp"):
+            y = nn.LayerNorm(dtype=jnp.float32)(x)
+            h = nn.Dense(d_model * self.mlp_ratio, dtype=self.dtype)(y)
+            h = nn.gelu(h)
+            return x + nn.Dense(d_model, dtype=self.dtype)(h)
 
 
 class TransformerLM(nn.Module):
@@ -277,9 +280,10 @@ class TransformerLM(nn.Module):
             x = (x + pos[:seq]).astype(self.dtype)
         for _ in range(self.num_layers):
             x = Block(self.num_heads, dtype=self.dtype, attention=self.attention)(x)
-        x = nn.LayerNorm(dtype=jnp.float32)(x.astype(jnp.float32))
-        # Next-token logits, tied head kept separate for simplicity.
-        return nn.Dense(self.vocab_size, dtype=jnp.float32, name="lm_head")(x)
+        with jax.named_scope("lm_head"):
+            x = nn.LayerNorm(dtype=jnp.float32)(x.astype(jnp.float32))
+            # Next-token logits, tied head kept separate for simplicity.
+            return nn.Dense(self.vocab_size, dtype=jnp.float32, name="lm_head")(x)
 
     def _decode_forward(self, tokens, x, pos, seq, pad_offset=None,
                         active=None):
@@ -325,8 +329,9 @@ class TransformerLM(nn.Module):
         for _ in range(self.num_layers):
             x = Block(self.num_heads, dtype=self.dtype, attention="dense",
                       decode=True)(x, pad_offset=pad_offset, active=active)
-        x = nn.LayerNorm(dtype=jnp.float32)(x.astype(jnp.float32))
-        return nn.Dense(self.vocab_size, dtype=jnp.float32, name="lm_head")(x)
+        with jax.named_scope("lm_head"):
+            x = nn.LayerNorm(dtype=jnp.float32)(x.astype(jnp.float32))
+            return nn.Dense(self.vocab_size, dtype=jnp.float32, name="lm_head")(x)
 
 
 def sample_tokens(logits, key, greedy, top_k, temperature):

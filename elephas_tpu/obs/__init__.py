@@ -48,6 +48,7 @@ from elephas_tpu.obs.trace import (  # noqa: F401
     activate,
     current_context,
     new_context,
+    new_span_id,
 )
 from elephas_tpu.obs.flight import (  # noqa: F401
     KINDS,
@@ -134,7 +135,12 @@ def set_default_tracer(tracer: Optional[Tracer]) -> Tracer:
 
 def enable_tracing(capacity: int = 65536, clock=time.monotonic,
                    annotate_device: bool = True) -> Tracer:
-    """Swap a live ring in as the global tracer and return it."""
+    """Swap a live ring in as the global tracer and return it. JAX's
+    compile events land on it as ``compile/*`` spans from here on
+    (``utils.compiler.install_compile_spans``)."""
+    from elephas_tpu.utils.compiler import install_compile_spans
+
+    install_compile_spans()
     return set_default_tracer(
         Tracer(capacity=capacity, clock=clock,
                annotate_device=annotate_device)

@@ -341,21 +341,31 @@ class Tracer:
         return _Span(self, name, args or None)
 
     def record(self, name: str, begin_s: float, end_s: float,
-               track: Optional[str] = None, **args) -> None:
+               track: Optional[str] = None, *,
+               span_id: Optional[str] = None,
+               parent_id: Optional[str] = None, **args) -> None:
         """Record a span whose endpoints the caller already timestamped
         (with THIS tracer's clock domain). Tagged with the active trace
         context (as a leaf: the retroactive span never becomes a parent,
-        so the serving hot path pays one contextvar read, no id mint)."""
+        so the serving hot path pays one contextvar read, no id mint).
+
+        A caller that records a parent AFTER its children (the scheduler
+        closes ``sched_step`` last) mints the parent's id up front with
+        ``new_span_id()``, passes it as ``parent_id`` on each child and
+        as ``span_id`` on the parent; such spans carry their ids with or
+        without an active trace context."""
         if not self.enabled:
             return
         if track is None:
             track = threading.current_thread().name
         ctx = _CTX.get()
         if ctx is None:
-            event = SpanEvent(name, begin_s, end_s, track, args or None)
+            event = SpanEvent(name, begin_s, end_s, track, args or None,
+                              None, span_id, parent_id)
         else:
             event = SpanEvent(name, begin_s, end_s, track, args or None,
-                              ctx.trace_id, new_span_id(), ctx.span_id)
+                              ctx.trace_id, span_id or new_span_id(),
+                              parent_id or ctx.span_id)
         self._append(event)
 
     def instant(self, name: str, at: Optional[float] = None,
@@ -463,11 +473,11 @@ def _to_chrome_events(events: List[SpanEvent]) -> List[dict]:
         if e.args:
             rec["args"] = dict(e.args)
         if e.trace_id is not None:
-            args = rec.setdefault("args", {})
-            args["trace_id"] = e.trace_id
-            args["span_id"] = e.span_id
-            if e.parent_id is not None:
-                args["parent_id"] = e.parent_id
+            rec.setdefault("args", {})["trace_id"] = e.trace_id
+        if e.span_id is not None:
+            rec.setdefault("args", {})["span_id"] = e.span_id
+        if e.parent_id is not None:
+            rec.setdefault("args", {})["parent_id"] = e.parent_id
         out.append(rec)
     return out
 

@@ -472,7 +472,8 @@ class InferenceEngine:
                 return jnp.full((1,), start, jnp.int32)
             return leaf
 
-        row_cache = jax.tree_util.tree_map_with_path(to_row, cache)
+        with jax.named_scope("kv_gather"):
+            row_cache = jax.tree_util.tree_map_with_path(to_row, cache)
         logits, mutated = self.decode_module.apply(
             {"params": params, "cache": row_cache},
             tokens,
@@ -484,10 +485,11 @@ class InferenceEngine:
                                             axis=1)[:, 0]
         # Paged rows are never left-padded, so the sampled token's
         # pad-free position is simply the prefilled depth start + valid.
-        first = sample_tokens_at(
-            last, rng, (start + valid)[None], self._greedy, self.top_k,
-            self.temperature,
-        )
+        with jax.named_scope("sample"):
+            first = sample_tokens_at(
+                last, rng, (start + valid)[None], self._greedy, self.top_k,
+                self.temperature,
+            )
 
         def back(path, pool_leaf, mut_leaf):
             name = self._leaf_name(path)
@@ -502,9 +504,10 @@ class InferenceEngine:
             # every other slot's entry is untouched.
             return pool_leaf.at[slot].set(start + valid)
 
-        new_cache = jax.tree_util.tree_map_with_path(
-            back, cache, mutated["cache"]
-        )
+        with jax.named_scope("kv_scatter"):
+            new_cache = jax.tree_util.tree_map_with_path(
+                back, cache, mutated["cache"]
+            )
         return first[0], new_cache
 
     def _paged_decode_impl(self, params, cache, table, prev_tokens,
@@ -536,7 +539,8 @@ class InferenceEngine:
                 return paged_to_contiguous(leaf, table)
             return leaf
 
-        contig = jax.tree_util.tree_map_with_path(to_contig, cache)
+        with jax.named_scope("kv_gather"):
+            contig = jax.tree_util.tree_map_with_path(to_contig, cache)
         tokens = jnp.where(override_mask, override_vals, prev_tokens)
         logits, mutated = self.decode_module.apply(
             {"params": params, "cache": contig},
@@ -545,10 +549,11 @@ class InferenceEngine:
             active=active_mask,
             mutable=["cache"],
         )
-        nxt = sample_tokens_at(
-            logits[:, -1], rng, idx - pad + 1, self._greedy, self.top_k,
-            self.temperature,
-        )
+        with jax.named_scope("sample"):
+            nxt = sample_tokens_at(
+                logits[:, -1], rng, idx - pad + 1, self._greedy, self.top_k,
+                self.temperature,
+            )
 
         def back(path, pool_leaf, mut_leaf):
             if self._leaf_name(path) in ("cached_key", "cached_value"):
@@ -556,9 +561,10 @@ class InferenceEngine:
                                               idx, active_mask)
             return mut_leaf  # index vectors: advanced for active lanes
 
-        new_cache = jax.tree_util.tree_map_with_path(
-            back, cache, mutated["cache"]
-        )
+        with jax.named_scope("kv_scatter"):
+            new_cache = jax.tree_util.tree_map_with_path(
+                back, cache, mutated["cache"]
+            )
         return nxt, new_cache
 
     def _next_rng(self):

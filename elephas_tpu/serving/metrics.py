@@ -7,12 +7,20 @@ engine's ``stats()``.
 Two record streams share the sink, tagged by ``event``:
 
 - ``event="request"`` — one line per FINISHED request: status, prompt /
-  generated token counts, ``ttft_s`` (submit → first token),
-  ``itl_s_avg`` (mean gap between consecutive tokens), decode
+  generated token counts, ``ttft_s`` (submit → first token) and its two
+  parts ``queue_s`` (submit → pop) and ``prefill_s`` (pop → first
+  token), ``itl_s_avg`` (mean gap between consecutive tokens), decode
   tokens/sec for that request.
 - ``event="step"``   — one line per scheduler iteration (sampled every
   ``step_log_every``): queue depth, active slots, tokens emitted this
-  step, step wall seconds, and ``dispatch_to_fetch_s`` — the
+  step, step wall seconds and the host seconds of its phases
+  (``harvest_wait_s`` — the one place the host blocks on the device, so
+  ``step_seconds - harvest_wait_s`` is the host's own time —
+  ``admit_s``, ``prefill_s``, ``dispatch_s``), the prompt work it
+  dispatched (``prefill_tokens``, ``prefill_chunks``), the cache columns
+  each decoding lane held before this step's decode (``lane_lengths``),
+  the paged pool's ``kv_blocks_in_use`` / ``kv_blocks_total``, and
+  ``dispatch_to_fetch_s`` — the
   device-overlap gauge: wall seconds between a decode step's dispatch
   and the harvest of its tokens. On the pipelined path all host
   bookkeeping for the previous step happens inside this window, so the
@@ -51,6 +59,11 @@ class ServingMetrics:
         self.requests_timed_out = 0
         self.requests_rejected = 0
         self.tokens_out = 0
+        # Step-grained twins of the finish-grained ``tokens_out``: work
+        # counted as the scheduler commits it, whether or not its request
+        # has finished.
+        self.tokens_emitted_total = 0
+        self.tokens_prefilled_total = 0
         self.steps = 0
         self.max_concurrent = 0
         self.ttft_s: list = []
@@ -84,6 +97,8 @@ class ServingMetrics:
         self.requests_timed_out = 0
         self.requests_rejected = 0
         self.tokens_out = 0
+        self.tokens_emitted_total = 0
+        self.tokens_prefilled_total = 0
         self.steps = 0
         self.max_concurrent = 0
         self.ttft_s = []
@@ -147,6 +162,8 @@ class ServingMetrics:
                 prompt_tokens=result.prompt_tokens,
                 new_tokens=len(result.tokens),
                 ttft_s=result.ttft_s,
+                queue_s=result.queue_s,
+                prefill_s=result.prefill_s,
                 itl_s_avg=result.itl_s_avg,
                 tokens_per_sec=result.tokens_per_sec,
                 tokens_per_step=result.tokens_per_step,
@@ -189,8 +206,15 @@ class ServingMetrics:
         self._last_overlap = seconds
 
     def record_step(self, queue_depth: int, active: int, tokens: int,
-                    step_seconds: float) -> None:
+                    step_seconds: float, *, harvest_wait_s: float = 0.0,
+                    admit_s: float = 0.0, prefill_s: float = 0.0,
+                    dispatch_s: float = 0.0, prefill_tokens: int = 0,
+                    prefill_chunks: int = 0, lane_lengths=(),
+                    kv_blocks_in_use: Optional[int] = None,
+                    kv_blocks_total: Optional[int] = None) -> None:
         self.steps += 1
+        self.tokens_emitted_total += tokens
+        self.tokens_prefilled_total += prefill_tokens
         self.max_concurrent = max(self.max_concurrent, active)
         overlap, self._last_overlap = self._last_overlap, None
         if self.sink is not None and self.steps % self.step_log_every == 0:
@@ -203,11 +227,24 @@ class ServingMetrics:
                 step_seconds=step_seconds,
                 dispatch_to_fetch_s=overlap,
                 tokens_per_sec=tokens / max(step_seconds, 1e-9),
+                harvest_wait_s=harvest_wait_s,
+                admit_s=admit_s,
+                prefill_s=prefill_s,
+                dispatch_s=dispatch_s,
+                prefill_tokens=prefill_tokens,
+                prefill_chunks=prefill_chunks,
+                lane_lengths=lane_lengths,
+                kv_blocks_in_use=kv_blocks_in_use,
+                kv_blocks_total=kv_blocks_total,
             )
 
     # -- aggregates --------------------------------------------------------
 
     def summary(self) -> dict:
+        """The aggregates as one dict. ``tokens_out`` and with it
+        ``tokens_per_sec`` are finish-grained (a request's tokens count
+        when it finishes); ``tokens_emitted_total`` and
+        ``tokens_prefilled_total`` count per scheduler step."""
         elapsed = None if self._t0 is None else self.clock() - self._t0
         mean = lambda xs: (sum(xs) / len(xs)) if xs else None  # noqa: E731
         out = {
@@ -216,6 +253,8 @@ class ServingMetrics:
             "timed_out": self.requests_timed_out,
             "rejected": self.requests_rejected,
             "tokens_out": self.tokens_out,
+            "tokens_emitted_total": self.tokens_emitted_total,
+            "tokens_prefilled_total": self.tokens_prefilled_total,
             "steps": self.steps,
             "max_concurrent": self.max_concurrent,
             "ttft_s_avg": mean(self.ttft_s),

@@ -101,6 +101,14 @@ class GenerationResult:
     ttft_s: Optional[float] = None
     itl_s_avg: Optional[float] = None
     tokens_per_sec: Optional[float] = None
+    # ``ttft_s`` in its two parts: submit → popped off the queue, and
+    # popped → first token (admission + the prompt's prefill).
+    queue_s: Optional[float] = None
+    prefill_s: Optional[float] = None
+    # Seconds since submit at which each token reached the host, one
+    # per token (``token_times[0] == ttft_s``): the per-token series
+    # ``itl_s_avg`` is the mean gap of.
+    token_times: Tuple[float, ...] = ()
     # Decode tokens per decode step: exactly 1.0 on the plain path,
     # up to gamma + 1 under speculative decode (multi-token harvests
     # would otherwise silently under-report ITL). The prefill-produced
@@ -165,7 +173,8 @@ class _Active:
     token_times: List[float]             # wall time per token, for ITL
     budget: int                          # tokens still allowed (cache cap)
     admitted_at: float = 0.0             # decode-batch join time (spans)
-    next_col: int = 0                    # paged: column the next decode writes
+    popped_at: Optional[float] = None    # left the queue (queue_s | prefill_s)
+    next_col: int = 0                    # column the next decode writes
     steps: int = 0                       # decode steps harvested (ITL unit)
 
 
@@ -309,6 +318,15 @@ class ContinuousBatchingScheduler:
         # slot -> first token to splice into the NEXT dispatch (set by
         # admissions that happened after the current inflight dispatch).
         self._overrides: Dict[int, int] = {}
+        # One step's accounting, zeroed at the top of ``step()``: host
+        # seconds by phase, the prompt work dispatched, the columns each
+        # decoding lane held — what the ``step`` event carries whether or
+        # not a tracer is on. ``_step_id`` parents the ``step/*`` spans.
+        self._step_id: Optional[str] = None
+        self._harvest_wait_s = self._admit_s = 0.0
+        self._prefill_s = self._dispatch_s = 0.0
+        self._prefill_tokens = self._prefill_chunks = 0
+        self._lane_lengths: List[int] = []
 
     # -- introspection -----------------------------------------------------
 
@@ -344,6 +362,7 @@ class ContinuousBatchingScheduler:
         req = entry.request
         times = entry.token_times
         ttft = times[0] - req.submitted_at if times else None
+        popped = entry.popped_at
         gaps = [b - a for a, b in zip(times, times[1:])]
         itl = sum(gaps) / len(gaps) if gaps else None
         span = times[-1] - req.submitted_at if times else None
@@ -357,6 +376,10 @@ class ContinuousBatchingScheduler:
             tokens_per_sec=(
                 len(entry.tokens) / span if span and span > 0 else None
             ),
+            queue_s=None if popped is None else popped - req.submitted_at,
+            prefill_s=(None if popped is None or not times
+                       else times[0] - popped),
+            token_times=tuple(t - req.submitted_at for t in times),
             # First token excluded: prefill produced it, no decode step.
             tokens_per_step=(
                 (len(entry.tokens) - 1) / entry.steps
@@ -428,6 +451,7 @@ class ContinuousBatchingScheduler:
             result = GenerationResult(
                 req_id=req.req_id, tokens=[], status="timeout",
                 prompt_tokens=len(req.prompt), tenant=req.tenant,
+                queue_s=pf.t_pop - req.submitted_at,
             )
             if self.tracer.enabled:
                 track = f"req:{req.req_id}"
@@ -469,6 +493,7 @@ class ContinuousBatchingScheduler:
         self._results.append(GenerationResult(
             req_id=req.req_id, tokens=[], status="timeout",
             prompt_tokens=len(req.prompt), tenant=req.tenant,
+            queue_s=t_pop - req.submitted_at,
         ))
         if self.metrics is not None:
             self.metrics.record_finish(
@@ -519,9 +544,13 @@ class ContinuousBatchingScheduler:
             entry = _Active(
                 request=req, slot=slot, tokens=[first],
                 token_times=[self.clock()], budget=budget,
+                # rows hold the fixed prompt width, pad columns included
+                next_col=self.max_prompt_len, popped_at=t_pop,
             )
             entry.admitted_at = self.clock()
             self._active[slot] = entry
+            self._prefill_tokens += plen
+            self._prefill_chunks += 1
             if self.costs is not None:
                 # Queue residency ends here; the prompt's prefill and
                 # its first emitted token bill now (the contiguous pool
@@ -591,8 +620,9 @@ class ContinuousBatchingScheduler:
         plen = len(req.prompt)
         start = pf.next_col
         valid = min(self.prefill_chunk, plen - start)
+        t0 = self.clock()
         if pf.t_pre0 is None:
-            pf.t_pre0 = self.clock()
+            pf.t_pre0 = t0
         self.pool.ensure_cols(pf.slot, start + valid)
         chunk = list(req.prompt[start:start + valid])
         chunk += [self.pad_token] * (self.prefill_chunk - valid)
@@ -603,6 +633,14 @@ class ContinuousBatchingScheduler:
             tokens, jnp.int32(pf.slot), jnp.int32(start), jnp.int32(valid),
         )
         pf.next_col = start + valid
+        self._prefill_tokens += valid
+        self._prefill_chunks += 1
+        if self.tracer.enabled:
+            self.tracer.record(
+                "step/prefill_chunk", t0, self.clock(),
+                parent_id=self._step_id, slot=pf.slot, start=start,
+                valid=valid,
+            )
         if pf.next_col >= plen:
             self._finalize_prefill(pf)
 
@@ -612,8 +650,11 @@ class ContinuousBatchingScheduler:
         admission), publish the prompt to the prefix cache, and join the
         decode batch."""
         req = pf.request
+        t_sync0 = self.clock()
         first = host_sync.fetch_scalar(pf.first_dev)
         t_pre1 = self.clock()
+        self.tracer.record("step/prefill_sync", t_sync0, t_pre1,
+                           parent_id=self._step_id, slot=pf.slot)
         del self._prefilling[pf.slot]
         self.pool.commit_prefix(pf.slot, req.prompt)
         self.pool.admitted_total += 1
@@ -628,7 +669,7 @@ class ContinuousBatchingScheduler:
         entry = _Active(
             request=req, slot=pf.slot, tokens=[first],
             token_times=[self.clock()], budget=budget,
-            next_col=len(req.prompt),
+            next_col=len(req.prompt), popped_at=pf.t_pop,
         )
         entry.admitted_at = self.clock()
         self._active[pf.slot] = entry
@@ -824,6 +865,8 @@ class ContinuousBatchingScheduler:
         lanes = sorted(self._active.items())
         for slot, _ in lanes:
             active_mask[slot] = True
+        # Cache columns each lane holds before this step's decode.
+        self._lane_lengths = [entry.next_col for _, entry in lanes]
         if self.spec_decode_fn is not None:
             # Conservatively back TWO windows of columns per lane before
             # the closure snapshots the device block table: window N
@@ -846,9 +889,10 @@ class ContinuousBatchingScheduler:
             if out is not None:
                 last, emitted, accepted = out
                 dispatched_at = self.clock()
+                self._dispatch_s += dispatched_at - t0
                 self.tracer.record(
-                    "dispatch", t0, dispatched_at, lanes=len(lanes),
-                    spec=True,
+                    "dispatch", t0, dispatched_at,
+                    parent_id=self._step_id, lanes=len(lanes), spec=True,
                 )
                 return _Inflight(
                     tokens=last, lanes=lanes, dispatched_at=dispatched_at,
@@ -858,21 +902,23 @@ class ContinuousBatchingScheduler:
             # decoder): degrade to ONE plain decode step — the blocks
             # backed above stay owned, and the plain path's
             # advance-at-dispatch accounting below takes over for it.
-        if self.paged:
-            # Back (and exclusively own) the column each lane writes
-            # this step BEFORE the engine closure snapshots the device
-            # block table.
-            for slot, entry in lanes:
+        for slot, entry in lanes:
+            if self.paged:
+                # Back (and exclusively own) the column each lane writes
+                # this step BEFORE the engine closure snapshots the
+                # device block table.
                 self.pool.ensure_decode_col(slot, entry.next_col)
-                entry.next_col += 1
+            entry.next_col += 1
         nxt, new_cache = self.decode_fn(
             self.pool.cache, prev_tokens, override_vals, override_mask,
             active_mask, self.pool.pad,
         )
         self.pool.swap(new_cache)
         dispatched_at = self.clock()
+        self._dispatch_s += dispatched_at - t0
         self.tracer.record(
-            "dispatch", t0, dispatched_at, lanes=len(lanes),
+            "dispatch", t0, dispatched_at, parent_id=self._step_id,
+            lanes=len(lanes),
         )
         return _Inflight(tokens=nxt, lanes=lanes,
                          dispatched_at=dispatched_at)
@@ -901,10 +947,12 @@ class ContinuousBatchingScheduler:
         ]
         if not live:
             return 0
+        t_wait0 = self.clock()
         fetched = host_sync.fetch_lanes(
             inflight.tokens, [slot for slot, _ in live]
         )
         now = self.clock()
+        self._harvest_wait_s += now - t_wait0
         if self.metrics is not None:
             self.metrics.record_overlap(now - inflight.dispatched_at)
         # One span per decode ITERATION (dispatch → tokens on host) —
@@ -936,7 +984,17 @@ class ContinuousBatchingScheduler:
         if tenant_tokens:
             for t, n in tenant_tokens.items():
                 self.costs.record_decode(t, n)
+        self._record_harvest(t_wait0, now)
         return emitted
+
+    def _record_harvest(self, t_wait0: float, t_fetched: float) -> None:
+        """The two halves of a harvest as spans: the fetch (the one place
+        the host blocks on the device) and the bookkeeping loop after it."""
+        if self.tracer.enabled:
+            self.tracer.record("step/harvest_wait", t_wait0, t_fetched,
+                               parent_id=self._step_id)
+            self.tracer.record("step/harvest_book", t_fetched, self.clock(),
+                               parent_id=self._step_id)
 
     def _harvest_spec(self, inflight: _Inflight) -> int:
         """Ragged speculative harvest: lane ``s`` gained
@@ -958,9 +1016,11 @@ class ContinuousBatchingScheduler:
         ]
         if not live:
             return 0
+        t_wait0 = self.clock()
         em = host_sync.fetch(inflight.spec_emitted)    # (S, gamma+1)
         ac = host_sync.fetch(inflight.spec_accepted)   # (S,)
         now = self.clock()
+        self._harvest_wait_s += now - t_wait0
         if self.metrics is not None:
             self.metrics.record_overlap(now - inflight.dispatched_at)
         self.tracer.record(
@@ -1010,7 +1070,24 @@ class ContinuousBatchingScheduler:
                 accepted=accepted_sum,
                 emitted=emitted,
             )
+        self._record_harvest(t_wait0, now)
         return emitted
+
+    def _evict_admit_prefill(self) -> None:
+        """The three host phases between harvest and the next dispatch,
+        each timed: deadline eviction, admission off the queue, and the
+        parked prefills' chunks (``step/prefill_chunk`` and
+        ``step/prefill_sync`` lie inside the third)."""
+        t0 = self.clock()
+        self._evict_expired()
+        t1 = self.clock()
+        self._admit_from_queue()
+        t2 = self.clock()
+        self._advance_prefills()
+        self._admit_s += t2 - t1
+        self._prefill_s += self.clock() - t2
+        self.tracer.record("step/evict", t0, t1, parent_id=self._step_id)
+        self.tracer.record("step/admit", t1, t2, parent_id=self._step_id)
 
     def _step_pipelined(self) -> int:
         """Dispatch N+1, then do ALL host work overlapped with it."""
@@ -1022,9 +1099,7 @@ class ContinuousBatchingScheduler:
             )
         emitted = self._harvest(prev) if prev is not None else 0
         # Host bookkeeping below overlaps the just-dispatched step.
-        self._evict_expired()
-        self._admit_from_queue()
-        self._advance_prefills()
+        self._evict_admit_prefill()
         if self._inflight is None and self._active:
             # Cold start: the pool was empty at the top of the step and
             # admissions just filled it — dispatch now rather than
@@ -1036,9 +1111,7 @@ class ContinuousBatchingScheduler:
         """The unpipelined reference path: evict, admit, decode, read —
         the device idles during every host phase. Kept as the oracle the
         pipelined path is tested token-identical against."""
-        self._evict_expired()
-        self._admit_from_queue()
-        self._advance_prefills()
+        self._evict_admit_prefill()
         if not self._active:
             return 0
         inflight = self._dispatch(self._host_prev_tokens())
@@ -1048,17 +1121,33 @@ class ContinuousBatchingScheduler:
         """One scheduler iteration; returns requests finished during it."""
         t0 = self.clock()
         before = len(self._results)
+        self._step_id = obs.new_span_id() if self.tracer.enabled else None
+        self._harvest_wait_s = self._admit_s = 0.0
+        self._prefill_s = self._dispatch_s = 0.0
+        self._prefill_tokens = self._prefill_chunks = 0
+        self._lane_lengths = []
         emitted = (
             self._step_pipelined() if self.pipeline else self._step_sync()
         )
         t1 = self.clock()
         self.tracer.record(
-            "sched_step", t0, t1, tokens=emitted, active=len(self._active),
+            "sched_step", t0, t1, span_id=self._step_id, tokens=emitted,
+            active=len(self._active),
         )
         if self.metrics is not None:
             self.metrics.record_step(
                 queue_depth=len(self.queue), active=len(self._active),
                 tokens=emitted, step_seconds=t1 - t0,
+                harvest_wait_s=self._harvest_wait_s,
+                admit_s=self._admit_s, prefill_s=self._prefill_s,
+                dispatch_s=self._dispatch_s,
+                prefill_tokens=self._prefill_tokens,
+                prefill_chunks=self._prefill_chunks,
+                lane_lengths=self._lane_lengths,
+                kv_blocks_in_use=(self.pool.blocks_in_use
+                                  if self.paged else None),
+                kv_blocks_total=(self.pool.num_blocks
+                                 if self.paged else None),
             )
         if self.load is not None:
             # Paged pools report BLOCK-granular KV pressure (free blocks

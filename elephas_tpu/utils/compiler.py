@@ -78,6 +78,48 @@ def note_retrace(program: str, **args) -> None:
     logger.debug("retrace: %s %s", program, args or "")
 
 
+# JAX's own duration events -> span names on the default tracer.
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile/trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile/lower",
+    "/jax/core/compile/backend_compile_duration": "compile/backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "compile/cache_load",
+}
+_compile_spans_installed = False
+
+
+def _on_compile_event(event: str, duration_secs: float, **kwargs) -> None:
+    name = _COMPILE_SPANS.get(event)
+    if name is None:
+        return
+    from elephas_tpu import obs
+
+    tracer = obs.default_tracer()
+    if not tracer.enabled:
+        return
+    # JAX reports an event as it ends: the span is [now - duration, now],
+    # on the thread that compiled, inside whatever span caused it.
+    now = tracer.clock()
+    args = {"program": kwargs["fun_name"]} if "fun_name" in kwargs else {}
+    tracer.record(name, now - duration_secs, now, **args)
+
+
+def install_compile_spans() -> None:
+    """Turn JAX's trace / lower / backend-compile / cache-load duration
+    events into retroactive ``compile/*`` spans on the default tracer
+    (arg ``program``: JAX's ``fun_name`` where it passes one). One
+    listener for the process, registered by ``obs.enable_tracing()``;
+    it returns at once while the default tracer is disabled. A cache
+    hit shows as a ``compile/cache_load`` inside its ``compile/backend``.
+    ``note_retrace``'s instants stay: they name the two serving programs
+    and count them, these time every program."""
+    global _compile_spans_installed
+    if _compile_spans_installed:
+        return
+    jax.monitoring.register_event_duration_secs_listener(_on_compile_event)
+    _compile_spans_installed = True
+
+
 def configure_compile_cache() -> str:
     """Place JAX's persistent compilation cache; return its directory.
 
