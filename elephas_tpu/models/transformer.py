@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 import flax.linen as nn
 import jax
@@ -30,6 +30,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from elephas_tpu.models import register_model
+
+
+class PagedDecode(NamedTuple):
+    """What a decode apply over the serving pool's physical blocks needs
+    beside the cache: the (slots, blocks_per_slot) block ``table`` and the
+    name of the attention ``body`` (``ops.attention.PAGED_BODIES``)."""
+
+    table: Any
+    body: str
 
 
 def dense_causal_attention(q, k, v):
@@ -50,7 +59,7 @@ class SelfAttention(nn.Module):
     decode: bool = False
 
     @nn.compact
-    def __call__(self, x, pad_offset=None, active=None):
+    def __call__(self, x, pad_offset=None, active=None, paged=None):
         d_model = x.shape[-1]
         head_dim = d_model // self.num_heads
         qkv = nn.DenseGeneral((3, self.num_heads, head_dim), dtype=self.dtype,
@@ -60,7 +69,8 @@ class SelfAttention(nn.Module):
         k = jnp.transpose(k, (0, 2, 1, 3))
         v = jnp.transpose(v, (0, 2, 1, 3))
         if self.decode:
-            return self._decode_attend(x, q, k, v, d_model, pad_offset, active)
+            return self._decode_attend(x, q, k, v, d_model, pad_offset, active,
+                                       paged)
         attention = self.attention
         if attention == "auto" and not self.is_initializing():
             # Resolved at trace time (axis size is static): sequence-
@@ -110,7 +120,7 @@ class SelfAttention(nn.Module):
         return nn.DenseGeneral(d_model, dtype=self.dtype, name="out")(out)
 
     def _decode_attend(self, x, q, k, v, d_model, pad_offset=None,
-                       active=None):
+                       active=None, paged=None):
         """Incremental (KV-cache) attention for autoregressive sampling.
 
         The cache is SHAPED on the init pass (which feeds a full-length
@@ -127,9 +137,13 @@ class SelfAttention(nn.Module):
         serving only — freezes INACTIVE rows' ``cache_index``: free pool
         slots ride along in the fixed-shape decode batch for the whole
         pool lifetime, and without the freeze their index vectors march
-        past ``max_len`` while nothing is admitted. Training never
-        touches this path — it exists for ``generate`` and
-        ``serving``."""
+        past ``max_len`` while nothing is admitted. ``paged``
+        (``PagedDecode``) — serving's paged pool only — says the K/V
+        variables ARE the pool's physical blocks: the step's one column
+        is written into its block and the query attends through the
+        block table (``ops.attention.paged_decode_attention``), no
+        contiguous row is ever built. Training never touches this path
+        — it exists for ``generate`` and ``serving``."""
         b, h, seq, head_dim = q.shape
         init_pass = not self.has_variable("cache", "cached_key")
         cached_key = self.variable(
@@ -147,6 +161,23 @@ class SelfAttention(nn.Module):
             # Shaping pass only: ordinary causal attention; caches start
             # zeroed at the full length.
             out = dense_causal_attention(q, k, v)
+        elif paged is not None:
+            from elephas_tpu.ops.attention import paged_decode_attention
+
+            if seq != 1 or active is None:
+                raise ValueError(
+                    "paged decode attends one token per lane under an "
+                    "active mask; prefill chunks and speculative windows "
+                    "gather their rows"
+                )
+            idx = cache_index.value
+            out, cached_key.value, cached_value.value = paged_decode_attention(
+                q[:, :, 0], k[:, :, 0].astype(self.dtype),
+                v[:, :, 0].astype(self.dtype), cached_key.value,
+                cached_value.value, paged.table, idx, active, paged.body,
+            )
+            cache_index.value = jnp.where(active, idx + 1, idx)
+            out = out[:, :, None, :]
         else:
             from elephas_tpu.ops.attention import cache_attention_mask
 
@@ -211,7 +242,7 @@ class Block(nn.Module):
     decode: bool = False
 
     @nn.compact
-    def __call__(self, x, pad_offset=None, active=None):
+    def __call__(self, x, pad_offset=None, active=None, paged=None):
         d_model = x.shape[-1]
         # Model-part names for the device trace (HLO metadata only).
         with jax.named_scope("attention"):
@@ -219,7 +250,8 @@ class Block(nn.Module):
             x = x + SelfAttention(self.num_heads, dtype=self.dtype,
                                   attention=self.attention,
                                   decode=self.decode)(y, pad_offset=pad_offset,
-                                                      active=active)
+                                                      active=active,
+                                                      paged=paged)
         with jax.named_scope("mlp"):
             y = nn.LayerNorm(dtype=jnp.float32)(x)
             h = nn.Dense(d_model * self.mlp_ratio, dtype=self.dtype)(y)
@@ -239,7 +271,7 @@ class TransformerLM(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, train: bool = False, pad_offset=None,
-                 active=None):
+                 active=None, paged=None):
         seq = tokens.shape[1]
         x = nn.Embed(self.vocab_size, self.d_model, name="tok_embed")(
             tokens.astype(jnp.int32)
@@ -251,11 +283,11 @@ class TransformerLM(nn.Module):
         )
         if self.decode:
             return self._decode_forward(tokens, x, pos, seq, pad_offset,
-                                        active)
-        if pad_offset is not None or active is not None:
+                                        active, paged)
+        if pad_offset is not None or active is not None or paged is not None:
             raise ValueError(
-                "pad_offset / active (ragged left-padded serving batches) "
-                "are only supported on the decode=True path"
+                "pad_offset / active / paged (ragged left-padded serving "
+                "batches) are only supported on the decode=True path"
             )
         from elephas_tpu.parallel.ring_attention import (
             require_seq_axis,
@@ -286,7 +318,7 @@ class TransformerLM(nn.Module):
             return nn.Dense(self.vocab_size, dtype=jnp.float32, name="lm_head")(x)
 
     def _decode_forward(self, tokens, x, pos, seq, pad_offset=None,
-                        active=None):
+                        active=None, paged=None):
         """Incremental forward for sampling: positional embedding from a
         module-level position counter (advanced by each apply's block
         length — the batched prompt prefill, then one token per sampling
@@ -328,7 +360,8 @@ class TransformerLM(nn.Module):
                 x = (x + jnp.take(pos, cols, axis=0)).astype(self.dtype)
         for _ in range(self.num_layers):
             x = Block(self.num_heads, dtype=self.dtype, attention="dense",
-                      decode=True)(x, pad_offset=pad_offset, active=active)
+                      decode=True)(x, pad_offset=pad_offset, active=active,
+                                   paged=paged)
         with jax.named_scope("lm_head"):
             x = nn.LayerNorm(dtype=jnp.float32)(x.astype(jnp.float32))
             return nn.Dense(self.vocab_size, dtype=jnp.float32, name="lm_head")(x)
@@ -479,15 +512,20 @@ def make_paged_decode_cache(decode_module, max_slots: int, num_blocks: int,
                             block_size: int):
     """Zeroed PAGED decode cache: the same pytree structure as
     ``make_decode_cache`` but with every K/V leaf laid out as physical
-    blocks ``(num_blocks, heads, block_size, head_dim)`` instead of one
-    contiguous ``(max_slots, heads, max_len, head_dim)`` row per slot.
-    A host-side block table maps ``slot -> block ids``; slots share
-    blocks by holding the same id (reference-counted by the pool).
+    blocks of ``block_size`` columns (``ops.attention.pool_leaf_shape``:
+    ``(num_blocks, heads, rows, lanes)``, a few columns packed to a
+    row) instead of one contiguous ``(max_slots, heads, max_len,
+    head_dim)`` row per slot. A host-side block table maps ``slot ->
+    block ids``; slots share blocks by holding the same id
+    (reference-counted by the pool).
 
     Index leaves (``cache_index``/``pos_index``) stay per-SLOT
     ``(max_slots,)`` vectors — positions are a property of the logical
     sequence, not of physical block placement — so the same flax apply
-    drives both layouts once the blocks are gathered contiguous."""
+    drives both layouts: over gathered rows (prefill chunks,
+    speculative windows) or over the blocks themselves (``paged=``)."""
+    from elephas_tpu.ops.attention import pool_leaf_shape
+
     cache_shapes = jax.eval_shape(
         lambda: decode_module.init(
             jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32)
@@ -498,8 +536,10 @@ def make_paged_decode_cache(decode_module, max_slots: int, num_blocks: int,
         name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
         if name in ("cached_key", "cached_value"):
             _, heads, _, head_dim = s.shape
-            return jnp.zeros((num_blocks, heads, block_size, head_dim),
-                             s.dtype)
+            return jnp.zeros(
+                pool_leaf_shape(num_blocks, heads, block_size, head_dim),
+                s.dtype,
+            )
         if name in ("cache_index", "pos_index"):
             return jnp.zeros((max_slots,), jnp.int32)
         return jnp.zeros(s.shape, s.dtype)

@@ -12,6 +12,7 @@ Shapes: q, k, v are (batch, heads, seq, head_dim); returns the same.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -107,111 +108,225 @@ def cache_attention_mask(max_len, seq, idx, pad_offset=None):
     return valid[:, None]  # broadcast over heads
 
 
-# -- paged KV-cache gather/scatter ------------------------------------------
+# -- paged KV cache: layout, gather/scatter, decode attention ------------------
 #
-# The serving pool's paged layout stores K/V as physical blocks
-# (num_blocks, heads, block_size, head_dim) shared across slots through a
-# (max_slots, blocks_per_slot) block table. These helpers are the bridge
-# between that layout and the contiguous (slots, heads, len, head_dim)
-# view the dense cache-attention path consumes: gather through the table
-# before the apply, scatter exactly the freshly-written columns back
-# after it. Unallocated table entries carry the OUT-OF-RANGE id
-# ``num_blocks``: gathers clamp (the garbage columns sit at or past
-# every reader's cache index, so the causal mask hides them) and
-# scatters drop (``mode="drop"``), so no index is ever negative.
+# The serving pool stores K/V as physical blocks shared across slots through
+# a (max_slots, blocks_per_slot) block table. A block holds ``block_size``
+# columns of ``head_dim`` values per head, PACKED ``pack`` columns to a row:
+# a leaf is ``(num_blocks, heads, block_size // pack, pack * head_dim)`` and
+# column ``c`` of a block is lanes ``[(c % pack) * head_dim, ...)`` of row
+# ``c // pack``. With ``head_dim`` 64 a row is 128 wide, the TPU's lane
+# count: the array's default device layout is then row-major, so a block is
+# one contiguous piece of HBM that a gather, a scatter along dimension 0 or
+# a DMA moves alone. (A 64-wide minor dimension is padded to 128 lanes, and
+# the default layout of ``(num_blocks, heads, bs, 64)`` puts ``num_blocks``
+# minor-most: every program that touched the pool first copied the whole
+# leaf into a workable layout and copied it back, 74 % of a decode step.)
+# Row-major, the packed leaf is the same memory as the logical
+# ``(num_blocks, heads, block_size, head_dim)``; the helpers below reshape
+# the few blocks they gather, never the pool.
+#
+# Unallocated table entries carry the OUT-OF-RANGE id ``num_blocks``:
+# gathers clamp (the garbage columns sit at or past every reader's cache
+# index, so the causal mask hides them) and scatters drop
+# (``mode="drop"``), so no index is ever negative. Every write is a
+# read-modify-write of whole blocks along dimension 0, in place on a
+# donated leaf: a scatter on two dimensions would relayout the pool.
+
+_LANES = 128
 
 
-def paged_to_contiguous(leaf, table):
-    """Gather a paged K/V leaf into per-slot contiguous rows.
-
-    ``leaf``: (num_blocks, heads, block_size, head_dim) physical blocks;
-    ``table``: (max_slots, blocks_per_slot) int32 block ids. Returns
-    (max_slots, heads, blocks_per_slot * block_size, head_dim).
-    """
-    slots, bps = table.shape
-    _, heads, bs, head_dim = leaf.shape
-    gathered = leaf[table]  # (slots, bps, heads, bs, head_dim); OOB clamps
-    gathered = jnp.transpose(gathered, (0, 2, 1, 3, 4))
-    return gathered.reshape(slots, heads, bps * bs, head_dim)
+def pool_leaf_shape(num_blocks, heads, block_size, head_dim):
+    """Shape of a K or V pool leaf: as many columns to a row as fill the
+    lanes and divide the block."""
+    pack = math.gcd(block_size, max(1, _LANES // head_dim))
+    return (num_blocks, heads, block_size // pack, pack * head_dim)
 
 
-def slot_row_to_contiguous(leaf, row_table):
-    """Gather ONE slot's blocks as a batch-1 contiguous cache row.
-
-    ``row_table``: (blocks_per_slot,) int32 block ids for the slot.
-    Returns (1, heads, blocks_per_slot * block_size, head_dim).
-    """
-    gathered = leaf[row_table]  # (bps, heads, bs, head_dim)
-    gathered = jnp.transpose(gathered, (1, 0, 2, 3))
-    heads, bps, bs, head_dim = gathered.shape
-    return gathered.reshape(heads, bps * bs, head_dim)[None]
+def _unpack(blocks, head_dim: int):
+    """(..., heads, rows, pack * d) gathered blocks -> (..., heads, bs, d)."""
+    return blocks.reshape(*blocks.shape[:-2], -1, head_dim)
 
 
-def scatter_decode_columns(pool_leaf, contiguous, table, idx, active):
-    """Write each slot's just-decoded column back into its physical block.
-
-    ``contiguous`` is the (max_slots, heads, L, head_dim) view AFTER the
-    apply wrote column ``idx[s]`` for every slot s (``idx`` is the
-    PRE-advance cache index vector). Inactive lanes scatter to the
-    out-of-range block id and drop — their computed column is garbage by
-    contract.
-    """
-    num_blocks, _, bs, _ = pool_leaf.shape
-    written = jnp.take_along_axis(
-        contiguous, idx[:, None, None, None], axis=2
-    )[:, :, 0, :]  # (max_slots, heads, head_dim)
-    blk = jnp.take_along_axis(table, (idx // bs)[:, None], axis=1)[:, 0]
-    target = jnp.where(active, blk, num_blocks)
-    return pool_leaf.at[target, :, idx % bs].set(written, mode="drop")
+def _rows_of(blocks):
+    """(..., n, heads, bs, d) blocks of one row -> (..., heads, n * bs, d)."""
+    blocks = jnp.moveaxis(blocks, -4, -3)
+    return blocks.reshape(*blocks.shape[:-3], -1, blocks.shape[-1])
 
 
-def scatter_prefill_columns(pool_leaf, row_table, start, chunk):
-    """Write one prefill chunk's columns ``[start, start + C)`` of ONE
-    slot into its physical blocks.
+# The helpers below are jitted: a 48-layer program calls each 96 times at
+# one shape, and the inner jit is traced and lowered once for all of them.
 
-    ``chunk``: (heads, C, head_dim) — the freshly-computed K or V
-    columns. Columns landing in unallocated blocks (right-pad garbage
-    past the slot's allocation) hit the out-of-range id and drop.
-    """
-    bs = pool_leaf.shape[2]
-    cols = start + jnp.arange(chunk.shape[1])
-    target = row_table[cols // bs]
-    return pool_leaf.at[target, :, cols % bs].set(
-        jnp.transpose(chunk, (1, 0, 2)), mode="drop"
+
+@functools.partial(jax.jit, static_argnames=("head_dim",))
+def paged_to_contiguous(leaf, table, head_dim: int):
+    """Gather a paged K/V leaf into per-slot contiguous rows: returns
+    (max_slots, heads, blocks_per_slot * block_size, head_dim)."""
+    return _rows_of(_unpack(leaf[table], head_dim))
+
+
+def _write_windows(pool_leaf, table, start, window, ok):
+    """Write ``window[s]``, (heads, count, head_dim), at columns
+    ``[start[s], start[s] + count)`` of row ``table[s]``: the blocks the
+    window touches are gathered, updated and set back along dimension 0.
+    Blocks of a row with ``ok[s]`` false, past the row's table, or
+    unallocated go to the out-of-range id and drop."""
+    num_blocks, _, r, lanes = pool_leaf.shape
+    rows, bps = table.shape
+    _, count, head_dim = window.shape[1:]
+    bs = r * lanes // head_dim
+    n = (count + bs - 2) // bs + 1  # blocks a window of `count` can touch
+    first = start // bs
+    js = first[:, None] + jnp.arange(n)[None, :]
+    ids = jnp.take_along_axis(table, jnp.clip(js, 0, bps - 1), axis=1)
+    touched = (js < bps) & (js * bs < (start + count)[:, None])
+    ids = jnp.where(ok[:, None] & touched, ids, num_blocks)
+    blocks = pool_leaf[ids]  # (rows, n, heads, r, lanes); OOB clamps
+    win = _rows_of(_unpack(blocks, head_dim))
+    win = jax.vmap(
+        lambda w, new, at: jax.lax.dynamic_update_slice(w, new, (0, at, 0))
+    )(win, window.astype(pool_leaf.dtype), start - first * bs)
+    heads = win.shape[1]
+    win = jnp.moveaxis(win.reshape(rows, heads, n, bs, head_dim), 2, 1)
+    return pool_leaf.at[ids.reshape(-1)].set(
+        win.reshape((rows * n,) + pool_leaf.shape[1:]), mode="drop"
     )
 
 
+@jax.jit
+def scatter_prefill_columns(pool_leaf, row_table, start, chunk):
+    """Write one prefill chunk's columns ``[start, start + C)`` of ONE
+    slot into its physical blocks. ``chunk``: (heads, C, head_dim).
+    Columns landing in unallocated blocks (right-pad garbage past the
+    slot's allocation) drop."""
+    return _write_windows(
+        pool_leaf, row_table[None], jnp.reshape(start, (1,)), chunk[None],
+        jnp.ones((1,), bool),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("count",))
 def scatter_spec_columns(pool_leaf, contiguous, table, idx, count, active):
     """Write each slot's ``count`` freshly-computed columns
-    ``[idx[s], idx[s] + count)`` back into its physical blocks — the
-    multi-column sibling of ``scatter_decode_columns`` for speculative
-    draft/verify windows.
+    ``[idx[s], idx[s] + count)`` back into its physical blocks, for
+    speculative draft/verify windows.
 
     ``contiguous`` is the (max_slots, heads, L, head_dim) view AFTER an
     apply with seq == count wrote those columns (``idx`` is the
     PRE-advance cache index vector; ``count`` is static). Inactive lanes
-    and columns past the row's virtual capacity scatter to the
-    out-of-range block id and drop. Rejected-suffix columns are written
-    too — they sit at or past every reader's causal frontier until a
-    later accepted token overwrites them, so they are never attended.
+    and columns past the row's virtual capacity drop. Rejected-suffix
+    columns are written too — they sit at or past every reader's causal
+    frontier until a later accepted token overwrites them, so they are
+    never attended.
     """
-    num_blocks, heads, bs, head_dim = pool_leaf.shape
-    slots, bps = table.shape
-    cols = idx[:, None] + jnp.arange(count)[None, :]  # (slots, count)
-    written = jnp.take_along_axis(
-        contiguous, cols[:, None, :, None], axis=2
-    )  # (slots, heads, count, head_dim)
-    written = jnp.transpose(written, (0, 2, 1, 3)).reshape(
-        slots * count, heads, head_dim
+    written = jax.vmap(
+        lambda row, at: jax.lax.dynamic_slice_in_dim(row, at, count, axis=1)
+    )(contiguous, idx)
+    return _write_windows(pool_leaf, table, idx, written, active)
+
+
+# -- paged decode attention: the pool read and written in place ---------------
+#
+# One decode step never builds a per-slot contiguous cache. Per layer,
+# (a) each active lane's new K/V column goes into its physical block and
+# (b) each lane's one query attends over that lane's live blocks through
+# the block table. Two bodies with the same numerics (float32 scores and
+# softmax): a Pallas kernel on a single TPU that moves live blocks only,
+# and a plain XLA body elsewhere (CPU, a GSPMD mesh) that is also the
+# kernel's reference.
+
+PAGED_BODIES = ("paged_pallas", "paged_xla")
+
+# VMEM the kernel may plan for, inside Mosaic's default scoped limit.
+_PAGED_VMEM_BUDGET = 12 << 20
+
+
+def _paged_pallas_fits(pool_shape, dtype) -> bool:
+    """Whether the kernel's tiles lower for this pool layout: rows a full
+    lane width, whole sublane tiles a head (so ``(heads, r, lanes)`` folds
+    to ``(heads * r, lanes)`` for free), and buffers that fit VMEM: two
+    each of a K and a V block read and written back, and the float32
+    scores of one block and what the softmax makes of them."""
+    _, heads, rows, lanes = pool_shape
+    itemsize = jnp.dtype(dtype).itemsize
+    if lanes % _LANES or itemsize not in (2, 4) or rows % (32 // itemsize):
+        return False
+    block_bytes = heads * rows * lanes * itemsize
+    query_rows = (lanes // _LANES) * (-(-heads // 16) * 16)
+    scores_bytes = 4 * query_rows * heads * rows
+    return 8 * block_bytes + 4 * scores_bytes <= _PAGED_VMEM_BUDGET
+
+
+def paged_decode_body(pool_shape, dtype, mesh=None) -> str:
+    """Name of the body ``paged_decode_attention`` runs for this backend,
+    mesh and pool layout. A Pallas call is not partitioned by sharding
+    annotations, so a mesh takes the XLA body."""
+    if _on_tpu() and mesh is None and _paged_pallas_fits(pool_shape, dtype):
+        return "paged_pallas"
+    return "paged_xla"
+
+
+def _paged_attend_xla(q, k_pool, v_pool, table, idx):
+    """Plain body: gather one layer's blocks through the table and score
+    them in the block layout ``(slots, blocks, heads, bs, head_dim)``."""
+    slots, _, head_dim = q.shape
+    bps = table.shape[1]
+    kb = _unpack(k_pool[table], head_dim)  # OOB ids clamp; masked below
+    vb = _unpack(v_pool[table], head_dim)
+    bs = kb.shape[-2]
+    scores = jnp.einsum(
+        "shd,sjhbd->shjb", q, kb, preferred_element_type=jnp.float32
+    ) * (1.0 / (head_dim ** 0.5))
+    cols = (jnp.arange(bps) * bs)[:, None] + jnp.arange(bs)[None, :]
+    valid = cols[None] <= idx[:, None, None]  # (slots, bps, bs)
+    scores = jnp.where(valid[:, None], scores, jnp.finfo(jnp.float32).min)
+    flat = scores.reshape(slots, scores.shape[1], bps * bs)
+    weights = jax.nn.softmax(flat, axis=-1).reshape(scores.shape)
+    out = jnp.einsum(
+        "shjb,sjhbd->shd", weights.astype(vb.dtype), vb,
+        preferred_element_type=jnp.float32,
     )
-    blk = jnp.take_along_axis(
-        table, jnp.clip(cols // bs, 0, bps - 1), axis=1
-    )  # (slots, count)
-    ok = active[:, None] & (cols < bps * bs)
-    target = jnp.where(ok, blk, num_blocks)
-    return pool_leaf.at[target.reshape(-1), :, (cols % bs).reshape(-1)].set(
-        written, mode="drop"
-    )
+    return out.astype(q.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("body",))
+def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, table, idx,
+                           active, body: str):
+    """One decode step's attention of one layer over the paged pool.
+
+    ``q``/``k_new``/``v_new``: (slots, heads, head_dim), the step's one
+    token per lane. ``k_pool``/``v_pool``: pool leaves (see the layout
+    above). ``table``: (slots, blocks_per_slot) block ids, unallocated
+    entries out of range. ``idx``: (slots,) the column each lane writes,
+    so lane ``s`` attends columns ``<= idx[s]`` of its blocks
+    ``table[s, : idx[s] // bs + 1]`` (paged rows are never left-padded).
+    ``active``: (slots,) bool; an inactive lane writes nothing and its
+    output is not read.
+
+    Returns ``(out, k_pool, v_pool)`` with ``out`` (slots, heads,
+    head_dim); the pools carry the new columns. ``body`` is one of
+    ``PAGED_BODIES`` (``paged_decode_body`` picks it).
+    """
+    if body not in PAGED_BODIES:
+        raise ValueError(f"unknown paged body {body!r}; expected one of "
+                         f"{PAGED_BODIES}")
+    if body == "paged_pallas":
+        from elephas_tpu.ops.attention_pallas import (
+            pallas_paged_decode_attention,
+        )
+
+        # one kernel writes the column and attends: an XLA scatter before
+        # it would have the compiler stage the whole leaf through VMEM
+        with jax.named_scope("paged_attention"):
+            return pallas_paged_decode_attention(
+                q, k_new, v_new, k_pool, v_pool, table, idx, active
+            )
+    with jax.named_scope("kv_write"):
+        k_pool = _write_windows(k_pool, table, idx, k_new[:, :, None], active)
+        v_pool = _write_windows(v_pool, table, idx, v_new[:, :, None], active)
+    with jax.named_scope("paged_attention"):
+        out = _paged_attend_xla(q, k_pool, v_pool, table, idx)
+    return out, k_pool, v_pool
 
 
 def pallas_min_seq(head_dim: int) -> int:

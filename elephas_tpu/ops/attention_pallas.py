@@ -473,3 +473,198 @@ def pallas_flash_attention_bwd(
     dk = dk.reshape(batch, heads, -1, head_dim)[:, :, :seq_len]
     dv = dv.reshape(batch, heads, -1, head_dim)[:, :, :seq_len]
     return dq, dk, dv
+
+
+# ------------------------------------------------- paged decode attention
+
+# Finite, so a row with no live column (a padded head row) stays finite.
+_MASKED = -1e30
+
+
+def _paged_decode_kernel(
+    lane_ref, block_ref, phys_ref, tail_ref, idx_ref,  # scalar prefetch (SMEM)
+    q_ref,  # (1, pack * rows, lanes)
+    kn_ref, vn_ref,  # (1, heads, 1, lanes): the new column, in every lane group
+    k_ref, v_ref,  # one physical block (heads, r, lanes) each
+    o_ref, ko_ref, vo_ref,  # the lane's output; its tail block of each pool
+    m_ref, l_ref, acc_ref,  # running max, sum, weighted values of one lane
+    *, heads: int, rows: int, pack: int, head_dim: int, blocks_per_slot: int,
+    sm_scale: float,
+):
+    """One live (lane, block) pair per grid step, a lane's blocks in
+    order, folded into the lane's running softmax; at the lane's last
+    block, its tail, the step's new K/V column is put into the block
+    before it is scored and the block goes back to the pool.
+
+    A block is ``heads * r`` rows of ``pack`` columns each. Query row
+    ``g * rows + h`` holds head ``h``'s query in lane group ``g`` and
+    zeros elsewhere, so one ``(pack * rows, lanes) x (heads * r,
+    lanes)^T`` matmul scores every column of the block: entry
+    ``[(g, h), (h', r)]`` is column ``r * pack + g`` of head ``h'``, and
+    the entries with ``h' != h`` are masked away. The MXU takes one call
+    a block where one-row calls, head by head, would starve it. Each
+    ``g`` keeps its own running softmax over its columns; they merge at
+    the tail, and lane group ``g`` of row ``(g, h)`` of the output then
+    holds its share of head ``h``'s result."""
+    del phys_ref, tail_ref  # read by the index maps only
+    w = pl.program_id(0)
+    lane = lane_ref[w]
+    j = block_ref[w]
+    idx = idx_ref[lane]
+    r = k_ref.shape[1]
+    width = heads * r
+    block_size = r * pack
+    is_tail = j == jnp.minimum(idx // block_size, blocks_per_slot - 1)
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _MASKED)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    # the new column's place in the tail block: row at // pack, group at % pack
+    at = idx - j * block_size
+    in_row = jax.lax.broadcasted_iota(jnp.int32, k_ref.shape, 1) == at // pack
+    lanes_at = jax.lax.broadcasted_iota(jnp.int32, k_ref.shape, 2)
+    first_lane = (at % pack) * head_dim
+    here = is_tail & in_row & (lanes_at >= first_lane) & (
+        lanes_at < first_lane + head_dim)
+    k_blk = jnp.where(here, kn_ref[0], k_ref[...])
+    v_blk = jnp.where(here, vn_ref[0], v_ref[...])
+
+    k = k_blk.reshape(width, k_blk.shape[-1])
+    v = v_blk.reshape(width, v_blk.shape[-1])
+    s = jax.lax.dot_general(
+        q_ref[0], k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * sm_scale
+    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    group = sum((row >= g * rows).astype(jnp.int32) for g in range(1, pack))
+    own = col - (row - group * rows) * r  # row of the block in this head
+    live = (own >= 0) & (own < r) & (own * pack + group <= at)
+    s = jnp.where(live, s, _MASKED)
+    m = m_ref[...]
+    m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+    p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+    alpha = jnp.exp(m - m_new)
+    l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
+    acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    m_ref[...] = m_new
+
+    @pl.when(is_tail)
+    def _finalize():
+        ko_ref[...] = k_blk
+        vo_ref[...] = v_blk
+        groups = [slice(g * rows, (g + 1) * rows) for g in range(pack)]
+        m_all = functools.reduce(jnp.maximum, [m_ref[g] for g in groups])
+        share = [jnp.exp(m_ref[g] - m_all) for g in groups]
+        # a group with no live column has l == 0 and share == 0 (a padded
+        # head row has all groups so: its row is not read)
+        total = sum(l_ref[g] * w_ for g, w_ in zip(groups, share))
+        inv = 1.0 / jnp.maximum(total, 1e-30)
+        for g, w_ in zip(groups, share):
+            o_ref[0, g] = (acc_ref[g] * (w_ * inv)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def pallas_paged_decode_attention(q, k_new, v_new, k_pool, v_pool, table,
+                                  idx, active, interpret: bool = False):
+    """One decode step of one layer on the paged pool, in place: each
+    active lane's new K/V column goes into its tail block and its query
+    attends over its blocks, columns ``<= idx``.
+
+    ``q``/``k_new``/``v_new``: (slots, heads, head_dim); ``k_pool``/
+    ``v_pool``: pool leaves (num_blocks, heads, r, pack * head_dim),
+    ``pack`` columns to a row (``ops/attention.py``), aliased to the
+    returned pools; ``table``: (slots, blocks_per_slot) int32; ``idx``:
+    (slots,) the column each lane writes; ``active``: (slots,) bool.
+    Returns ``(out, k_pool, v_pool)``, ``out`` (slots, heads, head_dim)
+    and zeros for an inactive lane.
+
+    The grid runs over the live (lane, block) pairs only, lane by lane:
+    their count is a run-time value (a dynamic grid bound) and the lane,
+    the block's place in its row and its physical id are scalar-prefetch
+    arguments that the index maps read, so a block that no lane holds
+    live is never moved and one compiled kernel serves every length. Of
+    the pools only each active lane's tail block is written back.
+    """
+    slots, heads, head_dim = q.shape
+    num_blocks, _, r, lanes = k_pool.shape
+    pack = lanes // head_dim
+    block_size = r * pack
+    blocks_per_slot = table.shape[1]
+    rows = -(-heads // 16) * 16  # whole sublane tiles for bf16 and f32
+    dtype = k_pool.dtype
+    # query row (g, h): head h's query in lane group g, zeros elsewhere
+    qp = jnp.zeros((slots, pack, rows, pack, head_dim), dtype)
+    for g in range(pack):
+        qp = qp.at[:, g, :heads, g].set(q.astype(dtype))
+    qp = qp.reshape(slots, pack * rows, lanes)
+    new = [jnp.tile(x.astype(dtype), (1, 1, pack))[:, :, None, :]
+           for x in (k_new, v_new)]
+
+    idx = idx.astype(jnp.int32)
+    table = jnp.clip(table.astype(jnp.int32), 0, num_blocks - 1)
+    last = jnp.clip(idx // block_size, 0, blocks_per_slot - 1)
+    live = active[:, None] & (jnp.arange(blocks_per_slot)[None] <= last[:, None])
+    (work,) = jnp.nonzero(live.reshape(-1), size=live.size, fill_value=0)
+    work = work.astype(jnp.int32)
+    lane, block = work // blocks_per_slot, work % blocks_per_slot
+    phys = table.reshape(-1)[work]
+    tail = jnp.take_along_axis(table, last[:, None], axis=1)[:, 0]
+
+    kernel = functools.partial(
+        _paged_decode_kernel, heads=heads, rows=rows, pack=pack,
+        head_dim=head_dim, blocks_per_slot=blocks_per_slot,
+        sm_scale=1.0 / (head_dim ** 0.5),
+    )
+    lane_rows = pl.BlockSpec(
+        (1, pack * rows, lanes), lambda w, lane, *_: (lane[w], 0, 0)
+    )
+    lane_column = pl.BlockSpec(
+        (1, heads, 1, lanes), lambda w, lane, *_: (lane[w], 0, 0, 0)
+    )
+    live_block = pl.BlockSpec(
+        (None, heads, r, lanes),
+        lambda w, lane, block, phys, *_: (phys[w], 0, 0, 0),
+    )
+    tail_block = pl.BlockSpec(
+        (None, heads, r, lanes),
+        lambda w, lane, block, phys, tail, idx: (tail[lane[w]], 0, 0, 0),
+    )
+    out, k_pool, v_pool = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(live.sum().astype(jnp.int32),),
+            in_specs=[lane_rows, lane_column, lane_column,
+                      live_block, live_block],
+            out_specs=[lane_rows, tail_block, tail_block],
+            scratch_shapes=[
+                pltpu.VMEM((pack * rows, 1), jnp.float32),
+                pltpu.VMEM((pack * rows, 1), jnp.float32),
+                pltpu.VMEM((pack * rows, lanes), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((slots, pack * rows, lanes), q.dtype),
+            jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
+            jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
+        ],
+        # operands count the scalar-prefetch arguments: the pools are 8, 9
+        input_output_aliases={8: 1, 9: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name="paged_decode_attention",
+    )(lane, block, phys, tail, idx, qp, *new, k_pool, v_pool)
+    # head h: the sum over g of lane group g of row (g, h)
+    out = out.reshape(slots, pack, rows, pack, head_dim)
+    out = sum(out[:, g, :heads, g] for g in range(pack))
+    # a lane the grid never visited holds whatever its output rows held
+    return jnp.where(active[:, None, None], out, 0), k_pool, v_pool

@@ -310,6 +310,7 @@ class InferenceEngine:
         self._prefill_traces = 0
         self._decode_traces = 0
         self.mesh = None  # set by shard_serving
+        self.metrics.decode_attention = self.decode_attention
         self._make_jits()
 
         self._req_ids = itertools.count()
@@ -456,18 +457,19 @@ class InferenceEngine:
         note_retrace("serving_prefill", count=self._prefill_traces)
         from elephas_tpu.models.transformer import sample_tokens_at
         from elephas_tpu.ops.attention import (
+            paged_to_contiguous,
             scatter_prefill_columns,
-            slot_row_to_contiguous,
         )
 
         chunk_width = tokens.shape[1]
+        head_dim = self.decode_module.d_model // self.decode_module.num_heads
         row = jax.lax.dynamic_index_in_dim(table, slot, axis=0,
                                            keepdims=False)
 
         def to_row(path, leaf):
             name = self._leaf_name(path)
             if name in ("cached_key", "cached_value"):
-                return slot_row_to_contiguous(leaf, row)
+                return paged_to_contiguous(leaf, row[None], head_dim)
             if name in ("cache_index", "pos_index"):
                 return jnp.full((1,), start, jnp.int32)
             return leaf
@@ -513,19 +515,19 @@ class InferenceEngine:
     def _paged_decode_impl(self, params, cache, table, prev_tokens,
                            override_vals, override_mask, active_mask,
                            pad, rng):
-        """One decode step over every slot, through the paged pool:
-        gather all slots' blocks contiguous, run the UNCHANGED decode
-        apply, scatter back only the column each active lane wrote.
-        Gathered garbage from unallocated/clamped blocks sits past every
-        lane's cache index and never survives the causal mask."""
+        """One decode step over every slot, on the paged pool in place:
+        the cache the module sees IS the pool plus the block table. Each
+        layer writes the column of every active lane straight into its
+        physical block and attends through the table
+        (``ops.attention.paged_decode_attention``); no per-slot
+        contiguous cache is built and nothing else of the pool moves."""
         self._decode_traces += 1
         from elephas_tpu.utils.compiler import note_retrace
 
         note_retrace("serving_decode", count=self._decode_traces)
-        from elephas_tpu.models.transformer import sample_tokens_at
-        from elephas_tpu.ops.attention import (
-            paged_to_contiguous,
-            scatter_decode_columns,
+        from elephas_tpu.models.transformer import (
+            PagedDecode,
+            sample_tokens_at,
         )
 
         # Pre-advance write column per lane (every layer advances in
@@ -533,20 +535,13 @@ class InferenceEngine:
         flat = jax.tree_util.tree_flatten_with_path(cache)[0]
         idx = next(leaf for path, leaf in flat
                    if self._leaf_name(path) == "cache_index")
-
-        def to_contig(path, leaf):
-            if self._leaf_name(path) in ("cached_key", "cached_value"):
-                return paged_to_contiguous(leaf, table)
-            return leaf
-
-        with jax.named_scope("kv_gather"):
-            contig = jax.tree_util.tree_map_with_path(to_contig, cache)
         tokens = jnp.where(override_mask, override_vals, prev_tokens)
         logits, mutated = self.decode_module.apply(
-            {"params": params, "cache": contig},
+            {"params": params, "cache": cache},
             tokens[:, None],
             pad_offset=pad,
             active=active_mask,
+            paged=PagedDecode(table, self.decode_attention),
             mutable=["cache"],
         )
         with jax.named_scope("sample"):
@@ -554,18 +549,27 @@ class InferenceEngine:
                 logits[:, -1], rng, idx - pad + 1, self._greedy, self.top_k,
                 self.temperature,
             )
+        return nxt, mutated["cache"]
 
-        def back(path, pool_leaf, mut_leaf):
-            if self._leaf_name(path) in ("cached_key", "cached_value"):
-                return scatter_decode_columns(pool_leaf, mut_leaf, table,
-                                              idx, active_mask)
-            return mut_leaf  # index vectors: advanced for active lanes
+    @property
+    def decode_attention(self) -> str:
+        """Name of the attention body the decode program is traced with:
+        ``"paged_pallas"`` or ``"paged_xla"`` over the paged pool (chosen
+        from the backend, the mesh and the pool's layout), ``"contiguous"``
+        for ``paged=False``."""
+        if not self.paged:
+            return "contiguous"
+        from elephas_tpu.ops.attention import (
+            paged_decode_body,
+            pool_leaf_shape,
+        )
 
-        with jax.named_scope("kv_scatter"):
-            new_cache = jax.tree_util.tree_map_with_path(
-                back, cache, mutated["cache"]
-            )
-        return nxt, new_cache
+        module = self.decode_module
+        shape = pool_leaf_shape(
+            self.pool.num_blocks, module.num_heads, self.pool.block_size,
+            module.d_model // module.num_heads,
+        )
+        return paged_decode_body(shape, module.dtype, self.mesh)
 
     def _next_rng(self):
         # Sampling keys derive from (base key, pad-free stream position)
@@ -706,6 +710,7 @@ class InferenceEngine:
                     )
                 self.spec.make_jits(p_sh, pool_sh, repl)
             self.mesh = mesh
+            self.metrics.decode_attention = self.decode_attention
             return self
         prefill_cache = make_decode_cache(self.decode_module, 1,
                                           self.pool.max_len)
@@ -721,6 +726,7 @@ class InferenceEngine:
             ),
         )
         self.mesh = mesh
+        self.metrics.decode_attention = self.decode_attention
         return self
 
     # -- frontend ----------------------------------------------------------
@@ -1077,6 +1083,7 @@ class InferenceEngine:
             "model_version": self.model_version,
             "prefill_traces": self._prefill_traces,
             "decode_traces": self._decode_traces,
+            "decode_attention": self.decode_attention,
             "pool_admitted_total": self.pool.admitted_total,
             "pool_active": self.pool.active_count,
             "pool_free": self.pool.free_count,

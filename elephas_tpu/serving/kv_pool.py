@@ -230,7 +230,7 @@ def _write_imported_blocks(cache, ids, payload, slot, next_col):
     """Scatter imported handoff block data into the paged cache and set
     ``slot``'s index vectors to the handoff's write frontier — the device
     half of ``PagedKVPool.import_blocks``. ``payload`` is a tuple of
-    ``(n, heads, block_size, head_dim)`` uploads, one per rank-4 K/V
+    ``(n, heads, rows, lanes)`` block uploads, one per rank-4 K/V
     leaf in tree order; the cache is donated (n block rows written in
     place, not a whole-pool copy). Retraces per distinct block count —
     bounded by ``blocks_per_slot``, and warmed by the first handoffs."""
@@ -393,14 +393,19 @@ class PagedKVPool(KVCachePool):
     ``PrefixCache`` so prompts whose prefix is already resident admit by
     bumping refcounts instead of re-prefilling.
 
-    Layout: every K/V leaf is ``(num_blocks, heads, block_size,
-    head_dim)``; a slot's logical cache row is the concatenation of its
-    table row's blocks — a VIRTUAL length ``blocks_per_slot *
-    block_size >= max_len`` (ceil, so ``block_size`` need not divide
-    ``max_len``). The compiled decode/prefill programs gather through
-    the table, run the same dense cache-attention apply as the
-    contiguous pool (token identity by construction), and scatter back
-    exactly the columns they wrote (``ops.attention`` paged helpers).
+    Layout: every K/V leaf is ``num_blocks`` physical blocks of
+    ``block_size`` columns, ``(num_blocks, heads, rows, lanes)`` with a
+    few columns packed to a row (``ops.attention.pool_leaf_shape``: rows
+    128 lanes wide keep the device layout row-major, a block one
+    contiguous piece of memory); a slot's logical cache row is the
+    concatenation of its table row's blocks — a VIRTUAL length
+    ``blocks_per_slot * block_size >= max_len`` (ceil, so ``block_size``
+    need not divide ``max_len``). The compiled decode program writes and
+    attends on the blocks in place through the table; prefill chunks and
+    speculative windows gather their rows through it, run the same dense
+    cache-attention apply as the contiguous pool (token identity by
+    construction), and set back exactly the blocks they wrote
+    (``ops.attention`` paged helpers).
 
     Invariants the allocator maintains (and tests pin):
 
@@ -767,7 +772,7 @@ class PagedKVPool(KVCachePool):
         buffers for a cross-tier handoff.
 
         Returns ``{"block_size", "blocks", "leaves", "arrays"}`` —
-        ``arrays[i]`` is the ``(blocks, heads, block_size, head_dim)``
+        ``arrays[i]`` is the ``(blocks, heads, rows, lanes)``
         host copy of leaf ``leaves[i]`` at the slot's block ids, in row
         order. Also CLOSES the slot's block-seconds billing window (the
         satellite-6 fix): occupancy up to this instant bills the owning

@@ -19,7 +19,9 @@ Two record streams share the sink, tagged by ``event``:
   ``admit_s``, ``prefill_s``, ``dispatch_s``), the prompt work it
   dispatched (``prefill_tokens``, ``prefill_chunks``), the cache columns
   each decoding lane held before this step's decode (``lane_lengths``),
-  the paged pool's ``kv_blocks_in_use`` / ``kv_blocks_total``, and
+  the paged pool's ``kv_blocks_in_use`` / ``kv_blocks_total``,
+  ``decode_attention`` (the attention body the decode program was traced
+  with: ``paged_pallas``, ``paged_xla`` or ``contiguous``), and
   ``dispatch_to_fetch_s`` — the
   device-overlap gauge: wall seconds between a decode step's dispatch
   and the harvest of its tokens. On the pipelined path all host
@@ -54,6 +56,8 @@ class ServingMetrics:
         self.sink = sink
         self.step_log_every = max(1, int(step_log_every))  # host-ok: arg
         self.clock = clock
+        # Set by the engine (and again by shard_serving); kept by reset().
+        self.decode_attention: Optional[str] = None
         self.requests_submitted = 0
         self.requests_completed = 0
         self.requests_timed_out = 0
@@ -236,6 +240,7 @@ class ServingMetrics:
                 lane_lengths=lane_lengths,
                 kv_blocks_in_use=kv_blocks_in_use,
                 kv_blocks_total=kv_blocks_total,
+                decode_attention=self.decode_attention,
             )
 
     # -- aggregates --------------------------------------------------------

@@ -373,10 +373,12 @@ class SpeculativeDecoder:
         from elephas_tpu.ops.attention import paged_to_contiguous
 
         idx0 = _first_index_leaf(cache)
+        module = self.engine.decode_module
+        head_dim = module.d_model // module.num_heads
 
         def to_contig(path, leaf):
             if _leaf_name(path) in ("cached_key", "cached_value"):
-                return paged_to_contiguous(leaf, table)
+                return paged_to_contiguous(leaf, table, head_dim)
             return leaf
 
         dcache = {"pos_index": cache["pos_index"]}
@@ -487,10 +489,11 @@ class SpeculativeDecoder:
         eng = self.engine
         W = self.gamma + 1
         idx0 = _first_index_leaf(cache)
+        head_dim = eng.decode_module.d_model // eng.decode_module.num_heads
 
         def to_contig(path, leaf):
             if _leaf_name(path) in ("cached_key", "cached_value"):
-                return paged_to_contiguous(leaf, table)
+                return paged_to_contiguous(leaf, table, head_dim)
             return leaf
 
         contig = jax.tree_util.tree_map_with_path(to_contig, cache)

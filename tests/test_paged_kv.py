@@ -426,3 +426,169 @@ def test_paged_shard_serving_refuses_warm_engine(compiled):
     _serve_all(eng, [[5, 3, 9]], max_new_tokens=2)
     with pytest.raises(RuntimeError, match="before the first request"):
         eng.shard_serving(None)
+
+
+# -- paged decode attention: the pool read and written in place ---------------
+
+# (heads, block_size, head_dim): two columns to a 128-lane row (the served
+# layout's kind), four to a 32-lane row, one column a row, and a block
+# size that packs nothing.
+_LAYOUTS = {"pack2": (3, 8, 64), "pack4": (5, 4, 8), "pack1": (2, 8, 128),
+            "odd": (3, 5, 8)}
+_BPS, _BLOCKS = 4, 10  # blocks per slot; physical blocks (id 10 is unallocated)
+
+
+def _paged_case(name, bs):
+    """table rows, the column each lane writes, and the active mask."""
+    rows = {
+        # one lane at the very first column of its only block
+        "column0": ([[3], [1, 4]], [0, bs + 2], [True, True]),
+        # a lane writing the first column of a fresh block
+        "block_edge": ([[3, 6], [1, 4]], [bs, 1], [True, True]),
+        # a lane at the last column of its last block
+        "last_column": ([[1, 5, 6, 7], [2]], [4 * bs - 1, 3], [True, True]),
+        # an inactive lane rides along: nothing of it is written
+        "inactive": ([[3, 6], [1, 4]], [bs + 1, 5], [True, False]),
+        # two lanes share their first (prefix) block and own their tails
+        "shared_prefix": ([[1, 4], [1, 5], [1, 6]],
+                          [bs, 2 * bs - 1, bs + 2], [True, True, True]),
+        # entries past a lane's allocation hold the out-of-range id
+        "unallocated": ([[2], [7, 8], []], [bs - 1, bs, 0],
+                        [True, True, False]),
+    }[name]
+    table = np.full((len(rows[0]), _BPS), _BLOCKS, np.int32)
+    for s, ids in enumerate(rows[0]):
+        table[s, :len(ids)] = ids
+    return (jnp.asarray(table), jnp.asarray(rows[1], jnp.int32),
+            jnp.asarray(rows[2]))
+
+
+@pytest.mark.parametrize("layout", sorted(_LAYOUTS))
+@pytest.mark.parametrize("body", ["paged_xla", "paged_pallas"])
+@pytest.mark.parametrize("case", ["column0", "block_edge", "last_column",
+                                  "inactive", "shared_prefix", "unallocated"])
+def test_paged_decode_attention_matches_dense(case, body, layout):
+    """Both bodies of the paged decode attention against dense attention
+    over the gathered cache: every active lane's new column lands in its
+    block and nowhere else, and its query attends columns ``<= idx``."""
+    from elephas_tpu.ops.attention import (
+        paged_decode_attention,
+        paged_to_contiguous,
+        pool_leaf_shape,
+    )
+    from elephas_tpu.ops.attention_pallas import pallas_paged_decode_attention
+
+    heads, bs, d = _LAYOUTS[layout]
+    table, idx, active = _paged_case(case, bs)
+    slots = table.shape[0]
+    rng = np.random.default_rng(7)
+    shape = pool_leaf_shape(_BLOCKS, heads, bs, d)
+    k_pool, v_pool = (jnp.asarray(rng.normal(size=shape), jnp.float32)
+                      for _ in range(2))
+    q, k_new, v_new = (jnp.asarray(rng.normal(size=(slots, heads, d)),
+                                   jnp.float32) for _ in range(3))
+    if body == "paged_pallas":  # the kernel, interpreted on the CPU
+        out, k_after, v_after = pallas_paged_decode_attention(
+            q, k_new, v_new, k_pool, v_pool, table, idx, active,
+            interpret=True)
+    else:
+        out, k_after, v_after = paged_decode_attention(
+            q, k_new, v_new, k_pool, v_pool, table, idx, active, body)
+
+    k_want, v_want = (np.array(paged_to_contiguous(p, table, d))
+                      for p in (k_pool, v_pool))
+    for s in range(slots):
+        if active[s]:
+            k_want[s, :, int(idx[s])] = k_new[s]
+            v_want[s, :, int(idx[s])] = v_new[s]
+    k_got = np.asarray(paged_to_contiguous(k_after, table, d))
+    v_got = np.asarray(paged_to_contiguous(v_after, table, d))
+    for s in range(slots):
+        live = int(idx[s]) + 1
+        if not active[s]:
+            continue
+        np.testing.assert_array_equal(k_got[s, :, :live], k_want[s, :, :live])
+        np.testing.assert_array_equal(v_got[s, :, :live], v_want[s, :, :live])
+        scores = np.einsum("hd,hkd->hk", q[s], k_want[s, :, :live]) / np.sqrt(d)
+        weights = np.exp(scores - scores.max(-1, keepdims=True))
+        weights /= weights.sum(-1, keepdims=True)
+        want = np.einsum("hk,hkd->hd", weights, v_want[s, :, :live])
+        np.testing.assert_allclose(np.asarray(out[s]), want,
+                                   rtol=2e-5, atol=2e-5)
+    # only the active lanes' tail blocks differ from the pool as it was
+    tails = {int(table[s, int(idx[s]) // bs]) for s in range(slots)
+             if active[s]}
+    changed = set(np.flatnonzero(
+        (np.asarray(k_after) != np.asarray(k_pool)).any(axis=(1, 2, 3))))
+    assert changed == tails
+
+
+@pytest.mark.parametrize("layout", sorted(_LAYOUTS))
+def test_window_scatters_write_their_columns_only(layout):
+    """``scatter_prefill_columns`` and ``scatter_spec_columns`` on the
+    packed blocks: a window's columns land, across block edges; columns
+    in unallocated blocks, inactive lanes and every other block stay."""
+    from elephas_tpu.ops.attention import (
+        paged_to_contiguous,
+        pool_leaf_shape,
+        scatter_prefill_columns,
+        scatter_spec_columns,
+    )
+
+    heads, bs, d = _LAYOUTS[layout]
+    table, _, _ = _paged_case("shared_prefix", bs)
+    table = table.at[0].set(jnp.asarray([3, _BLOCKS, _BLOCKS, _BLOCKS]))
+    rng = np.random.default_rng(11)
+    pool = jnp.asarray(rng.normal(
+        size=pool_leaf_shape(_BLOCKS, heads, bs, d)), jnp.float32)
+    held = [bs, 2 * bs, 2 * bs]  # columns each row's allocated blocks hold
+
+    def rows(leaf):
+        view = np.asarray(paged_to_contiguous(leaf, table, d))
+        return [view[s, :, :n] for s, n in enumerate(held)]
+
+    before = rows(pool)
+    width = bs + 2  # a chunk that crosses a block edge and runs off the row
+    chunk = np.asarray(rng.normal(size=(heads, width, d)), np.float32)
+    start = bs - 1
+    got = rows(scatter_prefill_columns(pool, table[1], jnp.int32(start),
+                                       jnp.asarray(chunk)))
+    want = [r.copy() for r in before]
+    want[1][:, start:] = chunk[:, :2 * bs - start]
+    want[2][:, :bs] = want[1][:, :bs]  # the first block is shared
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+
+    count = 3
+    view = np.asarray(rng.normal(size=(3, heads, _BPS * bs + count, d)),
+                      np.float32)
+    idx = [bs - 1, bs, 2 * bs - 2]
+    got = rows(scatter_spec_columns(
+        pool, jnp.asarray(view), table, jnp.asarray(idx, jnp.int32), count,
+        jnp.asarray([True, False, True])))
+    want = [r.copy() for r in before]
+    for s in (0, 2):  # lane 1 is inactive; what runs off a row drops
+        want[s][:, idx[s]:] = view[s, :, idx[s]:held[s]]
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+
+
+def test_decode_attention_is_named_on_stats_and_step_events(compiled):
+    """``stats()`` and every ``step`` event say which attention body the
+    decode program was traced with; the pool is the donated pytree still."""
+    events = []
+
+    class Sink:
+        def log(self, step, **fields):
+            events.append(fields)
+
+    eng = _paged_engine(compiled, kv_block_size=4, sink=Sink())
+    assert eng.stats()["decode_attention"] == "paged_xla"  # the CPU's body
+    stale = eng.pool.cache
+    res = eng.result(eng.submit([5, 3, 9], max_new_tokens=4), timeout_s=120)
+    assert res.status == "completed"
+    steps = [e for e in events if e.get("event") == "step"]
+    assert steps and all(e["decode_attention"] == "paged_xla" for e in steps)
+    assert all(leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(stale))
+    contiguous = _paged_engine(compiled, paged=False)
+    assert contiguous.stats()["decode_attention"] == "contiguous"
