@@ -2,10 +2,14 @@
 `v5e:2x2` chip, without the chip, and prints `memory_analysis()` of each:
 the reckoning quoted in the two configuration files.
 
-    JAX_PLATFORMS=cpu python3 benchmark/aot_compile.py [gpt2-xl] [resnet18-cifar10]
+    JAX_PLATFORMS=cpu python3 benchmark/aot_compile.py [<config> ...]
 
-Nothing runs, so this says nothing about times. It builds the engine's KV
-pool on the host (5 GB of zeros), so it wants that much memory.
+A serving configuration's engine is built through the family its file
+names (`models/<family>.py`), as the harness builds it. Nothing runs, so
+this says nothing about times. It builds the engine's KV pool on the host
+(5 GB of zeros at 16 slots), so it wants that much memory. Under the CPU
+backend the engine takes the XLA body of the paged decode; the Pallas
+kernel's program is compiled by `tests/test_aot_compile.py`.
 """
 
 from __future__ import annotations
@@ -40,29 +44,31 @@ def shapes_on(tree, sharding):
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), tree)
 
 
-def gpt2_xl(topo):
+def serving(topo, config_name):
+    """Both serving programs of every cell of `config_name`, the engine
+    built through the family its configuration file names, as
+    `lib/serve.py` builds it."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
     from elephas_tpu import InferenceEngine, compile_model
-    from elephas_tpu.models import get_model
-    from lib import weights
+    from lib.cells import Cell
 
     chip = SingleDeviceSharding(topo.devices[0])
-    with open(os.path.join(BENCH_DIR, "configs", "gpt2-xl.json")) as f:
-        config = json.load(f)
-    cfg = {k: config[k] for k in ("n_layer", "n_embd", "n_head", "n_positions", "vocab_size")}
-    params = jax.eval_shape(lambda: weights.gpt2_params(0, cfg, jnp.bfloat16))
-    module = get_model("transformer_lm", dtype="bfloat16", vocab_size=cfg["vocab_size"],
-                       d_model=cfg["n_embd"], num_heads=cfg["n_head"],
-                       num_layers=cfg["n_layer"], max_seq_len=cfg["n_positions"])
-    for traffic in ("chat-steady", "doc-batch"):
-        with open(os.path.join(BENCH_DIR, "traffic", traffic + ".json")) as f:
-            s = {**config["serving"], **json.load(f)["engine"]}
-        compiled = compile_model(module, params=params, optimizer="sgd",
-                                 loss="sparse_categorical_crossentropy", metrics=[],
-                                 input_shape=(s["max_prompt_len"],), input_dtype=jnp.int32)
+    with open(os.path.join(os.path.dirname(BENCH_DIR), "BENCHMARK.json")) as f:
+        names = [w["name"] for w in json.load(f)["workloads"] if w["config"] == config_name]
+    for name in names:
+        cell = Cell(name)
+        config = cell.config
+        family = cell.module("models", config["model"])
+        cfg = family.shape(config)
+        s = {**config["serving"], **cell.traffic["engine"]}
+        params = jax.eval_shape(lambda: family.params(0, cfg, jnp.dtype(config["dtype"])))
+        compiled = compile_model(family.flax_module(cfg, config["dtype"]), params=params,
+                                 optimizer="sgd", loss="sparse_categorical_crossentropy",
+                                 metrics=[], input_shape=(s["max_prompt_len"],),
+                                 input_dtype=jnp.int32)
         engine = InferenceEngine(
             compiled, max_slots=s["max_slots"], max_prompt_len=s["max_prompt_len"],
             max_len=s["max_len"], kv_block_size=s["kv_block_size"],
@@ -77,10 +83,10 @@ def gpt2_xl(topo):
         S = s["max_slots"]
         i32 = arg((), jnp.int32)
         rng = arg((2,), jnp.uint32)
-        report(f"gpt2-xl.{traffic} jit__chunk_prefill_impl", engine._jit_prefill.lower(
+        report(f"{name} ({S} slots) jit__chunk_prefill_impl", engine._jit_prefill.lower(
             p, cache, table, arg((1, s["prefill_chunk"]), jnp.int32), i32, i32, i32,
             rng).compile())
-        report(f"gpt2-xl.{traffic} jit__paged_decode_impl", engine._jit_decode.lower(
+        report(f"{name} ({S} slots) jit__paged_decode_impl", engine._jit_decode.lower(
             p, cache, table, arg((S,), jnp.int32), arg((S,), jnp.int32),
             arg((S,), jnp.bool_), arg((S,), jnp.bool_), arg((S,), jnp.int32),
             rng).compile())
@@ -122,11 +128,13 @@ def main():
 
     jax.config.update("jax_enable_compilation_cache", False)
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    which = sys.argv[1:] or ["gpt2-xl", "resnet18-cifar10"]
-    if "gpt2-xl" in which:
-        gpt2_xl(topo)
-    if "resnet18-cifar10" in which:
-        resnet18(topo)
+    with open(os.path.join(os.path.dirname(BENCH_DIR), "BENCHMARK.json")) as f:
+        configs = [c["name"] for c in json.load(f)["configs"]]
+    for name in sys.argv[1:] or configs:
+        if name == "resnet18-cifar10":
+            resnet18(topo)
+        else:
+            serving(topo, name)
 
 
 if __name__ == "__main__":

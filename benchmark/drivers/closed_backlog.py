@@ -54,7 +54,7 @@ def drive(cell, args) -> dict:
     t_open, t_close = run_job_window(step, top_up, lambda: finished() < total,
                                      run.drain_device, time.monotonic)
     serve.close_trace(run)
-    run.window = count_window(run.sink, run.chunks, t_open, t_close)
+    run.window = count_window(run.sink, t_open, t_close)
     serve.finish_trace(run)
     failed = sum(1 for s in run.sent if s.result.status != "completed")
     run.extra["ttft_ms"] = [s.result.ttft_s * 1e3 for s in run.sent

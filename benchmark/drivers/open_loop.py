@@ -42,7 +42,7 @@ def drive(cell, args) -> dict:
     run.drain_device()
     t_close = time.monotonic()
     serve.close_trace(run)
-    run.window = count_window(run.sink, run.chunks, t0, t_close)
+    run.window = count_window(run.sink, t0, t_close)
     backlog_at_close = len(run.engine.queue) + run.engine.scheduler.active_count
     deadline = time.monotonic() + 90.0
     while any(s.result is None for s in run.sent) and time.monotonic() < deadline:

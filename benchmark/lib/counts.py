@@ -3,65 +3,13 @@
 Every share the benchmark reports is of needed work: live KV columns,
 valid prompt tokens, unpadded rows. Padding, gathered-but-dead cache
 columns and recomputation count for nothing, so a later change that
-removes waste raises a share and none can pass 100%.
+removes waste raises a share and none can pass 100%. A language model's
+counts are its family's (`models/<family>.py`: `chunk_cost`, `decode_cost`).
 """
 
 from __future__ import annotations
 
-from typing import Iterable, List, Tuple
-
-# -- GPT-2 (TransformerLM): d = n_embd, L = n_layer, V = vocab_size --------
-
-
-def gpt2_weight_bytes(cfg: dict, bytes_per_param: int = 2) -> int:
-    """Bytes of every weight one forward step reads: the blocks, the final
-    LayerNorm and the (untied) head. The embedding tables are read a row
-    per token and are counted with the tokens."""
-    d, L, V = cfg["n_embd"], cfg["n_layer"], cfg["vocab_size"]
-    block = 12 * d * d + 13 * d  # qkv, out, two MLP matrices; biases, LayerNorms
-    return (L * block + 2 * d + d * V + V) * bytes_per_param
-
-
-def gpt2_param_count(cfg: dict) -> int:
-    d, V, P = cfg["n_embd"], cfg["vocab_size"], cfg["n_positions"]
-    return gpt2_weight_bytes(cfg, 1) + V * d + P * d
-
-
-def gpt2_kv_bytes_per_token(cfg: dict, bytes_per_value: int = 2) -> int:
-    return 2 * cfg["n_layer"] * cfg["n_embd"] * bytes_per_value
-
-
-def gpt2_token_flops(cfg: dict, position: int, head: bool) -> float:
-    """Forward FLOPs of one token at `position` (0-based; it attends
-    `position + 1` keys): 24·d² of matrix products and 4·d·(position+1) of
-    attention per layer, and 2·d·V for the head where a token is sampled."""
-    d, L, V = cfg["n_embd"], cfg["n_layer"], cfg["vocab_size"]
-    flops = L * (24.0 * d * d + 4.0 * d * (position + 1))
-    return flops + (2.0 * d * V if head else 0.0)
-
-
-def gpt2_chunk_cost(cfg: dict, start: int, valid: int) -> Tuple[float, float]:
-    """(FLOPs, bytes) one prefill chunk needs: `valid` tokens from column
-    `start`, one sampled position, the weights read once, the slot's live
-    KV read and the chunk's KV written."""
-    d, L, V = cfg["n_embd"], cfg["n_layer"], cfg["vocab_size"]
-    positions = valid * start + valid * (valid + 1) / 2.0  # sum of (p + 1)
-    flops = L * (24.0 * d * d * valid + 4.0 * d * positions) + 2.0 * d * V
-    kv = gpt2_kv_bytes_per_token(cfg)
-    nbytes = gpt2_weight_bytes(cfg) + kv * (start + valid) + 2 * d * valid
-    return flops, float(nbytes)
-
-
-def gpt2_decode_cost(cfg: dict, lengths: Iterable[int]) -> Tuple[float, float]:
-    """(FLOPs, bytes) one decode step needs for lanes whose caches hold
-    `lengths` columns before the step: the weights once, every lane's live
-    KV, one new column each."""
-    lengths = list(lengths)
-    flops = sum(gpt2_token_flops(cfg, c, head=True) for c in lengths)
-    kv = gpt2_kv_bytes_per_token(cfg)
-    nbytes = gpt2_weight_bytes(cfg) + kv * (sum(lengths) + len(lengths))
-    return flops, float(nbytes)
-
+from typing import List, Tuple
 
 # -- ResNet-18 at 32x32, by layer shapes (never the 6N rule) ---------------
 

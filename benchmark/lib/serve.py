@@ -1,5 +1,9 @@
 """What the two serving drivers share: the engine built from a cell's
-files, its warm-up, the counters of the counting rule, and `correct`.
+files, its warm-up, what the program's own events and spans say each step
+and chunk held, and `correct`. The language model is the family that the
+configuration file names (`"model"`), found as `models/<family>.py`: its
+sizes, its flax module, its weights from the seed, its plain reference's
+name and its needed work. Nothing here names a family.
 """
 
 from __future__ import annotations
@@ -13,22 +17,10 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from . import traffic as traffic_lib
-from . import weights
 from .cells import CHECKOUT, Cell, device_report, log, process_age_s
-from .stats import check
-from .window import ChunkCounter, StepSink
+from .stats import check, percentile
+from .window import StepSink
 from .xplane import TraceCapture
-
-
-class DecodeCounter:
-    """Wraps `scheduler.decode_fn`; remembers which lanes each step ran."""
-
-    def __init__(self, fn, clock):
-        self.fn, self.clock, self.calls = fn, clock, []
-
-    def __call__(self, cache, prev, vals, mask, active_mask, pad):
-        self.calls.append((self.clock(), np.flatnonzero(active_mask)))
-        return self.fn(cache, prev, vals, mask, active_mask, pad)
 
 
 @dataclass
@@ -45,12 +37,11 @@ class Sent:
 class Serving:
     """One run's state: what the readers read."""
     cell: Cell
-    cfg: dict
+    family: object            # `models/<family>.py`
+    cfg: dict                 # the family's `shape` of the sized configuration
     serving: dict
     engine: object
     sink: StepSink
-    chunks: ChunkCounter
-    decodes: DecodeCounter
     schedule: Optional[traffic_lib.Schedule] = None
     sent: List[Sent] = field(default_factory=list)
     by_id: Dict[int, Sent] = field(default_factory=dict)
@@ -60,6 +51,8 @@ class Serving:
     capture: Optional[TraceCapture] = None
     trace: object = None
     spans: list = field(default_factory=list)
+    # (begin, slot, start, valid) of every `step/prefill_chunk` span
+    chunk_spans: list = field(default_factory=list)
     peak: dict = None
     extra: dict = field(default_factory=dict)
 
@@ -85,25 +78,19 @@ class Serving:
         jax.block_until_ready(self.engine.pool.cache)
 
 
-def model_cfg(config: dict) -> dict:
-    return {k: config[k] for k in ("n_layer", "n_embd", "n_head", "n_positions", "vocab_size")}
-
-
 def build(cell: Cell, seed: int, rehearse: bool, trace: bool) -> Serving:
     import jax.numpy as jnp
 
     from elephas_tpu import InferenceEngine, compile_model, obs
-    from elephas_tpu.models import get_model
 
     config = cell.sized(cell.config, rehearse)
     tr = cell.sized(cell.traffic, rehearse)
-    cfg, serving = model_cfg(config), {**config["serving"], **tr["engine"]}
+    family = cell.module("models", config["model"])
+    cfg, serving = family.shape(config), {**config["serving"], **tr["engine"]}
     dtype = jnp.dtype(config["dtype"])
     tracer = obs.enable_tracing(capacity=1 << 20) if trace else None
-    params = weights.gpt2_params(seed, cfg, dtype)
-    module = get_model("transformer_lm", dtype=config["dtype"], vocab_size=cfg["vocab_size"],
-                       d_model=cfg["n_embd"], num_heads=cfg["n_head"],
-                       num_layers=cfg["n_layer"], max_seq_len=cfg["n_positions"])
+    params = family.params(seed, cfg, dtype)
+    module = family.flax_module(cfg, config["dtype"])
     compiled = compile_model(module, params=params, optimizer="sgd",
                              loss="sparse_categorical_crossentropy", metrics=[],
                              input_shape=(serving["max_prompt_len"],), input_dtype=jnp.int32)
@@ -114,11 +101,7 @@ def build(cell: Cell, seed: int, rehearse: bool, trace: bool) -> Serving:
         kv_block_size=serving["kv_block_size"], prefill_chunk=serving["prefill_chunk"],
         prefill_chunks_per_step=serving.get("prefill_chunks_per_step"),
         sink=sink, tracer=tracer)
-    chunks = ChunkCounter(engine.scheduler.chunk_prefill_fn, time.monotonic)
-    engine.scheduler.chunk_prefill_fn = chunks
-    decodes = DecodeCounter(engine.scheduler.decode_fn, time.monotonic)
-    engine.scheduler.decode_fn = decodes
-    run = Serving(cell, cfg, serving, engine, sink, chunks, decodes)
+    run = Serving(cell, family, cfg, serving, engine, sink)
     run.extra["traffic"] = tr
     run.extra["rehearse"] = rehearse
     run.extra["config"] = config
@@ -181,7 +164,13 @@ def finish_trace(run: Serving) -> None:
     run.capture.stop()
     from elephas_tpu import obs
 
-    run.spans = [(e.name, e.begin_s, e.end_s) for e in obs.default_tracer().events()]
+    tracer = obs.default_tracer()
+    events = tracer.events()
+    if tracer.dropped:
+        raise RuntimeError(f"the tracer dropped {tracer.dropped} events: its spans are not whole")
+    run.spans = [(e.name, e.begin_s, e.end_s) for e in events]
+    run.chunk_spans = [(e.begin_s, e.args["slot"], e.args["start"], e.args["valid"])
+                       for e in events if e.name == "step/prefill_chunk"]
     try:
         run.trace = run.capture.result()
     except ValueError:
@@ -190,24 +179,14 @@ def finish_trace(run: Serving) -> None:
 
 
 def lengths_before_each_step(run: Serving, t0: float, t1: float):
-    """For each decode step in [t0, t1]: the cache columns each of its
-    lanes held before it, rebuilt from the chunk and decode calls alone;
-    and each chunk call's (start, valid)."""
-    events = [(t, 0, (int(slot), int(start), int(valid)))
-              for t, slot, start, valid in run.chunks.detail] + \
-             [(t, 1, lanes) for t, lanes in run.decodes.calls]
-    length, steps, chunk_calls = {}, [], []
-    for t, kind, what in sorted(events, key=lambda e: (e[0], e[1])):
-        if kind == 0:
-            slot, start, valid = what
-            length[slot] = start + valid
-            if t0 <= t <= t1:
-                chunk_calls.append((start, valid))
-        else:
-            if t0 <= t <= t1:
-                steps.append([length.get(int(s), 0) for s in what])
-            for s in what:
-                length[int(s)] = length.get(int(s), 0) + 1
+    """For each decode step dispatched in [t0, t1]: the cache columns each
+    of its lanes held before it, as the program's `step` event says
+    (`lane_lengths`; a step dispatches its decode as it begins, at the
+    event's `t - step_seconds`); and (start, valid) of each chunk dispatched
+    in [t0, t1], from the `step/prefill_chunk` spans of a traced run."""
+    steps = [list(s["lane_lengths"]) for s in run.sink.steps
+             if s["lane_lengths"] and t0 <= s["t"] - s["step_seconds"] <= t1]
+    chunk_calls = [(start, valid) for t, _, start, valid in run.chunk_spans if t0 <= t <= t1]
     return steps, chunk_calls
 
 
@@ -234,7 +213,8 @@ def reference_gaps(run: Serving, seed: int, sample: List[Sent], control: bool = 
     import jax
     import jax.numpy as jnp
 
-    ref = run.cell.module("references", run.extra["config"]["reference"])
+    family = run.family
+    ref = run.cell.module("references", family.REFERENCE)
     cfg, T = run.cfg, run.serving["max_len"]
     width = max(len(s.result.tokens) for s in sample)
     tokens = np.zeros((len(sample), T), np.int32)
@@ -248,12 +228,12 @@ def reference_gaps(run: Serving, seed: int, sample: List[Sent], control: bool = 
         rows[i, : len(out)] = np.arange(plen - 1, plen - 1 + len(out))
         served[i, : len(out)], mask[i, : len(out)] = out, True
     dtype = jnp.dtype(run.extra["config"]["dtype"])
-    top = weights.gpt2_top_at(seed, cfg, dtype)
+    top, layers = family.top_at(seed, cfg, dtype), family.layers(cfg)
 
     def block_at(layer):
-        return weights.gpt2_block_at(seed, layer, cfg, dtype)
+        return family.block_at(seed, layer, cfg, dtype)
 
-    logits = ref.logits_at(jnp.asarray(tokens), jnp.asarray(rows), top, block_at, cfg["n_layer"])
+    logits = ref.logits_at(jnp.asarray(tokens), jnp.asarray(rows), top, block_at, layers)
     best = logits.max(-1)
 
     def gap_of(tok):
@@ -267,7 +247,7 @@ def reference_gaps(run: Serving, seed: int, sample: List[Sent], control: bool = 
            "tokens_off_best": int((gaps > 0).sum())}
     if control:
         low = ref.logits_at(jnp.asarray(tokens), jnp.asarray(rows), top, block_at,
-                            cfg["n_layer"], quant=ref.fp8)
+                            layers, quant=ref.fp8)
         low_gaps = gap_of(np.asarray(low.argmax(-1)))
         out["control_logit_gap_max"] = float(low_gaps.max())
         out["control_logit_gap_mean"] = float(low_gaps.sum() / mask.sum())
@@ -277,10 +257,7 @@ def reference_gaps(run: Serving, seed: int, sample: List[Sent], control: bool = 
 
 def free_program(run: Serving) -> None:
     """Drop the engine, its pool and its weights before the reference runs."""
-    run.engine.scheduler.chunk_prefill_fn = None
-    run.engine.scheduler.decode_fn = None
     run.engine = None
-    run.chunks.fn = run.decodes.fn = None
     gc.collect()
 
 
@@ -295,7 +272,6 @@ def conclude(run: Serving, args, attempted: int, failed: int, end_to_end: dict,
     run.peak = device_report(devices, run.trace)
     count = run.extra["traffic"]["check"]["requests"]
     sample = sample_finished(run, args.seed, count)
-    run.chunks.detail  # read the chunk calls' scalars while the engine lives
     free_program(run)
     t, controls = time.monotonic(), {}
     if sample:
@@ -317,13 +293,18 @@ def conclude(run: Serving, args, attempted: int, failed: int, end_to_end: dict,
              "window_s": run.window.seconds, "prompt_tokens": run.window.prompt_tokens,
              "output_tokens": run.window.output_tokens, "steps": len(run.window.steps),
              "chunk_calls": run.window.chunk_calls,
-             "reference": run.extra.get("reference")}
+             "reference": run.extra.get("reference"),
+             # filled by the roofline and MFU readers of a traced run: the
+             # needed seconds and FLOPs they stood on
+             "needed": run.extra.setdefault("needed", {})}
     for key in ("backlog_at_close", "generator_late_ms_p95", "generator_late_ms_max"):
         if key in run.extra:
             notes[key] = run.extra[key]
     if "tpot_ms" in run.extra:
         notes["tpot_ms_p50"] = end_to_end.get("tpot_ms_p50")
         notes["tpot_ms_p95"] = end_to_end.get("tpot_ms_p95")
+        notes["ttft_ms_p50"] = percentile(run.extra["ttft_ms"], 50)
+        notes["ttft_ms_p95"] = percentile(run.extra["ttft_ms"], 95)
     steps = run.window.steps
     if steps:
         notes["occupancy"] = sum(s["active_slots"] for s in steps) / (

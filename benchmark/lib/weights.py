@@ -1,14 +1,14 @@
 """Weights and rows made on the device from `--seed`.
 
 The same functions feed the program and the plain reference, so neither
-takes anything the other has made. A model's weights are drawn layer by
-layer by one jitted function (one compile, one dispatch a layer), in the
-type they are served or trained in.
+takes anything the other has made. A language model's weights are its
+family's (`models/<family>.py`), drawn layer by layer by one jitted
+function (one compile, one dispatch a layer), in the type they are served
+in; here are the key every family starts from and the training cell's.
 """
 
 from __future__ import annotations
 
-import functools
 import math
 
 import jax
@@ -18,62 +18,6 @@ import jax.numpy as jnp
 def seed_key(seed: int):
     """A key for any whole number up to 2**62: `PRNGKey` alone takes 32 bits."""
     return jax.random.fold_in(jax.random.PRNGKey(seed & 0x7FFFFFFF), seed >> 31)
-
-
-# -- GPT-2 (flax `TransformerLM` layout) -----------------------------------
-
-
-@functools.partial(jax.jit, static_argnames=("d", "heads", "layers", "dtype"))
-def gpt2_block(key, d: int, heads: int, layers: int, dtype):
-    ks = iter(jax.random.split(key, 12))
-
-    def normal(shape, std, mean=0.0):
-        return (mean + std * jax.random.normal(next(ks), shape, jnp.float32)).astype(dtype)
-
-    hd, out_std = d // heads, 0.02 / math.sqrt(2 * layers)
-    return {
-        "LayerNorm_0": {"scale": normal((d,), 0.02, 1.0), "bias": normal((d,), 0.02)},
-        "SelfAttention_0": {
-            "qkv": {"kernel": normal((d, 3, heads, hd), 0.02),
-                    "bias": normal((3, heads, hd), 0.02)},
-            "out": {"kernel": normal((d, d), out_std), "bias": normal((d,), 0.02)},
-        },
-        "LayerNorm_1": {"scale": normal((d,), 0.02, 1.0), "bias": normal((d,), 0.02)},
-        "Dense_0": {"kernel": normal((d, 4 * d), 0.02), "bias": normal((4 * d,), 0.02)},
-        "Dense_1": {"kernel": normal((4 * d, d), out_std), "bias": normal((d,), 0.02)},
-    }
-
-
-@functools.partial(jax.jit, static_argnames=("d", "vocab", "positions", "dtype"))
-def gpt2_top(key, d: int, vocab: int, positions: int, dtype):
-    ks = iter(jax.random.split(key, 6))
-
-    def normal(shape, std, mean=0.0):
-        return (mean + std * jax.random.normal(next(ks), shape, jnp.float32)).astype(dtype)
-
-    return {
-        "tok_embed": {"embedding": normal((vocab, d), 0.02)},
-        "pos_embed": normal((positions, d), 0.01),
-        "LayerNorm_0": {"scale": normal((d,), 0.02, 1.0), "bias": normal((d,), 0.02)},
-        "lm_head": {"kernel": normal((d, vocab), 0.02), "bias": normal((vocab,), 0.02)},
-    }
-
-
-def gpt2_block_at(seed: int, layer: int, cfg: dict, dtype):
-    return gpt2_block(jax.random.fold_in(seed_key(seed), layer + 1), cfg["n_embd"],
-                      cfg["n_head"], cfg["n_layer"], dtype)
-
-
-def gpt2_top_at(seed: int, cfg: dict, dtype):
-    return gpt2_top(seed_key(seed), cfg["n_embd"], cfg["vocab_size"],
-                    cfg["n_positions"], dtype)
-
-
-def gpt2_params(seed: int, cfg: dict, dtype) -> dict:
-    params = dict(gpt2_top_at(seed, cfg, dtype))
-    for layer in range(cfg["n_layer"]):
-        params[f"Block_{layer}"] = gpt2_block_at(seed, layer, cfg, dtype)
-    return params
 
 
 # -- ResNet-18 (flax `ResNet` layout) and its rows --------------------------

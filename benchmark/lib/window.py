@@ -3,9 +3,18 @@
 Every end-to-end rate is work committed inside the window divided by the
 window's own length. Both ends of the window are instants at which the
 device is drained, and work is counted at the finest grain at which the
-program commits it: one decode step's tokens (the `step` events the
-program's `ServingMetrics` hands to its sink) and one prefill chunk's
-valid tokens (the `valid` argument of `scheduler.chunk_prefill_fn`).
+program commits it, from the program's own `step` events (one a scheduler
+step, handed to the benchmark's sink by `ServingMetrics`): `step_tokens`,
+the tokens one decode step gave, and `prefill_tokens` / `prefill_chunks`,
+the valid prompt tokens and the chunks the step dispatched. The benchmark
+wraps no function of the program: a step that gains an argument, or a
+model that carries state through it, counts as before.
+
+An event is stamped when its step ends, so a chunk is counted at the end
+of the step that dispatched it and not at its dispatch. At the window's
+edges that changes nothing: the window opens on a drained device with no
+step begun, and closes on a drained device after the last step has ended,
+so every step that dispatched work in the window also ended in it.
 Finished requests are never counted against a deadline: the backlog's
 window is a whole job of a size fixed before it opens, and ends when the
 job does.
@@ -34,29 +43,6 @@ class StepSink:
             self.requests.append(fields)
 
 
-class ChunkCounter:
-    """Wraps `scheduler.chunk_prefill_fn`; remembers each call's `slot`,
-    `start` and `valid` (device scalars: read only after the window, never
-    inside it)."""
-
-    def __init__(self, fn: Callable, clock: Callable[[], float]):
-        self.fn = fn
-        self.clock = clock
-        self.calls: List[tuple] = []
-        self._detail: List[tuple] = []
-
-    def __call__(self, tokens, slot, start, valid):
-        self.calls.append((self.clock(), slot, start, valid))
-        return self.fn(tokens, slot, start, valid)
-
-    @property
-    def detail(self) -> List[tuple]:
-        """(time, slot, start, valid) of every call, as whole numbers."""
-        for t, slot, start, valid in self.calls[len(self._detail):]:
-            self._detail.append((t, int(slot), int(start), int(valid)))
-        return self._detail
-
-
 @dataclass
 class Window:
     t_open: float
@@ -75,17 +61,16 @@ class Window:
         return (self.prompt_tokens + self.output_tokens) / self.seconds
 
 
-def count_window(sink: StepSink, chunks: ChunkCounter, t_open: float,
-                 t_close: float) -> Window:
-    """Work committed in the window: steps that ended in (t_open, t_close]
-    (an event is stamped when its step ends) and chunks dispatched in
-    [t_open, t_close] (a call is stamped when it is made; the window closes
-    on a drained device, so whatever was dispatched is done)."""
+def count_window(sink: StepSink, t_open: float, t_close: float) -> Window:
+    """Work committed in the window: the steps that ended in (t_open,
+    t_close], their decode tokens and the prompt tokens of the chunks they
+    dispatched (the window closes on a drained device, so whatever was
+    dispatched is done)."""
     w = Window(t_open, t_close)
     w.steps = [s for s in sink.steps if t_open < s["t"] <= t_close]
     w.output_tokens = sum(int(s["step_tokens"]) for s in w.steps)
-    inside = [valid for t, _, _, valid in chunks.detail if t_open <= t <= t_close]
-    w.prompt_tokens, w.chunk_calls = sum(inside), len(inside)
+    w.prompt_tokens = sum(int(s["prefill_tokens"]) for s in w.steps)
+    w.chunk_calls = sum(int(s["prefill_chunks"]) for s in w.steps)
     return w
 
 

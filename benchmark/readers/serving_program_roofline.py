@@ -1,7 +1,8 @@
-"""The least time the chip could take for what a GPT-2 program's calls
-needed (`lib/counts.py`: live KV, valid tokens) over the device time those
-calls took, in the traced part of the window. `kind` is `decode` or `chunk`.
-"""
+"""The least time the chip could take for what a serving program's calls
+needed (the family's `decode_cost` / `chunk_cost`: live KV, valid tokens)
+over the device time those calls took, in the traced part of the window.
+`kind` is `decode` or `chunk`. The needed work is the family's, whatever
+program implements it."""
 
 from lib import counts, serve
 from lib.peaks import peaks
@@ -17,12 +18,14 @@ def read(run, program: str, kind: str):
     steps, chunks = serve.lengths_before_each_step(run, marks[MARK_OPEN], marks[MARK_CLOSE])
     peak = peaks(run.peak["kind"])
     if kind == "decode":
-        costs = [counts.gpt2_decode_cost(run.cfg, lengths) for lengths in steps]
+        costs = [run.family.decode_cost(run.cfg, lengths) for lengths in steps]
     else:
-        costs = [counts.gpt2_chunk_cost(run.cfg, start, valid) for start, valid in chunks]
+        costs = [run.family.chunk_cost(run.cfg, start, valid) for start, valid in chunks]
     if not costs or not traced or device_s <= 0:
         return None
     least = sum(counts.roofline_seconds(f, b, peak)[0] for f, b in costs)
+    run.extra.setdefault("needed", {})[program] = {"calls": len(costs), "seconds": least,
+                                                  "traced_calls": traced}
     # host calls in the marks and device calls in the trace differ by the
     # calls in flight at either mark
     return 100.0 * least * (traced / len(costs)) / device_s

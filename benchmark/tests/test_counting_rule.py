@@ -3,11 +3,15 @@ the window over the window's own length, at the grain of one decode step
 and one prefill chunk. The backlog's window is a whole job, so no deadline
 can move its work; where a window does end at a deadline (the open loop's),
 moving it by less than one step moves no count by more than one step's
-tokens (one chunk's for prefill)."""
+tokens (one chunk's for prefill). The counts come from the program's own
+`step` events; on recorded events they are what the parent's wrappers of
+`scheduler.chunk_prefill_fn` and `decode_fn` counted in the same run."""
 
 import pytest
 
-from lib.window import ChunkCounter, StepSink, count_window, run_job_window
+from lib import serve
+from lib.window import StepSink, count_window, run_job_window
+from test_counts import recorded
 
 STEP_S, LANES, CHUNK = 0.1, 8, 128
 
@@ -18,16 +22,15 @@ class FakeEngine:
     def __init__(self, step_s=STEP_S):
         self.now, self.step_s, self.steps = 0.0, step_s, 0
         self.sink = StepSink(self.clock)
-        self.chunks = ChunkCounter(lambda *a: None, self.clock)
 
     def clock(self):
         return self.now
 
     def step(self):
-        self.chunks(None, 0, 0, CHUNK)
         self.now += self.step_s
         self.steps += 1
         self.sink.log(0, event="step", step_tokens=LANES, active_slots=LANES,
+                      prefill_tokens=CHUNK, prefill_chunks=1,
                       step_seconds=self.step_s, queue_depth=16)
 
     def until(self, seconds):
@@ -35,7 +38,7 @@ class FakeEngine:
         t_open = self.clock()
         while self.clock() - t_open < seconds:
             self.step()
-        return count_window(self.sink, self.chunks, t_open, self.clock())
+        return count_window(self.sink, t_open, self.clock())
 
     def job(self, steps):
         """A window that ends when a job of `steps` steps is done."""
@@ -43,7 +46,7 @@ class FakeEngine:
         t_open, t_close = run_job_window(self.step, lambda: None,
                                          lambda: self.steps - first < steps,
                                          lambda: None, self.clock)
-        return count_window(self.sink, self.chunks, t_open, t_close)
+        return count_window(self.sink, t_open, t_close)
 
 
 @pytest.mark.parametrize("shift", [0.0, 0.01, 0.049, 0.051, 0.099])
@@ -69,3 +72,15 @@ def test_work_outside_the_window_is_not_counted():
     eng.step()  # warm-up work, before the window opens
     w = eng.job(10)
     assert w.output_tokens == 10 * LANES and w.chunk_calls == 10
+
+
+@pytest.mark.parametrize("name", ["events_chat.json", "events_batch.json"])
+def test_recorded_events_count_as_the_wrappers_counted(name):
+    rec, run = recorded(name)
+    w = count_window(run.sink, rec["t_open"], rec["t_close"])
+    assert [w.prompt_tokens, w.output_tokens, len(w.steps), w.chunk_calls] == \
+        rec["parent"]["window_counts"]
+    steps, chunks = serve.lengths_before_each_step(run, rec["t_open"], rec["t_close"])
+    assert steps == rec["parent"]["window_steps"] and len(chunks) == w.chunk_calls
+    _, traced = serve.lengths_before_each_step(run, *rec["marks"])
+    assert [list(c) for c in traced] == rec["parent"]["traced_chunks"] != []
