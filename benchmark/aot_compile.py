@@ -44,9 +44,9 @@ def shapes_on(tree, sharding):
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), tree)
 
 
-def serving(topo, config_name):
-    """Both serving programs of every cell of `config_name`, the engine
-    built through the family its configuration file names, as
+def serving(topo, cells):
+    """Both serving programs of each of `cells` (one configuration's), the
+    engine built through the family its configuration file names, as
     `lib/serve.py` builds it."""
     import jax
     import jax.numpy as jnp
@@ -56,9 +56,7 @@ def serving(topo, config_name):
     from lib.cells import Cell
 
     chip = SingleDeviceSharding(topo.devices[0])
-    with open(os.path.join(os.path.dirname(BENCH_DIR), "BENCHMARK.json")) as f:
-        names = [w["name"] for w in json.load(f)["workloads"] if w["config"] == config_name]
-    for name in names:
+    for name in cells:
         cell = Cell(name)
         config = cell.config
         family = cell.module("models", config["model"])
@@ -129,12 +127,12 @@ def main():
     jax.config.update("jax_enable_compilation_cache", False)
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     with open(os.path.join(os.path.dirname(BENCH_DIR), "BENCHMARK.json")) as f:
-        configs = [c["name"] for c in json.load(f)["configs"]]
-    for name in sys.argv[1:] or configs:
+        bench = json.load(f)
+    for name in sys.argv[1:] or [c["name"] for c in bench["configs"]]:
         if name == "resnet18-cifar10":
             resnet18(topo)
         else:
-            serving(topo, name)
+            serving(topo, [w["name"] for w in bench["workloads"] if w["config"] == name])
 
 
 if __name__ == "__main__":

@@ -51,7 +51,7 @@ class Serving:
     capture: Optional[TraceCapture] = None
     trace: object = None
     spans: list = field(default_factory=list)
-    # (begin, slot, start, valid) of every `step/prefill_chunk` span
+    # (begin, start, valid) of every `step/prefill_chunk` span
     chunk_spans: list = field(default_factory=list)
     peak: dict = None
     extra: dict = field(default_factory=dict)
@@ -169,7 +169,7 @@ def finish_trace(run: Serving) -> None:
     if tracer.dropped:
         raise RuntimeError(f"the tracer dropped {tracer.dropped} events: its spans are not whole")
     run.spans = [(e.name, e.begin_s, e.end_s) for e in events]
-    run.chunk_spans = [(e.begin_s, e.args["slot"], e.args["start"], e.args["valid"])
+    run.chunk_spans = [(e.begin_s, e.args["start"], e.args["valid"])
                        for e in events if e.name == "step/prefill_chunk"]
     try:
         run.trace = run.capture.result()
@@ -186,7 +186,7 @@ def lengths_before_each_step(run: Serving, t0: float, t1: float):
     in [t0, t1], from the `step/prefill_chunk` spans of a traced run."""
     steps = [list(s["lane_lengths"]) for s in run.sink.steps
              if s["lane_lengths"] and t0 <= s["t"] - s["step_seconds"] <= t1]
-    chunk_calls = [(start, valid) for t, _, start, valid in run.chunk_spans if t0 <= t <= t1]
+    chunk_calls = [(start, valid) for t, start, valid in run.chunk_spans if t0 <= t <= t1]
     return steps, chunk_calls
 
 

@@ -7,53 +7,62 @@ under `lib/` or `drivers/` names a family."""
 import json
 import os
 import re
-import shutil
 
 import run as bench_run
 from lib.cells import BENCH_DIR, CHECKOUT
 
 
-def test_a_throw_away_cell_runs_from_files_alone(tmp_path, capsys):
-    bench = json.load(open(os.path.join(CHECKOUT, "BENCHMARK.json")))
-    before = json.dumps(bench["workloads"])
-    xl = json.load(open(os.path.join(BENCH_DIR, "configs", "gpt2-xl.json")))
-    tiny = {**xl, **{k: v for k, v in xl["rehearsal"].items()}, "rehearsal": {}}
-    chat = json.load(open(os.path.join(BENCH_DIR, "traffic", "chat-steady.json")))
-    trickle = {**chat, **chat["rehearsal"], "rehearsal": {},
-               "arrivals": {"process": "poisson", "rate_per_s": 3.0}}
-    files = {
-        "benchmark/configs/gpt2-tiny.json": tiny,
-        "benchmark/traffic/trickle.json": trickle,
-        "benchmark/metrics/requests_seen.trickle.json": {"reader": "requests_seen", "args": {}},
-        "benchmark/metrics/ttft_ms_p90.trickle.json": {
-            "reader": "result_percentile", "args": {"series": "ttft_ms", "q": 90}},
-    }
+def overlay(tmp_path, files, config, cell, per_layer):
+    """Writes `files` (JSON by relative path, or text) under `tmp_path` with
+    a `BENCHMARK.json` that appends one configuration, one cell of the
+    `trickle` mix judged by `tpot_ms_p95`, and its per-layer metrics."""
     for rel, content in files.items():
         path = tmp_path / rel
         path.parent.mkdir(parents=True, exist_ok=True)
-        path.write_text(json.dumps(content))
-    reader = tmp_path / "benchmark/readers/requests_seen.py"
-    reader.parent.mkdir(parents=True, exist_ok=True)
-    reader.write_text("def read(run):\n    return float(len(run.sent)) or None\n")
-    cell = "gpt2-tiny.trickle"
-    bench["configs"].append({"name": "gpt2-tiny", "source": "test", "reduced": [],
-                             "file": "benchmark/configs/gpt2-tiny.json", "why": "throw-away"})
-    bench["workloads"].append({"name": cell, "config": "gpt2-tiny", "traffic": "trickle",
+        path.write_text(content if isinstance(content, str) else json.dumps(content))
+    bench = json.load(open(os.path.join(CHECKOUT, "BENCHMARK.json")))
+    bench["configs"].append({"name": config, "source": "test", "reduced": [],
+                             "file": f"benchmark/configs/{config}.json", "why": "throw-away"})
+    bench["workloads"].append({"name": cell, "config": config, "traffic": "trickle",
                                "chips": 1, "why": "throw-away"})
     next(m for m in bench["end_to_end"] if m["name"] == "tpot_ms_p95")["workloads"].append(cell)
-    for name in ("requests_seen.trickle", "ttft_ms_p90.trickle"):
-        bench["per_layer"].append({"name": name, "unit": "ms", "better": "lower",
+    for name, unit in per_layer:
+        bench["per_layer"].append({"name": name, "unit": unit, "better": "lower",
                                    "source": "program_span", "layer": "serving.scheduler",
                                    "moves": "tpot_ms_p95", "workloads": [cell]})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
-    code = bench_run.main(["--workload", cell, "--seed", "3", "--seconds", "2", "--trace", "1",
-                           "--rehearse", "--overlay", str(tmp_path)])
-    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+
+
+def rehearse(capsys, tmp_path, cell, *extra):
+    code = bench_run.main(["--workload", cell, "--seed", "2147483659", "--seconds", "2",
+                           "--trace", "1", "--rehearse", "--overlay", str(tmp_path), *extra])
     assert code == bench_run.REHEARSAL_EXIT
+    return json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+
+
+def trickle():
+    chat = json.load(open(os.path.join(BENCH_DIR, "traffic", "chat-steady.json")))
+    return {**chat, **chat["rehearsal"], "rehearsal": {}}
+
+
+def test_a_throw_away_cell_runs_from_files_alone(tmp_path, capsys):
+    before = open(os.path.join(CHECKOUT, "BENCHMARK.json")).read()
+    xl = json.load(open(os.path.join(BENCH_DIR, "configs", "gpt2-xl.json")))
+    overlay(tmp_path, {
+        "benchmark/configs/gpt2-tiny.json": {**xl, **xl["rehearsal"], "rehearsal": {}},
+        "benchmark/traffic/trickle.json": {**trickle(),
+                                           "arrivals": {"process": "poisson", "rate_per_s": 3.0}},
+        "benchmark/metrics/requests_seen.trickle.json": {"reader": "requests_seen", "args": {}},
+        "benchmark/metrics/ttft_ms_p90.trickle.json": {
+            "reader": "result_percentile", "args": {"series": "ttft_ms", "q": 90}},
+        "benchmark/readers/requests_seen.py":
+            "def read(run):\n    return float(len(run.sent)) or None\n",
+    }, "gpt2-tiny", "gpt2-tiny.trickle", [("requests_seen.trickle", "ms"), ("ttft_ms_p90.trickle", "ms")])
+    line = rehearse(capsys, tmp_path, "gpt2-tiny.trickle")
     assert line["correct"] is True and line["attempted"] > 0 and line["metrics"] == {}
     assert line["notes"]["metrics_read"] == ["requests_seen.trickle", "ttft_ms_p90.trickle"]
-    # the real file's cells are as they were
-    assert json.dumps(json.load(open(os.path.join(CHECKOUT, "BENCHMARK.json")))["workloads"]) == before
+    # the real file is as it was
+    assert open(os.path.join(CHECKOUT, "BENCHMARK.json")).read() == before
 
 
 OTHER_FAMILY = '''
@@ -131,47 +140,22 @@ def decode_cost(cfg, lengths):
 
 
 def test_a_throw_away_family_runs_from_files_alone(tmp_path, capsys):
-    bench = json.load(open(os.path.join(CHECKOUT, "BENCHMARK.json")))
-    chat = json.load(open(os.path.join(BENCH_DIR, "traffic", "chat-steady.json")))
-    config = {"model": "other", "depth": 2, "width": 32, "heads": 4, "context": 64,
-              "vocab_size": 211, "dtype": "float32",
-              "serving": {"max_slots": 4, "max_len": 64, "kv_block_size": 8, "prefill_chunk": 8}}
-    files = {
-        "benchmark/configs/other-tiny.json": config,
-        "benchmark/traffic/trickle.json": {**chat, **chat["rehearsal"], "rehearsal": {}},
+    overlay(tmp_path, {
+        "benchmark/configs/other-tiny.json": {
+            "model": "other", "depth": 2, "width": 32, "heads": 4, "context": 64,
+            "vocab_size": 211, "dtype": "float32",
+            "serving": {"max_slots": 4, "max_len": 64, "kv_block_size": 8, "prefill_chunk": 8}},
+        "benchmark/traffic/trickle.json": trickle(),
         "benchmark/metrics/other_decode_roofline.json": {
             "reader": "serving_program_roofline",
             "args": {"program": "jit__paged_decode_impl", "kind": "decode"}},
         "benchmark/metrics/ttft_ms_p90.trickle.json": {
             "reader": "result_percentile", "args": {"series": "ttft_ms", "q": 90}},
-    }
-    for rel, content in files.items():
-        path = tmp_path / rel
-        path.parent.mkdir(parents=True, exist_ok=True)
-        path.write_text(json.dumps(content))
-    for kind, name, text in (("models", "other", OTHER_FAMILY), ("references", "other_reference", None)):
-        path = tmp_path / "benchmark" / kind / (name + ".py")
-        path.parent.mkdir(parents=True, exist_ok=True)
-        if text is None:
-            shutil.copy(os.path.join(BENCH_DIR, "references", "gpt2.py"), path)
-        else:
-            path.write_text(text)
-    cell = "other-tiny.trickle"
-    bench["configs"].append({"name": "other-tiny", "source": "test", "reduced": [],
-                             "file": "benchmark/configs/other-tiny.json", "why": "throw-away"})
-    bench["workloads"].append({"name": cell, "config": "other-tiny", "traffic": "trickle",
-                               "chips": 1, "why": "throw-away"})
-    next(m for m in bench["end_to_end"] if m["name"] == "tpot_ms_p95")["workloads"].append(cell)
-    for name, unit in (("other_decode_roofline", "%"), ("ttft_ms_p90.trickle", "ms")):
-        bench["per_layer"].append({"name": name, "unit": unit, "better": "lower",
-                                   "source": "program_span", "layer": "serving.scheduler",
-                                   "moves": "tpot_ms_p95", "workloads": [cell]})
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
-    code = bench_run.main(["--workload", cell, "--seed", "2147483659", "--seconds", "2",
-                           "--trace", "1", "--rehearse", "--control", "1",
-                           "--overlay", str(tmp_path)])
-    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert code == bench_run.REHEARSAL_EXIT
+        "benchmark/models/other.py": OTHER_FAMILY,
+        "benchmark/references/other_reference.py":
+            open(os.path.join(BENCH_DIR, "references", "gpt2.py")).read(),
+    }, "other-tiny", "other-tiny.trickle", [("other_decode_roofline", "%"), ("ttft_ms_p90.trickle", "ms")])
+    line = rehearse(capsys, tmp_path, "other-tiny.trickle", "--control", "1")
     assert line["correct"] is True and line["attempted"] > 0
     assert line["checks"]["logit_gap_mean"]["ok"] and line["notes"]["reference"]["tokens_compared"] > 0
     assert line["control_correct"] == {"float8_reference": False}
@@ -192,7 +176,7 @@ def test_the_generic_roofline_reads_the_familys_costs():
     steps = [{"t": 1.0 + i, "step_seconds": 0.5, "lane_lengths": [3 + i, 9]} for i in range(4)]
     run = SimpleNamespace(
         family=family, cfg=cfg, extra={}, peak={"kind": "TPU v5 lite"},
-        sink=SimpleNamespace(steps=steps), chunk_spans=[(2.2, 0, 8, 8), (9.0, 1, 0, 8)],
+        sink=SimpleNamespace(steps=steps), chunk_spans=[(2.2, 8, 8), (9.0, 0, 8)],
         capture=SimpleNamespace(marks={MARK_OPEN: 1.0, MARK_CLOSE: 4.0}),
         trace=SimpleNamespace(program_seconds=lambda p: 2e-9, calls=lambda p: [1e-9, 1e-9]))
     reader = Cell("gpt2-xl.chat-steady").module("readers", "serving_program_roofline")

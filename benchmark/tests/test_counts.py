@@ -24,7 +24,7 @@ def recorded(name):
     `lib/counts.py` made of the same run (`data/events_*.json`)."""
     rec = json.load(open(os.path.join(HERE, "tests", "data", name)))
     run = SimpleNamespace(sink=SimpleNamespace(steps=rec["steps"]),
-                          chunk_spans=[tuple(c) for c in rec["chunk_spans"]])
+                          chunk_spans=[(t, start, valid) for t, _, start, valid in rec["chunk_spans"]])
     return rec, run
 
 
