@@ -135,6 +135,15 @@ def warm_up(run: Serving, seed: int) -> None:
 
 
 def open_window(run: Serving) -> float:
+    """Set-up ends here. The heap as it stands (a quarter of a million
+    objects: the imports, the schedule, the warm-up's) is collected once and
+    frozen, so that a full pass of the interpreter's collector inside the
+    window scans what the window allocated and no more. Unfrozen, one such
+    pass falls in every window at the same count of allocations and holds
+    every live request for 90-120 ms (PERF.md, Findings, PR 29): a tail then
+    reads where that pass happens to land. The collector stays on."""
+    gc.collect()
+    gc.freeze()
     run.setup_s = process_age_s()
     return time.monotonic()
 
@@ -258,6 +267,7 @@ def reference_gaps(run: Serving, seed: int, sample: List[Sent], control: bool = 
 def free_program(run: Serving) -> None:
     """Drop the engine, its pool and its weights before the reference runs."""
     run.engine = None
+    gc.unfreeze()  # `open_window` froze the heap, the engine's cycles with it
     gc.collect()
 
 

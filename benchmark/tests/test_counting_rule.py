@@ -84,3 +84,21 @@ def test_recorded_events_count_as_the_wrappers_counted(name):
     assert steps == rec["parent"]["window_steps"] and len(chunks) == w.chunk_calls
     _, traced = serve.lengths_before_each_step(run, *rec["marks"])
     assert [list(c) for c in traced] == rec["parent"]["traced_chunks"] != []
+
+
+def test_the_window_opens_on_a_frozen_heap_and_the_reference_on_a_thawed_one():
+    """A full pass of the collector inside the window scans what the window
+    allocated and no more (PERF.md, section 2); the engine's cycles are
+    collectable again before the reference runs."""
+    import gc
+    from types import SimpleNamespace
+
+    run = SimpleNamespace(setup_s=0.0, engine=object())
+    thawed = gc.get_freeze_count()  # the interpreter keeps a few hundred of its own
+    try:
+        serve.open_window(run)
+        assert gc.get_freeze_count() > thawed + 1000 and gc.isenabled() and run.setup_s > 0
+        serve.free_program(run)
+        assert gc.get_freeze_count() == thawed and run.engine is None
+    finally:
+        gc.unfreeze()
