@@ -43,11 +43,12 @@ def registered_models():
 
 
 # Import for side effect: populate the registry.
-from elephas_tpu.models import mlp, cnn, resnet, lstm, transformer  # noqa: E402,F401
+from elephas_tpu.models import mlp, cnn, resnet, lstm, transformer, jamba  # noqa: E402,F401
 from elephas_tpu.models.mlp import MLP  # noqa: E402,F401
 from elephas_tpu.models.cnn import SimpleCNN  # noqa: E402,F401
 from elephas_tpu.models.resnet import ResNet18  # noqa: E402,F401
 from elephas_tpu.models.lstm import LSTMClassifier  # noqa: E402,F401
+from elephas_tpu.models.jamba import JambaLM  # noqa: E402,F401
 from elephas_tpu.models.transformer import (  # noqa: E402,F401
     TransformerLM,
     generate,

@@ -523,7 +523,10 @@ def make_paged_decode_cache(decode_module, max_slots: int, num_blocks: int,
     ``(max_slots,)`` vectors — positions are a property of the logical
     sequence, not of physical block placement — so the same flax apply
     drives both layouts: over gathered rows (prefill chunks,
-    speculative windows) or over the blocks themselves (``paged=``)."""
+    speculative windows) or over the blocks themselves (``paged=``).
+    A state leaf (``models.decode_cache``: a recurrence's or a
+    convolution's) is one row a slot, ``(max_slots, ...)``, never paged."""
+    from elephas_tpu.models.decode_cache import INDEX, KV, leaf_kind
     from elephas_tpu.ops.attention import pool_leaf_shape
 
     cache_shapes = jax.eval_shape(
@@ -533,16 +536,16 @@ def make_paged_decode_cache(decode_module, max_slots: int, num_blocks: int,
     )["cache"]
 
     def build(path, s):
-        name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
-        if name in ("cached_key", "cached_value"):
+        kind = leaf_kind(path)
+        if kind == KV:
             _, heads, _, head_dim = s.shape
             return jnp.zeros(
                 pool_leaf_shape(num_blocks, heads, block_size, head_dim),
                 s.dtype,
             )
-        if name in ("cache_index", "pos_index"):
+        if kind == INDEX:
             return jnp.zeros((max_slots,), jnp.int32)
-        return jnp.zeros(s.shape, s.dtype)
+        return jnp.zeros((max_slots,) + s.shape[1:], s.dtype)
 
     return jax.tree_util.tree_map_with_path(build, cache_shapes)
 

@@ -241,52 +241,56 @@ PAGED_BODIES = ("paged_pallas", "paged_xla")
 _PAGED_VMEM_BUDGET = 12 << 20
 
 
-def _paged_pallas_fits(pool_shape, dtype) -> bool:
+def _paged_pallas_fits(pool_shape, dtype, q_heads=None) -> bool:
     """Whether the kernel's tiles lower for this pool layout: rows a full
     lane width, whole sublane tiles a head (so ``(heads, r, lanes)`` folds
     to ``(heads * r, lanes)`` for free), and buffers that fit VMEM: two
     each of a K and a V block read and written back, and the float32
-    scores of one block and what the softmax makes of them."""
+    scores of one block and what the softmax makes of them. ``q_heads``:
+    the query heads, where they are more than the pool's K/V heads."""
     _, heads, rows, lanes = pool_shape
     itemsize = jnp.dtype(dtype).itemsize
     if lanes % _LANES or itemsize not in (2, 4) or rows % (32 // itemsize):
         return False
     block_bytes = heads * rows * lanes * itemsize
-    query_rows = (lanes // _LANES) * (-(-heads // 16) * 16)
+    query_rows = (lanes // _LANES) * (-(-(q_heads or heads) // 16) * 16)
     scores_bytes = 4 * query_rows * heads * rows
     return 8 * block_bytes + 4 * scores_bytes <= _PAGED_VMEM_BUDGET
 
 
-def paged_decode_body(pool_shape, dtype, mesh=None) -> str:
+def paged_decode_body(pool_shape, dtype, mesh=None, q_heads=None) -> str:
     """Name of the body ``paged_decode_attention`` runs for this backend,
     mesh and pool layout. A Pallas call is not partitioned by sharding
     annotations, so a mesh takes the XLA body."""
-    if _on_tpu() and mesh is None and _paged_pallas_fits(pool_shape, dtype):
+    if _on_tpu() and mesh is None and _paged_pallas_fits(pool_shape, dtype,
+                                                         q_heads):
         return "paged_pallas"
     return "paged_xla"
 
 
 def _paged_attend_xla(q, k_pool, v_pool, table, idx):
     """Plain body: gather one layer's blocks through the table and score
-    them in the block layout ``(slots, blocks, heads, bs, head_dim)``."""
-    slots, _, head_dim = q.shape
+    them in the block layout ``(slots, blocks, heads, bs, head_dim)``.
+    The query heads are ``g`` to a K/V head (1 for full multi-head)."""
+    slots, q_heads, head_dim = q.shape
     bps = table.shape[1]
     kb = _unpack(k_pool[table], head_dim)  # OOB ids clamp; masked below
     vb = _unpack(v_pool[table], head_dim)
-    bs = kb.shape[-2]
+    heads, bs = kb.shape[-3], kb.shape[-2]
     scores = jnp.einsum(
-        "shd,sjhbd->shjb", q, kb, preferred_element_type=jnp.float32
+        "shgd,sjhbd->shgjb", q.reshape(slots, heads, q_heads // heads, head_dim),
+        kb, preferred_element_type=jnp.float32,
     ) * (1.0 / (head_dim ** 0.5))
     cols = (jnp.arange(bps) * bs)[:, None] + jnp.arange(bs)[None, :]
     valid = cols[None] <= idx[:, None, None]  # (slots, bps, bs)
-    scores = jnp.where(valid[:, None], scores, jnp.finfo(jnp.float32).min)
-    flat = scores.reshape(slots, scores.shape[1], bps * bs)
+    scores = jnp.where(valid[:, None, None], scores, jnp.finfo(jnp.float32).min)
+    flat = scores.reshape(*scores.shape[:3], bps * bs)
     weights = jax.nn.softmax(flat, axis=-1).reshape(scores.shape)
     out = jnp.einsum(
-        "shjb,sjhbd->shd", weights.astype(vb.dtype), vb,
+        "shgjb,sjhbd->shgd", weights.astype(vb.dtype), vb,
         preferred_element_type=jnp.float32,
     )
-    return out.astype(q.dtype)
+    return out.reshape(q.shape).astype(q.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("body",))
@@ -294,9 +298,11 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, table, idx,
                            active, body: str):
     """One decode step's attention of one layer over the paged pool.
 
-    ``q``/``k_new``/``v_new``: (slots, heads, head_dim), the step's one
-    token per lane. ``k_pool``/``v_pool``: pool leaves (see the layout
-    above). ``table``: (slots, blocks_per_slot) block ids, unallocated
+    ``q``: (slots, q_heads, head_dim); ``k_new``/``v_new``: (slots,
+    heads, head_dim), the step's one token per lane. ``heads`` is the
+    pool's, and ``q_heads`` a multiple of it: with grouped heads the pool
+    holds the K/V heads the model has, never a copy a query head.
+    ``k_pool``/``v_pool``: pool leaves (see the layout above). ``table``: (slots, blocks_per_slot) block ids, unallocated
     entries out of range. ``idx``: (slots,) the column each lane writes,
     so lane ``s`` attends columns ``<= idx[s]`` of its blocks
     ``table[s, : idx[s] // bs + 1]`` (paged rows are never left-padded).

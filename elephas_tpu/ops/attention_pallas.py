@@ -489,7 +489,7 @@ def _paged_decode_kernel(
     o_ref, ko_ref, vo_ref,  # the lane's output; its tail block of each pool
     m_ref, l_ref, acc_ref,  # running max, sum, weighted values of one lane
     *, heads: int, rows: int, pack: int, head_dim: int, blocks_per_slot: int,
-    sm_scale: float,
+    sm_scale: float, q_per_kv: int = 1,
 ):
     """One live (lane, block) pair per grid step, a lane's blocks in
     order, folded into the lane's running softmax; at the lane's last
@@ -501,7 +501,10 @@ def _paged_decode_kernel(
     zeros elsewhere, so one ``(pack * rows, lanes) x (heads * r,
     lanes)^T`` matmul scores every column of the block: entry
     ``[(g, h), (h', r)]`` is column ``r * pack + g`` of head ``h'``, and
-    the entries with ``h' != h`` are masked away. The MXU takes one call
+    the entries with ``h' != h`` are masked away (with grouped heads,
+    ``q_per_kv`` query heads to a K/V head, ``h' != h // q_per_kv``: the
+    pool holds the K/V heads the model has and none is replicated). The
+    MXU takes one call
     a block where one-row calls, head by head, would starve it. Each
     ``g`` keeps its own running softmax over its columns; they merge at
     the tail, and lane group ``g`` of row ``(g, h)`` of the output then
@@ -541,7 +544,10 @@ def _paged_decode_kernel(
     row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     group = sum((row >= g * rows).astype(jnp.int32) for g in range(1, pack))
-    own = col - (row - group * rows) * r  # row of the block in this head
+    q_head = row - group * rows
+    kv_head = q_head if q_per_kv == 1 else sum(
+        (q_head >= k * q_per_kv).astype(jnp.int32) for k in range(1, heads))
+    own = col - kv_head * r  # row of the block in this query's K/V head
     live = (own >= 0) & (own < r) & (own * pack + group <= at)
     s = jnp.where(live, s, _MASKED)
     m = m_ref[...]
@@ -577,12 +583,14 @@ def pallas_paged_decode_attention(q, k_new, v_new, k_pool, v_pool, table,
     active lane's new K/V column goes into its tail block and its query
     attends over its blocks, columns ``<= idx``.
 
-    ``q``/``k_new``/``v_new``: (slots, heads, head_dim); ``k_pool``/
+    ``q``: (slots, q_heads, head_dim); ``k_new``/``v_new``: (slots, heads,
+    head_dim), ``q_heads`` a multiple of ``heads`` (grouped heads: query
+    head ``h`` reads K/V head ``h // (q_heads // heads)``); ``k_pool``/
     ``v_pool``: pool leaves (num_blocks, heads, r, pack * head_dim),
     ``pack`` columns to a row (``ops/attention.py``), aliased to the
     returned pools; ``table``: (slots, blocks_per_slot) int32; ``idx``:
     (slots,) the column each lane writes; ``active``: (slots,) bool.
-    Returns ``(out, k_pool, v_pool)``, ``out`` (slots, heads, head_dim)
+    Returns ``(out, k_pool, v_pool)``, ``out`` (slots, q_heads, head_dim)
     and zeros for an inactive lane.
 
     The grid runs over the live (lane, block) pairs only, lane by lane:
@@ -592,17 +600,17 @@ def pallas_paged_decode_attention(q, k_new, v_new, k_pool, v_pool, table,
     live is never moved and one compiled kernel serves every length. Of
     the pools only each active lane's tail block is written back.
     """
-    slots, heads, head_dim = q.shape
-    num_blocks, _, r, lanes = k_pool.shape
+    slots, q_heads, head_dim = q.shape
+    num_blocks, heads, r, lanes = k_pool.shape
     pack = lanes // head_dim
     block_size = r * pack
     blocks_per_slot = table.shape[1]
-    rows = -(-heads // 16) * 16  # whole sublane tiles for bf16 and f32
+    rows = -(-q_heads // 16) * 16  # whole sublane tiles for bf16 and f32
     dtype = k_pool.dtype
     # query row (g, h): head h's query in lane group g, zeros elsewhere
     qp = jnp.zeros((slots, pack, rows, pack, head_dim), dtype)
     for g in range(pack):
-        qp = qp.at[:, g, :heads, g].set(q.astype(dtype))
+        qp = qp.at[:, g, :q_heads, g].set(q.astype(dtype))
     qp = qp.reshape(slots, pack * rows, lanes)
     new = [jnp.tile(x.astype(dtype), (1, 1, pack))[:, :, None, :]
            for x in (k_new, v_new)]
@@ -620,7 +628,7 @@ def pallas_paged_decode_attention(q, k_new, v_new, k_pool, v_pool, table,
     kernel = functools.partial(
         _paged_decode_kernel, heads=heads, rows=rows, pack=pack,
         head_dim=head_dim, blocks_per_slot=blocks_per_slot,
-        sm_scale=1.0 / (head_dim ** 0.5),
+        sm_scale=1.0 / (head_dim ** 0.5), q_per_kv=q_heads // heads,
     )
     lane_rows = pl.BlockSpec(
         (1, pack * rows, lanes), lambda w, lane, *_: (lane[w], 0, 0)
@@ -665,6 +673,6 @@ def pallas_paged_decode_attention(q, k_new, v_new, k_pool, v_pool, table,
     )(lane, block, phys, tail, idx, qp, *new, k_pool, v_pool)
     # head h: the sum over g of lane group g of row (g, h)
     out = out.reshape(slots, pack, rows, pack, head_dim)
-    out = sum(out[:, g, :heads, g] for g in range(pack))
+    out = sum(out[:, g, :q_heads, g] for g in range(pack))
     # a lane the grid never visited holds whatever its output rows held
     return jnp.where(active[:, None, None], out, 0), k_pool, v_pool

@@ -127,10 +127,11 @@ def decode_cache_specs(cache, axis: str = MODEL_AXIS) -> Dict:
     advances every slot's write position identically.
     """
 
+    from elephas_tpu.models.decode_cache import KV, leaf_kind
+
     def spec(path, leaf):
-        name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
-        if name in ("cached_key", "cached_value"):
-            assert leaf.ndim == 4, f"{name}: expected rank-4, got {leaf.shape}"
+        if leaf_kind(path) == KV:
+            assert leaf.ndim == 4, f"{path}: expected rank-4, got {leaf.shape}"
             return P(None, axis, None, None)
         return P()
 

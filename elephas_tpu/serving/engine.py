@@ -61,6 +61,14 @@ import jax
 import jax.numpy as jnp
 
 from elephas_tpu import obs
+from elephas_tpu.models.decode_cache import (
+    INDEX,
+    KV,
+    first_index,
+    has_state,
+    leaf_kind,
+    leaves_of_kind,
+)
 from elephas_tpu.serving.kv_pool import KVCachePool, PagedKVPool
 from elephas_tpu.serving.metrics import ServingMetrics
 from elephas_tpu.serving.scheduler import (
@@ -251,6 +259,16 @@ class InferenceEngine:
                 )
             self.prefill_chunk = None
             self.pool = KVCachePool(self.decode_module, max_slots, max_len)
+        # What the engine has to know of the model it reads from the cache
+        # tree: a leaf that is neither K/V nor an index is per-slot state.
+        self.stateful = has_state(self.pool.cache)
+        if self.stateful and not paged:
+            raise ValueError(
+                "a model with per-slot state (a recurrence's, a "
+                "convolution's) is served through the paged pool: the "
+                "contiguous prefill left-pads its prompts, and padding run "
+                "through a recurrence is not invisible"
+            )
         self.spec = None
         if speculative:
             from elephas_tpu.serving.spec import (
@@ -412,9 +430,7 @@ class InferenceEngine:
         # Pre-advance cache index per lane (first leaf speaks for all):
         # the token sampled this step sits at pad-free position
         # idx - pad + 1.
-        flat = jax.tree_util.tree_flatten_with_path(cache)[0]
-        idx = next(leaf for path, leaf in flat
-                   if self._leaf_name(path) == "cache_index")
+        idx = first_index(cache)
         # Freshly-admitted lanes get their prefill first token here,
         # INSIDE the one compiled program — the pipelined scheduler
         # never materializes the token vector host-side.
@@ -432,10 +448,6 @@ class InferenceEngine:
         )
         return nxt, mutated["cache"]
 
-    @staticmethod
-    def _leaf_name(path) -> str:
-        return path[-1].key if hasattr(path[-1], "key") else str(path[-1])
-
     def _chunk_prefill_impl(self, params, cache, table, tokens, slot,
                             start, valid, rng):
         """One prompt CHUNK for one slot, through the paged pool: gather
@@ -450,7 +462,14 @@ class InferenceEngine:
         slot's cache index, stays causally invisible, and is overwritten
         by subsequent decode steps. ``slot``/``start``/``valid`` are
         traced — one compile covers every slot, chunk position, and
-        ragged tail."""
+        ragged tail.
+
+        A state leaf (``models.decode_cache``) is this slot's row alone:
+        handed to the module zeroed where the chunk is a prompt's first,
+        and written back as the module left it. Padding run through a
+        recurrence is not invisible, so a stateful module is told
+        ``valid`` and leaves the state after ``valid`` tokens; it then
+        also returns the one row of logits that is sampled."""
         self._prefill_traces += 1
         from elephas_tpu.utils.compiler import note_retrace
 
@@ -462,29 +481,32 @@ class InferenceEngine:
         )
 
         chunk_width = tokens.shape[1]
-        head_dim = self.decode_module.d_model // self.decode_module.num_heads
+        head_dim = self._kv_head_dim(cache)
         row = jax.lax.dynamic_index_in_dim(table, slot, axis=0,
                                            keepdims=False)
 
         def to_row(path, leaf):
-            name = self._leaf_name(path)
-            if name in ("cached_key", "cached_value"):
+            kind = leaf_kind(path)
+            if kind == KV:
                 return paged_to_contiguous(leaf, row[None], head_dim)
-            if name in ("cache_index", "pos_index"):
+            if kind == INDEX:
                 return jnp.full((1,), start, jnp.int32)
-            return leaf
+            own = jax.lax.dynamic_index_in_dim(leaf, slot, axis=0)
+            return jnp.where(start == 0, jnp.zeros_like(own), own)
 
         with jax.named_scope("kv_gather"):
             row_cache = jax.tree_util.tree_map_with_path(to_row, cache)
+        told = {"valid": valid[None]} if self.stateful else {}
         logits, mutated = self.decode_module.apply(
             {"params": params, "cache": row_cache},
             tokens,
             mutable=["cache"],
+            **told,
         )
         # The chunk's LAST VALID position predicts the first new token
         # (only the final chunk's sample is ever read).
-        last = jax.lax.dynamic_slice_in_dim(logits, valid - 1, 1,
-                                            axis=1)[:, 0]
+        last = logits[:, 0] if self.stateful else jax.lax.dynamic_slice_in_dim(
+            logits, valid - 1, 1, axis=1)[:, 0]
         # Paged rows are never left-padded, so the sampled token's
         # pad-free position is simply the prefilled depth start + valid.
         with jax.named_scope("sample"):
@@ -494,17 +516,21 @@ class InferenceEngine:
             )
 
         def back(path, pool_leaf, mut_leaf):
-            name = self._leaf_name(path)
-            if name in ("cached_key", "cached_value"):
+            kind = leaf_kind(path)
+            if kind == KV:
                 written = jax.lax.dynamic_slice_in_dim(
                     mut_leaf[0], start, chunk_width, axis=1
                 )
                 return scatter_prefill_columns(pool_leaf, row, start,
                                                written)
-            # Index vectors: this slot advances to its true prefilled
-            # depth (NOT start + chunk — the right-pad tail is garbage);
-            # every other slot's entry is untouched.
-            return pool_leaf.at[slot].set(start + valid)
+            if kind == INDEX:
+                # This slot advances to its true prefilled depth (NOT
+                # start + chunk — the right-pad tail is garbage); every
+                # other slot's entry is untouched.
+                return pool_leaf.at[slot].set(start + valid)
+            # State: this slot's row alone, as the module left it.
+            return jax.lax.dynamic_update_slice_in_dim(
+                pool_leaf, mut_leaf.astype(pool_leaf.dtype), slot, 0)
 
         with jax.named_scope("kv_scatter"):
             new_cache = jax.tree_util.tree_map_with_path(
@@ -531,10 +557,9 @@ class InferenceEngine:
         )
 
         # Pre-advance write column per lane (every layer advances in
-        # lockstep, so the first index leaf speaks for all).
-        flat = jax.tree_util.tree_flatten_with_path(cache)[0]
-        idx = next(leaf for path, leaf in flat
-                   if self._leaf_name(path) == "cache_index")
+        # lockstep, so the first index leaf speaks for all). A state
+        # leaf is stepped by its layer, for the active lanes only.
+        idx = first_index(cache)
         tokens = jnp.where(override_mask, override_vals, prev_tokens)
         logits, mutated = self.decode_module.apply(
             {"params": params, "cache": cache},
@@ -559,17 +584,20 @@ class InferenceEngine:
         for ``paged=False``."""
         if not self.paged:
             return "contiguous"
-        from elephas_tpu.ops.attention import (
-            paged_decode_body,
-            pool_leaf_shape,
-        )
+        from elephas_tpu.ops.attention import paged_decode_body
 
-        module = self.decode_module
-        shape = pool_leaf_shape(
-            self.pool.num_blocks, module.num_heads, self.pool.block_size,
-            module.d_model // module.num_heads,
-        )
-        return paged_decode_body(shape, module.dtype, self.mesh)
+        # the pool's own K/V leaf: its heads are the model's K/V heads,
+        # which grouped attention has fewer of than query heads
+        leaf = leaves_of_kind(self.pool.cache, KV)[0][1]
+        return paged_decode_body(leaf.shape, leaf.dtype, self.mesh,
+                                 q_heads=self.decode_module.num_heads)
+
+    def _kv_head_dim(self, cache) -> int:
+        """Width of a K/V head, from a pool leaf ``(blocks, heads, rows,
+        lanes)``: a block's ``rows * lanes`` values are ``block_size``
+        columns of it."""
+        _, _, rows, lanes = leaves_of_kind(cache, KV)[0][1].shape
+        return rows * lanes // self.pool.block_size
 
     def _next_rng(self):
         # Sampling keys derive from (base key, pad-free stream position)
@@ -648,6 +676,12 @@ class InferenceEngine:
             param_specs,
         )
 
+        if self.stateful:
+            raise NotImplementedError(
+                "shard_serving is not built for a model with per-slot "
+                "state: the state rows have no sharding rule, and the "
+                "scan's kernel is not partitioned by a mesh"
+            )
         if self._prefill_traces or self._decode_traces or \
                 self.pool.admitted_total:
             raise RuntimeError(
@@ -1092,6 +1126,8 @@ class InferenceEngine:
             out["kv_blocks_free"] = self.pool.free_blocks
             out["kv_blocks_total"] = self.pool.num_blocks
             out.update(self.pool.prefix_stats())
+            out.update(self.pool.state_signals())
+            out["state_resets"] = self.pool.state_resets
         if self.spec is not None:
             out.update(self.spec.stats())
         if len(self.costs.tenants()) > 0:

@@ -54,14 +54,23 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from elephas_tpu.models.decode_cache import (
+    INDEX,
+    KV,
+    STATE,
+    has_state,
+    leaf_kind,
+    leaves_of_kind,
+    state_bytes,
+)
+
 
 def _vectorize_indices(cache, max_slots: int):
     """Replace every scalar cache index leaf with a per-slot vector."""
 
     def fix(path, leaf):
-        name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
-        if name in ("cache_index", "pos_index"):
-            assert leaf.ndim == 0, f"{name} already vectorized?"
+        if leaf_kind(path) == INDEX:
+            assert leaf.ndim == 0, f"{path} already vectorized?"
             return jnp.zeros((max_slots,), jnp.int32)
         return leaf
 
@@ -213,16 +222,29 @@ class KVCachePool:
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _copy_block(cache, src, dst):
     """Copy physical block ``src`` over ``dst`` in every K/V leaf — the
-    device half of copy-on-write. Leaves are rank-distinguished: paged
-    K/V pools are rank 4, per-slot index vectors rank 1. The cache is
-    donated (one block copied in place, not a whole-pool copy)."""
+    device half of copy-on-write. The cache is donated (one block copied
+    in place, not a whole-pool copy)."""
 
-    def cp(leaf):
-        if leaf.ndim == 4:
+    def cp(path, leaf):
+        if leaf_kind(path) == KV:
             return leaf.at[dst].set(leaf[src])
         return leaf
 
-    return jax.tree_util.tree_map(cp, cache)
+    return jax.tree_util.tree_map_with_path(cp, cache)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _clear_state_row(cache, slot):
+    """Zero ``slot``'s row of every state leaf, in place on the donated
+    cache: a released slot keeps nothing of the request it served."""
+
+    def clear(path, leaf):
+        if leaf_kind(path) == STATE:
+            return jax.lax.dynamic_update_slice_in_dim(
+                leaf, jnp.zeros((1,) + leaf.shape[1:], leaf.dtype), slot, 0)
+        return leaf
+
+    return jax.tree_util.tree_map_with_path(clear, cache)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -230,17 +252,17 @@ def _write_imported_blocks(cache, ids, payload, slot, next_col):
     """Scatter imported handoff block data into the paged cache and set
     ``slot``'s index vectors to the handoff's write frontier — the device
     half of ``PagedKVPool.import_blocks``. ``payload`` is a tuple of
-    ``(n, heads, rows, lanes)`` block uploads, one per rank-4 K/V
+    ``(n, heads, rows, lanes)`` block uploads, one per K/V
     leaf in tree order; the cache is donated (n block rows written in
     place, not a whole-pool copy). Retraces per distinct block count —
     bounded by ``blocks_per_slot``, and warmed by the first handoffs."""
     it = iter(payload)
 
     def put(path, leaf):
-        if leaf.ndim == 4:
+        kind = leaf_kind(path)
+        if kind == KV:
             return leaf.at[ids].set(next(it).astype(leaf.dtype))
-        name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
-        if name in ("cache_index", "pos_index"):
+        if kind == INDEX:
             return leaf.at[slot].set(next_col.astype(leaf.dtype))
         return leaf
 
@@ -461,6 +483,13 @@ class PagedKVPool(KVCachePool):
         self._cache = make_paged_decode_cache(
             decode_module, max_slots, self.num_blocks, block_size
         )
+        # A model with per-slot state (a recurrence's, a convolution's):
+        # the state rows ride in the same cache tree, one a slot, and what
+        # rests on "a block of K/V is all there is to a prefix" is off or
+        # refused, by name (``_refuse_state``).
+        self.stateful = has_state(self._cache)
+        self.state_bytes = state_bytes(self._cache)
+        self.state_resets = 0  # rows zeroed: at admission and at release
         # Paged prompts are never left-padded (shared prefixes must land
         # at identical cache columns in every slot); the zero pad vector
         # keeps the decode_fn signature identical to the contiguous pool.
@@ -471,7 +500,10 @@ class PagedKVPool(KVCachePool):
                                 self.num_blocks)
         self._ref = np.zeros((self.num_blocks,), np.int64)
         self._free_blocks: List[int] = list(range(self.num_blocks))
-        self.prefix = PrefixCache(block_size) if prefix_cache else None
+        # With state, a resident block of K/V says nothing of the state the
+        # other layers held at its last column: nothing is adopted.
+        self.prefix = (PrefixCache(block_size)
+                       if prefix_cache and not self.stateful else None)
         # Lazy process-registry mirror (same latch-False idiom as
         # ServingMetrics): the fleet aggregator federates these from
         # /metrics scrapes without the pool knowing it's being watched.
@@ -611,10 +643,32 @@ class PagedKVPool(KVCachePool):
             "then the scheduler activates the slot"
         )
 
+    def _refuse_state(self, what: str) -> None:
+        if self.stateful:
+            raise NotImplementedError(
+                f"{what} is not built for a model with per-slot state: the "
+                "pool's blocks hold the K/V of its attention layers only, and "
+                "the recurrent and convolution state of the other layers "
+                "would be left behind"
+            )
+
+    def state_signals(self) -> dict:
+        """What the ``step`` event says of the state rows: a row is in use
+        from its slot's admission to its release."""
+        return {
+            "state_slots_in_use": self.active_count if self.stateful else 0,
+            "state_slots_total": self.max_slots if self.stateful else 0,
+            "state_bytes": self.state_bytes,
+        }
+
     def admit_prefix(self, slot: int, prompt: Sequence[int]) -> int:
         """Bind the longest resident prefix of ``prompt`` to ``slot``
         (bump refcounts, no device work, no prefill compute). Returns
-        the matched token count — prefill resumes at that column."""
+        the matched token count — prefill resumes at that column.
+        A stateful pool matches nothing: prefill starts at column 0, where
+        the chunk program hands the module a zeroed state row."""
+        if self.stateful:
+            self.state_resets += 1
         if self.prefix is None:
             return 0
         self._bill_slot(slot)  # close the zero-block window pre-bind
@@ -690,6 +744,7 @@ class PagedKVPool(KVCachePool):
         one writes, at which point ``ensure_writable`` copies just the
         written block. Returns the child slot id, or None when the pool
         is out of slots."""
+        self._refuse_state("fork_slot")
         if parent in self._free:
             raise ValueError(f"slot {parent} is free; nothing to fork")
         child = self.acquire()
@@ -741,6 +796,9 @@ class PagedKVPool(KVCachePool):
                 self._decref(int(b))  # host-ok: numpy table
         self.table.clear_row(slot)
         self._free.append(slot)
+        if self.stateful:
+            self.swap(_clear_state_row(self.cache, jnp.int32(slot)))
+            self.state_resets += 1
         self._mirror_push()
 
     # -- cross-tier KV handoff -----------------------------------------------
@@ -757,15 +815,12 @@ class PagedKVPool(KVCachePool):
     # ``set_slot_owner``) so cross-tier block-seconds never double-bill.
 
     def _kv_leaf_names(self) -> Tuple[List[str], List]:
-        """(names, leaves) of every rank-4 K/V leaf in tree order —
+        """(names, leaves) of every K/V leaf in tree order —
         the deterministic leaf enumeration both handoff sides share
         (same model config → same tree → same order)."""
-        names, leaves = [], []
-        for path, leaf in jax.tree_util.tree_flatten_with_path(self.cache)[0]:
-            if getattr(leaf, "ndim", 0) == 4:
-                names.append(jax.tree_util.keystr(path))
-                leaves.append(leaf)
-        return names, leaves
+        found = leaves_of_kind(self.cache, KV)
+        return ([jax.tree_util.keystr(path) for path, _ in found],
+                [leaf for _, leaf in found])
 
     def export_blocks(self, slot: int) -> Dict:
         """Gather ``slot``'s resident blocks into contiguous host
@@ -783,6 +838,7 @@ class PagedKVPool(KVCachePool):
         in-flight span."""
         from elephas_tpu.serving import host_sync
 
+        self._refuse_state("export_blocks (a cross-tier KV handoff)")
         if slot in self._free:
             raise ValueError(f"slot {slot} is free; nothing to export")
         row = self.table.rows[slot]
@@ -823,6 +879,7 @@ class PagedKVPool(KVCachePool):
         ``set_slot_owner`` (which opens the billing window the exporter
         closed). Raises ``ValueError`` on any structural mismatch —
         callers map that to the handoff reject path."""
+        self._refuse_state("import_blocks (a cross-tier KV handoff)")
         bs = self.block_size
         if slot in self._free:
             raise ValueError(f"slot {slot} is free; acquire it first")
@@ -919,8 +976,11 @@ class PagedKVPool(KVCachePool):
         if self.prefix is None:
             return {"prefix_hits": 0, "prefix_lookups": 0,
                     "prefix_hit_rate": None, "prefix_tokens_saved": 0,
-                    "prefix_evictions": 0, "prefix_resident": 0}
+                    "prefix_evictions": 0, "prefix_resident": 0,
+                    "prefix_cache": ("off: per-slot state" if self.stateful
+                                     else "off")}
         return {
+            "prefix_cache": "on",
             "prefix_hits": self.prefix.hits_total,
             "prefix_lookups": self.prefix.lookups_total,
             "prefix_hit_rate": self.prefix.hit_rate,

@@ -215,7 +215,9 @@ class ServingMetrics:
                     dispatch_s: float = 0.0, prefill_tokens: int = 0,
                     prefill_chunks: int = 0, lane_lengths=(),
                     kv_blocks_in_use: Optional[int] = None,
-                    kv_blocks_total: Optional[int] = None) -> None:
+                    kv_blocks_total: Optional[int] = None,
+                    state_slots_in_use: int = 0, state_slots_total: int = 0,
+                    state_bytes: int = 0) -> None:
         self.steps += 1
         self.tokens_emitted_total += tokens
         self.tokens_prefilled_total += prefill_tokens
@@ -240,6 +242,11 @@ class ServingMetrics:
                 lane_lengths=lane_lengths,
                 kv_blocks_in_use=kv_blocks_in_use,
                 kv_blocks_total=kv_blocks_total,
+                # per-slot state rows (a recurrence's, a convolution's)
+                # held beside the K/V blocks: none for a model without
+                state_slots_in_use=state_slots_in_use,
+                state_slots_total=state_slots_total,
+                state_bytes=state_bytes,
                 decode_attention=self.decode_attention,
             )
 

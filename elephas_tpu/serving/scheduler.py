@@ -1148,6 +1148,7 @@ class ContinuousBatchingScheduler:
                                   if self.paged else None),
                 kv_blocks_total=(self.pool.num_blocks
                                  if self.paged else None),
+                **(self.pool.state_signals() if self.paged else {}),
             )
         if self.load is not None:
             # Paged pools report BLOCK-granular KV pressure (free blocks

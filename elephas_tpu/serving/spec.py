@@ -65,6 +65,13 @@ import jax
 import jax.numpy as jnp
 
 from elephas_tpu import obs
+from elephas_tpu.models.decode_cache import (
+    INDEX,
+    KV,
+    first_index,
+    has_state,
+    leaf_kind,
+)
 
 __all__ = [
     "DraftSource",
@@ -73,17 +80,6 @@ __all__ = [
     "SpeculativeDecoder",
 ]
 
-
-def _leaf_name(path) -> str:
-    return path[-1].key if hasattr(path[-1], "key") else str(path[-1])
-
-
-def _first_index_leaf(cache):
-    """The (max_slots,) pre-advance cache index — every layer advances
-    in lockstep, so the first ``cache_index`` leaf speaks for all."""
-    flat = jax.tree_util.tree_flatten_with_path(cache)[0]
-    return next(leaf for path, leaf in flat
-                if _leaf_name(path) == "cache_index")
 
 
 def _renest(template, tree):
@@ -247,6 +243,14 @@ class SpeculativeDecoder:
     def __init__(self, engine, source: DraftSource, gamma: int = 4):
         if gamma < 1:
             raise ValueError(f"gamma must be >= 1, got {gamma}")
+        if has_state(engine.pool.cache):
+            raise NotImplementedError(
+                "speculative decoding is not built for a model with "
+                "per-slot state: a verify window runs its draft tokens "
+                "through the recurrence, and a rejected draft cannot be "
+                "rolled back out of the state (K/V past the accepted "
+                "column is merely never read)"
+            )
         self.engine = engine
         self.source = source
         self.gamma = int(gamma)  # host-ok: constructor arg
@@ -266,7 +270,7 @@ class SpeculativeDecoder:
             )
 
             def vectorize(path, leaf):
-                if _leaf_name(path) in ("cache_index", "pos_index"):
+                if leaf_kind(path) == INDEX:
                     return jnp.zeros((pool.max_slots,), jnp.int32)
                 return leaf
 
@@ -372,12 +376,12 @@ class SpeculativeDecoder:
         note_retrace("serving_draft", count=self.draft_traces)
         from elephas_tpu.ops.attention import paged_to_contiguous
 
-        idx0 = _first_index_leaf(cache)
+        idx0 = first_index(cache)
         module = self.engine.decode_module
         head_dim = module.d_model // module.num_heads
 
         def to_contig(path, leaf):
-            if _leaf_name(path) in ("cached_key", "cached_value"):
+            if leaf_kind(path) == KV:
                 return paged_to_contiguous(leaf, table, head_dim)
             return leaf
 
@@ -409,10 +413,10 @@ class SpeculativeDecoder:
 
         note_retrace("serving_draft", count=self.draft_traces)
 
-        idx0 = _first_index_leaf(cache)
+        idx0 = first_index(cache)
 
         def reset_idx(path, leaf):
-            if _leaf_name(path) in ("cache_index", "pos_index"):
+            if leaf_kind(path) == INDEX:
                 return idx0
             return leaf
 
@@ -439,11 +443,11 @@ class SpeculativeDecoder:
                      count=self.draft_prefill_traces)
 
         def to_row(path, leaf):
-            name = _leaf_name(path)
-            if name in ("cached_key", "cached_value"):
+            kind = leaf_kind(path)
+            if kind == KV:
                 return jax.lax.dynamic_index_in_dim(leaf, slot, axis=0,
                                                     keepdims=True)
-            if name in ("cache_index", "pos_index"):
+            if kind == INDEX:
                 return jnp.full((1,), start, jnp.int32)
             return leaf
 
@@ -454,8 +458,8 @@ class SpeculativeDecoder:
         )
 
         def back(path, leaf, mut):
-            name = _leaf_name(path)
-            if name in ("cached_key", "cached_value"):
+            kind = leaf_kind(path)
+            if kind == KV:
                 return jax.lax.dynamic_update_slice(
                     leaf, mut.astype(leaf.dtype), (slot, 0, 0, 0)
                 )
@@ -488,11 +492,11 @@ class SpeculativeDecoder:
 
         eng = self.engine
         W = self.gamma + 1
-        idx0 = _first_index_leaf(cache)
+        idx0 = first_index(cache)
         head_dim = eng.decode_module.d_model // eng.decode_module.num_heads
 
         def to_contig(path, leaf):
-            if _leaf_name(path) in ("cached_key", "cached_value"):
+            if leaf_kind(path) == KV:
                 return paged_to_contiguous(leaf, table, head_dim)
             return leaf
 
@@ -515,7 +519,7 @@ class SpeculativeDecoder:
         frontier = jnp.where(active_mask, idx0 + accepted + 1, idx0)
 
         def back(path, pool_leaf, mut_leaf):
-            if _leaf_name(path) in ("cached_key", "cached_value"):
+            if leaf_kind(path) == KV:
                 return scatter_spec_columns(
                     pool_leaf, mut_leaf, table, idx0, W, active_mask
                 )

@@ -175,6 +175,60 @@ def test_paged_decode_program_temporaries_for_v5e(one_chip, monkeypatch):
     assert memory.alias_size_in_bytes >= pool_bytes
 
 
+# The long-document cell (benchmark/configs/jamba2-3b.json): 20 query heads
+# on 1 K/V head of 128, KV blocks of 64 columns, 32 slots of 72 blocks;
+# chunks of 512 tokens through 5,120 channels of 16 states.
+_HYBRID = dict(q_heads=20, heads=1, head_dim=128, block_size=64, slots=32,
+               blocks_per_slot=72, chunk=512, d_inner=5120, d_state=16)
+
+
+def test_grouped_paged_decode_kernel_compiles_for_v5e(one_chip):
+    """The decode attention kernel with grouped heads at the cell's shapes:
+    the pool holds the model's one K/V head, and all 20 query heads score
+    a block in one call."""
+    from elephas_tpu.ops.attention import _paged_pallas_fits, pool_leaf_shape
+    from elephas_tpu.ops.attention_pallas import pallas_paged_decode_attention
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    h = _HYBRID
+    pool = pool_leaf_shape(h["slots"] * h["blocks_per_slot"], h["heads"],
+                           h["block_size"], h["head_dim"])
+    assert pool[1:] == (1, 64, 128)
+    assert _paged_pallas_fits(pool, jnp.bfloat16, q_heads=h["q_heads"])
+    compiled = jax.jit(
+        pallas_paged_decode_attention, donate_argnums=(3, 4)
+    ).lower(
+        arg((h["slots"], h["q_heads"], h["head_dim"]), jnp.bfloat16),
+        arg((h["slots"], h["heads"], h["head_dim"]), jnp.bfloat16),
+        arg((h["slots"], h["heads"], h["head_dim"]), jnp.bfloat16),
+        arg(pool, jnp.bfloat16), arg(pool, jnp.bfloat16),
+        arg((h["slots"], h["blocks_per_slot"]), jnp.int32),
+        arg((h["slots"],), jnp.int32), arg((h["slots"],), jnp.bool_),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * 2 * np.prod(pool)
+
+
+def test_selective_scan_kernel_compiles_for_v5e(one_chip):
+    """The scan's kernel at a chunk of the cell: the state in registers,
+    `B` and `C` in SMEM, and nothing of `(T, d_inner, d_state)` anywhere:
+    its temporaries are nothing beside the 336 MB the naive form writes."""
+    from elephas_tpu.ops.selective_scan import _pallas_fits, pallas_selective_scan
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    T, d, n = _HYBRID["chunk"], _HYBRID["d_inner"], _HYBRID["d_state"]
+    assert _pallas_fits(1, T, d, n)
+    compiled = jax.jit(pallas_selective_scan).lower(
+        arg(1, T, d), arg(1, T, d), arg(n, d), arg(1, T, n), arg(1, T, n), arg(d),
+        arg(1, n, d)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20
+
+
 @pytest.mark.slow  # a 43 s compile here; tier-1 is cut by its clock (ROADMAP D0)
 def test_resnet18_train_step_compiles_for_v5e(one_chip):
     """The jitted ResNet-18 step at batch 512 bf16 — ``chip_smoke.py``'s

@@ -330,7 +330,7 @@ def test_chunked_prefill_eos_stop_identity(compiled):
                         prefill_chunk=2, prefill_chunks_per_step=1)
     res = eng.result(eng.submit([5, 3, 9], max_new_tokens=10), timeout_s=120)
     assert res.status == "completed"
-    assert res.tokens == free[:4]
+    assert res.tokens == free[:free.index(stop) + 1]  # its first occurrence
     assert eng.pool.free_count == eng.pool.max_slots
     eng.pool.assert_block_invariants()
 
@@ -434,7 +434,11 @@ def test_paged_shard_serving_refuses_warm_engine(compiled):
 # layout's kind), four to a 32-lane row, one column a row, and a block
 # size that packs nothing.
 _LAYOUTS = {"pack2": (3, 8, 64), "pack4": (5, 4, 8), "pack1": (2, 8, 128),
-            "odd": (3, 5, 8)}
+            "odd": (3, 5, 8),
+            # grouped heads: 20 query heads on the pool's one K/V head of
+            # 128, and 6 on 2 with two columns to a row
+            "grouped20on1": (1, 8, 128), "grouped6on2": (2, 8, 64)}
+_QUERY_HEADS = {"grouped20on1": 20, "grouped6on2": 6}
 _BPS, _BLOCKS = 4, 10  # blocks per slot; physical blocks (id 10 is unallocated)
 
 
@@ -479,14 +483,15 @@ def test_paged_decode_attention_matches_dense(case, body, layout):
     from elephas_tpu.ops.attention_pallas import pallas_paged_decode_attention
 
     heads, bs, d = _LAYOUTS[layout]
+    q_heads = _QUERY_HEADS.get(layout, heads)
     table, idx, active = _paged_case(case, bs)
     slots = table.shape[0]
     rng = np.random.default_rng(7)
     shape = pool_leaf_shape(_BLOCKS, heads, bs, d)
     k_pool, v_pool = (jnp.asarray(rng.normal(size=shape), jnp.float32)
                       for _ in range(2))
-    q, k_new, v_new = (jnp.asarray(rng.normal(size=(slots, heads, d)),
-                                   jnp.float32) for _ in range(3))
+    q, k_new, v_new = (jnp.asarray(rng.normal(size=(slots, h, d)), jnp.float32)
+                       for h in (q_heads, heads, heads))
     if body == "paged_pallas":  # the kernel, interpreted on the CPU
         out, k_after, v_after = pallas_paged_decode_attention(
             q, k_new, v_new, k_pool, v_pool, table, idx, active,
@@ -509,10 +514,12 @@ def test_paged_decode_attention_matches_dense(case, body, layout):
             continue
         np.testing.assert_array_equal(k_got[s, :, :live], k_want[s, :, :live])
         np.testing.assert_array_equal(v_got[s, :, :live], v_want[s, :, :live])
-        scores = np.einsum("hd,hkd->hk", q[s], k_want[s, :, :live]) / np.sqrt(d)
+        # query head h reads K/V head h // (q_heads // heads)
+        kv_of = np.arange(q_heads) // (q_heads // heads)
+        scores = np.einsum("hd,hkd->hk", q[s], k_want[s, kv_of, :live]) / np.sqrt(d)
         weights = np.exp(scores - scores.max(-1, keepdims=True))
         weights /= weights.sum(-1, keepdims=True)
-        want = np.einsum("hk,hkd->hd", weights, v_want[s, :, :live])
+        want = np.einsum("hk,hkd->hd", weights, v_want[s, kv_of, :live])
         np.testing.assert_allclose(np.asarray(out[s]), want,
                                    rtol=2e-5, atol=2e-5)
     # only the active lanes' tail blocks differ from the pool as it was

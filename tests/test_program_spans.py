@@ -67,15 +67,13 @@ def compiled():
     )
 
 
-def _serve(compiled, tracer, clock=time.monotonic, wrap=None):
+def _serve(compiled, tracer, clock=time.monotonic):
     """Every prompt through a 3-slot paged engine, one chunk a step."""
     sink = ListSink()
     eng = InferenceEngine(compiled, max_slots=3, max_prompt_len=12, max_len=32,
                           queue_depth=8, kv_block_size=4, prefill_chunk=4,
                           prefill_chunks_per_step=1, prefix_cache=False,
                           sink=sink, clock=clock, tracer=tracer)
-    if wrap is not None:
-        wrap(eng)
     ids = [eng.submit(p, max_new_tokens=5 + i, stop_token=None)
            for i, p in enumerate(PROMPTS)]
     results = [eng.result(rid, timeout_s=120) for rid in ids]
@@ -141,29 +139,53 @@ def test_step_counters_conserve_tokens(traced):
     assert max(s["kv_blocks_in_use"] for s in steps) >= 3 * 3
 
 
-def test_lane_lengths_match_the_benchmarks_wrappers(compiled):
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark")
-    sys.path.insert(0, bench)
-    try:
-        from lib import serve
-        from lib.window import ChunkCounter
-    finally:
-        sys.path.remove(bench)
-    run = SimpleNamespace()
+def _family(name):
+    """A tiny model of each family the engine serves: K/V alone, and K/V
+    of one layer in four beside the recurrent and convolution state of the
+    others."""
+    sizes = dict(vocab_size=VOCAB, d_model=32, num_heads=4, max_seq_len=SEQ)
+    if name == "jamba_lm":
+        sizes.update(num_layers=4, num_kv_heads=1, d_ff=64, d_state=4, dt_rank=8,
+                     attn_period=4, attn_offset=1)
+    else:
+        sizes.update(num_layers=2)
+    return CompiledModel(
+        get_model(name, **sizes), optimizer={"name": "adam", "learning_rate": 3e-3},
+        loss="sparse_categorical_crossentropy", metrics=[],
+        input_shape=(SEQ,), input_dtype=jnp.int32, seed=0)
 
-    def wrap(eng):
-        run.chunks = ChunkCounter(eng.scheduler.chunk_prefill_fn, time.monotonic)
-        run.decodes = serve.DecodeCounter(eng.scheduler.decode_fn, time.monotonic)
-        eng.scheduler.chunk_prefill_fn = run.chunks
-        eng.scheduler.decode_fn = run.decodes
 
-    _, sink, _ = _serve(compiled, obs.NULL_TRACER, wrap=wrap)
-    rebuilt, chunks = serve.lengths_before_each_step(run, 0.0, float("inf"))
-    ours = [s["lane_lengths"] for s in sink.steps if s["lane_lengths"]]
-    assert ours == rebuilt and len(ours) > 5
-    assert sum(s["prefill_tokens"] for s in sink.steps) == sum(v for _, v in chunks)
+@pytest.mark.parametrize("family", ["transformer_lm", "jamba_lm"])
+def test_step_events_and_chunk_spans_say_what_the_engine_was_asked(family):
+    """What the benchmark counts from: the `step` events' `prefill_tokens`,
+    `prefill_chunks` and `lane_lengths` and the `step/prefill_chunk` spans
+    agree with the prompts and answers the engine was asked for, whatever
+    the model carries through its steps."""
+    clock = TickClock()
+    tracer = Tracer(capacity=1 << 16, clock=clock, annotate_device=False)
+    eng, sink, results = _serve(_family(family), tracer, clock)
+    asked = [(len(p), 5 + i) for i, p in enumerate(PROMPTS)]
+    assert [len(r.tokens) for r in results] == [n for _, n in asked]
+    assert sum(s["prefill_tokens"] for s in sink.steps) == sum(n for n, _ in asked)
+    chunks = sorted((e.args["start"], e.args["valid"]) for e in tracer.events()
+                    if e.name == "step/prefill_chunk")
+    assert chunks == sorted((at, min(4, n - at)) for n, _ in asked
+                            for at in range(0, n, 4))
+    assert sum(s["prefill_chunks"] for s in sink.steps) == len(chunks)
+    # a prompt of n columns answered with m tokens decodes from n, n + 1,
+    # ... n + m - 2 columns (its first token is its prefill's); the step in
+    # flight when it finishes may hold it once more
+    held = sorted(c for s in sink.steps for c in s["lane_lengths"])
+    needed = sorted(c for n, m in asked for c in range(n, n + m - 1))
+    spare = list(held)
+    for c in needed:
+        spare.remove(c)
+    assert len(spare) <= len(asked) and set(spare) <= {n + m - 1 for n, m in asked}
     assert all(len(s["lane_lengths"]) <= 3 for s in sink.steps)
+    rows = 3 if family == "jamba_lm" else 0
+    assert {s["state_slots_total"] for s in sink.steps} == {rows}
+    assert max(s["state_slots_in_use"] for s in sink.steps) == rows
+    assert all((s["state_bytes"] > 0) == bool(rows) for s in sink.steps)
 
 
 def test_result_carries_queue_prefill_and_token_times(traced):

@@ -123,7 +123,8 @@ def test_identity_eos_stop(compiled):
     ]
     pipelined, sync = _run_both(compiled, script, stop_token=stop)
     _assert_identical(pipelined, sync)
-    assert pipelined["a"].tokens == free[:4]  # stopped at EOS inclusive
+    # stopped at EOS inclusive: its first occurrence in the free stream
+    assert pipelined["a"].tokens == free[:free.index(stop) + 1]
 
 
 def test_identity_mid_decode_admission(compiled):
