@@ -19,7 +19,10 @@ by the leaf's name:
 
 from __future__ import annotations
 
+from typing import Any, NamedTuple
+
 import jax
+import jax.numpy as jnp
 
 KV, INDEX, STATE = "kv", "index", "state"
 
@@ -57,3 +60,58 @@ def first_index(cache):
     first ``cache_index`` leaf speaks for all."""
     return next(leaf for path, leaf in leaves_of_kind(cache, INDEX)
                 if leaf_name(path) == "cache_index")
+
+
+class PagedDecode(NamedTuple):
+    """What an apply over the serving pool's physical blocks needs beside
+    the cache: the (rows, blocks_per_slot) block ``table`` of the rows it
+    is given (every slot for a decode step, the one slot of a prefill
+    chunk), the name of the attention ``body``
+    (``ops.attention.PAGED_BODIES``) and, for a chunk, whether it is
+    ``aligned``: it starts at a block's first column and is whole blocks
+    wide, so its columns are written as blocks."""
+
+    table: Any
+    body: str
+    aligned: bool = False
+
+
+def attend_paged(q, k, v, cached_key, cached_value, cache_index, active,
+                 paged: PagedDecode):
+    """A decode module's attention where its K/V variables ARE the pool's
+    physical blocks: the new columns go into their blocks and the queries
+    attend through the block table, no contiguous row is ever built.
+    ``q``: (rows, q_heads, T, head_dim), ``k``/``v``: (rows, heads, T,
+    head_dim). Under an ``active`` mask it is a decode step, one token a
+    lane (``ops.attention.paged_decode_attention``); without one, a
+    prefill chunk of one slot, ``T`` tokens from column ``cache_index``
+    on (``paged_chunk_attention``). Returns (rows, q_heads, T, head_dim)
+    and leaves the three variables as the step left them."""
+    from elephas_tpu.ops.attention import (
+        paged_chunk_attention,
+        paged_decode_attention,
+    )
+
+    rows, _, T, _ = q.shape
+    idx = cache_index.value
+    pools = (cached_key.value, cached_value.value)
+    if active is not None:
+        if T != 1:
+            raise ValueError("a paged decode step attends one token per lane; "
+                             "speculative windows gather their rows")
+        out, *pools = paged_decode_attention(
+            q[:, :, 0], k[:, :, 0], v[:, :, 0], *pools, paged.table, idx,
+            active, paged.body)
+        cache_index.value = jnp.where(active, idx + 1, idx)
+        out = out[:, :, None, :]
+    else:
+        if rows != 1:
+            raise ValueError("a prefill chunk over the paged pool is one "
+                             f"slot's; got {rows} rows")
+        out, *pools = paged_chunk_attention(
+            q[0], k[0], v[0], *pools, paged.table[0], idx[0], paged.body,
+            paged.aligned)
+        cache_index.value = idx + T
+        out = out[None]
+    cached_key.value, cached_value.value = pools
+    return out

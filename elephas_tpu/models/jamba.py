@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from elephas_tpu.models import register_model
+from elephas_tpu.models.decode_cache import attend_paged
 
 
 class RMSNorm(nn.Module):
@@ -192,10 +193,7 @@ class GroupedAttention(nn.Module):
         it for the serving pool: rows never left-padded, a write column a
         row (a scalar in a fresh cache), and over the paged pool in place
         where ``paged`` says so."""
-        from elephas_tpu.ops.attention import (
-            cache_attention_mask,
-            paged_decode_attention,
-        )
+        from elephas_tpu.ops.attention import cache_attention_mask
 
         b, _, T, hd = q.shape
         init_pass = not self.has_variable("cache", "cached_key")
@@ -210,14 +208,8 @@ class GroupedAttention(nn.Module):
         idx = cache_index.value
         k, v = k.astype(self.dtype), v.astype(self.dtype)
         if paged is not None:
-            if T != 1 or active is None:
-                raise ValueError("paged decode attends one token per lane under "
-                                 "an active mask; prefill chunks gather their rows")
-            out, cached_key.value, cached_value.value = paged_decode_attention(
-                q[:, :, 0], k[:, :, 0], v[:, :, 0], cached_key.value,
-                cached_value.value, paged.table, idx, active, paged.body)
-            cache_index.value = jnp.where(active, idx + 1, idx)
-            return out[:, :, None, :]
+            return attend_paged(q, k, v, cached_key, cached_value, cache_index,
+                                active, paged)
         scalar = idx.ndim == 0
         idx = jnp.broadcast_to(idx, (b,))
         write = jax.vmap(lambda cache, new, i: jax.lax.dynamic_update_slice(

@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, NamedTuple, Optional
+from typing import Any, Optional
 
 import flax.linen as nn
 import jax
@@ -30,15 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from elephas_tpu.models import register_model
-
-
-class PagedDecode(NamedTuple):
-    """What a decode apply over the serving pool's physical blocks needs
-    beside the cache: the (slots, blocks_per_slot) block ``table`` and the
-    name of the attention ``body`` (``ops.attention.PAGED_BODIES``)."""
-
-    table: Any
-    body: str
+from elephas_tpu.models.decode_cache import attend_paged
 
 
 def dense_causal_attention(q, k, v):
@@ -139,10 +131,11 @@ class SelfAttention(nn.Module):
         pool lifetime, and without the freeze their index vectors march
         past ``max_len`` while nothing is admitted. ``paged``
         (``PagedDecode``) — serving's paged pool only — says the K/V
-        variables ARE the pool's physical blocks: the step's one column
-        is written into its block and the query attends through the
-        block table (``ops.attention.paged_decode_attention``), no
-        contiguous row is ever built. Training never touches this path
+        variables ARE the pool's physical blocks: a decode step's one
+        column a lane, or a prefill chunk's columns of one slot, go into
+        their blocks and the queries attend through the block table
+        (``decode_cache.attend_paged``), no contiguous row is ever
+        built. Training never touches this path
         — it exists for ``generate`` and ``serving``."""
         b, h, seq, head_dim = q.shape
         init_pass = not self.has_variable("cache", "cached_key")
@@ -162,22 +155,9 @@ class SelfAttention(nn.Module):
             # zeroed at the full length.
             out = dense_causal_attention(q, k, v)
         elif paged is not None:
-            from elephas_tpu.ops.attention import paged_decode_attention
-
-            if seq != 1 or active is None:
-                raise ValueError(
-                    "paged decode attends one token per lane under an "
-                    "active mask; prefill chunks and speculative windows "
-                    "gather their rows"
-                )
-            idx = cache_index.value
-            out, cached_key.value, cached_value.value = paged_decode_attention(
-                q[:, :, 0], k[:, :, 0].astype(self.dtype),
-                v[:, :, 0].astype(self.dtype), cached_key.value,
-                cached_value.value, paged.table, idx, active, paged.body,
-            )
-            cache_index.value = jnp.where(active, idx + 1, idx)
-            out = out[:, :, None, :]
+            out = attend_paged(q, k.astype(self.dtype), v.astype(self.dtype),
+                               cached_key, cached_value, cache_index, active,
+                               paged)
         else:
             from elephas_tpu.ops.attention import cache_attention_mask
 

@@ -268,26 +268,30 @@ def paged_decode_body(pool_shape, dtype, mesh=None, q_heads=None) -> str:
     return "paged_xla"
 
 
-def _paged_attend_xla(q, k_pool, v_pool, table, idx):
-    """Plain body: gather one layer's blocks through the table and score
-    them in the block layout ``(slots, blocks, heads, bs, head_dim)``.
-    The query heads are ``g`` to a K/V head (1 for full multi-head)."""
-    slots, q_heads, head_dim = q.shape
+def _paged_attend_xla(q, k_pool, v_pool, table, last):
+    """Plain body of both paged attentions: gather one layer's blocks
+    through the table and score them in the block layout ``(rows, blocks,
+    heads, bs, head_dim)``. ``q``: (rows, q_heads, Q, head_dim), ``Q``
+    queries a table row, ``g`` query heads to a K/V head (1 for full
+    multi-head); ``last``: (rows, Q) the last column each query attends."""
+    rows, q_heads, count, head_dim = q.shape
     bps = table.shape[1]
     kb = _unpack(k_pool[table], head_dim)  # OOB ids clamp; masked below
     vb = _unpack(v_pool[table], head_dim)
     heads, bs = kb.shape[-3], kb.shape[-2]
     scores = jnp.einsum(
-        "shgd,sjhbd->shgjb", q.reshape(slots, heads, q_heads // heads, head_dim),
-        kb, preferred_element_type=jnp.float32,
+        "shgqd,sjhbd->shgqjb",
+        q.reshape(rows, heads, q_heads // heads, count, head_dim), kb,
+        preferred_element_type=jnp.float32,
     ) * (1.0 / (head_dim ** 0.5))
     cols = (jnp.arange(bps) * bs)[:, None] + jnp.arange(bs)[None, :]
-    valid = cols[None] <= idx[:, None, None]  # (slots, bps, bs)
-    scores = jnp.where(valid[:, None, None], scores, jnp.finfo(jnp.float32).min)
-    flat = scores.reshape(*scores.shape[:3], bps * bs)
+    valid = cols <= last[:, :, None, None]  # (rows, Q, bps, bs)
+    scores = jnp.where(valid[:, None, None], scores,
+                       jnp.finfo(jnp.float32).min)
+    flat = scores.reshape(*scores.shape[:4], bps * bs)
     weights = jax.nn.softmax(flat, axis=-1).reshape(scores.shape)
     out = jnp.einsum(
-        "shgjb,sjhbd->shgd", weights.astype(vb.dtype), vb,
+        "shgqjb,sjhbd->shgqd", weights.astype(vb.dtype), vb,
         preferred_element_type=jnp.float32,
     )
     return out.reshape(q.shape).astype(q.dtype)
@@ -331,7 +335,121 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, table, idx,
         k_pool = _write_windows(k_pool, table, idx, k_new[:, :, None], active)
         v_pool = _write_windows(v_pool, table, idx, v_new[:, :, None], active)
     with jax.named_scope("paged_attention"):
-        out = _paged_attend_xla(q, k_pool, v_pool, table, idx)
+        out = _paged_attend_xla(q[:, :, None], k_pool, v_pool, table,
+                                idx[:, None])[:, :, 0]
+    return out, k_pool, v_pool
+
+
+# -- paged chunk attention: a prefill chunk over the pool in place ------------
+#
+# A prefill chunk of ONE slot never builds the slot's contiguous row
+# either. Per layer, (a) the chunk's K/V columns go into the blocks they
+# land in (block-grained, ``scatter_prefill_columns``) and (b) the chunk's
+# queries attend the slot's live blocks through its table row, query ``i``
+# over columns ``<= start + i``. The same two bodies, chosen the same way.
+
+
+def _chunk_tiles(heads, block_size, chunk):
+    """Tile sizes of the chunk kernel, from the shapes alone: K/V heads a
+    grid step, queries a tile, blocks a grid step. A step covers as many
+    columns as the chunk is wide and 256 at the least: a grid step costs
+    about as much as a head's work in it, and what a wider step scores
+    past the chunk's last column is dead (on the chip, microseconds a call
+    at ``start`` 0 / the longest: chunks of 128 on 25 heads, 256 columns
+    a step 24 / 60, 512 32 / 51, 128 23 / 99; chunks of 512 on one K/V
+    head of 20 query heads, 512 columns 56 / 319, 256 88 / 568)."""
+    head_group = max(n for n in range(1, 9) if heads % n == 0)
+    tile = max(n for n in range(1, min(chunk, 512) + 1) if chunk % n == 0)
+    return head_group, tile, max(1, max(256, min(chunk, 512)) // block_size)
+
+
+def _paged_chunk_fits(pool_shape, dtype, head_dim, chunk) -> bool:
+    """Whether the chunk kernel's tiles lower for this pool layout and
+    chunk width: rows a full lane width, whole sublane tiles a block and
+    a query tile, and buffers inside the VMEM budget."""
+    _, heads, rows, lanes = pool_shape
+    itemsize = jnp.dtype(dtype).itemsize
+    sublanes = 32 // itemsize
+    pack = lanes // head_dim
+    head_group, tile, blocks = _chunk_tiles(heads, rows * pack, chunk)
+    if (lanes % _LANES or itemsize not in (2, 4) or rows % sublanes
+            or tile % sublanes):
+        return False
+    query_rows = pack * tile
+    moved = (2 * head_group * query_rows * lanes  # queries in, output back
+             + 2 * blocks * head_group * rows * lanes) * itemsize
+    held = 4 * head_group * query_rows * (lanes + 2 * _LANES)  # acc, max, sum
+    scores = 4 * 4 * query_rows * blocks * rows
+    bounds = 4 * query_rows * _LANES
+    return 2 * (moved + bounds) + held + scores <= _PAGED_VMEM_BUDGET
+
+
+def paged_chunk_body(pool_shape, dtype, head_dim, chunk, mesh=None) -> str:
+    """Name of the body ``paged_chunk_attention`` runs for this backend,
+    mesh, pool layout and chunk width, as ``paged_decode_body`` has it."""
+    if _on_tpu() and mesh is None and _paged_chunk_fits(pool_shape, dtype,
+                                                        head_dim, chunk):
+        return "paged_pallas"
+    return "paged_xla"
+
+
+@jax.jit
+def scatter_prefill_blocks(pool_leaf, row_table, start, chunk):
+    """``scatter_prefill_columns`` for a chunk of whole blocks: ``start``
+    and the chunk's width are multiples of the block size, so the chunk IS
+    its blocks and nothing of the pool is read. The one relayout is the
+    chunk's own, ``pack`` columns to a row."""
+    num_blocks, heads, r, lanes = pool_leaf.shape
+    count, head_dim = chunk.shape[1:]
+    bs = r * lanes // head_dim
+    js = start // bs + jnp.arange(count // bs)
+    ids = jnp.where(js < row_table.shape[0],
+                    row_table[jnp.clip(js, 0, row_table.shape[0] - 1)],
+                    num_blocks)
+    blocks = chunk.astype(pool_leaf.dtype).reshape(heads, count // bs, r, lanes)
+    return pool_leaf.at[ids].set(jnp.moveaxis(blocks, 1, 0), mode="drop")
+
+
+@functools.partial(jax.jit, static_argnames=("body", "aligned"))
+def paged_chunk_attention(q, k_new, v_new, k_pool, v_pool, row, start,
+                          body: str, aligned: bool = False):
+    """One prefill chunk's attention of one layer of ONE slot over the
+    paged pool.
+
+    ``q``: (q_heads, C, head_dim); ``k_new``/``v_new``: (heads, C,
+    head_dim), the chunk's columns ``[start, start + C)``; ``heads`` is
+    the pool's and ``q_heads`` a multiple of it. ``k_pool``/``v_pool``:
+    pool leaves; ``row``: (blocks_per_slot,) the slot's block ids,
+    unallocated entries out of range; ``start``: scalar. Query ``i``
+    attends columns ``<= start + i`` of the slot's blocks.
+
+    Returns ``(out, k_pool, v_pool)`` with ``out`` (q_heads, C, head_dim);
+    the pools carry the chunk's columns, but those in unallocated blocks,
+    which drop (the right-pad tail past the slot's allocation: its queries
+    are not read). ``body`` is one of ``PAGED_BODIES``
+    (``paged_chunk_body`` picks it). ``aligned`` is the caller's promise
+    that ``start`` is a multiple of the block size, as ``C`` then has to
+    be: the chunk is written as whole blocks, where otherwise the blocks
+    it touches are read, updated and set back.
+    """
+    if body not in PAGED_BODIES:
+        raise ValueError(f"unknown paged body {body!r}; expected one of "
+                         f"{PAGED_BODIES}")
+    write = scatter_prefill_blocks if aligned else scatter_prefill_columns
+    with jax.named_scope("kv_write"):
+        k_pool = write(k_pool, row, start, k_new)
+        v_pool = write(v_pool, row, start, v_new)
+    with jax.named_scope("paged_attention"):
+        if body == "paged_pallas":
+            from elephas_tpu.ops.attention_pallas import (
+                pallas_paged_chunk_attention,
+            )
+
+            out = pallas_paged_chunk_attention(q, k_pool, v_pool, row, start)
+        else:
+            out = _paged_attend_xla(
+                q[None], k_pool, v_pool, row[None],
+                (start + jnp.arange(q.shape[1]))[None])[0]
     return out, k_pool, v_pool
 
 

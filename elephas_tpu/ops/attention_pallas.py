@@ -676,3 +676,167 @@ def pallas_paged_decode_attention(q, k_new, v_new, k_pool, v_pool, table,
     out = sum(out[:, g, :q_heads, g] for g in range(pack))
     # a lane the grid never visited holds whatever its output rows held
     return jnp.where(active[:, None, None], out, 0), k_pool, v_pool
+
+
+# -------------------------------------------------- paged chunk attention
+
+
+def _paged_chunk_kernel(
+    phys_ref, steps_ref,  # scalar prefetch (SMEM)
+    q_ref,  # (head_group, tile, lanes): a query in every lane group
+    bound_ref,  # (pack * tile, 1): the last live column of each query row
+    *refs,  # K blocks, V blocks, (head_group, r, lanes) each; out; scratch
+    blocks: int, pack: int, head_dim: int, sm_scale: float,
+):
+    """Grid step (head group, query tile, j): fold ``blocks`` blocks of the
+    slot's row, from block ``j * blocks`` on, into the running softmax of
+    one tile of queries, for each K/V head of the group.
+
+    A block is ``r`` rows of ``pack`` columns a head. Query row ``(g, i)``
+    of a tile keeps query ``i`` in lane group ``g`` and zeros elsewhere
+    (``_paged_decode_kernel``'s layout), so ``(pack * tile, lanes) x
+    (blocks * r, lanes)^T`` scores query ``i`` against column ``pack * n +
+    g`` of the step's columns in entry ``[(g, i), n]``: ``bound`` holds
+    ``start + i - g`` and the entry is live where ``pack * n`` plus the
+    step's first column is at most that. Each ``g`` keeps its own running
+    softmax; they merge at the last step, where lane group ``g`` of row
+    ``(g, i)`` holds its share of query ``i``'s result and is rolled to
+    lane group 0."""
+    del phys_ref  # read by the index maps only
+    k_refs, v_refs = refs[:blocks], refs[blocks:2 * blocks]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * blocks:]
+    j = pl.program_id(2)
+    head_group, r, lanes = k_refs[0].shape
+    tile = q_ref.shape[1]
+    query_rows = pack * tile
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _MASKED)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    first = jax.lax.broadcasted_iota(jnp.int32, (query_rows, blocks * r), 1)
+    live = first * pack <= bound_ref[...] - j * (blocks * r * pack)
+    lane_group = jax.lax.broadcasted_iota(jnp.int32, (tile, lanes), 1) // head_dim
+    for h in range(head_group):
+        k = jnp.concatenate([ref[h] for ref in k_refs], axis=0)
+        v = jnp.concatenate([ref[h] for ref in v_refs], axis=0)
+        q = q_ref[h]
+        q = jnp.concatenate([jnp.where(lane_group == g, q, jnp.zeros_like(q))
+                             for g in range(pack)], axis=0)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * sm_scale
+        # the running max starts finite, so a dead entry's weight is
+        # exp(-inf) = 0 also in a row that has met no live column yet
+        s = jnp.where(live, s, _NEG_INF)
+        m = m_ref[h]
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l_ref[h] = alpha * l_ref[h] + p.sum(axis=-1, keepdims=True)
+        acc_ref[h] = alpha * acc_ref[h] + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_ref[h] = m_new
+
+    @pl.when(j == steps_ref[0] - 1)
+    def _finalize():
+        groups = [slice(g * tile, (g + 1) * tile) for g in range(pack)]
+        for h in range(head_group):
+            m, l, acc = m_ref[h], l_ref[h], acc_ref[h]
+            m_all = functools.reduce(jnp.maximum, [m[g] for g in groups])
+            share = [jnp.exp(m[g] - m_all) for g in groups]
+            total = sum(l[g] * w for g, w in zip(groups, share))
+            inv = 1.0 / jnp.maximum(total, 1e-30)
+            out = acc[groups[0]] * (share[0] * inv)
+            for n, (g, w) in enumerate(zip(groups[1:], share[1:]), 1):
+                out = out + pltpu.roll(acc[g] * (w * inv),
+                                       lanes - n * head_dim, 1)
+            o_ref[h] = out.astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def pallas_paged_chunk_attention(q, k_pool, v_pool, row, start,
+                                 interpret: bool = False):
+    """One prefill chunk of one layer of ONE slot against the paged pool,
+    which already holds the chunk's own columns: query ``i`` attends
+    columns ``<= start + i`` of the slot's blocks.
+
+    ``q``: (q_heads, C, head_dim); ``k_pool``/``v_pool``: pool leaves
+    (num_blocks, heads, r, pack * head_dim), read only, ``q_heads`` a
+    multiple of ``heads``; ``row``: (blocks_per_slot,) int32; ``start``:
+    scalar. Returns (q_heads, C, head_dim).
+
+    The grid is (head groups, query tiles, steps over the row's blocks):
+    the steps a call takes are a run-time value (a dynamic grid bound),
+    ``(start + C) / (blocks a step * block_size)`` rounded up, and the
+    blocks' physical ids are a scalar-prefetch argument that the index
+    maps read. So a block past the chunk's last column is never moved, one
+    compiled kernel serves every ``start``, and no score leaves VMEM.
+    """
+    from elephas_tpu.ops.attention import _chunk_tiles
+
+    q_heads, chunk, head_dim = q.shape
+    num_blocks, heads, r, lanes = k_pool.shape
+    pack = lanes // head_dim
+    block_size = r * pack
+    per_kv = q_heads // heads
+    head_group, tile, blocks = _chunk_tiles(heads, block_size, chunk)
+    tiles = chunk // tile
+    dtype = k_pool.dtype
+
+    # every query in every lane group of its row: the kernel keeps one
+    qp = jnp.tile(q.astype(dtype).reshape(heads, per_kv * chunk, head_dim),
+                  (1, 1, pack))
+    start = start.astype(jnp.int32)
+    bound = (start + jnp.arange(chunk, dtype=jnp.int32).reshape(tiles, 1, tile)
+             - jnp.arange(pack, dtype=jnp.int32)[:, None])
+    bound = bound.reshape(tiles * pack * tile, 1)
+
+    most = -(-row.shape[0] // blocks)  # steps that cover the whole row
+    phys = jnp.clip(row.astype(jnp.int32), 0, num_blocks - 1)
+    phys = jnp.pad(phys, (0, most * blocks - row.shape[0]), mode="edge")
+    steps = jnp.clip((start + chunk - 1) // (blocks * block_size) + 1, 1, most)
+
+    kernel = functools.partial(
+        _paged_chunk_kernel, blocks=blocks, pack=pack, head_dim=head_dim,
+        sm_scale=1.0 / (head_dim ** 0.5),
+    )
+    queries = pl.BlockSpec((head_group, tile, lanes),
+                           lambda hg, t, j, *_: (hg, t, 0))
+    bounds = pl.BlockSpec((pack * tile, 1), lambda hg, t, j, *_: (t % tiles, 0))
+    live_blocks = [
+        pl.BlockSpec(
+            (None, head_group, r, lanes),
+            lambda hg, t, j, phys, steps, b=b: (phys[j * blocks + b], hg, 0, 0),
+        )
+        for b in range(blocks)
+    ]
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(heads // head_group, per_kv * tiles, steps),
+            in_specs=[queries, bounds, *live_blocks, *live_blocks],
+            out_specs=pl.BlockSpec((head_group, tile, lanes),
+                                   lambda hg, t, j, *_: (hg, t, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((head_group, pack * tile, 1), jnp.float32),
+                pltpu.VMEM((head_group, pack * tile, 1), jnp.float32),
+                pltpu.VMEM((head_group, pack * tile, lanes), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((heads, per_kv * chunk, lanes), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")
+        ),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name="paged_chunk_attention",
+    )(phys, steps.reshape(1), qp, bound, *([k_pool] * blocks),
+      *([v_pool] * blocks))
+    # lane group 0 of row (kv head, query head of its group, i)
+    return out[:, :, :head_dim].reshape(q_heads, chunk, head_dim)

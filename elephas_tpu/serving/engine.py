@@ -64,6 +64,7 @@ from elephas_tpu import obs
 from elephas_tpu.models.decode_cache import (
     INDEX,
     KV,
+    PagedDecode,
     first_index,
     has_state,
     leaf_kind,
@@ -328,7 +329,7 @@ class InferenceEngine:
         self._prefill_traces = 0
         self._decode_traces = 0
         self.mesh = None  # set by shard_serving
-        self.metrics.decode_attention = self.decode_attention
+        self._name_attention()
         self._make_jits()
 
         self._req_ids = itertools.count()
@@ -450,19 +451,22 @@ class InferenceEngine:
 
     def _chunk_prefill_impl(self, params, cache, table, tokens, slot,
                             start, valid, rng):
-        """One prompt CHUNK for one slot, through the paged pool: gather
-        the slot's blocks contiguous, run the same dense cache-attention
-        apply the contiguous prefill uses (positions/causality from the
-        cache index — token identity by construction), scatter exactly
-        the chunk's columns back, and advance the slot's index vectors
-        to ``start + valid``.
+        """One prompt CHUNK for one slot, on the paged pool in place: the
+        K/V leaves the module sees ARE the pool's, beside the slot's table
+        row and ``start`` as its cache index. Each attention layer writes
+        the chunk's columns into the blocks they land in and attends the
+        slot's live blocks through the row
+        (``ops.attention.paged_chunk_attention``): positions and
+        causality come from the cache index, as in the contiguous prefill
+        (token identity), and no contiguous row is built. The slot's
+        index vectors then advance to ``start + valid``.
 
         ``tokens`` is (1, chunk) with the final chunk RIGHT-padded;
         padded columns compute garbage K/V that lands at-or-past the
-        slot's cache index, stays causally invisible, and is overwritten
-        by subsequent decode steps. ``slot``/``start``/``valid`` are
-        traced — one compile covers every slot, chunk position, and
-        ragged tail.
+        slot's cache index (or, past its allocation, nowhere), stays
+        causally invisible, and is overwritten by subsequent decode
+        steps. ``slot``/``start``/``valid`` are traced — one compile
+        covers every slot, chunk position, and ragged tail.
 
         A state leaf (``models.decode_cache``) is this slot's row alone:
         handed to the module zeroed where the chunk is a prompt's first,
@@ -475,31 +479,28 @@ class InferenceEngine:
 
         note_retrace("serving_prefill", count=self._prefill_traces)
         from elephas_tpu.models.transformer import sample_tokens_at
-        from elephas_tpu.ops.attention import (
-            paged_to_contiguous,
-            scatter_prefill_columns,
-        )
 
-        chunk_width = tokens.shape[1]
-        head_dim = self._kv_head_dim(cache)
-        row = jax.lax.dynamic_index_in_dim(table, slot, axis=0,
-                                           keepdims=False)
+        row = jax.lax.dynamic_index_in_dim(table, slot, axis=0)
 
         def to_row(path, leaf):
             kind = leaf_kind(path)
             if kind == KV:
-                return paged_to_contiguous(leaf, row[None], head_dim)
+                return leaf
             if kind == INDEX:
                 return jnp.full((1,), start, jnp.int32)
             own = jax.lax.dynamic_index_in_dim(leaf, slot, axis=0)
             return jnp.where(start == 0, jnp.zeros_like(own), own)
 
-        with jax.named_scope("kv_gather"):
-            row_cache = jax.tree_util.tree_map_with_path(to_row, cache)
+        row_cache = jax.tree_util.tree_map_with_path(to_row, cache)
         told = {"valid": valid[None]} if self.stateful else {}
         logits, mutated = self.decode_module.apply(
             {"params": params, "cache": row_cache},
             tokens,
+            # a prompt's first chunk starts at 0 or where the whole blocks
+            # of an adopted prefix end, every later one a chunk further
+            paged=PagedDecode(
+                row, self.prefill_attention,
+                aligned=self.prefill_chunk % self.pool.block_size == 0),
             mutable=["cache"],
             **told,
         )
@@ -518,11 +519,7 @@ class InferenceEngine:
         def back(path, pool_leaf, mut_leaf):
             kind = leaf_kind(path)
             if kind == KV:
-                written = jax.lax.dynamic_slice_in_dim(
-                    mut_leaf[0], start, chunk_width, axis=1
-                )
-                return scatter_prefill_columns(pool_leaf, row, start,
-                                               written)
+                return mut_leaf  # the pool, the chunk's columns in it
             if kind == INDEX:
                 # This slot advances to its true prefilled depth (NOT
                 # start + chunk — the right-pad tail is garbage); every
@@ -532,10 +529,9 @@ class InferenceEngine:
             return jax.lax.dynamic_update_slice_in_dim(
                 pool_leaf, mut_leaf.astype(pool_leaf.dtype), slot, 0)
 
-        with jax.named_scope("kv_scatter"):
-            new_cache = jax.tree_util.tree_map_with_path(
-                back, cache, mutated["cache"]
-            )
+        new_cache = jax.tree_util.tree_map_with_path(
+            back, cache, mutated["cache"]
+        )
         return first[0], new_cache
 
     def _paged_decode_impl(self, params, cache, table, prev_tokens,
@@ -551,10 +547,7 @@ class InferenceEngine:
         from elephas_tpu.utils.compiler import note_retrace
 
         note_retrace("serving_decode", count=self._decode_traces)
-        from elephas_tpu.models.transformer import (
-            PagedDecode,
-            sample_tokens_at,
-        )
+        from elephas_tpu.models.transformer import sample_tokens_at
 
         # Pre-advance write column per lane (every layer advances in
         # lockstep, so the first index leaf speaks for all). A state
@@ -592,12 +585,26 @@ class InferenceEngine:
         return paged_decode_body(leaf.shape, leaf.dtype, self.mesh,
                                  q_heads=self.decode_module.num_heads)
 
-    def _kv_head_dim(self, cache) -> int:
-        """Width of a K/V head, from a pool leaf ``(blocks, heads, rows,
-        lanes)``: a block's ``rows * lanes`` values are ``block_size``
-        columns of it."""
-        _, _, rows, lanes = leaves_of_kind(cache, KV)[0][1].shape
-        return rows * lanes // self.pool.block_size
+    @property
+    def prefill_attention(self) -> str:
+        """Name of the attention body the chunk program is traced with,
+        as ``decode_attention`` names the decode program's: chosen from
+        the backend, the mesh, the pool's layout and the chunk's width."""
+        if not self.paged:
+            return "contiguous"
+        from elephas_tpu.ops.attention import paged_chunk_body
+
+        leaf = leaves_of_kind(self.pool.cache, KV)[0][1]
+        _, _, rows, lanes = leaf.shape  # a block: block_size columns a head
+        return paged_chunk_body(
+            leaf.shape, leaf.dtype, rows * lanes // self.pool.block_size,
+            self.prefill_chunk, self.mesh)
+
+    def _name_attention(self):
+        """The bodies both programs will be traced with, onto every
+        ``step`` event."""
+        self.metrics.decode_attention = self.decode_attention
+        self.metrics.prefill_attention = self.prefill_attention
 
     def _next_rng(self):
         # Sampling keys derive from (base key, pad-free stream position)
@@ -744,7 +751,7 @@ class InferenceEngine:
                     )
                 self.spec.make_jits(p_sh, pool_sh, repl)
             self.mesh = mesh
-            self.metrics.decode_attention = self.decode_attention
+            self._name_attention()
             return self
         prefill_cache = make_decode_cache(self.decode_module, 1,
                                           self.pool.max_len)
@@ -760,7 +767,7 @@ class InferenceEngine:
             ),
         )
         self.mesh = mesh
-        self.metrics.decode_attention = self.decode_attention
+        self._name_attention()
         return self
 
     # -- frontend ----------------------------------------------------------
@@ -1118,6 +1125,7 @@ class InferenceEngine:
             "prefill_traces": self._prefill_traces,
             "decode_traces": self._decode_traces,
             "decode_attention": self.decode_attention,
+            "prefill_attention": self.prefill_attention,
             "pool_admitted_total": self.pool.admitted_total,
             "pool_active": self.pool.active_count,
             "pool_free": self.pool.free_count,

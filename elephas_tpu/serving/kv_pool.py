@@ -422,8 +422,8 @@ class PagedKVPool(KVCachePool):
     contiguous piece of memory); a slot's logical cache row is the
     concatenation of its table row's blocks — a VIRTUAL length
     ``blocks_per_slot * block_size >= max_len`` (ceil, so ``block_size``
-    need not divide ``max_len``). The compiled decode program writes and
-    attends on the blocks in place through the table; prefill chunks and
+    need not divide ``max_len``). The compiled decode and chunk-prefill
+    programs write and attend on the blocks in place through the table;
     speculative windows gather their rows through it, run the same dense
     cache-attention apply as the contiguous pool (token identity by
     construction), and set back exactly the blocks they wrote

@@ -20,8 +20,9 @@ Two record streams share the sink, tagged by ``event``:
   dispatched (``prefill_tokens``, ``prefill_chunks``), the cache columns
   each decoding lane held before this step's decode (``lane_lengths``),
   the paged pool's ``kv_blocks_in_use`` / ``kv_blocks_total``,
-  ``decode_attention`` (the attention body the decode program was traced
-  with: ``paged_pallas``, ``paged_xla`` or ``contiguous``), and
+  ``decode_attention`` and ``prefill_attention`` (the attention body the
+  decode program and the prefill program were traced with:
+  ``paged_pallas``, ``paged_xla`` or ``contiguous``), and
   ``dispatch_to_fetch_s`` — the
   device-overlap gauge: wall seconds between a decode step's dispatch
   and the harvest of its tokens. On the pipelined path all host
@@ -58,6 +59,7 @@ class ServingMetrics:
         self.clock = clock
         # Set by the engine (and again by shard_serving); kept by reset().
         self.decode_attention: Optional[str] = None
+        self.prefill_attention: Optional[str] = None
         self.requests_submitted = 0
         self.requests_completed = 0
         self.requests_timed_out = 0
@@ -248,6 +250,7 @@ class ServingMetrics:
                 state_slots_total=state_slots_total,
                 state_bytes=state_bytes,
                 decode_attention=self.decode_attention,
+                prefill_attention=self.prefill_attention,
             )
 
     # -- aggregates --------------------------------------------------------

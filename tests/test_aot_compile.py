@@ -120,11 +120,10 @@ def test_paged_decode_kernel_compiles_for_v5e(one_chip):
     assert memory.temp_size_in_bytes < 16 << 20
 
 
-def test_paged_decode_program_temporaries_for_v5e(one_chip, monkeypatch):
-    """The whole paged decode program of gpt2-xl (48 layers, 8 slots of 17
-    blocks) with the kernel in it: its temporaries stay under 0.6 GB (they
-    were 5.2 GB when every layer's cache was gathered contiguous), and
-    the pool comes back in place."""
+def _xl_engine(monkeypatch, slots):
+    """gpt2-xl's engine (48 layers, blocks of 64, chunks of 128, prompts to
+    960) over parameters that are shapes, and the two helpers that describe
+    a program's arguments on the chip."""
     from elephas_tpu import InferenceEngine, compile_model
     from elephas_tpu.models import get_model
     from elephas_tpu.ops import attention
@@ -145,22 +144,32 @@ def test_paged_decode_program_temporaries_for_v5e(one_chip, monkeypatch):
         loss="sparse_categorical_crossentropy", metrics=[],
         input_shape=(max_prompt,), input_dtype=jnp.int32)
     engine = InferenceEngine(
-        compiled_model, max_slots=_XL["slots"], max_prompt_len=max_prompt,
+        compiled_model, max_slots=slots, max_prompt_len=max_prompt,
         max_len=1024, kv_block_size=_XL["block_size"], prefill_chunk=128)
     assert engine.pool.blocks_per_slot == _XL["blocks_per_slot"]
-    assert engine.decode_attention == "paged_pallas"
+    return engine, params
 
-    def described(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                           sharding=one_chip), tree)
+
+def _described(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def test_paged_decode_program_temporaries_for_v5e(one_chip, monkeypatch):
+    """The whole paged decode program of gpt2-xl (48 layers, 8 slots of 17
+    blocks) with the kernel in it: its temporaries stay under 0.6 GB (they
+    were 5.2 GB when every layer's cache was gathered contiguous), and
+    the pool comes back in place."""
+    slots = _XL["slots"]
+    engine, params = _xl_engine(monkeypatch, slots)
+    assert engine.decode_attention == "paged_pallas"
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    slots = _XL["slots"]
     program = engine._jit_decode.lower(
-        described(params), described(engine.pool.cache),
+        _described(params, one_chip), _described(engine.pool.cache, one_chip),
         arg((slots, _XL["blocks_per_slot"]), jnp.int32),
         arg((slots,), jnp.int32), arg((slots,), jnp.int32),
         arg((slots,), jnp.bool_), arg((slots,), jnp.bool_),
@@ -173,6 +182,62 @@ def test_paged_decode_program_temporaries_for_v5e(one_chip, monkeypatch):
     pool_bytes = sum(a.size * a.dtype.itemsize
                      for a in jax.tree_util.tree_leaves(engine.pool.cache))
     assert memory.alias_size_in_bytes >= pool_bytes
+
+
+def test_paged_chunk_program_temporaries_for_v5e(one_chip, monkeypatch):
+    """The whole chunk program of gpt2-xl at the batch cell's sizes (16
+    slots of 17 blocks) with the chunk kernel in it, once a layer: no
+    slot's row is gathered, so its temporaries stay under 0.4 GB (1.23 GB
+    when every layer's K and V rows were gathered, written and scored
+    dense), and the pool comes back in place."""
+    slots = 16
+    engine, params = _xl_engine(monkeypatch, slots)
+    assert engine.prefill_attention == "paged_pallas"
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    program = engine._jit_prefill.lower(
+        _described(params, one_chip), _described(engine.pool.cache, one_chip),
+        arg((slots, _XL["blocks_per_slot"]), jnp.int32),
+        arg((1, 128), jnp.int32), arg((), jnp.int32), arg((), jnp.int32),
+        arg((), jnp.int32), arg((2,), jnp.uint32),
+    ).compile()
+    memory = program.memory_analysis()
+    print("gpt2-xl chunk prefill, 16 slots, v5e:", memory)
+    text = program.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 48
+    assert "paged_chunk_attention" in text
+    assert memory.temp_size_in_bytes < 0.4e9
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree_util.tree_leaves(engine.pool.cache))
+    assert memory.alias_size_in_bytes >= pool_bytes
+
+
+@pytest.mark.parametrize("cell", ["xl", "hybrid"])
+def test_paged_chunk_kernel_compiles_for_v5e(one_chip, cell):
+    """The chunk attention kernel at both serving configurations' shapes:
+    25 heads of 64 two columns to a row under chunks of 128, and 20 query
+    heads on one K/V head of 128 under chunks of 512. The pool is read,
+    never copied."""
+    from elephas_tpu.ops.attention import _paged_chunk_fits, pool_leaf_shape
+    from elephas_tpu.ops.attention_pallas import pallas_paged_chunk_attention
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q_heads, heads, d, bs, slots, bps, chunk = {
+        "xl": (25, 25, 64, 64, 16, 17, 128),
+        "hybrid": (20, 1, 128, 64, 32, 72, 512),
+    }[cell]
+    pool = pool_leaf_shape(slots * bps, heads, bs, d)
+    assert _paged_chunk_fits(pool, jnp.bfloat16, d, chunk)
+    compiled = jax.jit(pallas_paged_chunk_attention).lower(
+        arg((q_heads, chunk, d), jnp.bfloat16), arg(pool, jnp.bfloat16),
+        arg(pool, jnp.bfloat16), arg((bps,), jnp.int32), arg((), jnp.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
 
 
 # The long-document cell (benchmark/configs/jamba2-3b.json): 20 query heads

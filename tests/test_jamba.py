@@ -147,6 +147,35 @@ def test_chunked_prefill_and_decode_match_the_reference(served, family, referenc
         assert not np.asarray(leaf).any()  # every slot released: nothing left
 
 
+@pytest.mark.parametrize("chunk", [1, 5, 8, 16])
+def test_every_chunk_width_gives_the_one_shot_prefills_tokens(served, chunk):
+    """The hybrid's chunk program over the pool in place (its attention
+    layers through ``paged_chunk_attention``, its state carried beside):
+    whatever the chunk's width, the tokens are those of a prompt prefilled
+    in one chunk, from one trace."""
+    _, _, compiled = served
+    prompts = _prompts([5, 13, 29, 17])
+    whole = [r.tokens for r in _serve(_engine(compiled, prefill_chunk=30), prompts)]
+    eng = _engine(compiled, prefill_chunk=chunk)
+    assert eng.stats()["prefill_attention"] == "paged_xla"  # the CPU's body
+    assert [r.tokens for r in _serve(eng, prompts)] == whole
+    stats = eng.stats()
+    assert stats["prefill_traces"] == stats["decode_traces"] == 1
+
+
+def test_the_hybrids_chunk_program_gathers_no_row(served):
+    _, _, compiled = served
+    eng = _engine(compiled)
+    program = str(jax.make_jaxpr(eng._chunk_prefill_impl)(
+        eng.params, eng.pool.cache, eng.pool.device_table(),
+        jnp.zeros((1, 8), jnp.int32), jnp.int32(0), jnp.int32(0), jnp.int32(8),
+        eng._next_rng()))
+    layers = sum(eng.decode_module.layer_kind(i) == "attention"
+                 for i in range(eng.decode_module.num_layers))
+    assert program.count("name=paged_chunk_attention") == layers > 0
+    assert "paged_to_contiguous" not in program
+
+
 def test_dropping_the_carried_state_between_chunks_is_seen(served, family, reference):
     """The fault the comparison has to see: a chunk program that starts
     every chunk from a zero state."""

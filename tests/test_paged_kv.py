@@ -394,6 +394,44 @@ def test_prefix_hit_skips_prefill_and_stays_identical(compiled):
     eng.pool.assert_block_invariants()
 
 
+@pytest.mark.parametrize("chunk,per_step", [(3, None), (3, 1), (2, 2), (1, 1),
+                                            (4, None), (8, None)])
+def test_paged_chunks_with_a_prefix_hit_identical_to_contiguous(
+        compiled, chunk, per_step):
+    """The chunk program over the pool in place against the contiguous
+    oracle (``paged=False``), turn by turn, at every chunk width: the later
+    turns adopt the shared system prompt's block, so their first chunk
+    starts at ``start > 0`` behind blocks it reads and never writes."""
+    sys_prompt = [7, 8, 9, 10]
+    prompts = [sys_prompt + [1, 2], sys_prompt + [3, 4, 5], [11, 12],
+               sys_prompt + [1, 2]]
+
+    def turns(eng):
+        return [eng.result(eng.submit(p, max_new_tokens=6), timeout_s=120).tokens
+                for p in prompts]
+
+    oracle = turns(_paged_engine(compiled, paged=False, max_slots=2))
+    eng = _paged_engine(compiled, max_slots=2, kv_block_size=4,
+                        prefill_chunk=chunk, prefill_chunks_per_step=per_step)
+    assert turns(eng) == oracle
+    st = eng.stats()
+    assert st["prefill_traces"] == 1 and st["decode_traces"] == 1
+    assert st["prefix_hits"] == 2 and st["prefix_tokens_saved"] == 8
+    eng.pool.assert_block_invariants()
+
+
+def test_chunk_program_gathers_no_row(compiled):
+    """The chunk program calls the paged chunk attention, once a layer, and
+    nowhere ``paged_to_contiguous``: no contiguous row is built."""
+    eng = _paged_engine(compiled, kv_block_size=4, prefill_chunk=3)
+    program = str(jax.make_jaxpr(eng._chunk_prefill_impl)(
+        eng.params, eng.pool.cache, eng.pool.device_table(),
+        jnp.zeros((1, 3), jnp.int32), jnp.int32(0), jnp.int32(0), jnp.int32(3),
+        eng._next_rng()))
+    assert program.count("name=paged_chunk_attention") == 2
+    assert "paged_to_contiguous" not in program
+
+
 def test_paged_stats_and_load_signals(compiled):
     eng = _paged_engine(compiled, kv_block_size=4)
     _serve_all(eng, [[5, 3, 9]], max_new_tokens=4)
@@ -530,6 +568,105 @@ def test_paged_decode_attention_matches_dense(case, body, layout):
     assert changed == tails
 
 
+# -- paged chunk attention: a prefill chunk over the pool in place ------------
+
+
+def _chunk_case(name, bs):
+    """The one slot's block ids, the chunk's ``start`` and width, how many
+    of its tokens are the prompt's (the rest is the right-pad tail), and
+    whether the caller promises whole blocks from a block's first column."""
+    return {
+        # a prompt's first chunk, over a block edge
+        "start0": ([3, 6], 0, bs + 2, bs + 2, False),
+        # from the middle of a block into the next two
+        "mid_block": ([1, 5, 6], bs + bs // 2, bs + 1, bs + 1, False),
+        # from a block's first column (a chunk boundary, or a prefix
+        # adopted up to there): two whole blocks behind two that are read
+        "block_aligned": ([1, 5, 6, 7], 2 * bs, 2 * bs, 2 * bs, False),
+        # a prompt's last chunk: two tokens and a right-pad tail
+        "ragged_valid": ([2, 4], bs, bs, 2, False),
+        # the tail runs past the slot's allocation: those columns drop
+        "past_allocation": ([7, 8], bs + 1, bs + 3, bs - 1, False),
+        # the same three as whole blocks, written without a read
+        "whole_blocks": ([1, 5, 6, 7], 2 * bs, 2 * bs, 2 * bs, True),
+        "whole_ragged": ([2, 4], bs, bs, 2, True),
+        "whole_past_allocation": ([7, 8], bs, 2 * bs, bs - 1, True),
+    }[name]
+
+
+@pytest.mark.parametrize("layout", sorted(_LAYOUTS))
+@pytest.mark.parametrize("body", ["paged_xla", "paged_pallas"])
+@pytest.mark.parametrize("case", ["start0", "mid_block", "block_aligned",
+                                  "ragged_valid", "past_allocation",
+                                  "whole_blocks", "whole_ragged",
+                                  "whole_past_allocation"])
+def test_paged_chunk_attention_matches_dense(case, body, layout):
+    """Both bodies of the paged chunk attention against dense attention
+    over the gathered row: the chunk's columns land in the blocks they
+    fall in and nowhere else, and query ``i`` attends columns
+    ``<= start + i``."""
+    from elephas_tpu.ops.attention import (
+        paged_chunk_attention,
+        paged_to_contiguous,
+        pool_leaf_shape,
+        scatter_prefill_blocks,
+        scatter_prefill_columns,
+    )
+    from elephas_tpu.ops.attention_pallas import pallas_paged_chunk_attention
+
+    heads, bs, d = _LAYOUTS[layout]
+    q_heads = _QUERY_HEADS.get(layout, heads)
+    ids, start, width, valid, aligned = _chunk_case(case, bs)
+    row = np.full((_BPS,), _BLOCKS, np.int32)
+    row[:len(ids)] = ids
+    row, held = jnp.asarray(row), len(ids) * bs
+    rng = np.random.default_rng(13)
+    shape = pool_leaf_shape(_BLOCKS, heads, bs, d)
+    k_pool, v_pool = (jnp.asarray(rng.normal(size=shape), jnp.float32)
+                      for _ in range(2))
+    q, k_new, v_new = (jnp.asarray(rng.normal(size=(h, width, d)), jnp.float32)
+                       for h in (q_heads, heads, heads))
+    at = jnp.int32(start)
+    if body == "paged_pallas":  # the kernel, interpreted on the CPU
+        write = scatter_prefill_blocks if aligned else scatter_prefill_columns
+        k_after = write(k_pool, row, at, k_new)
+        v_after = write(v_pool, row, at, v_new)
+        out = pallas_paged_chunk_attention(q, k_after, v_after, row, at,
+                                           interpret=True)
+    else:
+        out, k_after, v_after = paged_chunk_attention(
+            q, k_new, v_new, k_pool, v_pool, row, at, body, aligned)
+
+    landed = min(width, held - start)  # the rest runs off the allocation
+    assert valid <= landed
+    want, got = [], []
+    for pool, after, new in ((k_pool, k_after, k_new), (v_pool, v_after, v_new)):
+        dense = np.array(paged_to_contiguous(pool, row[None], d))[0, :, :held]
+        dense[:, start:start + landed] = np.asarray(new)[:, :landed]
+        want.append(dense)
+        got.append(np.asarray(paged_to_contiguous(after, row[None], d))[0, :, :held])
+        np.testing.assert_array_equal(got[-1], dense)
+    kv_of = np.arange(q_heads) // (q_heads // heads)
+    for i in range(valid):  # the right-pad tail's queries are not read
+        live = start + i + 1
+        scores = np.einsum("hd,hkd->hk", np.asarray(q)[:, i],
+                           want[0][kv_of, :live]) / np.sqrt(d)
+        weights = np.exp(scores - scores.max(-1, keepdims=True))
+        weights /= weights.sum(-1, keepdims=True)
+        np.testing.assert_allclose(
+            np.asarray(out)[:, i],
+            np.einsum("hk,hkd->hd", weights, want[1][kv_of, :live]),
+            rtol=2e-5, atol=2e-5)
+    # only the slot's own blocks under the chunk differ from the pool as it
+    # was: no block below ``start``'s, none of another slot, none unallocated
+    under = {ids[j] for j in range(start // bs, (start + width - 1) // bs + 1)
+             if j < len(ids)}
+    for before, after in ((k_pool, k_after), (v_pool, v_after)):
+        changed = set(np.flatnonzero(
+            (np.asarray(after) != np.asarray(before)).any(axis=(1, 2, 3))))
+        assert changed == under
+
+
 @pytest.mark.parametrize("layout", sorted(_LAYOUTS))
 def test_window_scatters_write_their_columns_only(layout):
     """``scatter_prefill_columns`` and ``scatter_spec_columns`` on the
@@ -582,7 +719,8 @@ def test_window_scatters_write_their_columns_only(layout):
 
 def test_decode_attention_is_named_on_stats_and_step_events(compiled):
     """``stats()`` and every ``step`` event say which attention body the
-    decode program was traced with; the pool is the donated pytree still."""
+    decode program and the chunk program were traced with; the pool is the
+    donated pytree still."""
     events = []
 
     class Sink:
@@ -591,11 +729,14 @@ def test_decode_attention_is_named_on_stats_and_step_events(compiled):
 
     eng = _paged_engine(compiled, kv_block_size=4, sink=Sink())
     assert eng.stats()["decode_attention"] == "paged_xla"  # the CPU's body
+    assert eng.stats()["prefill_attention"] == "paged_xla"
     stale = eng.pool.cache
     res = eng.result(eng.submit([5, 3, 9], max_new_tokens=4), timeout_s=120)
     assert res.status == "completed"
     steps = [e for e in events if e.get("event") == "step"]
     assert steps and all(e["decode_attention"] == "paged_xla" for e in steps)
+    assert all(e["prefill_attention"] == "paged_xla" for e in steps)
     assert all(leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(stale))
     contiguous = _paged_engine(compiled, paged=False)
     assert contiguous.stats()["decode_attention"] == "contiguous"
+    assert contiguous.stats()["prefill_attention"] == "contiguous"

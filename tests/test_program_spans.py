@@ -182,6 +182,9 @@ def test_step_events_and_chunk_spans_say_what_the_engine_was_asked(family):
         spare.remove(c)
     assert len(spare) <= len(asked) and set(spare) <= {n + m - 1 for n, m in asked}
     assert all(len(s["lane_lengths"]) <= 3 for s in sink.steps)
+    # both programs' attention bodies, on every event and in stats()
+    for key in ("decode_attention", "prefill_attention"):
+        assert {s[key] for s in sink.steps} == {"paged_xla"} == {eng.stats()[key]}
     rows = 3 if family == "jamba_lm" else 0
     assert {s["state_slots_total"] for s in sink.steps} == {rows}
     assert max(s["state_slots_in_use"] for s in sink.steps) == rows
