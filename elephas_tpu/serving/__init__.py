@@ -6,15 +6,14 @@ parameter server + engine drivers); this package is the inference
 counterpart — the subsystem that turns ``TransformerLM``'s compiled
 decode step into an engine that serves request traffic:
 
-- ``KVCachePool``      — a fixed-shape slot pool of per-layer KV caches;
-                         admission/eviction never reshapes the compiled
-                         decode program (``serving.kv_pool``),
-- ``PagedKVPool``      — its block/paged successor (the default):
-                         reference-counted fixed-size KV blocks behind a
-                         ``BlockTable``, a ``PrefixCache`` that admits
-                         resident prompt prefixes by refcount instead of
-                         re-prefilling, LRU prefix eviction under
-                         pressure, copy-on-write at shared boundaries
+- ``PagedKVPool``      — the fixed-shape pool of per-layer KV caches
+                         (admission/eviction never reshapes a compiled
+                         program): reference-counted fixed-size KV
+                         blocks behind a ``BlockTable``, a
+                         ``PrefixCache`` that admits resident prompt
+                         prefixes by refcount instead of re-prefilling,
+                         LRU prefix eviction under pressure,
+                         copy-on-write at shared boundaries
                          (``serving.kv_pool``),
 - ``ContinuousBatchingScheduler`` — bounded request queue, prefill/decode
                          interleaving, deadline eviction, backpressure
@@ -50,14 +49,14 @@ The decode hot path is PIPELINED (one-step lookahead: dispatch N+1
 before reading N's tokens) and DONATION-CLEAN (the pool cache is donated
 to every program that rewrites it; ``DonatedBufferError`` guards stale
 reads). Both are engine-internal: token streams are identical to the
-unpipelined path (``pipeline=False``).
+unpipelined path (``pipeline=False``), and both are held, one row at a
+time, to ``models.transformer.generate()``.
 """
 
 from elephas_tpu.serving import host_sync  # noqa: F401
 from elephas_tpu.serving.kv_pool import (  # noqa: F401
     BlockTable,
     DonatedBufferError,
-    KVCachePool,
     PagedKVPool,
     PrefixCache,
 )

@@ -2,19 +2,24 @@
 scheduler.
 
 Binds a ``CompiledModel`` (or bare ``TransformerLM`` + params) to TWO
-compiled programs that together serve arbitrary request traffic:
+compiled programs that together serve arbitrary request traffic, both
+over the paged ``PagedKVPool`` in place through its block table:
 
-- ``prefill``: batch-1, fixed ``max_prompt_len`` width (prompts are
-  left-padded into it), emits the first token and the prompt's KV cache;
+- ``prefill``: one prompt CHUNK of one slot per call, fixed
+  ``(1, prefill_chunk)`` width (the last chunk right-padded); writes the
+  chunk's K/V into the slot's blocks and samples the token after it;
 - ``decode``: one token for every pool slot per call, fixed
-  ``(max_slots,)`` shapes, per-slot cache positions. The KV-cache
-  operand is DONATED (``donate_argnums``), so XLA rewrites the pool in
-  place instead of copying every layer's K/V each token, and the
-  previous step's device token vector chains straight back in as the
-  next step's input (one-step-lookahead pipelining — see
-  ``serving.scheduler``). Freshly admitted lanes are spliced in with a
-  ``where`` override INSIDE the program; free lanes are masked so their
-  cache index vectors freeze.
+  ``(max_slots,)`` shapes, per-slot cache positions. The previous
+  step's device token vector chains straight back in as the next step's
+  input (one-step-lookahead pipelining — see ``serving.scheduler``).
+  Freshly admitted lanes are spliced in with a ``where`` override INSIDE
+  the program; free lanes are masked so their cache index vectors
+  freeze.
+
+The KV-cache operand of both is DONATED (``donate_argnums``), so XLA
+rewrites the pool in place instead of copying every layer's K/V each
+call. What the served tokens are held to is ``models.transformer.
+generate()``, one row at a time.
 
 Admission, eviction, slot reuse and backpressure all happen HOST-side
 between calls — neither program ever retraces once warm, which is the
@@ -59,6 +64,7 @@ from typing import Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from elephas_tpu import obs
 from elephas_tpu.models.decode_cache import (
@@ -70,7 +76,7 @@ from elephas_tpu.models.decode_cache import (
     leaf_kind,
     leaves_of_kind,
 )
-from elephas_tpu.serving.kv_pool import KVCachePool, PagedKVPool
+from elephas_tpu.serving.kv_pool import PagedKVPool
 from elephas_tpu.serving.metrics import ServingMetrics
 from elephas_tpu.serving.scheduler import (
     ContinuousBatchingScheduler,
@@ -94,7 +100,7 @@ class InferenceEngine:
     compiled: ``CompiledModel`` (module + params) or a flax
         ``TransformerLM``; in the latter case pass ``params=``.
     max_slots: concurrent sequences (decode batch width).
-    max_prompt_len: fixed prefill width; prompts are left-padded to it.
+    max_prompt_len: the longest prompt ``submit`` takes.
     max_len: KV-cache columns per slot; a sequence may generate up to
         ``max_len - max_prompt_len`` tokens.
     stop_token: default EOS (per-request override via ``submit``).
@@ -104,33 +110,25 @@ class InferenceEngine:
     pipeline: one-step-lookahead decode (default). ``False`` selects the
         unpipelined oracle path — token-identical, device idles during
         host bookkeeping; exists for A/B tests and benchmarks.
-    paged: block/paged KV pool (default). The pool stores fixed-size KV
-        blocks behind a reference-counted block table with a prefix
-        cache, prompts are never left-padded (shared prefixes must land
-        at identical columns), and prefill runs through the CHUNKED
-        program — still exactly one prefill + one decode compile,
-        token-identical to ``paged=False``. ``False`` selects the
-        contiguous per-slot layout (the oracle the paged path is tested
-        against).
-    kv_block_size: columns per physical KV block (paged only; default
+    kv_block_size: columns per physical KV block (default
         ``max_prompt_len``). Smaller blocks share finer-grained
         prefixes at the cost of a wider block table.
-    kv_blocks: physical block count (paged only; default
+    kv_blocks: physical block count (default
         ``max_slots * ceil(max_len / kv_block_size)`` — always enough
         for every slot, so prefix eviction can never dead-end).
     prefix_cache: keep released/committed prompt chains resident so
         later prompts sharing a full-block prefix admit by refcount
-        instead of re-prefilling (paged only; default True).
-    prefill_chunk: prefill chunk width (paged only; default
-        ``max_prompt_len`` = one-shot). Smaller chunks split long
-        prompts into several compiled-program calls so decode steps can
-        interleave between them.
+        instead of re-prefilling (default True).
+    prefill_chunk: prefill chunk width (default ``max_prompt_len`` =
+        one-shot). Smaller chunks split long prompts into several
+        compiled-program calls so decode steps can interleave between
+        them.
     prefill_chunks_per_step: max prefill chunks dispatched per scheduler
-        step (paged only; default None = run every pending chunk at
-        admission). Set to a small int to bound how long any one step's
-        prefill work can stall in-flight decodes — the ITL-p99
-        protection the chunked program exists for.
-    speculative: draft-and-verify decode (paged only; default False).
+        step (default None = run every pending chunk at admission). Set
+        to a small int to bound how long any one step's prefill work can
+        stall in-flight decodes — the ITL-p99 protection the chunked
+        program exists for.
+    speculative: draft-and-verify decode (default False).
         Each pipelined dispatch drafts ``gamma`` tokens per slot with a
         cheap draft source and verifies the whole window in ONE batched
         target forward — between 1 and ``gamma + 1`` tokens emitted per
@@ -169,7 +167,6 @@ class InferenceEngine:
         top_k: int = 0,
         seed: int = 0,
         pipeline: bool = True,
-        paged: bool = True,
         kv_block_size: Optional[int] = None,
         kv_blocks: Optional[int] = None,
         prefix_cache: bool = True,
@@ -213,63 +210,41 @@ class InferenceEngine:
         self._greedy = temperature == 0.0
 
         self.tracer = tracer if tracer is not None else obs.default_tracer()
-        self.paged = paged
         if (draft_layers is not None or draft_source is not None) \
                 and not speculative:
             raise ValueError(
                 "draft_layers/draft_source require speculative=True"
             )
-        if speculative:
-            if not paged:
-                raise ValueError("speculative decode requires paged=True")
-            if draft_layers is not None and draft_source is not None:
-                raise ValueError(
-                    "draft_layers and draft_source are mutually exclusive"
-                )
-        if paged:
-            chunk = (prefill_chunk if prefill_chunk is not None
-                     else max_prompt_len)
-            if not 1 <= chunk <= max_prompt_len:
-                raise ValueError(
-                    f"prefill_chunk ({chunk}) must be in "
-                    f"[1, max_prompt_len={max_prompt_len}]"
-                )
-            self.prefill_chunk = chunk
-            # A chunk may start as late as the last prompt column; its
-            # compiled slice/scatter window must fit the virtual row
-            # without clamping. A speculative verify window writes up to
-            # gamma columns past the last decode column the same way.
-            virtual_len = max_prompt_len - 1 + chunk
-            if speculative:
-                virtual_len = max(virtual_len, max_len + gamma)
-            self.pool = PagedKVPool(
-                self.decode_module, max_slots, max_len,
-                block_size=(kv_block_size if kv_block_size is not None
-                            else max_prompt_len),
-                num_blocks=kv_blocks,
-                prefix_cache=prefix_cache,
-                virtual_len=virtual_len,
+        if draft_layers is not None and draft_source is not None:
+            raise ValueError(
+                "draft_layers and draft_source are mutually exclusive"
             )
-        else:
-            if (kv_block_size is not None or kv_blocks is not None
-                    or prefill_chunk is not None
-                    or prefill_chunks_per_step is not None):
-                raise ValueError(
-                    "kv_block_size/kv_blocks/prefill_chunk/"
-                    "prefill_chunks_per_step require paged=True"
-                )
-            self.prefill_chunk = None
-            self.pool = KVCachePool(self.decode_module, max_slots, max_len)
+        chunk = (prefill_chunk if prefill_chunk is not None
+                 else max_prompt_len)
+        if not 1 <= chunk <= max_prompt_len:
+            raise ValueError(
+                f"prefill_chunk ({chunk}) must be in "
+                f"[1, max_prompt_len={max_prompt_len}]"
+            )
+        self.prefill_chunk = chunk
+        # A chunk may start as late as the last prompt column; its
+        # compiled slice/scatter window must fit the virtual row
+        # without clamping. A speculative verify window writes up to
+        # gamma columns past the last decode column the same way.
+        virtual_len = max_prompt_len - 1 + chunk
+        if speculative:
+            virtual_len = max(virtual_len, max_len + gamma)
+        self.pool = PagedKVPool(
+            self.decode_module, max_slots, max_len,
+            block_size=(kv_block_size if kv_block_size is not None
+                        else max_prompt_len),
+            num_blocks=kv_blocks,
+            prefix_cache=prefix_cache,
+            virtual_len=virtual_len,
+        )
         # What the engine has to know of the model it reads from the cache
         # tree: a leaf that is neither K/V nor an index is per-slot state.
         self.stateful = has_state(self.pool.cache)
-        if self.stateful and not paged:
-            raise ValueError(
-                "a model with per-slot state (a recurrence's, a "
-                "convolution's) is served through the paged pool: the "
-                "contiguous prefill left-pads its prompts, and padding run "
-                "through a recurrence is not invisible"
-            )
         self.spec = None
         if speculative:
             from elephas_tpu.serving.spec import (
@@ -298,17 +273,16 @@ class InferenceEngine:
         self.slo = obs.GoodputLedger(clock=clock)
         # Per-tenant cost attribution: the scheduler bills queue
         # seconds, prefill/decode tokens, spec windows and terminal
-        # statuses per request tenant; the paged pool integrates KV
+        # statuses per request tenant; the pool integrates KV
         # block-seconds per owning slot. Canary-blind goodput rides
         # _publish (mirroring self.slo), so per-tenant burn matches
         # the fleet ledger's exclusions.
         self.costs = obs.CostLedger(clock=clock)
-        if paged:
-            self.pool.attach_cost_ledger(self.costs, clock)
+        self.pool.attach_cost_ledger(self.costs, clock)
         self.scheduler = ContinuousBatchingScheduler(
             self.pool,
             self.queue,
-            self._prefill,
+            self._chunk_prefill,
             self._decode,
             max_prompt_len=max_prompt_len,
             pad_token=pad_token,
@@ -318,7 +292,6 @@ class InferenceEngine:
             tracer=self.tracer,
             load=self.load,
             costs=self.costs,
-            chunk_prefill_fn=self._chunk_prefill if paged else None,
             prefill_chunk=self.prefill_chunk,
             prefill_chunks_per_step=prefill_chunks_per_step,
             spec_decode_fn=(self.spec.dispatch if self.spec is not None
@@ -360,94 +333,19 @@ class InferenceEngine:
         if in_shardings is not None:
             pre_in, dec_in = in_shardings
             pre_out, dec_out = out_shardings
-        if self.paged:
-            # BOTH paged programs rewrite the pool, so both donate it
-            # (argnum 1); chunk prefill scatters its columns in place
-            # exactly like decode does.
-            self._jit_prefill = jax.jit(
-                self._chunk_prefill_impl, donate_argnums=(1,),
-                in_shardings=pre_in, out_shardings=pre_out,
-            )
-            self._jit_decode = jax.jit(
-                self._paged_decode_impl, donate_argnums=(1,),
-                in_shardings=dec_in, out_shardings=dec_out,
-            )
-            return
+        # BOTH programs rewrite the pool, so both donate it (argnum 1):
+        # the stale reference dies at dispatch (the pool's guard turns
+        # any later read into a loud error).
         self._jit_prefill = jax.jit(
-            self._prefill_impl, in_shardings=pre_in, out_shardings=pre_out
+            self._chunk_prefill_impl, donate_argnums=(1,),
+            in_shardings=pre_in, out_shardings=pre_out,
         )
-        # The pool cache (argnum 1) is donated: decode rewrites it in
-        # place; the stale reference dies at dispatch (KVCachePool's
-        # guard turns any later read into a loud error).
         self._jit_decode = jax.jit(
-            self._decode_impl, donate_argnums=(1,),
+            self._paged_decode_impl, donate_argnums=(1,),
             in_shardings=dec_in, out_shardings=dec_out,
         )
 
     # -- compiled bodies ---------------------------------------------------
-
-    def _prefill_impl(self, params, prompt, pad_offset, rng):
-        # Traced once per compilation — the counter measures retraces,
-        # and the obs hook makes a surprise retrace (a silent 10×
-        # regression if it happened per request) a visible counter +
-        # trace marker.
-        self._prefill_traces += 1
-        from elephas_tpu.utils.compiler import note_retrace
-
-        note_retrace("serving_prefill", count=self._prefill_traces)
-        from elephas_tpu.models.transformer import (
-            make_decode_cache,
-            sample_tokens_at,
-        )
-
-        cache = make_decode_cache(
-            self.decode_module, 1, self.pool.max_len
-        )
-        logits, mutated = self.decode_module.apply(
-            {"params": params, "cache": cache},
-            prompt,
-            pad_offset=pad_offset[None],
-            mutable=["cache"],
-        )
-        # Position-keyed sampling: the token after a plen-token prompt
-        # sits at pad-free stream position plen — every program (plain
-        # decode, chunked prefill, speculative verify) derives the same
-        # key for the same position, which is what makes temperature
-        # decode byte-identical across all of them.
-        first = sample_tokens_at(
-            logits[:, -1], rng, (prompt.shape[1] - pad_offset)[None],
-            self._greedy, self.top_k, self.temperature,
-        )
-        return first[0], mutated["cache"]
-
-    def _decode_impl(self, params, cache, prev_tokens, override_vals,
-                     override_mask, active_mask, pad, rng):
-        self._decode_traces += 1
-        from elephas_tpu.utils.compiler import note_retrace
-
-        note_retrace("serving_decode", count=self._decode_traces)
-        from elephas_tpu.models.transformer import sample_tokens_at
-
-        # Pre-advance cache index per lane (first leaf speaks for all):
-        # the token sampled this step sits at pad-free position
-        # idx - pad + 1.
-        idx = first_index(cache)
-        # Freshly-admitted lanes get their prefill first token here,
-        # INSIDE the one compiled program — the pipelined scheduler
-        # never materializes the token vector host-side.
-        tokens = jnp.where(override_mask, override_vals, prev_tokens)
-        logits, mutated = self.decode_module.apply(
-            {"params": params, "cache": cache},
-            tokens[:, None],
-            pad_offset=pad,
-            active=active_mask,
-            mutable=["cache"],
-        )
-        nxt = sample_tokens_at(
-            logits[:, -1], rng, idx - pad + 1, self._greedy, self.top_k,
-            self.temperature,
-        )
-        return nxt, mutated["cache"]
 
     def _chunk_prefill_impl(self, params, cache, table, tokens, slot,
                             start, valid, rng):
@@ -457,9 +355,9 @@ class InferenceEngine:
         the chunk's columns into the blocks they land in and attends the
         slot's live blocks through the row
         (``ops.attention.paged_chunk_attention``): positions and
-        causality come from the cache index, as in the contiguous prefill
-        (token identity), and no contiguous row is built. The slot's
-        index vectors then advance to ``start + valid``.
+        causality come from the cache index, as in ``generate()``'s
+        prefill (token identity), and no contiguous row is built. The
+        slot's index vectors then advance to ``start + valid``.
 
         ``tokens`` is (1, chunk) with the final chunk RIGHT-padded;
         padded columns compute garbage K/V that lands at-or-past the
@@ -474,6 +372,10 @@ class InferenceEngine:
         recurrence is not invisible, so a stateful module is told
         ``valid`` and leaves the state after ``valid`` tokens; it then
         also returns the one row of logits that is sampled."""
+        # Traced once per compilation — the counter measures retraces,
+        # and the obs hook makes a surprise retrace (a silent 10×
+        # regression if it happened per request) a visible counter +
+        # trace marker.
         self._prefill_traces += 1
         from elephas_tpu.utils.compiler import note_retrace
 
@@ -508,8 +410,11 @@ class InferenceEngine:
         # (only the final chunk's sample is ever read).
         last = logits[:, 0] if self.stateful else jax.lax.dynamic_slice_in_dim(
             logits, valid - 1, 1, axis=1)[:, 0]
-        # Paged rows are never left-padded, so the sampled token's
-        # pad-free position is simply the prefilled depth start + valid.
+        # Position-keyed sampling: rows are never left-padded, so the
+        # sampled token's stream position is the prefilled depth start +
+        # valid, and every program (chunk prefill, decode, speculative
+        # verify) derives the same key for the same position: what makes
+        # temperature decode byte-identical across all of them.
         with jax.named_scope("sample"):
             first = sample_tokens_at(
                 last, rng, (start + valid)[None], self._greedy, self.top_k,
@@ -553,6 +458,9 @@ class InferenceEngine:
         # lockstep, so the first index leaf speaks for all). A state
         # leaf is stepped by its layer, for the active lanes only.
         idx = first_index(cache)
+        # Freshly-admitted lanes get their prefill first token here,
+        # INSIDE the one compiled program — the pipelined scheduler
+        # never materializes the token vector host-side.
         tokens = jnp.where(override_mask, override_vals, prev_tokens)
         logits, mutated = self.decode_module.apply(
             {"params": params, "cache": cache},
@@ -572,11 +480,8 @@ class InferenceEngine:
     @property
     def decode_attention(self) -> str:
         """Name of the attention body the decode program is traced with:
-        ``"paged_pallas"`` or ``"paged_xla"`` over the paged pool (chosen
-        from the backend, the mesh and the pool's layout), ``"contiguous"``
-        for ``paged=False``."""
-        if not self.paged:
-            return "contiguous"
+        ``"paged_pallas"`` or ``"paged_xla"``, chosen from the backend,
+        the mesh and the pool's layout."""
         from elephas_tpu.ops.attention import paged_decode_body
 
         # the pool's own K/V leaf: its heads are the model's K/V heads,
@@ -590,8 +495,6 @@ class InferenceEngine:
         """Name of the attention body the chunk program is traced with,
         as ``decode_attention`` names the decode program's: chosen from
         the backend, the mesh, the pool's layout and the chunk's width."""
-        if not self.paged:
-            return "contiguous"
         from elephas_tpu.ops.attention import paged_chunk_body
 
         leaf = leaves_of_kind(self.pool.cache, KV)[0][1]
@@ -616,17 +519,6 @@ class InferenceEngine:
         # the whole temperature-identity story.
         return self._rng
 
-    def _prefill(self, prompt, pad_offset):
-        if self.paged:
-            raise RuntimeError(
-                "paged engines prefill through _chunk_prefill (the "
-                "scheduler's chunked path), not the contiguous program"
-            )
-        first, cache = self._jit_prefill(
-            self.params, prompt, pad_offset, self._next_rng()
-        )
-        return first, cache
-
     def _chunk_prefill(self, tokens, slot, start, valid):
         """Scheduler-facing chunk closure: runs one compiled chunk and
         swaps the donated pool; returns the device token sampled at the
@@ -644,18 +536,17 @@ class InferenceEngine:
 
     def _decode(self, cache, prev_tokens, override_vals, override_mask,
                 active_mask, pad):
-        if self.paged:
-            nxt, new_cache = self._jit_decode(
-                self.params, cache, self.pool.device_table(), prev_tokens,
-                override_vals, override_mask, active_mask, pad,
-                self._next_rng(),
-            )
-        else:
-            nxt, new_cache = self._jit_decode(
-                self.params, cache, prev_tokens, override_vals,
-                override_mask, active_mask, pad, self._next_rng(),
-            )
-        return nxt, new_cache
+        if self.mesh is not None:
+            # An engine's first ``prev_tokens`` is the host's; every later
+            # one is the previous step's output, whose type carries the
+            # mesh. Placed alike, both hit one trace of the program.
+            prev_tokens = jax.device_put(
+                prev_tokens, NamedSharding(self.mesh, P()))
+        return self._jit_decode(
+            self.params, cache, self.pool.device_table(), prev_tokens,
+            override_vals, override_mask, active_mask, pad,
+            self._next_rng(),
+        )
 
     # -- tensor-parallel serving -------------------------------------------
 
@@ -674,9 +565,6 @@ class InferenceEngine:
 
         Returns ``self`` (builder style).
         """
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from elephas_tpu.models.transformer import make_decode_cache
         from elephas_tpu.parallel.mesh import MODEL_AXIS
         from elephas_tpu.parallel.tensor_parallel import (
             decode_cache_specs,
@@ -722,50 +610,32 @@ class InferenceEngine:
             jax.device_put(self.pool.cache, pool_sh),
             jax.device_put(self.pool.pad, repl),
         )
-        if self.paged:
-            # Both paged programs take (params, pool, table, ...): the
-            # block pool shards over heads exactly like the contiguous
-            # layout (decode_cache_specs keys on leaf NAME, and block
-            # leaves keep heads at dim 1); the block table and every
-            # scalar/lane operand replicate. Chunk prefill writes the
-            # sharded pool directly, so there is no separate prefill
-            # cache to lay out.
-            self.pool.table.sharding = repl
-            self.pool.table.invalidate()
-            self._make_jits(
-                in_shardings=(
-                    (p_sh, pool_sh) + (repl,) * 6,             # prefill
-                    (p_sh, pool_sh) + (repl,) * 7,             # decode
-                ),
-                out_shardings=(
-                    (repl, pool_sh),                           # prefill
-                    (repl, pool_sh),                           # decode
-                ),
-            )
-            if self.spec is not None:
-                if self.spec.source.kind != "self":
-                    raise NotImplementedError(
-                        "tensor-parallel serving with a model draft "
-                        "source is not supported yet (the draft model "
-                        "has no sharding rules); use a self-draft"
-                    )
-                self.spec.make_jits(p_sh, pool_sh, repl)
-            self.mesh = mesh
-            self._name_attention()
-            return self
-        prefill_cache = make_decode_cache(self.decode_module, 1,
-                                          self.pool.max_len)
-        prefill_sh = named(decode_cache_specs(prefill_cache))
+        # Both programs take (params, pool, table, ...): the block pool
+        # shards over heads (decode_cache_specs keys on leaf NAME, and
+        # block leaves keep heads at dim 1); the block table and every
+        # scalar/lane operand replicate. Chunk prefill writes the
+        # sharded pool directly, so there is no separate prefill cache
+        # to lay out.
+        self.pool.table.sharding = repl
+        self.pool.table.invalidate()
         self._make_jits(
             in_shardings=(
-                (p_sh, repl, repl, repl),                      # prefill
-                (p_sh, pool_sh) + (repl,) * 6,                 # decode
+                (p_sh, pool_sh) + (repl,) * 6,                 # prefill
+                (p_sh, pool_sh) + (repl,) * 7,                 # decode
             ),
             out_shardings=(
-                (repl, prefill_sh),                            # prefill
+                (repl, pool_sh),                               # prefill
                 (repl, pool_sh),                               # decode
             ),
         )
+        if self.spec is not None:
+            if self.spec.source.kind != "self":
+                raise NotImplementedError(
+                    "tensor-parallel serving with a model draft "
+                    "source is not supported yet (the draft model "
+                    "has no sharding rules); use a self-draft"
+                )
+            self.spec.make_jits(p_sh, pool_sh, repl)
         self.mesh = mesh
         self._name_attention()
         return self
@@ -785,7 +655,7 @@ class InferenceEngine:
         """Enqueue a request; returns its id. Raises ``QueueFull`` (with
         ``.retry_after``) when admission control rejects it.
 
-        ``prefill_only=True`` (paged engines only) runs this engine as a
+        ``prefill_only=True`` runs this engine as a
         PREFILL TIER member for the request: the prompt prefills into
         paged blocks as usual, but instead of joining the decode batch
         the filled blocks export as a KV handoff — claim it with
@@ -813,9 +683,6 @@ class InferenceEngine:
             )
         if max_new_tokens < 1:
             raise ValueError(f"max_new_tokens must be >= 1, got {max_new_tokens}")
-        if prefill_only and not self.paged:
-            raise ValueError("prefill_only requires paged=True (the KV "
-                             "handoff ships paged blocks)")
         now = self.clock()
         req = Request(
             req_id=next(self._req_ids),
@@ -923,8 +790,6 @@ class InferenceEngine:
         already billed the submit, the prompt, and the first token;
         this engine bills decode tokens from token two and block-seconds
         from the import instant (the window the exporter closed)."""
-        if not self.paged:
-            raise RuntimeError("KV handoff import requires paged=True")
         from elephas_tpu.serving.handoff import decode_handoff
 
         data = decode_handoff(frame)
@@ -1129,13 +994,12 @@ class InferenceEngine:
             "pool_admitted_total": self.pool.admitted_total,
             "pool_active": self.pool.active_count,
             "pool_free": self.pool.free_count,
+            "kv_blocks_free": self.pool.free_blocks,
+            "kv_blocks_total": self.pool.num_blocks,
+            **self.pool.prefix_stats(),
+            **self.pool.state_signals(),
+            "state_resets": self.pool.state_resets,
         }
-        if self.paged:
-            out["kv_blocks_free"] = self.pool.free_blocks
-            out["kv_blocks_total"] = self.pool.num_blocks
-            out.update(self.pool.prefix_stats())
-            out.update(self.pool.state_signals())
-            out["state_resets"] = self.pool.state_resets
         if self.spec is not None:
             out.update(self.spec.stats())
         if len(self.costs.tenants()) > 0:

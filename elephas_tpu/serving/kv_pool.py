@@ -1,48 +1,39 @@
-"""Slot-based KV-cache pool for continuous batching.
+"""Paged KV-cache pool for continuous batching.
 
-The pool owns ONE cache pytree of fixed shape — per layer,
-``cached_key``/``cached_value`` of (max_slots, heads, max_len, head_dim)
-plus per-slot ``cache_index``/``pos_index`` (max_slots,) vectors — so the
-compiled decode step's operand shapes never change as sequences come and
-go. Admission writes a finished prefill's batch-1 cache into a free
-slot's row (a jitted dynamic_update_slice with the slot id TRACED — one
-compile covers every slot); eviction just returns the slot id to the
-free list, since the next admit overwrites the row wholesale.
+``PagedKVPool`` owns ONE cache pytree of fixed shape, so the compiled
+programs' operand shapes never change as sequences come and go: per
+layer, K/V leaves of ``num_blocks`` physical blocks of ``block_size``
+columns (the vLLM layout) and per-slot ``cache_index``/``pos_index``
+``(max_slots,)`` vectors; a model with per-slot state (a recurrence's, a
+convolution's) keeps one row a slot in the same tree. Slots reach their
+blocks through a reference-counted ``BlockTable``; a ``PrefixCache``
+admits already-resident prompt prefixes by bumping refcounts instead of
+re-prefilling, evicts unreferenced prefixes LRU-first under allocation
+pressure, and any shared boundary a fork creates is copied on write.
 
-The cache pytree is DONATED to every program that rewrites it — the
-admission ``_write_slot`` here and the engine's decode step — so XLA
-updates the pool in place instead of materializing a full copy of every
-layer's K/V each token (the copy was PR 1's single biggest per-step
-cost after the host sync). Donation makes the OLD buffers poison: any
-read through a stale reference raises, so ``self._cache`` is private
-and the ``cache`` property guards every access with an explicit
+The cache pytree is DONATED to every program that rewrites it (the
+engine's chunk-prefill and decode programs, the block copies and state
+clears here), so XLA updates the pool in place instead of materializing
+a copy of every layer's K/V each token. Donation makes the OLD buffers
+poison: any read through a stale reference raises, so ``self._cache`` is
+private and the ``cache`` property guards every access with an explicit
 use-after-donate check (a stale read would otherwise surface as an
 opaque ``Array has been deleted`` deep inside XLA).
 
 Per-slot state the model consumes each step:
 
-- ``cache_index``/``pos_index`` — the column the slot's next token
-  writes (advanced by the apply itself, per row — ONLY for rows the
+- ``cache_index``/``pos_index``: the column the slot's next token
+  writes (advanced by the apply itself, per row, ONLY for rows the
   decode step's ``active`` mask marks occupied; free slots' vectors
   freeze so they can't march past ``max_len`` between admissions),
-- ``pad``        — the slot's left-pad column count (prompts are
-  left-padded to the engine's fixed prefill length so prefill is one
-  compiled program; the pad columns stay masked out of attention for
-  the sequence's whole lifetime).
+- ``pad``: always zero. Prompts are never left-padded (shared prefixes
+  must land at identical cache columns in every slot); the vector is an
+  operand of the decode and speculation programs all the same.
 
 Inactive slots ride along in the decode batch (their logits are
-discarded and their rows rewritten on admit) — the price of a
+discarded and their blocks rebound at admission): the price of a
 fixed-shape program, and exactly the slot semantics of continuous
 batching servers (Orca-style iteration-level scheduling).
-
-``PagedKVPool`` (below) is the block/paged successor — the vLLM layout:
-fixed-size physical KV blocks shared across slots through a
-reference-counted ``BlockTable``, a ``PrefixCache`` that admits
-already-resident prompt prefixes by bumping refcounts instead of
-re-prefilling, LRU eviction of unreferenced prefixes under allocation
-pressure, and copy-on-write at any shared boundary a fork creates. The
-contiguous ``KVCachePool`` stays as the oracle layout the paged path is
-tested token-identical against (and the ``paged=False`` engine mode).
 """
 
 from __future__ import annotations
@@ -65,158 +56,8 @@ from elephas_tpu.models.decode_cache import (
 )
 
 
-def _vectorize_indices(cache, max_slots: int):
-    """Replace every scalar cache index leaf with a per-slot vector."""
-
-    def fix(path, leaf):
-        if leaf_kind(path) == INDEX:
-            assert leaf.ndim == 0, f"{path} already vectorized?"
-            return jnp.zeros((max_slots,), jnp.int32)
-        return leaf
-
-    return jax.tree_util.tree_map_with_path(fix, cache)
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1))
-def _write_slot(pool_cache, pad, prefill_cache, slot, pad_offset):
-    """Copy a batch-1 prefill cache into ``slot``'s row of the pool.
-
-    ``slot`` is a traced int32 — one compiled program admits to any
-    slot. Index leaves (pool (S,), prefill scalar) are distinguished
-    from data leaves (pool (S, ...), prefill (1, ...)) by rank. The
-    pool cache and pad vector are DONATED: XLA writes the slot row in
-    place, so admission costs one row, not a whole-pool copy.
-    """
-
-    def write(pool_leaf, pre_leaf):
-        if pre_leaf.ndim == 0:  # cache_index / pos_index
-            return jax.lax.dynamic_update_slice(
-                pool_leaf, pre_leaf[None].astype(pool_leaf.dtype), (slot,)
-            )
-        return jax.lax.dynamic_update_slice(
-            pool_leaf, pre_leaf.astype(pool_leaf.dtype),
-            (slot,) + (0,) * (pre_leaf.ndim - 1),
-        )
-
-    new_cache = jax.tree_util.tree_map(write, pool_cache, prefill_cache)
-    new_pad = jax.lax.dynamic_update_slice(pad, pad_offset[None], (slot,))
-    return new_cache, new_pad
-
-
 class DonatedBufferError(RuntimeError):
     """A pool cache reference was read after its buffers were donated."""
-
-
-class KVCachePool:
-    """Fixed-shape KV cache + slot bookkeeping for the serving engine.
-
-    ``decode_module``: a ``TransformerLM`` with ``decode=True``.
-    ``max_slots``: decode batch width (concurrent sequences).
-    ``max_len``: cache columns per slot — an admitted sequence may run
-    to ``prefill_len + generated <= max_len``.
-
-    The live cache is read through the ``cache`` property and replaced
-    with ``swap(new_cache)`` after every donating program. The property
-    refuses to hand out donated (deleted) buffers — the failure mode
-    donation introduces is a stale alias kept across a swap, and that
-    must fail loudly at the POOL boundary, not as a deep XLA error.
-    """
-
-    def __init__(self, decode_module, max_slots: int, max_len: int):
-        from elephas_tpu.models.transformer import make_decode_cache
-
-        if max_slots < 1:
-            raise ValueError(f"max_slots must be >= 1, got {max_slots}")
-        self.max_slots = max_slots
-        self.max_len = max_len
-        self._cache = _vectorize_indices(
-            make_decode_cache(decode_module, max_slots, max_len), max_slots
-        )
-        self._pad = jnp.zeros((max_slots,), jnp.int32)
-        self._free: List[int] = list(range(max_slots))
-        self.admitted_total = 0  # lifetime admissions (slot reuse visible)
-
-    # -- donation-guarded cache access -------------------------------------
-
-    @staticmethod
-    def _guard(tree, name: str):
-        # One leaf suffices: every leaf of a donated pytree is deleted
-        # by the same program call.
-        leaf = jax.tree_util.tree_leaves(tree)[0]
-        if getattr(leaf, "is_deleted", lambda: False)():
-            raise DonatedBufferError(
-                f"KV pool {name} was donated to a compiled program and "
-                "its buffers are gone; use the value returned by that "
-                "program (the engine swaps it back via pool.swap)"
-            )
-        return tree
-
-    @property
-    def cache(self):
-        """The live cache pytree (raises ``DonatedBufferError`` if the
-        held buffers were donated without a ``swap``)."""
-        return self._guard(self._cache, "cache")
-
-    @property
-    def pad(self):
-        """Per-slot left-pad counts, same donation guard as ``cache``."""
-        return self._guard(self._pad, "pad")
-
-    def swap(self, new_cache, new_pad=None) -> None:
-        """Install the cache (and optionally pad) a donating program
-        returned. The old references are dead the moment the program was
-        dispatched — this is the only legal way to keep the pool live."""
-        self._cache = new_cache
-        if new_pad is not None:
-            self._pad = new_pad
-
-    # -- slot bookkeeping --------------------------------------------------
-
-    @property
-    def free_count(self) -> int:
-        return len(self._free)
-
-    @property
-    def active_count(self) -> int:
-        return self.max_slots - len(self._free)
-
-    def active_slots(self) -> List[int]:
-        """Occupied slot ids, ascending (the decode step's active mask)."""
-        free = set(self._free)
-        return [s for s in range(self.max_slots) if s not in free]
-
-    def acquire(self) -> Optional[int]:
-        """Claim a free slot id, or None when the pool is saturated."""
-        if not self._free:
-            return None
-        return self._free.pop()
-
-    def admit(self, slot: int, prefill_cache, pad_offset: int) -> None:
-        """Write a finished batch-1 prefill into ``slot`` and record its
-        left-pad count. The prefill cache's scalar indices carry the
-        write position (= prefill length) into the slot's vectors."""
-        self.swap(*_write_slot(
-            self.cache, self.pad, prefill_cache, jnp.int32(slot),
-            jnp.int32(pad_offset),
-        ))
-        self.admitted_total += 1
-
-    def release(self, slot: int) -> None:
-        """Return ``slot`` to the free list. No device work: in THIS
-        contiguous layout the slot exclusively owns its cache row, so
-        the stale contents are simply overwritten by the next admit.
-        (``PagedKVPool.release`` is the refcount-aware version — under
-        paging a released slot's blocks may still be shared with other
-        slots or the prefix cache, so release drops references instead
-        of abandoning storage.) Double-release raises."""
-        if slot in self._free:
-            raise ValueError(f"slot {slot} is already free")
-        if not 0 <= slot < self.max_slots:
-            raise ValueError(f"slot {slot} out of range [0, {self.max_slots})")
-        self._free.append(slot)
-
-
-# -- paged layout ------------------------------------------------------------
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -409,11 +250,17 @@ class PrefixCache:
         return entry
 
 
-class PagedKVPool(KVCachePool):
-    """Block/paged KV pool: fixed-size physical blocks shared across
-    slots through a ``BlockTable``, reference-counted, with a
-    ``PrefixCache`` so prompts whose prefix is already resident admit by
-    bumping refcounts instead of re-prefilling.
+class PagedKVPool:
+    """Fixed-shape paged KV cache + slot bookkeeping for the serving
+    engine: fixed-size physical blocks shared across slots through a
+    ``BlockTable``, reference-counted, with a ``PrefixCache`` so prompts
+    whose prefix is already resident admit by bumping refcounts instead
+    of re-prefilling.
+
+    ``decode_module``: a language model with ``decode=True``.
+    ``max_slots``: decode batch width (concurrent sequences).
+    ``max_len``: cache columns a sequence may fill, prompt and generated
+    tokens together.
 
     Layout: every K/V leaf is ``num_blocks`` physical blocks of
     ``block_size`` columns, ``(num_blocks, heads, rows, lanes)`` with a
@@ -424,10 +271,9 @@ class PagedKVPool(KVCachePool):
     ``blocks_per_slot * block_size >= max_len`` (ceil, so ``block_size``
     need not divide ``max_len``). The compiled decode and chunk-prefill
     programs write and attend on the blocks in place through the table;
-    speculative windows gather their rows through it, run the same dense
-    cache-attention apply as the contiguous pool (token identity by
-    construction), and set back exactly the blocks they wrote
-    (``ops.attention`` paged helpers).
+    speculative windows gather their rows through it, run the dense
+    cache-attention apply that ``generate()`` runs, and set back exactly
+    the blocks they wrote (``ops.attention`` paged helpers).
 
     Invariants the allocator maintains (and tests pin):
 
@@ -435,8 +281,7 @@ class PagedKVPool(KVCachePool):
     - a slot's row references each of its blocks exactly once, a prefix
       cache entry once per entry containing it;
     - ``release`` decrefs, never abandons — double-releasing a block
-      raises ``RuntimeError`` loudly (the contiguous pool could never
-      detect this);
+      raises ``RuntimeError`` loudly;
     - allocation under pressure evicts UNREFERENCED-by-slots prefix
       entries LRU-first (flight kind ``prefix_evict``), and with the
       default ``num_blocks = max_slots * blocks_per_slot`` sizing can
@@ -447,9 +292,11 @@ class PagedKVPool(KVCachePool):
     boundary. ``ensure_writable`` is the copy-on-write safety net for
     explicit ``fork_slot`` aliases (tests, speculative decoding).
 
-    Donation discipline is inherited: the cache property refuses
-    donated buffers (``DonatedBufferError``) and ``swap`` is the only
-    legal reinstall.
+    The live cache is read through the ``cache`` property and replaced
+    with ``swap(new_cache)`` after every donating program. The property
+    refuses to hand out donated (deleted) buffers — the failure mode
+    donation introduces is a stale alias kept across a swap, and that
+    must fail loudly at the POOL boundary, not as a deep XLA error.
     """
 
     def __init__(self, decode_module, max_slots: int, max_len: int,
@@ -490,12 +337,12 @@ class PagedKVPool(KVCachePool):
         self.stateful = has_state(self._cache)
         self.state_bytes = state_bytes(self._cache)
         self.state_resets = 0  # rows zeroed: at admission and at release
-        # Paged prompts are never left-padded (shared prefixes must land
-        # at identical cache columns in every slot); the zero pad vector
-        # keeps the decode_fn signature identical to the contiguous pool.
+        # Prompts are never left-padded (shared prefixes must land at
+        # identical cache columns in every slot): the pad vector stays
+        # zero, an operand the decode and speculation programs still take.
         self._pad = jnp.zeros((max_slots,), jnp.int32)
         self._free: List[int] = list(range(max_slots))
-        self.admitted_total = 0
+        self.admitted_total = 0  # lifetime admissions (slot reuse visible)
         self.table = BlockTable(max_slots, self.blocks_per_slot,
                                 self.num_blocks)
         self._ref = np.zeros((self.num_blocks,), np.int64)
@@ -523,6 +370,62 @@ class PagedKVPool(KVCachePool):
         self._cost_clock = None
         self._owner: Dict[int, Optional[str]] = {}
         self._billed_at: Dict[int, float] = {}
+
+    # -- donation-guarded cache access -------------------------------------
+
+    @staticmethod
+    def _guard(tree, name: str):
+        # One leaf suffices: every leaf of a donated pytree is deleted
+        # by the same program call.
+        leaf = jax.tree_util.tree_leaves(tree)[0]
+        if getattr(leaf, "is_deleted", lambda: False)():
+            raise DonatedBufferError(
+                f"KV pool {name} was donated to a compiled program and "
+                "its buffers are gone; use the value returned by that "
+                "program (the engine swaps it back via pool.swap)"
+            )
+        return tree
+
+    @property
+    def cache(self):
+        """The live cache pytree (raises ``DonatedBufferError`` if the
+        held buffers were donated without a ``swap``)."""
+        return self._guard(self._cache, "cache")
+
+    @property
+    def pad(self):
+        """Per-slot left-pad counts (all zero: nothing pads a prompt),
+        same donation guard as ``cache``."""
+        return self._guard(self._pad, "pad")
+
+    def swap(self, new_cache, new_pad=None) -> None:
+        """Install the cache (and optionally pad) a donating program
+        returned. The old references are dead the moment the program was
+        dispatched — this is the only legal way to keep the pool live."""
+        self._cache = new_cache
+        if new_pad is not None:
+            self._pad = new_pad
+
+    # -- slot bookkeeping --------------------------------------------------
+
+    @property
+    def free_count(self) -> int:
+        return len(self._free)
+
+    @property
+    def active_count(self) -> int:
+        return self.max_slots - len(self._free)
+
+    def active_slots(self) -> List[int]:
+        """Occupied slot ids, ascending (the decode step's active mask)."""
+        free = set(self._free)
+        return [s for s in range(self.max_slots) if s not in free]
+
+    def acquire(self) -> Optional[int]:
+        """Claim a free slot id, or None when the pool is saturated."""
+        if not self._free:
+            return None
+        return self._free.pop()
 
     # -- block accounting ----------------------------------------------------
 
@@ -635,13 +538,6 @@ class PagedKVPool(KVCachePool):
                 f"block {b}: ref={self._ref[b]} != holders={holders[b]}")
 
     # -- slot lifecycle ------------------------------------------------------
-
-    def admit(self, slot, prefill_cache, pad_offset) -> None:
-        raise RuntimeError(
-            "PagedKVPool has no wholesale admit: prefill writes through "
-            "the block table (the engine's chunked-prefill program), "
-            "then the scheduler activates the slot"
-        )
 
     def _refuse_state(self, what: str) -> None:
         if self.stateful:
@@ -766,14 +662,13 @@ class PagedKVPool(KVCachePool):
                 tokens: Optional[Sequence[int]] = None) -> None:
         """Refcount-aware release: ``slot`` returns to the free list and
         DROPS one reference on each of its blocks — shared blocks
-        survive for their other holders (unlike the contiguous pool,
-        a released row's storage is NOT simply overwritten by the next
-        admit). ``tokens`` — the slot's full token chain, prompt +
-        generated — lets the prefix cache adopt the full-block prefixes
-        before the references drop, so a follow-up turn of the same
-        conversation admits without re-prefilling. Double-releasing the
-        slot raises ``ValueError``; a corrupt row that decrefs a free
-        block raises ``RuntimeError``."""
+        survive for their other holders. ``tokens`` — the slot's full
+        token chain, prompt + generated — lets the prefix cache adopt
+        the full-block prefixes before the references drop, so a
+        follow-up turn of the same conversation admits without
+        re-prefilling. Double-releasing the slot raises ``ValueError``;
+        a corrupt row that decrefs a free block raises
+        ``RuntimeError``."""
         if slot in self._free:
             raise ValueError(f"slot {slot} is already free")
         if not 0 <= slot < self.max_slots:

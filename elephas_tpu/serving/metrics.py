@@ -19,11 +19,10 @@ Two record streams share the sink, tagged by ``event``:
   ``admit_s``, ``prefill_s``, ``dispatch_s``), the prompt work it
   dispatched (``prefill_tokens``, ``prefill_chunks``), the cache columns
   each decoding lane held before this step's decode (``lane_lengths``),
-  the paged pool's ``kv_blocks_in_use`` / ``kv_blocks_total``,
+  the pool's ``kv_blocks_in_use`` / ``kv_blocks_total``,
   ``decode_attention`` and ``prefill_attention`` (the attention body the
   decode program and the prefill program were traced with:
-  ``paged_pallas``, ``paged_xla`` or ``contiguous``), and
-  ``dispatch_to_fetch_s`` — the
+  ``paged_pallas`` or ``paged_xla``), and ``dispatch_to_fetch_s`` — the
   device-overlap gauge: wall seconds between a decode step's dispatch
   and the harvest of its tokens. On the pipelined path all host
   bookkeeping for the previous step happens inside this window, so the

@@ -1,6 +1,6 @@
 """Continuous-batching scheduler: iteration-level request scheduling
-over a fixed-shape KV-cache pool, with a one-step-lookahead pipelined
-decode hot path.
+over a fixed-shape paged KV-cache pool, with chunked prefill and a
+one-step-lookahead pipelined decode hot path.
 
 The scheduling unit is one DECODE ITERATION, not one request (Orca-style
 continuous batching). In the default PIPELINED mode each ``step()``:
@@ -14,10 +14,14 @@ continuous batching). In the default PIPELINED mode each ``step()``:
    host bookkeeping — stop-token checks, budget exhaustion, deadline
    eviction, admission prefills, metrics — OVERLAPPED with step N+1's
    device compute,
-3. admits queued requests while free slots last; an admitted request's
-   prefill-produced first token reaches the device as a per-lane
-   OVERRIDE on the next dispatch (a ``where`` folded into the one
-   compiled decode program, not a new program).
+3. admits queued requests while free slots last: a slot is claimed,
+   the longest resident prefix of the prompt is bound by refcount, and
+   the rest of the prompt lands chunk by chunk
+   (``prefill_chunks_per_step`` bounds the chunks a step dispatches, so
+   decode steps interleave with a long prompt). The token the last
+   chunk samples reaches the device as a per-lane OVERRIDE on the next
+   dispatch (a ``where`` folded into the one compiled decode program,
+   not a new program).
 
 Pipelining semantics: token streams are IDENTICAL to the unpipelined
 path (``pipeline=False``). The only observable differences are (a) a
@@ -33,13 +37,13 @@ the same reject-then-backoff contract the parameter-server client
 implements on its side with ``_RETRY_DELAYS``.
 
 The scheduler is deliberately device-agnostic: it drives two injected
-callables (``prefill_fn``, ``decode_fn``) and a ``KVCachePool``, so
-tests can clock it with fakes and the engine owns the compiled closures.
+callables (``chunk_prefill_fn``, ``decode_fn``) and a ``PagedKVPool``,
+so tests can clock it with fakes and the engine owns the compiled
+closures.
 """
 
 from __future__ import annotations
 
-import threading
 import time
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -180,8 +184,8 @@ class _Active:
 
 @dataclass
 class _Prefilling:
-    """A slot mid-chunked-prefill (paged pools only): the prompt's
-    columns land chunk-by-chunk, interleaved with decode steps when a
+    """A slot mid-chunked-prefill: the prompt's columns land
+    chunk-by-chunk, interleaved with decode steps when a
     per-step chunk budget is set. Holds only the device token from the
     LATEST chunk — it is read (one fetch) at finalize, never between
     chunks."""
@@ -211,12 +215,19 @@ class _Inflight:
 
 
 class ContinuousBatchingScheduler:
-    """Drives prefill/decode interleaving over a ``KVCachePool``.
+    """Drives prefill/decode interleaving over a ``PagedKVPool``.
 
-    ``prefill_fn(prompt, pad_offset) -> (first_token, prefill_cache)``
-        batch-1 prefill at the fixed prompt width; ``prompt`` is the
-        left-padded (1, max_prompt_len) token array, ``pad_offset`` the
-        scalar pad-column count. ``first_token`` is a DEVICE scalar.
+    ``chunk_prefill_fn(tokens, slot, start, valid) -> first_token``
+        one prompt CHUNK for one slot through the block table.
+        ``tokens`` is the (1, prefill_chunk) right-padded chunk,
+        ``start`` the slot column it begins at, ``valid`` its real token
+        count; the returned DEVICE scalar is the token sampled at the
+        chunk's last valid position (read only for the final chunk).
+        Admission matches the prompt against the prefix cache, prefill
+        runs chunked (``prefill_chunks_per_step`` bounds chunks
+        dispatched per step; None runs every pending chunk at
+        admission), every decode column is backed by a block before its
+        step, and release publishes the slot's token chain.
     ``decode_fn(cache, prev_tokens, override_vals, override_mask,
     active_mask, pad) -> (next_tokens, new_cache)``
         one decode step over all ``pool.max_slots`` rows.
@@ -228,20 +239,9 @@ class ContinuousBatchingScheduler:
         whose cache index vectors may advance. The cache argument is
         DONATED — callers must treat it as dead and use ``new_cache``
         (the scheduler swaps it into the pool immediately).
-    ``chunk_prefill_fn(tokens, slot, start, valid) -> first_token``
-        paged pools only: one prompt CHUNK for one slot through the
-        block table. ``tokens`` is the (1, prefill_chunk) right-padded
-        chunk, ``start`` the slot column it begins at, ``valid`` its
-        real token count; the returned DEVICE scalar is the token
-        sampled at the chunk's last valid position (read only for the
-        final chunk). When set, the scheduler runs the paged admission
-        path: prefix-cache match at admission, chunked prefill
-        (``prefill_chunks_per_step`` bounds chunks dispatched per step;
-        None runs every pending chunk at admission), block backing per
-        decode column, and chain-publishing release.
     ``spec_decode_fn(cache, prev_tokens, override_vals, override_mask,
     active_mask, pad) -> (last, emitted, accepted) | None``
-        speculative decode (paged + chunked prefill only): ONE
+        speculative decode: ONE
         draft-and-verify window over all lanes. ``last`` chains as the
         next dispatch's ``prev_tokens`` exactly like ``decode_fn``'s
         output; ``emitted`` is the (max_slots, gamma + 1) matrix of
@@ -257,7 +257,7 @@ class ContinuousBatchingScheduler:
         self,
         pool,
         queue: RequestQueue,
-        prefill_fn: Callable,
+        chunk_prefill_fn: Callable,
         decode_fn: Callable,
         max_prompt_len: int,
         pad_token: int = 0,
@@ -266,7 +266,6 @@ class ContinuousBatchingScheduler:
         pipeline: bool = True,
         tracer=None,
         load=None,
-        chunk_prefill_fn: Optional[Callable] = None,
         prefill_chunk: Optional[int] = None,
         prefill_chunks_per_step: Optional[int] = None,
         spec_decode_fn: Optional[Callable] = None,
@@ -275,19 +274,14 @@ class ContinuousBatchingScheduler:
     ):
         self.pool = pool
         self.queue = queue
-        self.prefill_fn = prefill_fn
-        self.decode_fn = decode_fn
         self.chunk_prefill_fn = chunk_prefill_fn
-        self.paged = chunk_prefill_fn is not None
+        self.decode_fn = decode_fn
         self.prefill_chunk = (
             prefill_chunk if prefill_chunk is not None else max_prompt_len
         )
         self.prefill_chunks_per_step = prefill_chunks_per_step
         self.spec_decode_fn = spec_decode_fn
         self.gamma = gamma
-        if spec_decode_fn is not None and not self.paged:
-            raise ValueError("spec_decode_fn requires the paged path "
-                             "(chunk_prefill_fn)")
         self.max_prompt_len = max_prompt_len
         self.pad_token = pad_token
         self.metrics = metrics
@@ -309,7 +303,7 @@ class ContinuousBatchingScheduler:
         # cheap early return, so recording can stay in the hot path.
         self.tracer = tracer if tracer is not None else obs.default_tracer()
         self._active: Dict[int, _Active] = {}  # slot -> _Active
-        self._prefilling: Dict[int, _Prefilling] = {}  # paged mid-prefill
+        self._prefilling: Dict[int, _Prefilling] = {}  # slot -> mid-prefill
         # req_id -> exported handoff (prefill-only requests park their
         # finished prompt here for the engine's ``pop_handoff``).
         self._handoffs: Dict[int, Dict] = {}
@@ -346,17 +340,14 @@ class ContinuousBatchingScheduler:
     # -- lifecycle ---------------------------------------------------------
 
     def _finish(self, entry: _Active, status: str) -> GenerationResult:
-        if self.paged:
-            # Publish the slot's token chain to the prefix cache before
-            # the block references drop: exactly the columns with K/V
-            # deterministically on device — ``next_col`` counts
-            # dispatched writes, including the pipelined in-flight step
-            # (device-ordered before any later sharer's gather).
-            chain = (list(entry.request.prompt)
-                     + list(entry.tokens))[:entry.next_col]
-            self.pool.release(entry.slot, tokens=chain)
-        else:
-            self.pool.release(entry.slot)
+        # Publish the slot's token chain to the prefix cache before the
+        # block references drop: exactly the columns with K/V
+        # deterministically on device — ``next_col`` counts dispatched
+        # writes, including the pipelined in-flight step (device-ordered
+        # before any later sharer's gather).
+        chain = (list(entry.request.prompt)
+                 + list(entry.tokens))[:entry.next_col]
+        self.pool.release(entry.slot, tokens=chain)
         del self._active[entry.slot]
         self._overrides.pop(entry.slot, None)
         req = entry.request
@@ -508,82 +499,10 @@ class ContinuousBatchingScheduler:
             self.costs.record_status(req.tenant, "timeout")
 
     def _admit_from_queue(self) -> None:
-        import jax.numpy as jnp
-
-        if self.paged:
-            self._admit_paged()
-            return
-        while self.pool.free_count > 0:
-            req = self.queue.pop()
-            if req is None:
-                return
-            t_pop = self.clock()
-            track = f"req:{req.req_id}"
-            if req.deadline is not None and t_pop >= req.deadline:
-                self._expire_queued(req, t_pop)
-                continue
-            plen = len(req.prompt)
-            pad = self.max_prompt_len - plen
-            padded = jnp.asarray(  # host list → device upload
-                [[self.pad_token] * pad + list(req.prompt)], jnp.int32
-            )
-            t_pre0 = self.clock()
-            first_dev, prefill_cache = self.prefill_fn(padded, jnp.int32(pad))
-            # The admission-path sync: on the pipelined path this overlaps
-            # the in-flight decode step dispatched before bookkeeping.
-            first = host_sync.fetch_scalar(first_dev)
-            t_pre1 = self.clock()
-            slot = self.pool.acquire()
-            assert slot is not None  # guarded by free_count above
-            self.pool.admit(slot, prefill_cache, pad)
-            # Cache capacity bounds generation: prompt + generated tokens
-            # all live in max_len columns (pad columns included).
-            budget = min(
-                req.max_new_tokens, self.pool.max_len - self.max_prompt_len
-            )
-            entry = _Active(
-                request=req, slot=slot, tokens=[first],
-                token_times=[self.clock()], budget=budget,
-                # rows hold the fixed prompt width, pad columns included
-                next_col=self.max_prompt_len, popped_at=t_pop,
-            )
-            entry.admitted_at = self.clock()
-            self._active[slot] = entry
-            self._prefill_tokens += plen
-            self._prefill_chunks += 1
-            if self.costs is not None:
-                # Queue residency ends here; the prompt's prefill and
-                # its first emitted token bill now (the contiguous pool
-                # has no prefix cache — nothing is ever discounted).
-                self.costs.record_queue(req.tenant,
-                                        t_pop - req.submitted_at)
-                self.costs.record_prefill(req.tenant, plen)
-                self.costs.record_decode(req.tenant, 1)
-            if self.tracer.enabled:
-                self.tracer.record(
-                    "queue", req.submitted_at, t_pop, track=track,
-                    req_id=req.req_id,
-                )
-                self.tracer.record(
-                    "prefill", t_pre0, t_pre1, track=track,
-                    req_id=req.req_id, prompt_tokens=plen,
-                )
-                self.tracer.record(
-                    "admit", t_pop, entry.admitted_at, track=track,
-                    req_id=req.req_id, slot=slot,
-                )
-            if first == req.stop_token or len(entry.tokens) >= budget:
-                self._finish(entry, "completed")
-            else:
-                self._overrides[slot] = first
-
-    # -- paged admission: prefix match + chunked prefill ---------------------
-
-    def _admit_paged(self) -> None:
-        """Paged admission: claim a slot, bind the longest resident
-        prompt prefix (refcount bumps, zero prefill compute), and park
-        the request mid-prefill — ``_advance_prefills`` lands the
-        remaining columns chunk by chunk."""
+        """Admission: claim a slot, bind the longest resident prompt
+        prefix (refcount bumps, zero prefill compute), and park the
+        request mid-prefill — ``_advance_prefills`` lands the remaining
+        columns chunk by chunk."""
         while self.pool.free_count > 0:
             req = self.queue.pop()
             if req is None:
@@ -597,8 +516,7 @@ class ContinuousBatchingScheduler:
             # Declare the slot's owner BEFORE the first block binds so
             # every block-second — including the prefix-bound ones —
             # bills this tenant from the first instant.
-            if self.costs is not None and \
-                    hasattr(self.pool, "set_slot_owner"):
+            if self.costs is not None:
                 self.pool.set_slot_owner(slot, req.tenant)
             matched = self.pool.admit_prefix(slot, req.prompt)
             if self.costs is not None:
@@ -646,9 +564,8 @@ class ContinuousBatchingScheduler:
 
     def _finalize_prefill(self, pf: _Prefilling) -> None:
         """Every prompt column is on device: fetch the first generated
-        token (the ONE prefill-path sync, same as the contiguous
-        admission), publish the prompt to the prefix cache, and join the
-        decode batch."""
+        token (the ONE prefill-path sync), publish the prompt to the
+        prefix cache, and join the decode batch."""
         req = pf.request
         t_sync0 = self.clock()
         first = host_sync.fetch_scalar(pf.first_dev)
@@ -661,8 +578,8 @@ class ContinuousBatchingScheduler:
         if req.prefill_only:
             self._finalize_handoff(pf, first, t_pre1)
             return
-        # Same budget as the contiguous pool (capacity from the FIXED
-        # prompt width, not this prompt's length) — oracle parity.
+        # Capacity from the FIXED prompt width, not this prompt's length:
+        # what a request may generate does not depend on its prompt.
         budget = min(
             req.max_new_tokens, self.pool.max_len - self.max_prompt_len
         )
@@ -771,7 +688,7 @@ class ContinuousBatchingScheduler:
         if slot is None:
             raise QueueFull(self.pool.max_slots, self.pool.max_slots,
                             self.queue.retry_hint_s)
-        if self.costs is not None and hasattr(self.pool, "set_slot_owner"):
+        if self.costs is not None:
             self.pool.set_slot_owner(slot, request.tenant)
         try:
             self.pool.import_blocks(slot, chain, arrays,
@@ -903,11 +820,10 @@ class ContinuousBatchingScheduler:
             # backed above stay owned, and the plain path's
             # advance-at-dispatch accounting below takes over for it.
         for slot, entry in lanes:
-            if self.paged:
-                # Back (and exclusively own) the column each lane writes
-                # this step BEFORE the engine closure snapshots the
-                # device block table.
-                self.pool.ensure_decode_col(slot, entry.next_col)
+            # Back (and exclusively own) the column each lane writes
+            # this step BEFORE the engine closure snapshots the device
+            # block table.
+            self.pool.ensure_decode_col(slot, entry.next_col)
             entry.next_col += 1
         nxt, new_cache = self.decode_fn(
             self.pool.cache, prev_tokens, override_vals, override_mask,
@@ -1144,20 +1060,16 @@ class ContinuousBatchingScheduler:
                 prefill_tokens=self._prefill_tokens,
                 prefill_chunks=self._prefill_chunks,
                 lane_lengths=self._lane_lengths,
-                kv_blocks_in_use=(self.pool.blocks_in_use
-                                  if self.paged else None),
-                kv_blocks_total=(self.pool.num_blocks
-                                 if self.paged else None),
-                **(self.pool.state_signals() if self.paged else {}),
+                kv_blocks_in_use=self.pool.blocks_in_use,
+                kv_blocks_total=self.pool.num_blocks,
+                **self.pool.state_signals(),
             )
         if self.load is not None:
-            # Paged pools report BLOCK-granular KV pressure (free blocks
-            # beat free slots once blocks are shared across slots).
-            kv = (self.pool.load_signals()
-                  if hasattr(self.pool, "load_signals") else {})
+            # BLOCK-granular KV pressure (free blocks beat free slots
+            # once blocks are shared across slots).
+            kv = self.pool.load_signals()
             kv_free_frac = (
                 kv["kv_blocks_free"] / max(1, kv["kv_blocks_total"])
-                if kv else self.pool.free_count / self.pool.max_slots
             )
             self.load.observe(
                 queue_depth=len(self.queue),
@@ -1172,9 +1084,9 @@ class ContinuousBatchingScheduler:
                 tokens_total=(self.metrics.tokens_out
                               if self.metrics else 0),
                 now=t1,
-                kv_blocks_free=kv.get("kv_blocks_free"),
-                kv_blocks_total=kv.get("kv_blocks_total"),
-                prefix_hit_rate=kv.get("prefix_hit_rate"),
+                kv_blocks_free=kv["kv_blocks_free"],
+                kv_blocks_total=kv["kv_blocks_total"],
+                prefix_hit_rate=kv["prefix_hit_rate"],
                 spec_accept_rate=(
                     self.metrics.spec_accept_rate
                     if self.metrics is not None

@@ -459,10 +459,10 @@ def bench_prefix(compiled, max_slots: int, prompt_len: int,
     1. Prefix economics — multi-turn sessions sharing a system prompt
        on the paged engine: committed hit rate (the gate floors it at
        0.5) and prefill tokens the cache actually skipped.
-    2. Correctness — the SAME conversation workload on the contiguous
-       (``paged=False``) oracle engine must produce identical token
-       streams request-for-request (``token_identical`` is an
-       equal-rule in the gate, like the fleet router's).
+    2. Correctness — every conversation's token stream must equal
+       ``generate()`` of its full prompt, one row at a time
+       (``token_identical`` is an equal-rule in the gate, like the
+       fleet router's).
     3. Chunked prefill — a saturating long-prompt workload (prompts as
        long as the model seats, short decodes, admissions arriving
        faster than prefill drains) run twice: unchunked, every decode
@@ -476,6 +476,7 @@ def bench_prefix(compiled, max_slots: int, prompt_len: int,
     """
     import numpy as np
 
+    from elephas_tpu.models.transformer import generate
     from elephas_tpu.serving import InferenceEngine
 
     vocab = compiled.module.vocab_size
@@ -483,42 +484,40 @@ def bench_prefix(compiled, max_slots: int, prompt_len: int,
     sys_prompt = np.random.default_rng(9).integers(
         1, vocab, 2 * block).tolist()
 
-    def make_engine(paged: bool, **kw):
-        if paged:
-            kw.setdefault("kv_block_size", block)
-        return InferenceEngine(
+    def run_conversations():
+        eng = InferenceEngine(
             compiled,
             max_slots=max_slots,
             max_prompt_len=prompt_len,
             max_len=prompt_len + new_tokens + 1,
             queue_depth=sessions * turns + 3 * max_slots + 2,
             pipeline=True,
-            paged=paged,
-            **kw,
+            kv_block_size=block,
         )
-
-    def run_conversations(paged: bool):
-        eng = make_engine(paged)
         eng.result(eng.submit([1] * prompt_len, max_new_tokens=2))
         eng.metrics.reset()
         rng = np.random.default_rng(13)
-        streams = []
+        prompts, streams = [], []
         for _turn in range(turns):
             rids = []
             for _s in range(sessions):
                 plen = int(rng.integers(
                     1, prompt_len - len(sys_prompt) + 1))
                 prompt = sys_prompt + rng.integers(1, vocab, plen).tolist()
+                prompts.append(prompt)
                 rids.append(eng.submit(prompt, max_new_tokens=new_tokens))
             # Turn barrier: later turns arrive after earlier ones
             # published their prefixes — the repeat-conversation shape.
             streams.extend(
                 list(eng.result(r).tokens) for r in rids)
-        return streams, eng.stats()
+        return prompts, streams, eng.stats()
 
-    paged_streams, paged_stats = run_conversations(True)
-    oracle_streams, _ = run_conversations(False)
-    token_identical = paged_streams == oracle_streams
+    prompts, paged_streams, paged_stats = run_conversations()
+    token_identical = all(
+        stream == [int(t) for t in generate(
+            compiled, np.asarray([prompt], np.int32),
+            new_tokens)[0][len(prompt):]]
+        for prompt, stream in zip(prompts, paged_streams))
 
     itl_new = 4
     long_prompt = compiled.module.max_seq_len - itl_new - 1
@@ -532,7 +531,6 @@ def bench_prefix(compiled, max_slots: int, prompt_len: int,
             max_len=long_prompt + itl_new + 1,
             queue_depth=itl_requests + 2,
             pipeline=True,
-            paged=True,
             kv_block_size=block,
             prefill_chunk=chunk,
             prefill_chunks_per_step=per_step,
@@ -654,7 +652,6 @@ def bench_spec(compiled, max_slots: int, prompt_len: int,
             max_len=prompt_len + new_tokens + 1,
             queue_depth=sessions * turns + 2,
             pipeline=True,
-            paged=True,
             kv_block_size=block,
             # Model draft sources require prefix_cache=False (a
             # prefix-matched admission would leave the draft cache
@@ -1317,7 +1314,6 @@ def bench_fleet_disagg(compiled, max_slots: int, prompt_len: int,
             max_len=long_len + interactive_new + 1,
             queue_depth=2 * requests + 8,
             pipeline=True,
-            paged=True,
             kv_block_size=block,
         )
 
@@ -1727,8 +1723,8 @@ def main(argv=None) -> list:
     parser.add_argument("--prefix", action="store_true",
                         help="run the paged-pool arm: prefix-cache hit "
                              "economics on a shared-system-prompt "
-                             "multi-turn workload, paged-vs-contiguous "
-                             "token identity, and the chunked-vs-"
+                             "multi-turn workload, token identity "
+                             "to generate() per row, and the chunked-vs-"
                              "unchunked prefill ITL p99 tail")
     parser.add_argument("--spec", action="store_true",
                         help="run the speculative-decoding arm: draft-"

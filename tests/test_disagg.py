@@ -80,7 +80,6 @@ def _engine(compiled, **kw):
     kw.setdefault("max_prompt_len", 8)
     kw.setdefault("max_len", 24)
     kw.setdefault("queue_depth", 8)
-    kw.setdefault("paged", True)
     kw.setdefault("kv_block_size", 4)
     return InferenceEngine(compiled, **kw)
 

@@ -295,8 +295,6 @@ def test_speculation_fork_and_handoff_refuse_a_model_with_state(served):
     _, _, compiled = served
     with pytest.raises(NotImplementedError, match="rolled back"):
         _engine(compiled, speculative=True, gamma=2)
-    with pytest.raises(ValueError, match="paged pool"):
-        _engine(compiled, paged=False, kv_block_size=None, prefill_chunk=None)
     eng = _engine(compiled)
     rid = eng.submit(_prompts([9])[0], max_new_tokens=8, stop_token=None)
     eng.step()

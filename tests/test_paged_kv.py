@@ -5,10 +5,9 @@ The block allocator's contract is conservation — a block is free iff
 its refcount is 0, and the refcount equals the number of holders (slot
 rows + prefix-cache entries) at all times, including after adversarial
 seeded churn. The serving contract is identity: the paged layout and
-the chunked prefill program must emit EXACTLY the tokens the contiguous
-oracle (``paged=False``) and the per-row ``generate()`` oracle emit,
-over the full matrix (ragged prompts, EOS stops, deadline evictions,
-prefix-cache hits, per-step chunk budgets).
+the chunked prefill program must emit EXACTLY the tokens the per-row
+``generate()`` oracle emits, over the full matrix (ragged prompts, EOS
+stops, deadline evictions, prefix-cache hits, per-step chunk budgets).
 """
 
 import dataclasses
@@ -73,7 +72,6 @@ def _paged_engine(compiled, **kw):
     kw.setdefault("max_prompt_len", 8)
     kw.setdefault("max_len", 24)
     kw.setdefault("queue_depth", 8)
-    kw.setdefault("paged", True)
     return InferenceEngine(compiled, **kw)
 
 
@@ -112,12 +110,6 @@ def test_block_double_release_fails_loudly(compiled):
     pool._decref(block)  # simulate a corrupt row releasing early
     with pytest.raises(RuntimeError, match="double-released"):
         pool._decref(block)
-
-
-def test_wholesale_admit_is_refused(compiled):
-    pool = _pool(compiled)
-    with pytest.raises(RuntimeError, match="no wholesale admit"):
-        pool.admit(0, None, 0)
 
 
 def test_undersized_pool_dead_ends_loudly(compiled):
@@ -288,24 +280,19 @@ def _serve_all(eng, prompts, max_new_tokens=10, **submit_kw):
 PROMPTS = [[5, 3, 9], [1, 2, 3, 4, 5, 6, 7], [11, 12]]
 
 
-def test_paged_identical_to_contiguous_oracle(compiled):
-    """THE tentpole pin: the paged layout (gather → same apply →
-    scatter) emits exactly the contiguous pool's tokens, at one prefill
-    and one decode compile, across block sizes that do and don't divide
-    the prompt/cache lengths."""
-    oracle = None
-    for kw in (dict(paged=False), dict(paged=True),
-               dict(paged=True, kv_block_size=4),
-               dict(paged=True, kv_block_size=5)):
-        eng = _paged_engine(compiled, **kw)
-        got = _serve_all(eng, PROMPTS)
-        st = eng.stats()
-        assert st["prefill_traces"] == 1 and st["decode_traces"] == 1
-        if oracle is None:
-            oracle = got
-        else:
-            assert got == oracle, kw
-    for prompt, tokens in zip(PROMPTS, oracle):
+@pytest.mark.parametrize("layout", [{}, {"kv_block_size": 4},
+                                    {"kv_block_size": 5}],
+                         ids=["default_block", "block4", "block5"])
+def test_paged_identical_to_per_row_oracle(compiled, layout):
+    """THE tentpole pin: the paged layout emits exactly the tokens
+    ``generate()`` gives each row alone, at one prefill and one decode
+    compile, across block sizes that do and don't divide the
+    prompt/cache lengths."""
+    eng = _paged_engine(compiled, **layout)
+    got = _serve_all(eng, PROMPTS)
+    st = eng.stats()
+    assert st["prefill_traces"] == 1 and st["decode_traces"] == 1
+    for prompt, tokens in zip(PROMPTS, got):
         assert tokens == _per_row(compiled, prompt, 10)
 
 
@@ -396,24 +383,20 @@ def test_prefix_hit_skips_prefill_and_stays_identical(compiled):
 
 @pytest.mark.parametrize("chunk,per_step", [(3, None), (3, 1), (2, 2), (1, 1),
                                             (4, None), (8, None)])
-def test_paged_chunks_with_a_prefix_hit_identical_to_contiguous(
+def test_paged_chunks_with_a_prefix_hit_identical_to_per_row(
         compiled, chunk, per_step):
-    """The chunk program over the pool in place against the contiguous
-    oracle (``paged=False``), turn by turn, at every chunk width: the later
+    """The chunk program over the pool in place against ``generate()`` of
+    each turn's full prompt, turn by turn, at every chunk width: the later
     turns adopt the shared system prompt's block, so their first chunk
     starts at ``start > 0`` behind blocks it reads and never writes."""
     sys_prompt = [7, 8, 9, 10]
     prompts = [sys_prompt + [1, 2], sys_prompt + [3, 4, 5], [11, 12],
                sys_prompt + [1, 2]]
-
-    def turns(eng):
-        return [eng.result(eng.submit(p, max_new_tokens=6), timeout_s=120).tokens
-                for p in prompts]
-
-    oracle = turns(_paged_engine(compiled, paged=False, max_slots=2))
     eng = _paged_engine(compiled, max_slots=2, kv_block_size=4,
                         prefill_chunk=chunk, prefill_chunks_per_step=per_step)
-    assert turns(eng) == oracle
+    for prompt in prompts:
+        served = eng.result(eng.submit(prompt, max_new_tokens=6), timeout_s=120)
+        assert served.tokens == _per_row(compiled, prompt, 6), prompt
     st = eng.stats()
     assert st["prefill_traces"] == 1 and st["decode_traces"] == 1
     assert st["prefix_hits"] == 2 and st["prefix_tokens_saved"] == 8
@@ -737,6 +720,3 @@ def test_decode_attention_is_named_on_stats_and_step_events(compiled):
     assert steps and all(e["decode_attention"] == "paged_xla" for e in steps)
     assert all(e["prefill_attention"] == "paged_xla" for e in steps)
     assert all(leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(stale))
-    contiguous = _paged_engine(compiled, paged=False)
-    assert contiguous.stats()["decode_attention"] == "contiguous"
-    assert contiguous.stats()["prefill_attention"] == "contiguous"

@@ -218,19 +218,6 @@ def test_disabled_tracer_records_nothing_and_events_keep_their_fields(compiled):
     assert all(r.token_times and r.queue_s is not None for r in results)
 
 
-def test_contiguous_pool_counts_prompts_and_has_no_blocks(compiled):
-    sink = ListSink()
-    eng = InferenceEngine(compiled, max_slots=2, max_prompt_len=12, max_len=32,
-                          paged=False, sink=sink, tracer=obs.NULL_TRACER)
-    res = eng.result(eng.submit(PROMPTS[0], max_new_tokens=4, stop_token=None),
-                     timeout_s=120)
-    assert sum(s["prefill_tokens"] for s in sink.steps) == len(PROMPTS[0])
-    assert all(s["kv_blocks_in_use"] is None for s in sink.steps)
-    # rows hold the fixed prompt width and every token but the newest
-    assert [s["lane_lengths"] for s in sink.steps if s["lane_lengths"]][:2] == [[12], [13]]
-    assert res.queue_s + res.prefill_s == pytest.approx(res.ttft_s)
-
-
 def test_serving_metrics_reset_zeroes_the_step_counters():
     m = ServingMetrics()
     m.record_step(0, 1, tokens=3, step_seconds=0.1, prefill_tokens=7, prefill_chunks=1)

@@ -23,7 +23,7 @@ from elephas_tpu.metrics import (
 )
 from elephas_tpu.models import get_model
 from elephas_tpu.models.transformer import generate, generate_trace_count
-from elephas_tpu.serving import InferenceEngine, KVCachePool, QueueFull
+from elephas_tpu.serving import InferenceEngine, PagedKVPool, QueueFull
 
 VOCAB, SEQ = 97, 64
 
@@ -126,7 +126,7 @@ def test_pool_acquire_release_cycle(compiled):
     module = dataclasses.replace(
         compiled.module, decode=True, attention="dense"
     )
-    pool = KVCachePool(module, max_slots=2, max_len=16)
+    pool = PagedKVPool(module, max_slots=2, max_len=16, block_size=4)
     a, b = pool.acquire(), pool.acquire()
     assert {a, b} == {0, 1} and pool.acquire() is None
     assert pool.free_count == 0 and pool.active_count == 2

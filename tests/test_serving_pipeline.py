@@ -211,7 +211,7 @@ def test_decode_donation_kills_stale_cache_reference(compiled):
     silently see pre-donation data."""
     eng = _engine(compiled, max_slots=2)
     eng.submit([5, 3, 9], max_new_tokens=6)
-    eng.step()  # admit (admission's _write_slot already donates the pool)
+    eng.step()  # admit (the chunk program already donates the pool)
     stale = eng.pool.cache
     eng.step()  # decode step donates `stale`
     leaf = jax.tree_util.tree_leaves(stale)[0]

@@ -358,9 +358,7 @@ def test_spec_load_signals(compiled):
     assert "spec_accept_rate" not in plain_eng.load.snapshot()["signals"]
 
 
-def test_spec_requires_paged_and_validates(compiled):
-    with pytest.raises(ValueError, match="paged"):
-        _engine(compiled, paged=False, speculative=True)
+def test_spec_validates(compiled):
     with pytest.raises(ValueError, match="mutually exclusive"):
         _engine(compiled, speculative=True, draft_layers=1,
                 draft_source=SelfDraftSource(1))
