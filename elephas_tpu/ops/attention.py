@@ -240,30 +240,55 @@ PAGED_BODIES = ("paged_pallas", "paged_xla")
 # VMEM the kernel may plan for, inside Mosaic's default scoped limit.
 _PAGED_VMEM_BUDGET = 12 << 20
 
+# K and V bytes a grid step of the decode kernel moves at the least, where
+# a row has as many blocks and VMEM the room: a step costs about 0.4 us
+# whatever it moves (on the chip, microseconds a call of one layer at 1 / 2
+# / 4 / 8 blocks a step: gpt2-xl's 400-KB blocks 204 / 164 / 154 / 158,
+# where the copies alone take 146; the hybrid's 32-KB blocks at 1 / 8 / 32
+# / 48 / 72 a step 711 / 186 / 129 / 128 / 127).
+_DECODE_STEP_BYTES = 3 << 19
 
-def _paged_pallas_fits(pool_shape, dtype, q_heads=None) -> bool:
-    """Whether the kernel's tiles lower for this pool layout: rows a full
-    lane width, whole sublane tiles a head (so ``(heads, r, lanes)`` folds
-    to ``(heads * r, lanes)`` for free), and buffers that fit VMEM: two
-    each of a K and a V block read and written back, and the float32
-    scores of one block and what the softmax makes of them. ``q_heads``:
-    the query heads, where they are more than the pool's K/V heads."""
+
+def _paged_pallas_fits(pool_shape, dtype, head_dim, q_heads=None,
+                       blocks=1) -> bool:
+    """Whether the kernel's tiles lower for this pool layout at ``blocks``
+    blocks a grid step: rows a full lane width, whole sublane tiles a head
+    (so ``(blocks, heads, r, lanes)`` folds to ``(blocks * heads * r,
+    lanes)`` for free), and buffers that fit VMEM: two each of a step's K
+    and V blocks, and the float32 scores of a step, their columns and what
+    the softmax makes of them. ``q_heads``: the query heads, where they
+    are more than the pool's K/V heads."""
     _, heads, rows, lanes = pool_shape
     itemsize = jnp.dtype(dtype).itemsize
     if lanes % _LANES or itemsize not in (2, 4) or rows % (32 // itemsize):
         return False
     block_bytes = heads * rows * lanes * itemsize
-    query_rows = (lanes // _LANES) * (-(-(q_heads or heads) // 16) * 16)
+    query_rows = (lanes // head_dim) * (-(-(q_heads or heads) // 16) * 16)
     scores_bytes = 4 * query_rows * heads * rows
-    return 8 * block_bytes + 4 * scores_bytes <= _PAGED_VMEM_BUDGET
+    return blocks * (4 * block_bytes + 5 * scores_bytes) <= _PAGED_VMEM_BUDGET
 
 
-def paged_decode_body(pool_shape, dtype, mesh=None, q_heads=None) -> str:
+def paged_decode_blocks(pool_shape, dtype, head_dim, q_heads,
+                        blocks_per_slot) -> int:
+    """Blocks of a lane that one grid step of the decode kernel folds, from
+    the layout alone: as many as move ``_DECODE_STEP_BYTES``, a row's worth
+    at the most, and no more than VMEM holds (one, where that is all)."""
+    _, heads, rows, lanes = pool_shape
+    moved = 2 * heads * rows * lanes * jnp.dtype(dtype).itemsize  # K and V
+    blocks = max(1, min(blocks_per_slot, -(-_DECODE_STEP_BYTES // moved)))
+    while blocks > 1 and not _paged_pallas_fits(pool_shape, dtype, head_dim,
+                                                q_heads, blocks):
+        blocks -= 1
+    return blocks
+
+
+def paged_decode_body(pool_shape, dtype, head_dim, mesh=None,
+                      q_heads=None) -> str:
     """Name of the body ``paged_decode_attention`` runs for this backend,
     mesh and pool layout. A Pallas call is not partitioned by sharding
     annotations, so a mesh takes the XLA body."""
-    if _on_tpu() and mesh is None and _paged_pallas_fits(pool_shape, dtype,
-                                                         q_heads):
+    if _on_tpu() and mesh is None and _paged_pallas_fits(
+            pool_shape, dtype, head_dim, q_heads):
         return "paged_pallas"
     return "paged_xla"
 

@@ -482,77 +482,155 @@ _MASKED = -1e30
 
 
 def _paged_decode_kernel(
-    lane_ref, block_ref, phys_ref, tail_ref, idx_ref,  # scalar prefetch (SMEM)
+    lane_ref, first_ref, table_ref, idx_ref, steps_ref,  # scalar prefetch (SMEM)
     q_ref,  # (1, pack * rows, lanes)
     kn_ref, vn_ref,  # (1, heads, 1, lanes): the new column, in every lane group
-    k_ref, v_ref,  # one physical block (heads, r, lanes) each
-    o_ref, ko_ref, vo_ref,  # the lane's output; its tail block of each pool
+    k_hbm, v_hbm,  # the pools, left where they are
+    o_ref, ko_hbm, vo_hbm,  # the lane's output; the pools again (aliased)
+    k_buf, v_buf,  # (2, blocks, heads, r, lanes): a step's blocks, twice
+    sem, tail_sem,  # DMAs in flight: (pool, buffer, block); (pool,)
+    pos_ref,  # (pack * rows, blocks * heads * r): each score's column
     m_ref, l_ref, acc_ref,  # running max, sum, weighted values of one lane
-    *, heads: int, rows: int, pack: int, head_dim: int, blocks_per_slot: int,
-    sm_scale: float, q_per_kv: int = 1,
+    *, heads: int, rows: int, pack: int, head_dim: int, blocks: int,
+    blocks_per_slot: int, sm_scale: float, q_per_kv: int = 1,
 ):
-    """One live (lane, block) pair per grid step, a lane's blocks in
-    order, folded into the lane's running softmax; at the lane's last
-    block, its tail, the step's new K/V column is put into the block
-    before it is scored and the block goes back to the pool.
+    """Grid step ``w``: ``blocks`` consecutive blocks of one lane, from its
+    block ``first_ref[w]`` on, folded into the lane's running softmax; the
+    step that holds the lane's last block, its tail, puts the step's new
+    K/V column into that block before it is scored and sends the block
+    back to the pool.
+
+    The kernel moves the blocks itself: while step ``w`` is scored, the
+    live blocks of step ``w + 1`` are on their way into the other half of
+    ``k_buf`` / ``v_buf``. A block of a lane's last step that holds no live
+    column is not moved; what its place in the buffer holds (zeros, or a
+    block of an earlier step) is scored dead.
 
     A block is ``heads * r`` rows of ``pack`` columns each. Query row
     ``g * rows + h`` holds head ``h``'s query in lane group ``g`` and
-    zeros elsewhere, so one ``(pack * rows, lanes) x (heads * r,
-    lanes)^T`` matmul scores every column of the block: entry
-    ``[(g, h), (h', r)]`` is column ``r * pack + g`` of head ``h'``, and
-    the entries with ``h' != h`` are masked away (with grouped heads,
-    ``q_per_kv`` query heads to a K/V head, ``h' != h // q_per_kv``: the
-    pool holds the K/V heads the model has and none is replicated). The
-    MXU takes one call
-    a block where one-row calls, head by head, would starve it. Each
-    ``g`` keeps its own running softmax over its columns; they merge at
-    the tail, and lane group ``g`` of row ``(g, h)`` of the output then
-    holds its share of head ``h``'s result."""
-    del phys_ref, tail_ref  # read by the index maps only
+    zeros elsewhere, so one ``(pack * rows, lanes) x (blocks * heads * r,
+    lanes)^T`` matmul scores every column of the step: entry
+    ``[(g, h), (b, h', i)]`` is column ``(b * r + i) * pack + g`` of head
+    ``h'`` among the step's columns, and the entries with ``h' != h`` are
+    masked away (with grouped heads, ``q_per_kv`` query heads to a K/V
+    head, ``h' != h // q_per_kv``: the pool holds the K/V heads the model
+    has and none is replicated). ``pos_ref`` holds that column for the
+    entries a row owns and a number past every column for the rest; it is
+    built once a call, and a step's mask is one comparison with the new
+    column's place. The MXU takes one call a step where one-row calls,
+    head by head, would starve it. Each ``g`` keeps its own running
+    softmax over its columns; they merge at the tail, and lane group ``g``
+    of row ``(g, h)`` of the output then holds its share of head ``h``'s
+    result."""
     w = pl.program_id(0)
-    lane = lane_ref[w]
-    j = block_ref[w]
-    idx = idx_ref[lane]
-    r = k_ref.shape[1]
+    r, lanes = k_buf.shape[3:]
     width = heads * r
     block_size = r * pack
-    is_tail = j == jnp.minimum(idx // block_size, blocks_per_slot - 1)
 
-    @pl.when(j == 0)
+    def last_block(lane):
+        return jnp.minimum(idx_ref[lane] // block_size, blocks_per_slot - 1)
+
+    def each_live_block(step, buf, act):
+        """``act`` on the two copies of each block of step ``step`` that
+        holds a live column, into half ``buf`` of the buffers. A loop, not
+        ``blocks`` copies of its body: a step of many small blocks would
+        take seconds to trace and lower."""
+        lane, first = lane_ref[step], first_ref[step]
+
+        def body(b, carry):
+            phys = table_ref[lane * blocks_per_slot + first + b]
+            act(pltpu.make_async_copy(k_hbm.at[phys], k_buf.at[buf, b],
+                                      sem.at[0, buf, b]))
+            act(pltpu.make_async_copy(v_hbm.at[phys], v_buf.at[buf, b],
+                                      sem.at[1, buf, b]))
+            return carry
+
+        live = jnp.minimum(blocks, last_block(lane) - first + 1)
+        jax.lax.fori_loop(0, live, body, 0)
+
+    def fetch(step, buf):
+        each_live_block(step, buf, lambda copy: copy.start())
+
+    buf = jax.lax.rem(w, 2)
+
+    @pl.when(w == 0)
+    def _first():
+        # a place no copy has filled is scored dead: it has to be finite
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, blocks * width), 1)
+        block = sum((col >= b * width).astype(jnp.int32)
+                    for b in range(1, blocks))
+        row = jax.lax.broadcasted_iota(jnp.int32, (pack * rows, 1), 0)
+        group = sum((row >= g * rows).astype(jnp.int32)
+                    for g in range(1, pack))
+        q_head = row - group * rows
+        kv_head = q_head if q_per_kv == 1 else sum(
+            (q_head >= k * q_per_kv).astype(jnp.int32)
+            for k in range(1, heads))
+        # row of the block in this query's K/V head
+        own = col - block * width - kv_head * r
+        pos_ref[...] = jnp.broadcast_to(jnp.where(
+            (own >= 0) & (own < r),
+            block * block_size + own * pack + group,
+            jnp.iinfo(jnp.int32).max,
+        ), pos_ref.shape)
+        fetch(0, 0)
+
+    @pl.when(w + 1 < steps_ref[0])
+    def _ahead():
+        fetch(w + 1, 1 - buf)
+
+    each_live_block(w, buf, lambda copy: copy.wait())
+
+    lane, first = lane_ref[w], first_ref[w]
+    idx = idx_ref[lane]
+    last = last_block(lane)
+    is_tail = first + blocks > last  # the step holds the lane's last block
+
+    @pl.when(first == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _MASKED)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # the new column's place in the tail block: row at // pack, group at % pack
-    at = idx - j * block_size
-    in_row = jax.lax.broadcasted_iota(jnp.int32, k_ref.shape, 1) == at // pack
-    lanes_at = jax.lax.broadcasted_iota(jnp.int32, k_ref.shape, 2)
-    first_lane = (at % pack) * head_dim
-    here = is_tail & in_row & (lanes_at >= first_lane) & (
-        lanes_at < first_lane + head_dim)
-    k_blk = jnp.where(here, kn_ref[0], k_ref[...])
-    v_blk = jnp.where(here, vn_ref[0], v_ref[...])
+    def tail_copies():
+        phys = table_ref[lane * blocks_per_slot + last]
+        return [pltpu.make_async_copy(buffer.at[buf, last - first],
+                                      pool.at[phys], tail_sem.at[n])
+                for n, (buffer, pool) in enumerate(((k_buf, ko_hbm),
+                                                    (v_buf, vo_hbm)))]
 
-    k = k_blk.reshape(width, k_blk.shape[-1])
-    v = v_blk.reshape(width, v_blk.shape[-1])
+    @pl.when(is_tail)
+    def _write():
+        # the new column's place in the tail block: row at // pack, group
+        # at % pack
+        at = idx - last * block_size
+        shape = k_buf.shape[2:]
+        in_row = jax.lax.broadcasted_iota(jnp.int32, shape, 1) == at // pack
+        lanes_at = jax.lax.broadcasted_iota(jnp.int32, shape, 2)
+        first_lane = (at % pack) * head_dim
+        here = in_row & (lanes_at >= first_lane) & (
+            lanes_at < first_lane + head_dim)
+        b = last - first
+        k_buf[buf, b] = jnp.where(here, kn_ref[0], k_buf[buf, b])
+        v_buf[buf, b] = jnp.where(here, vn_ref[0], v_buf[buf, b])
+        for copy in tail_copies():
+            copy.start()
+
+    k = k_buf[buf].reshape(blocks * width, lanes)
+    v = v_buf[buf].reshape(blocks * width, lanes)
     s = jax.lax.dot_general(
         q_ref[0], k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     ) * sm_scale
-    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    group = sum((row >= g * rows).astype(jnp.int32) for g in range(1, pack))
-    q_head = row - group * rows
-    kv_head = q_head if q_per_kv == 1 else sum(
-        (q_head >= k * q_per_kv).astype(jnp.int32) for k in range(1, heads))
-    own = col - kv_head * r  # row of the block in this query's K/V head
-    live = (own >= 0) & (own < r) & (own * pack + group <= at)
-    s = jnp.where(live, s, _MASKED)
+    # the running max starts finite, so a dead entry's weight is
+    # exp(-inf) = 0 also in a row that has met no live column yet (a
+    # padded head row never does: its row is not read)
+    s = jnp.where(pos_ref[...] <= idx - first * block_size, s, _NEG_INF)
     m = m_ref[...]
     m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-    p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+    p = jnp.exp(s - m_new)
     alpha = jnp.exp(m - m_new)
     l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
     acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
@@ -563,22 +641,24 @@ def _paged_decode_kernel(
 
     @pl.when(is_tail)
     def _finalize():
-        ko_ref[...] = k_blk
-        vo_ref[...] = v_blk
         groups = [slice(g * rows, (g + 1) * rows) for g in range(pack)]
         m_all = functools.reduce(jnp.maximum, [m_ref[g] for g in groups])
         share = [jnp.exp(m_ref[g] - m_all) for g in groups]
-        # a group with no live column has l == 0 and share == 0 (a padded
-        # head row has all groups so: its row is not read)
+        # a group with no live column has l == 0 and share == 0
         total = sum(l_ref[g] * w_ for g, w_ in zip(groups, share))
         inv = 1.0 / jnp.maximum(total, 1e-30)
         for g, w_ in zip(groups, share):
             o_ref[0, g] = (acc_ref[g] * (w_ * inv)).astype(o_ref.dtype)
+        # the tail block left while the step was scored; the next step's
+        # copies may land in this half of the buffers only after it
+        for copy in tail_copies():
+            copy.wait()
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("blocks", "interpret"))
 def pallas_paged_decode_attention(q, k_new, v_new, k_pool, v_pool, table,
-                                  idx, active, interpret: bool = False):
+                                  idx, active, blocks: int | None = None,
+                                  interpret: bool = False):
     """One decode step of one layer on the paged pool, in place: each
     active lane's new K/V column goes into its tail block and its query
     attends over its blocks, columns ``<= idx``.
@@ -591,20 +671,30 @@ def pallas_paged_decode_attention(q, k_new, v_new, k_pool, v_pool, table,
     returned pools; ``table``: (slots, blocks_per_slot) int32; ``idx``:
     (slots,) the column each lane writes; ``active``: (slots,) bool.
     Returns ``(out, k_pool, v_pool)``, ``out`` (slots, q_heads, head_dim)
-    and zeros for an inactive lane.
+    and zeros for an inactive lane. ``blocks``: how many of a lane's
+    blocks a grid step folds; left out, ``ops.attention.
+    paged_decode_blocks`` derives it from the layout (tests pass it).
 
-    The grid runs over the live (lane, block) pairs only, lane by lane:
-    their count is a run-time value (a dynamic grid bound) and the lane,
-    the block's place in its row and its physical id are scalar-prefetch
-    arguments that the index maps read, so a block that no lane holds
-    live is never moved and one compiled kernel serves every length. Of
-    the pools only each active lane's tail block is written back.
+    The grid runs over the active lanes' steps only, lane by lane, a step
+    ``blocks`` consecutive blocks of the lane's row: their count is a
+    run-time value (a dynamic grid bound) and the kernel reads each step's
+    lane and first block, and the blocks' physical ids, from scalar-
+    prefetch arguments, so a block that holds no live column of its lane
+    is never moved and one compiled kernel serves every length. The pools
+    stay in HBM: the kernel copies a step's live blocks in while it scores
+    the step before, and of the pools only each active lane's tail block
+    is written back.
     """
+    from elephas_tpu.ops.attention import paged_decode_blocks
+
     slots, q_heads, head_dim = q.shape
     num_blocks, heads, r, lanes = k_pool.shape
     pack = lanes // head_dim
     block_size = r * pack
     blocks_per_slot = table.shape[1]
+    if blocks is None:
+        blocks = paged_decode_blocks(k_pool.shape, k_pool.dtype, head_dim,
+                                     q_heads, blocks_per_slot)
     rows = -(-q_heads // 16) * 16  # whole sublane tiles for bf16 and f32
     dtype = k_pool.dtype
     # query row (g, h): head h's query in lane group g, zeros elsewhere
@@ -618,16 +708,17 @@ def pallas_paged_decode_attention(q, k_new, v_new, k_pool, v_pool, table,
     idx = idx.astype(jnp.int32)
     table = jnp.clip(table.astype(jnp.int32), 0, num_blocks - 1)
     last = jnp.clip(idx // block_size, 0, blocks_per_slot - 1)
-    live = active[:, None] & (jnp.arange(blocks_per_slot)[None] <= last[:, None])
+    most = -(-blocks_per_slot // blocks)  # steps that cover a whole row
+    live = active[:, None] & (
+        jnp.arange(most)[None] * blocks <= last[:, None])
     (work,) = jnp.nonzero(live.reshape(-1), size=live.size, fill_value=0)
     work = work.astype(jnp.int32)
-    lane, block = work // blocks_per_slot, work % blocks_per_slot
-    phys = table.reshape(-1)[work]
-    tail = jnp.take_along_axis(table, last[:, None], axis=1)[:, 0]
+    lane, first = work // most, work % most * blocks
+    steps = live.sum().astype(jnp.int32)
 
     kernel = functools.partial(
         _paged_decode_kernel, heads=heads, rows=rows, pack=pack,
-        head_dim=head_dim, blocks_per_slot=blocks_per_slot,
+        head_dim=head_dim, blocks=blocks, blocks_per_slot=blocks_per_slot,
         sm_scale=1.0 / (head_dim ** 0.5), q_per_kv=q_heads // heads,
     )
     lane_rows = pl.BlockSpec(
@@ -636,23 +727,20 @@ def pallas_paged_decode_attention(q, k_new, v_new, k_pool, v_pool, table,
     lane_column = pl.BlockSpec(
         (1, heads, 1, lanes), lambda w, lane, *_: (lane[w], 0, 0, 0)
     )
-    live_block = pl.BlockSpec(
-        (None, heads, r, lanes),
-        lambda w, lane, block, phys, *_: (phys[w], 0, 0, 0),
-    )
-    tail_block = pl.BlockSpec(
-        (None, heads, r, lanes),
-        lambda w, lane, block, phys, tail, idx: (tail[lane[w]], 0, 0, 0),
-    )
+    pool = pl.BlockSpec(memory_space=pl.ANY)
     out, k_pool, v_pool = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
-            grid=(live.sum().astype(jnp.int32),),
-            in_specs=[lane_rows, lane_column, lane_column,
-                      live_block, live_block],
-            out_specs=[lane_rows, tail_block, tail_block],
+            grid=(steps,),
+            in_specs=[lane_rows, lane_column, lane_column, pool, pool],
+            out_specs=[lane_rows, pool, pool],
             scratch_shapes=[
+                pltpu.VMEM((2, blocks, heads, r, lanes), dtype),
+                pltpu.VMEM((2, blocks, heads, r, lanes), dtype),
+                pltpu.SemaphoreType.DMA((2, 2, blocks)),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((pack * rows, blocks * heads * r), jnp.int32),
                 pltpu.VMEM((pack * rows, 1), jnp.float32),
                 pltpu.VMEM((pack * rows, 1), jnp.float32),
                 pltpu.VMEM((pack * rows, lanes), jnp.float32),
@@ -670,7 +758,8 @@ def pallas_paged_decode_attention(q, k_new, v_new, k_pool, v_pool, table,
         ),
         interpret=pltpu.InterpretParams() if interpret else False,
         name="paged_decode_attention",
-    )(lane, block, phys, tail, idx, qp, *new, k_pool, v_pool)
+    )(lane, first, table.reshape(-1), idx, steps.reshape(1), qp, *new,
+      k_pool, v_pool)
     # head h: the sum over g of lane group g of row (g, h)
     out = out.reshape(slots, pack, rows, pack, head_dim)
     out = sum(out[:, g, :q_heads, g] for g in range(pack))

@@ -477,6 +477,15 @@ class InferenceEngine:
             )
         return nxt, mutated["cache"]
 
+    def _kv_layout(self):
+        """The pool's own K/V leaf and the head width its rows pack: the
+        leaf's heads are the model's K/V heads, which grouped attention
+        has fewer of than query heads; a block is ``block_size`` columns
+        a head."""
+        leaf = leaves_of_kind(self.pool.cache, KV)[0][1]
+        _, _, rows, lanes = leaf.shape
+        return leaf, rows * lanes // self.pool.block_size
+
     @property
     def decode_attention(self) -> str:
         """Name of the attention body the decode program is traced with:
@@ -484,11 +493,23 @@ class InferenceEngine:
         the mesh and the pool's layout."""
         from elephas_tpu.ops.attention import paged_decode_body
 
-        # the pool's own K/V leaf: its heads are the model's K/V heads,
-        # which grouped attention has fewer of than query heads
-        leaf = leaves_of_kind(self.pool.cache, KV)[0][1]
-        return paged_decode_body(leaf.shape, leaf.dtype, self.mesh,
+        leaf, head_dim = self._kv_layout()
+        return paged_decode_body(leaf.shape, leaf.dtype, head_dim, self.mesh,
                                  q_heads=self.decode_module.num_heads)
+
+    @property
+    def decode_kernel_blocks(self) -> Optional[int]:
+        """Blocks of a lane that a grid step of the decode kernel folds,
+        derived from the pool's layout as the kernel derives it; ``None``
+        where the decode program runs the XLA body."""
+        from elephas_tpu.ops.attention import paged_decode_blocks
+
+        if self.decode_attention != "paged_pallas":
+            return None
+        leaf, head_dim = self._kv_layout()
+        return paged_decode_blocks(
+            leaf.shape, leaf.dtype, head_dim, self.decode_module.num_heads,
+            self.pool.blocks_per_slot)
 
     @property
     def prefill_attention(self) -> str:
@@ -497,17 +518,17 @@ class InferenceEngine:
         the backend, the mesh, the pool's layout and the chunk's width."""
         from elephas_tpu.ops.attention import paged_chunk_body
 
-        leaf = leaves_of_kind(self.pool.cache, KV)[0][1]
-        _, _, rows, lanes = leaf.shape  # a block: block_size columns a head
-        return paged_chunk_body(
-            leaf.shape, leaf.dtype, rows * lanes // self.pool.block_size,
-            self.prefill_chunk, self.mesh)
+        leaf, head_dim = self._kv_layout()
+        return paged_chunk_body(leaf.shape, leaf.dtype, head_dim,
+                                self.prefill_chunk, self.mesh)
 
     def _name_attention(self):
-        """The bodies both programs will be traced with, onto every
-        ``step`` event."""
+        """The bodies both programs will be traced with, and the decode
+        kernel's blocks a grid step, onto every ``step`` event."""
         self.metrics.decode_attention = self.decode_attention
         self.metrics.prefill_attention = self.prefill_attention
+        self.metrics.decode_kernel_blocks = self.decode_kernel_blocks
+        self.metrics.kv_block_size = self.pool.block_size
 
     def _next_rng(self):
         # Sampling keys derive from (base key, pad-free stream position)
@@ -990,6 +1011,7 @@ class InferenceEngine:
             "prefill_traces": self._prefill_traces,
             "decode_traces": self._decode_traces,
             "decode_attention": self.decode_attention,
+            "decode_kernel_blocks": self.decode_kernel_blocks,
             "prefill_attention": self.prefill_attention,
             "pool_admitted_total": self.pool.admitted_total,
             "pool_active": self.pool.active_count,

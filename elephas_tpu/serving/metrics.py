@@ -22,7 +22,9 @@ Two record streams share the sink, tagged by ``event``:
   the pool's ``kv_blocks_in_use`` / ``kv_blocks_total``,
   ``decode_attention`` and ``prefill_attention`` (the attention body the
   decode program and the prefill program were traced with:
-  ``paged_pallas`` or ``paged_xla``), and ``dispatch_to_fetch_s`` — the
+  ``paged_pallas`` or ``paged_xla``), ``decode_kernel_blocks`` (the blocks
+  of a lane that a grid step of the decode kernel folds; ``None`` under
+  ``paged_xla``), and ``dispatch_to_fetch_s`` — the
   device-overlap gauge: wall seconds between a decode step's dispatch
   and the harvest of its tokens. On the pipelined path all host
   bookkeeping for the previous step happens inside this window, so the
@@ -59,6 +61,8 @@ class ServingMetrics:
         # Set by the engine (and again by shard_serving); kept by reset().
         self.decode_attention: Optional[str] = None
         self.prefill_attention: Optional[str] = None
+        self.decode_kernel_blocks: Optional[int] = None
+        self.kv_block_size: Optional[int] = None
         self.requests_submitted = 0
         self.requests_completed = 0
         self.requests_timed_out = 0
@@ -69,6 +73,12 @@ class ServingMetrics:
         # has finished.
         self.tokens_emitted_total = 0
         self.tokens_prefilled_total = 0
+        # Blocks the decode kernel's grid steps covered, step by step:
+        # those that held a live column of their lane, and those that
+        # only filled a lane's last grid step up to ``decode_kernel_blocks``
+        # (scored dead, not moved). Zero under ``paged_xla``.
+        self.decode_live_blocks = 0
+        self.decode_dead_blocks = 0
         self.steps = 0
         self.max_concurrent = 0
         self.ttft_s: list = []
@@ -104,6 +114,8 @@ class ServingMetrics:
         self.tokens_out = 0
         self.tokens_emitted_total = 0
         self.tokens_prefilled_total = 0
+        self.decode_live_blocks = 0
+        self.decode_dead_blocks = 0
         self.steps = 0
         self.max_concurrent = 0
         self.ttft_s = []
@@ -222,6 +234,12 @@ class ServingMetrics:
         self.steps += 1
         self.tokens_emitted_total += tokens
         self.tokens_prefilled_total += prefill_tokens
+        if self.decode_kernel_blocks:
+            # a lane that held ``n`` columns writes column ``n``
+            live = [n // self.kv_block_size + 1 for n in lane_lengths]
+            self.decode_live_blocks += sum(live)
+            self.decode_dead_blocks += sum(
+                -n % self.decode_kernel_blocks for n in live)
         self.max_concurrent = max(self.max_concurrent, active)
         overlap, self._last_overlap = self._last_overlap, None
         if self.sink is not None and self.steps % self.step_log_every == 0:
@@ -250,6 +268,7 @@ class ServingMetrics:
                 state_bytes=state_bytes,
                 decode_attention=self.decode_attention,
                 prefill_attention=self.prefill_attention,
+                decode_kernel_blocks=self.decode_kernel_blocks,
             )
 
     # -- aggregates --------------------------------------------------------
@@ -269,6 +288,8 @@ class ServingMetrics:
             "tokens_out": self.tokens_out,
             "tokens_emitted_total": self.tokens_emitted_total,
             "tokens_prefilled_total": self.tokens_prefilled_total,
+            "decode_live_blocks": self.decode_live_blocks,
+            "decode_dead_blocks": self.decode_dead_blocks,
             "steps": self.steps,
             "max_concurrent": self.max_concurrent,
             "ttft_s_avg": mean(self.ttft_s),

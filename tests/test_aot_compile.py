@@ -89,28 +89,40 @@ def test_pallas_backward_compiles_for_v5e(one_chip, seq, head_dim, dtype):
 _XL = dict(heads=25, head_dim=64, block_size=64, slots=8, blocks_per_slot=17)
 
 
-def test_paged_decode_kernel_compiles_for_v5e(one_chip):
-    """The decode attention kernel at the cell's shapes: ``head_dim`` 64
-    meets the chip's 128-lane tiling here, before it meets the chip."""
-    from elephas_tpu.ops.attention import (
-        _paged_pallas_fits,
-        pool_leaf_shape,
-    )
+# 25 heads of 64 in blocks of 256 columns: a K and a V block are 0.8 MB
+# each, and VMEM holds the buffers of one block a grid step and not of two.
+_WIDE = dict(heads=25, head_dim=64, block_size=256, slots=8, blocks_per_slot=4)
+
+
+@pytest.mark.parametrize("cell,blocks", [("xl", 4), ("wide", 1)])
+def test_paged_decode_kernel_compiles_for_v5e(one_chip, cell, blocks):
+    """The decode attention kernel at the cell's shapes, with the blocks a
+    grid step that the chooser gives them: ``head_dim`` 64 meets the chip's
+    128-lane tiling here, before it meets the chip. A layout whose blocks
+    fit VMEM one a step only still takes the kernel."""
+    from elephas_tpu.ops import attention
     from elephas_tpu.ops.attention_pallas import pallas_paged_decode_attention
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    slots, heads, d = _XL["slots"], _XL["heads"], _XL["head_dim"]
-    pool = pool_leaf_shape(slots * _XL["blocks_per_slot"], heads,
-                           _XL["block_size"], d)
-    assert pool[-1] == 128 and _paged_pallas_fits(pool, jnp.bfloat16)
+    h = {"xl": _XL, "wide": _WIDE}[cell]
+    slots, heads, d = h["slots"], h["heads"], h["head_dim"]
+    pool = attention.pool_leaf_shape(slots * h["blocks_per_slot"], heads,
+                                     h["block_size"], d)
+    assert pool[-1] == 128
+    assert attention.paged_decode_blocks(
+        pool, jnp.bfloat16, d, heads, h["blocks_per_slot"]) == blocks
+    # held to the buffers of that many blocks, and of no more
+    assert attention._paged_pallas_fits(pool, jnp.bfloat16, d, blocks=blocks)
+    assert not attention._paged_pallas_fits(pool, jnp.bfloat16, d,
+                                            blocks=2 * blocks)
     lane = arg((slots, heads, d), jnp.bfloat16)
     compiled = jax.jit(
         pallas_paged_decode_attention, donate_argnums=(3, 4)
     ).lower(
         lane, lane, lane, arg(pool, jnp.bfloat16), arg(pool, jnp.bfloat16),
-        arg((slots, _XL["blocks_per_slot"]), jnp.int32),
+        arg((slots, h["blocks_per_slot"]), jnp.int32),
         arg((slots,), jnp.int32), arg((slots,), jnp.bool_),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -249,19 +261,25 @@ _HYBRID = dict(q_heads=20, heads=1, head_dim=128, block_size=64, slots=32,
 
 def test_grouped_paged_decode_kernel_compiles_for_v5e(one_chip):
     """The decode attention kernel with grouped heads at the cell's shapes:
-    the pool holds the model's one K/V head, and all 20 query heads score
-    a block in one call."""
-    from elephas_tpu.ops.attention import _paged_pallas_fits, pool_leaf_shape
+    the pool holds the model's one K/V head, all 20 query heads score a
+    step in one call, and a step is as many of the 16-KB blocks as move
+    what a step of gpt2-xl's 200-KB blocks moves."""
+    from elephas_tpu.ops import attention
     from elephas_tpu.ops.attention_pallas import pallas_paged_decode_attention
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     h = _HYBRID
-    pool = pool_leaf_shape(h["slots"] * h["blocks_per_slot"], h["heads"],
-                           h["block_size"], h["head_dim"])
+    pool = attention.pool_leaf_shape(
+        h["slots"] * h["blocks_per_slot"], h["heads"], h["block_size"],
+        h["head_dim"])
     assert pool[1:] == (1, 64, 128)
-    assert _paged_pallas_fits(pool, jnp.bfloat16, q_heads=h["q_heads"])
+    blocks = attention.paged_decode_blocks(
+        pool, jnp.bfloat16, h["head_dim"], h["q_heads"], h["blocks_per_slot"])
+    assert blocks == attention._DECODE_STEP_BYTES // (2 * 2 * 64 * 128)
+    assert attention._paged_pallas_fits(pool, jnp.bfloat16, h["head_dim"],
+                                        q_heads=h["q_heads"], blocks=blocks)
     compiled = jax.jit(
         pallas_paged_decode_attention, donate_argnums=(3, 4)
     ).lower(

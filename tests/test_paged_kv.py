@@ -461,10 +461,13 @@ _LAYOUTS = {"pack2": (3, 8, 64), "pack4": (5, 4, 8), "pack1": (2, 8, 128),
             "grouped20on1": (1, 8, 128), "grouped6on2": (2, 8, 64)}
 _QUERY_HEADS = {"grouped20on1": 20, "grouped6on2": 6}
 _BPS, _BLOCKS = 4, 10  # blocks per slot; physical blocks (id 10 is unallocated)
+_LONG_BPS, _LONG_BLOCKS = 11, 40  # rows long enough for several grid steps
+_LONG_CASES = ("many_steps", "one_beside_many")
 
 
 def _paged_case(name, bs):
     """table rows, the column each lane writes, and the active mask."""
+    long = list(range(1, _LONG_BLOCKS))
     rows = {
         # one lane at the very first column of its only block
         "column0": ([[3], [1, 4]], [0, bs + 2], [True, True]),
@@ -480,8 +483,22 @@ def _paged_case(name, bs):
         # entries past a lane's allocation hold the out-of-range id
         "unallocated": ([[2], [7, 8], []], [bs - 1, bs, 0],
                         [True, True, False]),
+        # lanes over several grid steps of 2 and of 4 blocks: 8 live
+        # blocks (a multiple of both, the tail last in its step), 9 (the
+        # tail first in its step, the rest of the step dead), 10 and 11
+        # (the tail in the middle of a step of 4, last and first of 2)
+        "many_steps": ([long[0:8], long[8:17], long[17:27], long[27:38]],
+                       [8 * bs - 1, 8 * bs, 9 * bs + bs // 2, 11 * bs - 1],
+                       [True] * 4),
+        # a lane of one block beside a lane of many, and an inactive lane
+        # of many between two active ones
+        "one_beside_many": ([long[0:1], long[1:11], long[11:22], long[22:24]],
+                            [bs // 2, 9 * bs + 1, 10 * bs + 2, bs],
+                            [True, True, False, True]),
     }[name]
-    table = np.full((len(rows[0]), _BPS), _BLOCKS, np.int32)
+    bps, unallocated = ((_LONG_BPS, _LONG_BLOCKS) if name in _LONG_CASES
+                        else (_BPS, _BLOCKS))
+    table = np.full((len(rows[0]), bps), unallocated, np.int32)
     for s, ids in enumerate(rows[0]):
         table[s, :len(ids)] = ids
     return (jnp.asarray(table), jnp.asarray(rows[1], jnp.int32),
@@ -489,13 +506,16 @@ def _paged_case(name, bs):
 
 
 @pytest.mark.parametrize("layout", sorted(_LAYOUTS))
-@pytest.mark.parametrize("body", ["paged_xla", "paged_pallas"])
+@pytest.mark.parametrize("body", ["paged_xla", "paged_pallas-1",
+                                  "paged_pallas-2", "paged_pallas-4"])
 @pytest.mark.parametrize("case", ["column0", "block_edge", "last_column",
-                                  "inactive", "shared_prefix", "unallocated"])
+                                  "inactive", "shared_prefix", "unallocated",
+                                  *_LONG_CASES])
 def test_paged_decode_attention_matches_dense(case, body, layout):
     """Both bodies of the paged decode attention against dense attention
-    over the gathered cache: every active lane's new column lands in its
-    block and nowhere else, and its query attends columns ``<= idx``."""
+    over the gathered cache, the kernel at 1, 2 and 4 blocks a grid step:
+    every active lane's new column lands in its block and nowhere else,
+    and its query attends columns ``<= idx``."""
     from elephas_tpu.ops.attention import (
         paged_decode_attention,
         paged_to_contiguous,
@@ -507,16 +527,18 @@ def test_paged_decode_attention_matches_dense(case, body, layout):
     q_heads = _QUERY_HEADS.get(layout, heads)
     table, idx, active = _paged_case(case, bs)
     slots = table.shape[0]
+    num_blocks = _LONG_BLOCKS if case in _LONG_CASES else _BLOCKS
     rng = np.random.default_rng(7)
-    shape = pool_leaf_shape(_BLOCKS, heads, bs, d)
+    shape = pool_leaf_shape(num_blocks, heads, bs, d)
     k_pool, v_pool = (jnp.asarray(rng.normal(size=shape), jnp.float32)
                       for _ in range(2))
     q, k_new, v_new = (jnp.asarray(rng.normal(size=(slots, h, d)), jnp.float32)
                        for h in (q_heads, heads, heads))
+    body, _, blocks = body.partition("-")
     if body == "paged_pallas":  # the kernel, interpreted on the CPU
         out, k_after, v_after = pallas_paged_decode_attention(
             q, k_new, v_new, k_pool, v_pool, table, idx, active,
-            interpret=True)
+            blocks=int(blocks), interpret=True)
     else:
         out, k_after, v_after = paged_decode_attention(
             q, k_new, v_new, k_pool, v_pool, table, idx, active, body)
@@ -549,6 +571,8 @@ def test_paged_decode_attention_matches_dense(case, body, layout):
     changed = set(np.flatnonzero(
         (np.asarray(k_after) != np.asarray(k_pool)).any(axis=(1, 2, 3))))
     assert changed == tails
+    assert changed == set(np.flatnonzero(
+        (np.asarray(v_after) != np.asarray(v_pool)).any(axis=(1, 2, 3))))
 
 
 # -- paged chunk attention: a prefill chunk over the pool in place ------------
@@ -702,8 +726,9 @@ def test_window_scatters_write_their_columns_only(layout):
 
 def test_decode_attention_is_named_on_stats_and_step_events(compiled):
     """``stats()`` and every ``step`` event say which attention body the
-    decode program and the chunk program were traced with; the pool is the
-    donated pytree still."""
+    decode program and the chunk program were traced with, and how many
+    blocks a grid step of the decode kernel folds; the pool is the donated
+    pytree still."""
     events = []
 
     class Sink:
@@ -713,10 +738,32 @@ def test_decode_attention_is_named_on_stats_and_step_events(compiled):
     eng = _paged_engine(compiled, kv_block_size=4, sink=Sink())
     assert eng.stats()["decode_attention"] == "paged_xla"  # the CPU's body
     assert eng.stats()["prefill_attention"] == "paged_xla"
+    assert eng.stats()["decode_kernel_blocks"] is None  # the kernel's alone
     stale = eng.pool.cache
     res = eng.result(eng.submit([5, 3, 9], max_new_tokens=4), timeout_s=120)
     assert res.status == "completed"
     steps = [e for e in events if e.get("event") == "step"]
     assert steps and all(e["decode_attention"] == "paged_xla" for e in steps)
     assert all(e["prefill_attention"] == "paged_xla" for e in steps)
+    assert all(e["decode_kernel_blocks"] is None for e in steps)
+    assert eng.stats()["decode_live_blocks"] == 0
+    assert eng.stats()["decode_dead_blocks"] == 0
     assert all(leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(stale))
+
+    # what the kernel's steps cover, from the lanes' lengths alone: blocks
+    # of 4 columns, 4 blocks a grid step. A lane that holds n columns
+    # writes column n: 1, 1, 2, 4, 5 and 8 live blocks, in steps that
+    # cover 4, 4, 4, 4, 8 and 8.
+    from elephas_tpu.serving.metrics import ServingMetrics
+
+    metrics = ServingMetrics(sink=Sink())
+    metrics.decode_attention = "paged_pallas"
+    metrics.decode_kernel_blocks, metrics.kv_block_size = 4, 4
+    metrics.record_step(0, 4, 4, 0.01, lane_lengths=[0, 3, 4, 15])
+    metrics.record_step(0, 2, 2, 0.01, lane_lengths=[16, 31])
+    assert events[-1]["decode_kernel_blocks"] == 4
+    assert metrics.summary()["decode_live_blocks"] == 1 + 1 + 2 + 4 + 5 + 8
+    assert metrics.summary()["decode_dead_blocks"] == 3 + 3 + 2 + 0 + 3 + 0
+    metrics.reset()
+    assert metrics.summary()["decode_live_blocks"] == 0
+    assert metrics.decode_kernel_blocks == 4  # kept, as the bodies' names
