@@ -6,6 +6,16 @@ configs). The rebuild provides those architectures as flax modules so the
 five benchmark configs are runnable out of the box, plus a *registry* so
 architectures serialize by name (the TPU-native analogue of Keras's
 ``model_to_json`` arch string — SURVEY.md §2.1 serialization row).
+
+Three of the registered families are language models that
+``serving.InferenceEngine`` serves: ``transformer_lm`` (GPT-2's block),
+``jamba_lm`` (Mamba-1 mixers with an attention layer every few; per-slot
+state beside paged K/V; the engine refuses it prefix adoption, fork,
+handoff, speculation and ``shard_serving``) and ``latent_moe_lm`` (latent
+attention with rotary positions and a routed expert layer; it takes
+``experts_held=(first, count)`` beside ``n_routed_experts`` and computes
+the held experts' part, ``(0, n_routed_experts)`` being the uncut layer;
+the engine refuses it speculation and ``shard_serving``).
 """
 
 from __future__ import annotations
@@ -43,12 +53,15 @@ def registered_models():
 
 
 # Import for side effect: populate the registry.
-from elephas_tpu.models import mlp, cnn, resnet, lstm, transformer, jamba  # noqa: E402,F401
+from elephas_tpu.models import (  # noqa: E402,F401
+    mlp, cnn, resnet, lstm, transformer, jamba, latent_moe,
+)
 from elephas_tpu.models.mlp import MLP  # noqa: E402,F401
 from elephas_tpu.models.cnn import SimpleCNN  # noqa: E402,F401
 from elephas_tpu.models.resnet import ResNet18  # noqa: E402,F401
 from elephas_tpu.models.lstm import LSTMClassifier  # noqa: E402,F401
 from elephas_tpu.models.jamba import JambaLM  # noqa: E402,F401
+from elephas_tpu.models.latent_moe import LatentMoELM  # noqa: E402,F401
 from elephas_tpu.models.transformer import (  # noqa: E402,F401
     TransformerLM,
     generate,

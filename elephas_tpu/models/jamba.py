@@ -58,6 +58,15 @@ class RMSNorm(nn.Module):
         return x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + self.eps) * scale
 
 
+def gated_feed_forward(y, d_ff: int, dtype):
+    """``down(silu(gate y) * (up y))``, no bias; the three ``Dense`` layers
+    are the calling module's own (``gate``, ``up``, ``down``)."""
+    gate = nn.Dense(d_ff, use_bias=False, dtype=dtype, name="gate")(y)
+    up = nn.Dense(d_ff, use_bias=False, dtype=dtype, name="up")(y)
+    return nn.Dense(y.shape[-1], use_bias=False, dtype=dtype,
+                    name="down")(nn.silu(gate) * up)
+
+
 def _a_log_init(key, shape):
     """Mamba's: ``A = -(1 .. d_state)`` for every channel."""
     del key
@@ -246,10 +255,7 @@ class HybridLayer(nn.Module):
                     name="mamba")(y, active=active, valid=valid)
         with jax.named_scope("mlp"):
             y = RMSNorm(self.eps, name="mlp_norm")(x)
-            gate = nn.Dense(self.d_ff, use_bias=False, dtype=self.dtype, name="gate")(y)
-            up = nn.Dense(self.d_ff, use_bias=False, dtype=self.dtype, name="up")(y)
-            return x + nn.Dense(x.shape[-1], use_bias=False, dtype=self.dtype,
-                                name="down")(nn.silu(gate) * up)
+            return x + gated_feed_forward(y, self.d_ff, self.dtype)
 
 
 class JambaLM(nn.Module):

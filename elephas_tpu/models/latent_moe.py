@@ -1,0 +1,377 @@
+"""Decoder LM with latent attention and routed experts: the block of the
+DeepSeek-V2 family.
+
+Every layer is ``x + attn(rms(x))`` then ``x + ffn(rms(x))`` with RMSNorm,
+no bias anywhere, an untied head. The first ``first_dense`` layers have a
+gated feed-forward of width ``d_ff``; the others the routed layer below.
+
+Latent attention (``heads`` query heads, one latent a token)::
+
+    c_q            = rms(q_a y)                        (q_lora_rank)
+    [q_nope, q_pe] = split(q_b c_q)     a head         (qk_nope + qk_rope)
+    [c_kv, k_pe]   = split(kv_a y)      ONE k_pe for all heads
+    c_kv           = rms(c_kv)                         (kv_lora_rank)
+    [k_nope, v]    = split(kv_b c_kv)   a head         (qk_nope + v_head)
+    q_pe, k_pe     = rope(q_pe), rope(k_pe)  at the token's own position
+    score_h        = (q_nope_h . k_nope_h + q_pe_h . k_pe) * scale
+    out            = out concat_h(softmax_h v_h)
+
+The rotary pairs are (2i, 2i+1); ``rope`` is YaRN's parameters (theta,
+factor, original_max_position_embeddings, beta_fast, beta_slow, mscale,
+mscale_all_dim) or None for the plain rotary at theta 10,000; the softmax
+scale is ``(qk_nope + qk_rope)^-0.5 * mscale(factor, mscale_all_dim)^2``.
+
+Routed layer: ``p = softmax(router y)`` over all ``n_routed_experts`` in
+float32; the ``topk_group`` best of ``n_group`` groups keep their ``p``;
+the ``top_k`` largest are taken, weighted ``routed_scaling_factor * p``, not
+renormalised; ``ffn(y) = shared(y) + sum_e w_e expert_e(y)``, every expert
+and the shared one a gated feed-forward. The module is told which experts
+it holds, ``experts_held = (first, count)``: it routes over all, holds the
+matrices of its own and computes their part (``ops.routed_experts``); with
+``(0, n_routed_experts)``, the default, it is the uncut layer.
+
+``decode=True`` is the serving path. What a layer caches a token is the
+latent: ``c_kv`` after its norm and ``k_pe`` after its rotation, one leaf
+``cached_latent`` of ``kv_lora_rank + qk_rope`` values and a
+``cache_index``; no key or value a head. It is attended through the
+serving pool's block table (``models.decode_cache.attend_paged``, which is
+handed ``kv_b``): a decode step in the absorbed form (``q_nope_h W_uk_h`` is
+a query of ``kv_lora_rank`` against ``c_kv``, the softmax weighs ``c_kv``
+itself and ``W_uv_h`` expands the result, so ``heads`` query heads read one
+key whose first ``kv_lora_rank`` columns are also its value), a prefill
+chunk with keys and values a head expanded from each block as it is read
+(``ops.attention`` says why each). A row's rotary position is its own
+cache index: a lane's its own length, a chunk's ``start .. start + T - 1``.
+Given ``valid`` (a chunk's real tokens), the apply returns the logits of
+position ``valid - 1`` alone and routes no padding token. Each routed layer
+sows its counters (assignments, those on held experts, experts touched and
+held, the busiest and the mean expert's load) into the ``counters``
+collection.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from elephas_tpu.models import register_model
+from elephas_tpu.models.decode_cache import attend_paged
+from elephas_tpu.models.jamba import RMSNorm, _rows, gated_feed_forward
+
+Rope = Optional[Tuple[float, ...]]
+
+
+def yarn_mscale(factor: float, m: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * m * math.log(factor) + 1.0
+
+
+def rotary_frequencies(dim: int, rope: Rope) -> np.ndarray:
+    """The ``dim / 2`` angular frequencies: YaRN blends ``theta_i`` and
+    ``theta_i / factor`` by a linear ramp between the two correction
+    dimensions (``beta_fast`` and ``beta_slow`` rotations over the original
+    context)."""
+    theta = 10000.0 if rope is None else rope[0]
+    plain = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if rope is None or rope[1] <= 1:
+        return plain
+    _, factor, original, beta_fast, beta_slow = rope[:5]
+
+    def correction_dim(rotations):
+        return dim * math.log(original / (rotations * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return plain / factor * ramp + plain * (1.0 - ramp)
+
+
+def softmax_scale(width: int, rope: Rope) -> float:
+    return width ** -0.5 * (1.0 if rope is None
+                            else yarn_mscale(rope[1], rope[6]) ** 2)
+
+
+def rotate(x, positions, rope: Rope):
+    """``x``: (batch, T, ..., dim) with pairs (2i, 2i+1); ``positions``:
+    (batch, T). Float32."""
+    dim = x.shape[-1]
+    angle = positions[..., None].astype(jnp.float32) * jnp.asarray(
+        rotary_frequencies(dim, rope), jnp.float32)
+    m = 1.0 if rope is None else (yarn_mscale(rope[1], rope[5])
+                                  / yarn_mscale(rope[1], rope[6]))
+    angle = angle.reshape(angle.shape[:2] + (1,) * (x.ndim - 3) + (dim // 2,))
+    cos, sin = jnp.cos(angle) * m, jnp.sin(angle) * m
+    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], dim // 2, 2)
+    a, b = pairs[..., 0], pairs[..., 1]
+    return jnp.stack([a * cos - b * sin, a * sin + b * cos], -1).reshape(x.shape)
+
+
+class LatentAttention(nn.Module):
+    num_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope: Rope = None
+    eps: float = 1e-6
+    dtype: Any = jnp.float32
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, x, active=None, paged=None):
+        b, T, d_model = x.shape
+        heads, rank = self.num_heads, self.kv_lora_rank
+        nope, pe = self.qk_nope_head_dim, self.qk_rope_head_dim
+        scale = softmax_scale(nope + pe, self.rope)
+
+        c_q = RMSNorm(self.eps, name="q_a_norm")(
+            nn.Dense(self.q_lora_rank, use_bias=False, dtype=self.dtype, name="q_a")(x))
+        q = nn.DenseGeneral((heads, nope + pe), use_bias=False, dtype=self.dtype,
+                            name="q_b")(c_q.astype(self.dtype))  # (b, T, h, f)
+        kv = nn.Dense(rank + pe, use_bias=False, dtype=self.dtype, name="kv_a")(x)
+        c_kv = RMSNorm(self.eps, name="kv_a_norm")(kv[..., :rank])
+        # W_uk and W_uv side by side, by head: (rank, heads, nope + v)
+        kv_b = self.param("kv_b", nn.initializers.lecun_normal(),
+                          (rank, heads, nope + self.v_head_dim))
+        kv_b = kv_b.astype(self.dtype)
+
+        carried = self.decode and self.has_variable("cache", "cached_latent")
+        if self.decode:
+            cached_latent = self.variable("cache", "cached_latent", jnp.zeros,
+                                          (b, 1, T, rank + pe), self.dtype)
+            cache_index = self.variable("cache", "cache_index",
+                                        lambda: jnp.array(0, jnp.int32))
+        if carried:
+            start = jnp.broadcast_to(cache_index.value, (b,))
+        else:  # a full sequence, or the pass that shapes the cache
+            start = jnp.zeros((b,), jnp.int32)
+        positions = start[:, None] + jnp.arange(T)[None, :]
+        q_pe = rotate(q[..., nope:], positions, self.rope)
+        k_pe = rotate(kv[..., rank:], positions, self.rope)
+
+        if carried:
+            if paged is None:
+                raise NotImplementedError(
+                    "a latent cache is attended through the serving pool's "
+                    "block table only: there is no contiguous row of keys "
+                    "and values a head to gather")
+            q_all = jnp.concatenate(
+                [q[..., :nope].astype(jnp.float32), q_pe], -1).astype(self.dtype)
+            latent = jnp.concatenate([c_kv, k_pe], -1).astype(self.dtype)[:, None]
+            out = attend_paged(jnp.moveaxis(q_all, 2, 1), latent, None,
+                               cached_latent, None, cache_index, active, paged,
+                               scale=scale, kv_b=kv_b)  # (b, h, T, v)
+            out = jnp.moveaxis(out, 1, 2)
+        else:
+            # the published form: keys and values a head, expanded
+            expanded = jnp.einsum("btr,rhf->bthf", c_kv.astype(self.dtype), kv_b,
+                                  preferred_element_type=jnp.float32)
+            scores = (jnp.einsum("bqhf,bkhf->bhqk", q[..., :nope].astype(jnp.float32),
+                                 expanded[..., :nope])
+                      + jnp.einsum("bqhf,bkf->bhqk", q_pe, k_pe)) * scale
+            mask = jnp.tril(jnp.ones((T, T), bool))
+            scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
+            out = jnp.einsum("bhqk,bkhf->bqhf", nn.softmax(scores, -1),
+                             expanded[..., nope:])
+        return nn.DenseGeneral(d_model, axis=(-2, -1), use_bias=False,
+                               dtype=self.dtype, name="out")(out.astype(self.dtype))
+
+
+class GatedFeedForward(nn.Module):
+    d_ff: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, y):
+        return gated_feed_forward(y, self.d_ff, self.dtype)
+
+
+class Head(nn.Module):
+    """The untied head: logits in float32 from operands in ``dtype``."""
+    vocab_size: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param("kernel", nn.initializers.lecun_normal(),
+                            (x.shape[-1], self.vocab_size))
+        return jnp.einsum("btd,dv->btv", x.astype(self.dtype),
+                          kernel.astype(self.dtype),
+                          preferred_element_type=jnp.float32)
+
+
+class RoutedExperts(nn.Module):
+    n_routed_experts: int
+    experts_held: Tuple[int, int]
+    moe_d_ff: int
+    top_k: int
+    n_group: int
+    topk_group: int
+    routed_scaling_factor: float
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, y, live=None):
+        """``y``: (tokens, d); ``live``: (tokens,) bool, the tokens that are
+        no padding and no idle lane (all of them where it is None)."""
+        from elephas_tpu.ops.routed_experts import (
+            group_limited_top_k,
+            routed_experts,
+        )
+
+        first, count = self.experts_held
+        d, f = y.shape[-1], self.moe_d_ff
+        init = nn.initializers.lecun_normal(batch_axis=(0,))
+        gate = self.param("gate", init, (count, d, f)).astype(self.dtype)
+        up = self.param("up", init, (count, d, f)).astype(self.dtype)
+        down = self.param("down", init, (count, f, d)).astype(self.dtype)
+        logits = nn.Dense(self.n_routed_experts, use_bias=False, dtype=jnp.float32,
+                          name="router")(y.astype(jnp.float32))
+        ids, p = group_limited_top_k(nn.softmax(logits, -1), self.n_group,
+                                     self.topk_group, self.top_k)
+        if live is not None:  # an id past every expert is held nowhere
+            ids = jnp.where(live[:, None], ids, self.n_routed_experts)
+        out, load = routed_experts(y, ids, p * self.routed_scaling_factor,
+                                   gate, up, down, first)
+        tokens = y.shape[0] if live is None else live.sum()
+        for name, value in (
+                ("moe_assignments", tokens * self.top_k),
+                ("moe_assignments_held", load.sum()),
+                ("moe_experts_touched", (load > 0).sum()),
+                ("moe_experts_held", count),
+                ("moe_load_max", load.max()),
+                ("moe_load_mean", load.sum() / count)):
+            self.sow("counters", name, jnp.asarray(value, jnp.float32))
+        return out
+
+
+class LatentMoELayer(nn.Module):
+    routed: bool
+    d_ff: int
+    attention: dict  # LatentAttention's sizes
+    experts: dict    # RoutedExperts' sizes, and the shared experts' width
+    eps: float = 1e-6
+    dtype: Any = jnp.float32
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, x, active=None, paged=None, live=None):
+        with jax.named_scope("attention"):
+            x = x + LatentAttention(
+                **self.attention, eps=self.eps, dtype=self.dtype, decode=self.decode,
+                name="attention")(RMSNorm(self.eps, name="attn_norm")(x).astype(self.dtype),
+                                  active=active, paged=paged)
+        with jax.named_scope("mlp"):
+            y = RMSNorm(self.eps, name="ffn_norm")(x).astype(self.dtype)
+            if not self.routed:
+                return x + gated_feed_forward(y, self.d_ff, self.dtype)
+            experts = dict(self.experts)
+            shared = GatedFeedForward(experts.pop("shared_d_ff"), self.dtype,
+                                      name="shared")(y)
+            b, T, d = y.shape
+            routed = RoutedExperts(**experts, dtype=self.dtype, name="experts")(
+                y.reshape(b * T, d), None if live is None else live.reshape(b * T))
+            return x + shared + routed.reshape(b, T, d).astype(shared.dtype)
+
+
+class LatentMoELM(nn.Module):
+    vocab_size: int = 102400
+    d_model: int = 256
+    num_layers: int = 3
+    num_heads: int = 4
+    q_lora_rank: int = 64
+    kv_lora_rank: int = 32
+    qk_nope_head_dim: int = 16
+    qk_rope_head_dim: int = 8
+    v_head_dim: int = 16
+    d_ff: int = 512
+    first_dense: int = 1
+    n_routed_experts: int = 8
+    experts_held: Optional[Tuple[int, int]] = None  # (first, count); all
+    n_shared_experts: int = 2
+    moe_d_ff: int = 64
+    top_k: int = 2
+    n_group: int = 4
+    topk_group: int = 2
+    routed_scaling_factor: float = 1.0
+    rope: Rope = None
+    rms_eps: float = 1e-6
+    max_seq_len: int = 163840
+    dtype: Any = jnp.float32
+    attention: str = "dense"  # the one full-sequence form; the engine sets it
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, tokens, train: bool = False, pad_offset=None, active=None,
+                 paged=None, valid=None):
+        del train, pad_offset  # no dropout; serving rows are never left-padded
+        if not self.decode and (active is not None or paged is not None
+                                or valid is not None):
+            raise ValueError("active / paged / valid are the decode=True path's")
+        b, T = tokens.shape
+        x = nn.Embed(self.vocab_size, self.d_model, name="tok_embed")(
+            tokens.astype(jnp.int32)).astype(self.dtype)
+        attention = dict(
+            num_heads=self.num_heads, q_lora_rank=self.q_lora_rank,
+            kv_lora_rank=self.kv_lora_rank, qk_nope_head_dim=self.qk_nope_head_dim,
+            qk_rope_head_dim=self.qk_rope_head_dim, v_head_dim=self.v_head_dim,
+            rope=self.rope)
+        experts = dict(
+            n_routed_experts=self.n_routed_experts,
+            experts_held=tuple(self.experts_held or (0, self.n_routed_experts)),
+            moe_d_ff=self.moe_d_ff, top_k=self.top_k, n_group=self.n_group,
+            topk_group=self.topk_group,
+            routed_scaling_factor=self.routed_scaling_factor,
+            shared_d_ff=self.n_shared_experts * self.moe_d_ff)
+        live = None
+        if valid is not None:
+            live = jnp.arange(T)[None, :] < valid[:, None]
+        elif active is not None:
+            live = jnp.broadcast_to(active[:, None], (b, T))
+        for i in range(self.num_layers):
+            x = LatentMoELayer(i >= self.first_dense, self.d_ff, attention, experts,
+                               eps=self.rms_eps, dtype=self.dtype, decode=self.decode,
+                               name=f"Layer_{i}")(x, active=active, paged=paged, live=live)
+        if valid is not None:  # the one row a prefill chunk samples from
+            x = _rows(x, valid - 1, 1)
+        with jax.named_scope("lm_head"):
+            x = RMSNorm(self.rms_eps, name="final_norm")(x)
+            return Head(self.vocab_size, self.dtype, name="lm_head")(x)
+
+
+@register_model("latent_moe_lm")
+def build_latent_moe_lm(vocab_size=102400, d_model=256, num_layers=3, num_heads=4,
+                        q_lora_rank=64, kv_lora_rank=32, qk_nope_head_dim=16,
+                        qk_rope_head_dim=8, v_head_dim=16, d_ff=512, first_dense=1,
+                        n_routed_experts=8, experts_held=None, n_shared_experts=2,
+                        moe_d_ff=64, top_k=2, n_group=4, topk_group=2,
+                        routed_scaling_factor=1.0, rope=None, rms_eps=1e-6,
+                        max_seq_len=163840, dtype="float32"):
+    first, count = experts_held or (0, n_routed_experts)
+    if n_routed_experts % n_group or not 0 < topk_group <= n_group:
+        raise ValueError(f"{n_routed_experts} experts do not divide into "
+                         f"{n_group} groups, or top {topk_group} of them")
+    if top_k > topk_group * (n_routed_experts // n_group):
+        raise ValueError(f"top_k ({top_k}) is more than {topk_group} groups hold")
+    if first < 0 or count < 1 or first + count > n_routed_experts:
+        raise ValueError(f"experts_held {(first, count)} is no range of the "
+                         f"{n_routed_experts} routed experts")
+    if qk_rope_head_dim % 2:
+        raise ValueError("the rotary width is a number of pairs")
+    return LatentMoELM(
+        vocab_size=vocab_size, d_model=d_model, num_layers=num_layers,
+        num_heads=num_heads, q_lora_rank=q_lora_rank, kv_lora_rank=kv_lora_rank,
+        qk_nope_head_dim=qk_nope_head_dim, qk_rope_head_dim=qk_rope_head_dim,
+        v_head_dim=v_head_dim, d_ff=d_ff, first_dense=first_dense,
+        n_routed_experts=n_routed_experts, experts_held=(first, count),
+        n_shared_experts=n_shared_experts, moe_d_ff=moe_d_ff, top_k=top_k,
+        n_group=n_group, topk_group=topk_group,
+        routed_scaling_factor=routed_scaling_factor,
+        rope=None if rope is None else tuple(float(v) for v in rope),
+        rms_eps=rms_eps, max_seq_len=max_seq_len, dtype=jnp.dtype(dtype))

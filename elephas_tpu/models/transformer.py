@@ -504,10 +504,12 @@ def make_paged_decode_cache(decode_module, max_slots: int, num_blocks: int,
     sequence, not of physical block placement — so the same flax apply
     drives both layouts: over gathered rows (prefill chunks,
     speculative windows) or over the blocks themselves (``paged=``).
+    A latent leaf (``cached_latent``: one head, key and value in one) is
+    laid out ``ops.attention.latent_leaf_shape``, a block's columns minor.
     A state leaf (``models.decode_cache``: a recurrence's or a
     convolution's) is one row a slot, ``(max_slots, ...)``, never paged."""
-    from elephas_tpu.models.decode_cache import INDEX, KV, leaf_kind
-    from elephas_tpu.ops.attention import pool_leaf_shape
+    from elephas_tpu.models.decode_cache import INDEX, KV, leaf_kind, leaf_name
+    from elephas_tpu.ops.attention import latent_leaf_shape, pool_leaf_shape
 
     cache_shapes = jax.eval_shape(
         lambda: decode_module.init(
@@ -519,6 +521,10 @@ def make_paged_decode_cache(decode_module, max_slots: int, num_blocks: int,
         kind = leaf_kind(path)
         if kind == KV:
             _, heads, _, head_dim = s.shape
+            if leaf_name(path) == "cached_latent":
+                return jnp.zeros(
+                    latent_leaf_shape(num_blocks, block_size, head_dim),
+                    s.dtype)
             return jnp.zeros(
                 pool_leaf_shape(num_blocks, heads, block_size, head_dim),
                 s.dtype,

@@ -143,6 +143,18 @@ def pool_leaf_shape(num_blocks, heads, block_size, head_dim):
     return (num_blocks, heads, block_size // pack, pack * head_dim)
 
 
+def latent_leaf_shape(num_blocks, block_size, width):
+    """Shape of a latent pool leaf, one head whose columns are key and, in
+    their first part, value: ``(num_blocks, 1, width, block_size)``, a
+    block's COLUMNS minor. A latent is 576 values wide where the lanes are
+    128: rows of 576 would be padded to 640 on the device (and the default
+    layout of ``(..., 128, 576)`` puts the 128 minor-most anyway, so every
+    program would relayout the pool); with ``block_size`` a multiple of 128
+    this leaf is row-major and dense, a block one contiguous piece of HBM,
+    and a column's ``width`` values cost their own bytes and no more."""
+    return (num_blocks, 1, width, block_size)
+
+
 def _unpack(blocks, head_dim: int):
     """(..., heads, rows, pack * d) gathered blocks -> (..., heads, bs, d)."""
     return blocks.reshape(*blocks.shape[:-2], -1, head_dim)
@@ -165,12 +177,13 @@ def paged_to_contiguous(leaf, table, head_dim: int):
     return _rows_of(_unpack(leaf[table], head_dim))
 
 
-def _write_windows(pool_leaf, table, start, window, ok):
+def _write_windows(pool_leaf, table, start, window, ok, latent=False):
     """Write ``window[s]``, (heads, count, head_dim), at columns
     ``[start[s], start[s] + count)`` of row ``table[s]``: the blocks the
     window touches are gathered, updated and set back along dimension 0.
     Blocks of a row with ``ok[s]`` false, past the row's table, or
-    unallocated go to the out-of-range id and drop."""
+    unallocated go to the out-of-range id and drop. A ``latent`` leaf's
+    blocks are turned columns-major for the update and back."""
     num_blocks, _, r, lanes = pool_leaf.shape
     rows, bps = table.shape
     _, count, head_dim = window.shape[1:]
@@ -182,26 +195,30 @@ def _write_windows(pool_leaf, table, start, window, ok):
     touched = (js < bps) & (js * bs < (start + count)[:, None])
     ids = jnp.where(ok[:, None] & touched, ids, num_blocks)
     blocks = pool_leaf[ids]  # (rows, n, heads, r, lanes); OOB clamps
+    if latent:
+        blocks = jnp.swapaxes(blocks, -1, -2)
     win = _rows_of(_unpack(blocks, head_dim))
     win = jax.vmap(
         lambda w, new, at: jax.lax.dynamic_update_slice(w, new, (0, at, 0))
     )(win, window.astype(pool_leaf.dtype), start - first * bs)
     heads = win.shape[1]
     win = jnp.moveaxis(win.reshape(rows, heads, n, bs, head_dim), 2, 1)
+    if latent:
+        win = jnp.swapaxes(win, -1, -2)
     return pool_leaf.at[ids.reshape(-1)].set(
         win.reshape((rows * n,) + pool_leaf.shape[1:]), mode="drop"
     )
 
 
-@jax.jit
-def scatter_prefill_columns(pool_leaf, row_table, start, chunk):
+@functools.partial(jax.jit, static_argnames=("latent",))
+def scatter_prefill_columns(pool_leaf, row_table, start, chunk, latent=False):
     """Write one prefill chunk's columns ``[start, start + C)`` of ONE
     slot into its physical blocks. ``chunk``: (heads, C, head_dim).
     Columns landing in unallocated blocks (right-pad garbage past the
     slot's allocation) drop."""
     return _write_windows(
         pool_leaf, row_table[None], jnp.reshape(start, (1,)), chunk[None],
-        jnp.ones((1,), bool),
+        jnp.ones((1,), bool), latent,
     )
 
 
@@ -250,14 +267,17 @@ _DECODE_STEP_BYTES = 3 << 19
 
 
 def _paged_pallas_fits(pool_shape, dtype, head_dim, q_heads=None,
-                       blocks=1) -> bool:
+                       blocks=1, latent=False) -> bool:
     """Whether the kernel's tiles lower for this pool layout at ``blocks``
     blocks a grid step: rows a full lane width, whole sublane tiles a head
     (so ``(blocks, heads, r, lanes)`` folds to ``(blocks * heads * r,
     lanes)`` for free), and buffers that fit VMEM: two each of a step's K
     and V blocks, and the float32 scores of a step, their columns and what
     the softmax makes of them. ``q_heads``: the query heads, where they
-    are more than the pool's K/V heads."""
+    are more than the pool's K/V heads. A ``latent`` pool has its own
+    layout and kernels (``_latent_fits``)."""
+    if latent:
+        return _latent_fits(pool_shape, dtype, head_dim, q_heads, blocks)
     _, heads, rows, lanes = pool_shape
     itemsize = jnp.dtype(dtype).itemsize
     if lanes % _LANES or itemsize not in (2, 4) or rows % (32 // itemsize):
@@ -268,47 +288,80 @@ def _paged_pallas_fits(pool_shape, dtype, head_dim, q_heads=None,
     return blocks * (4 * block_bytes + 5 * scores_bytes) <= _PAGED_VMEM_BUDGET
 
 
+def _latent_fits(pool_shape, dtype, head_dim, q_heads, blocks=1) -> bool:
+    """Whether the latent kernels' tiles lower for this pool layout
+    (``latent_leaf_shape``), at ``blocks`` blocks a grid step of the decode
+    kernel: one head, a block's columns a whole number of lane tiles, its
+    width and a lane's heads whole sublane tiles, and buffers that fit
+    VMEM: two of a step's blocks, and the float32 scores of the heads
+    against them (and what the softmax makes of them)."""
+    _, heads, width, block_size = pool_shape
+    itemsize = jnp.dtype(dtype).itemsize
+    sublanes = 32 // itemsize
+    if (heads != 1 or width != head_dim or itemsize not in (2, 4)
+            or block_size % _LANES or width % sublanes
+            or not q_heads or q_heads % sublanes):
+        return False
+    held = 2 * blocks * width * block_size * itemsize
+    scores = 3 * 4 * q_heads * blocks * block_size
+    return held + scores <= _PAGED_VMEM_BUDGET
+
+
 def paged_decode_blocks(pool_shape, dtype, head_dim, q_heads,
-                        blocks_per_slot) -> int:
+                        blocks_per_slot, latent: bool = False) -> int:
     """Blocks of a lane that one grid step of the decode kernel folds, from
     the layout alone: as many as move ``_DECODE_STEP_BYTES``, a row's worth
-    at the most, and no more than VMEM holds (one, where that is all)."""
+    at the most, and no more than VMEM holds (one, where that is all). A
+    ``latent`` pool is one leaf: a column is read once for key and value."""
     _, heads, rows, lanes = pool_shape
-    moved = 2 * heads * rows * lanes * jnp.dtype(dtype).itemsize  # K and V
+    moved = (1 if latent else 2) * heads * rows * lanes * \
+        jnp.dtype(dtype).itemsize  # K and V
     blocks = max(1, min(blocks_per_slot, -(-_DECODE_STEP_BYTES // moved)))
     while blocks > 1 and not _paged_pallas_fits(pool_shape, dtype, head_dim,
-                                                q_heads, blocks):
+                                                q_heads, blocks, latent):
         blocks -= 1
     return blocks
 
 
 def paged_decode_body(pool_shape, dtype, head_dim, mesh=None,
-                      q_heads=None) -> str:
+                      q_heads=None, latent: bool = False) -> str:
     """Name of the body ``paged_decode_attention`` runs for this backend,
     mesh and pool layout. A Pallas call is not partitioned by sharding
     annotations, so a mesh takes the XLA body."""
     if _on_tpu() and mesh is None and _paged_pallas_fits(
-            pool_shape, dtype, head_dim, q_heads):
+            pool_shape, dtype, head_dim, q_heads, latent=latent):
         return "paged_pallas"
     return "paged_xla"
 
 
-def _paged_attend_xla(q, k_pool, v_pool, table, last):
+def _paged_attend_xla(q, k_pool, v_pool, table, last, scale=None,
+                      kv_b=None):
     """Plain body of both paged attentions: gather one layer's blocks
     through the table and score them in the block layout ``(rows, blocks,
     heads, bs, head_dim)``. ``q``: (rows, q_heads, Q, head_dim), ``Q``
     queries a table row, ``g`` query heads to a K/V head (1 for full
-    multi-head); ``last``: (rows, Q) the last column each query attends."""
+    multi-head); ``last``: (rows, Q) the last column each query attends.
+    Without a ``v_pool`` the pool is a latent one and ``kv_b`` expands it
+    (``paged_decode_attention``): the queries are absorbed, a column's first
+    ``rank`` values are its value, and the result goes through ``W_uv``."""
+    if v_pool is None:
+        nope = _nope_width(q, kv_b, k_pool.shape[2])
+        q = _absorb(q, kv_b, k_pool.shape[2])
     rows, q_heads, count, head_dim = q.shape
     bps = table.shape[1]
-    kb = _unpack(k_pool[table], head_dim)  # OOB ids clamp; masked below
-    vb = _unpack(v_pool[table], head_dim)
+    kb = k_pool[table]  # OOB ids clamp; masked below
+    if v_pool is None:  # a latent leaf: a block's columns minor
+        kb = jnp.swapaxes(kb, -1, -2)
+        vb = kb[..., :kv_b.shape[0]]
+    else:
+        kb = _unpack(kb, head_dim)
+        vb = _unpack(v_pool[table], head_dim)
     heads, bs = kb.shape[-3], kb.shape[-2]
     scores = jnp.einsum(
         "shgqd,sjhbd->shgqjb",
         q.reshape(rows, heads, q_heads // heads, count, head_dim), kb,
         preferred_element_type=jnp.float32,
-    ) * (1.0 / (head_dim ** 0.5))
+    ) * (1.0 / (head_dim ** 0.5) if scale is None else scale)
     cols = (jnp.arange(bps) * bs)[:, None] + jnp.arange(bs)[None, :]
     valid = cols <= last[:, :, None, None]  # (rows, Q, bps, bs)
     scores = jnp.where(valid[:, None, None], scores,
@@ -319,12 +372,38 @@ def _paged_attend_xla(q, k_pool, v_pool, table, last):
         "shgqjb,sjhbd->shgqd", weights.astype(vb.dtype), vb,
         preferred_element_type=jnp.float32,
     )
-    return out.reshape(q.shape).astype(q.dtype)
+    out = out.reshape(q.shape[:-1] + vb.shape[-1:]).astype(q.dtype)
+    return out if v_pool is not None else _expand_values(out, kv_b, nope)
 
 
-@functools.partial(jax.jit, static_argnames=("body",))
+def _nope_width(q, kv_b, width: int) -> int:
+    """Of a head's query (nope + pe wide), the part that meets the expanded
+    keys: a latent column is ``width`` = rank + pe wide, ``kv_b`` has the
+    rank."""
+    return q.shape[-1] - (width - kv_b.shape[0])
+
+
+def _absorb(q, kv_b, width: int):
+    """A head's query against its expanded keys is a query of ``rank``
+    against the latent itself. ``q``: (..., heads, queries, nope + pe);
+    ``kv_b``: (rank, heads, nope + v_head). Returns (..., heads, queries,
+    width): ``q_nope_h W_uk_h``, then the rotary part as it is."""
+    nope = _nope_width(q, kv_b, width)
+    lat = jnp.einsum("...hqf,rhf->...hqr", q[..., :nope], kv_b[..., :nope],
+                     preferred_element_type=jnp.float32)
+    return jnp.concatenate([lat.astype(q.dtype), q[..., nope:]], -1)
+
+
+def _expand_values(out, kv_b, nope: int):
+    """What the softmax made of the latents, (..., heads, queries, rank),
+    through ``W_uv``: a head's own values, (..., heads, queries, v_head)."""
+    return jnp.einsum("...hqr,rhf->...hqf", out, kv_b[..., nope:],
+                      preferred_element_type=jnp.float32).astype(out.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("body", "scale"))
 def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, table, idx,
-                           active, body: str):
+                           active, body: str, scale=None, kv_b=None):
     """One decode step's attention of one layer over the paged pool.
 
     ``q``: (slots, q_heads, head_dim); ``k_new``/``v_new``: (slots,
@@ -341,27 +420,52 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, table, idx,
     Returns ``(out, k_pool, v_pool)`` with ``out`` (slots, heads,
     head_dim); the pools carry the new columns. ``body`` is one of
     ``PAGED_BODIES`` (``paged_decode_body`` picks it).
+
+    A latent pool (``latent_leaf_shape``): ``v_new`` and ``v_pool`` are
+    None and ``kv_b``, (rank, q_heads, nope + v_head), is the matrix that
+    expands a latent into a head's key and value; ``q`` is (slots, q_heads,
+    nope + pe) and ``k_new`` (slots, 1, rank + pe). The step runs absorbed:
+    ``q_nope_h W_uk_h`` is a query of ``rank`` against the latent itself, a
+    column's first ``rank`` values are also its value, read with the key and
+    not again, and ``W_uv_h`` expands the result, so every head reads the
+    one latent head and nothing a head is ever built (a lane has one query:
+    expanding its thousands of columns would cost ``rank`` products a
+    column where absorbing costs them once). ``out`` is (slots, q_heads,
+    v_head) and the returned ``v_pool`` None. ``scale`` is the softmax
+    scale where it is not ``head_dim ** -0.5``.
     """
     if body not in PAGED_BODIES:
         raise ValueError(f"unknown paged body {body!r}; expected one of "
                          f"{PAGED_BODIES}")
     if body == "paged_pallas":
         from elephas_tpu.ops.attention_pallas import (
+            pallas_latent_decode_attention,
             pallas_paged_decode_attention,
         )
 
         # one kernel writes the column and attends: an XLA scatter before
         # it would have the compiler stage the whole leaf through VMEM
         with jax.named_scope("paged_attention"):
+            if v_pool is None:
+                width = k_pool.shape[2]
+                out, k_pool = pallas_latent_decode_attention(
+                    _absorb(q[:, :, None], kv_b, width)[:, :, 0], k_new, k_pool,
+                    table, idx, active, kv_b.shape[0], scale)
+                out = _expand_values(out[:, :, None], kv_b,
+                                     _nope_width(q, kv_b, width))[:, :, 0]
+                return out, k_pool, None
             return pallas_paged_decode_attention(
                 q, k_new, v_new, k_pool, v_pool, table, idx, active
             )
     with jax.named_scope("kv_write"):
-        k_pool = _write_windows(k_pool, table, idx, k_new[:, :, None], active)
-        v_pool = _write_windows(v_pool, table, idx, v_new[:, :, None], active)
+        k_pool = _write_windows(k_pool, table, idx, k_new[:, :, None], active,
+                                v_pool is None)
+        if v_pool is not None:
+            v_pool = _write_windows(v_pool, table, idx, v_new[:, :, None],
+                                    active)
     with jax.named_scope("paged_attention"):
         out = _paged_attend_xla(q[:, :, None], k_pool, v_pool, table,
-                                idx[:, None])[:, :, 0]
+                                idx[:, None], scale, kv_b)[:, :, 0]
     return out, k_pool, v_pool
 
 
@@ -409,17 +513,29 @@ def _paged_chunk_fits(pool_shape, dtype, head_dim, chunk) -> bool:
     return 2 * (moved + bounds) + held + scores <= _PAGED_VMEM_BUDGET
 
 
-def paged_chunk_body(pool_shape, dtype, head_dim, chunk, mesh=None) -> str:
+def _latent_chunk_tiles(q_heads, block_size):
+    """Tile sizes of the latent chunk kernel: heads a grid step (each with
+    all of the chunk's queries; a block is moved once for them) and blocks
+    a grid step (512 columns, or a block)."""
+    group = max(n for n in range(1, 5) if q_heads % n == 0)
+    return group, max(1, 512 // block_size)
+
+
+def paged_chunk_body(pool_shape, dtype, head_dim, chunk, mesh=None,
+                     q_heads=None, latent: bool = False) -> str:
     """Name of the body ``paged_chunk_attention`` runs for this backend,
     mesh, pool layout and chunk width, as ``paged_decode_body`` has it."""
-    if _on_tpu() and mesh is None and _paged_chunk_fits(pool_shape, dtype,
-                                                        head_dim, chunk):
+    if latent:  # the chunk kernel sets its own VMEM limit
+        fits = _latent_fits(pool_shape, dtype, head_dim, q_heads)
+    else:
+        fits = _paged_chunk_fits(pool_shape, dtype, head_dim, chunk)
+    if _on_tpu() and mesh is None and fits:
         return "paged_pallas"
     return "paged_xla"
 
 
-@jax.jit
-def scatter_prefill_blocks(pool_leaf, row_table, start, chunk):
+@functools.partial(jax.jit, static_argnames=("latent",))
+def scatter_prefill_blocks(pool_leaf, row_table, start, chunk, latent=False):
     """``scatter_prefill_columns`` for a chunk of whole blocks: ``start``
     and the chunk's width are multiples of the block size, so the chunk IS
     its blocks and nothing of the pool is read. The one relayout is the
@@ -431,13 +547,19 @@ def scatter_prefill_blocks(pool_leaf, row_table, start, chunk):
     ids = jnp.where(js < row_table.shape[0],
                     row_table[jnp.clip(js, 0, row_table.shape[0] - 1)],
                     num_blocks)
-    blocks = chunk.astype(pool_leaf.dtype).reshape(heads, count // bs, r, lanes)
+    if latent:  # (1, count, width) -> (count / bs, 1, width, bs)
+        blocks = jnp.swapaxes(chunk.astype(pool_leaf.dtype).reshape(
+            heads, count // bs, bs, head_dim), -1, -2)
+    else:
+        blocks = chunk.astype(pool_leaf.dtype).reshape(
+            heads, count // bs, r, lanes)
     return pool_leaf.at[ids].set(jnp.moveaxis(blocks, 1, 0), mode="drop")
 
 
-@functools.partial(jax.jit, static_argnames=("body", "aligned"))
+@functools.partial(jax.jit, static_argnames=("body", "aligned", "scale"))
 def paged_chunk_attention(q, k_new, v_new, k_pool, v_pool, row, start,
-                          body: str, aligned: bool = False):
+                          body: str, aligned: bool = False, scale=None,
+                          kv_b=None):
     """One prefill chunk's attention of one layer of ONE slot over the
     paged pool.
 
@@ -456,25 +578,47 @@ def paged_chunk_attention(q, k_new, v_new, k_pool, v_pool, row, start,
     that ``start`` is a multiple of the block size, as ``C`` then has to
     be: the chunk is written as whole blocks, where otherwise the blocks
     it touches are read, updated and set back.
+
+    A latent pool: ``v_new`` and ``v_pool`` None, ``kv_b`` and ``scale`` as
+    ``paged_decode_attention`` has them, ``q`` (q_heads, C, nope + pe) and
+    ``k_new`` (1, C, rank + pe); ``out`` is (q_heads, C, v_head). The kernel
+    runs a chunk EXPANDED: each block of latents it reads goes through
+    ``W_uk_h`` and ``W_uv_h`` for the head at hand and is scored as keys and
+    values of that head. With thousands of queries to a column, the
+    expansion (``rank`` products a column and head) is a fifth of the
+    scores it feeds, and those cost ``nope + pe + v_head`` products a
+    (query, column, head) where the absorbed form costs ``2 rank + pe``,
+    3.4 times as many at the published sizes (on the chip the absorbed
+    kernel ran at three quarters of the MXU's peak and was still three
+    fifths of a chunk's time). The XLA body runs absorbed, as a decode step.
     """
     if body not in PAGED_BODIES:
         raise ValueError(f"unknown paged body {body!r}; expected one of "
                          f"{PAGED_BODIES}")
     write = scatter_prefill_blocks if aligned else scatter_prefill_columns
     with jax.named_scope("kv_write"):
-        k_pool = write(k_pool, row, start, k_new)
-        v_pool = write(v_pool, row, start, v_new)
+        if v_pool is None:
+            k_pool = write(k_pool, row, start, k_new, latent=True)
+        else:
+            k_pool = write(k_pool, row, start, k_new)
+            v_pool = write(v_pool, row, start, v_new)
     with jax.named_scope("paged_attention"):
         if body == "paged_pallas":
             from elephas_tpu.ops.attention_pallas import (
+                pallas_latent_chunk_attention,
                 pallas_paged_chunk_attention,
             )
 
-            out = pallas_paged_chunk_attention(q, k_pool, v_pool, row, start)
+            if v_pool is None:
+                out = pallas_latent_chunk_attention(q, kv_b, k_pool, row,
+                                                    start, scale)
+            else:
+                out = pallas_paged_chunk_attention(q, k_pool, v_pool, row,
+                                                   start)
         else:
             out = _paged_attend_xla(
                 q[None], k_pool, v_pool, row[None],
-                (start + jnp.arange(q.shape[1]))[None])[0]
+                (start + jnp.arange(q.shape[1]))[None], scale, kv_b)[0]
     return out, k_pool, v_pool
 
 

@@ -929,3 +929,359 @@ def pallas_paged_chunk_attention(q, k_pool, v_pool, row, start,
       *([v_pool] * blocks))
     # lane group 0 of row (kv head, query head of its group, i)
     return out[:, :, :head_dim].reshape(q_heads, chunk, head_dim)
+
+
+# ------------------------------------------------ latent paged attention
+#
+# A latent pool is ONE leaf of one head, ``(num_blocks, 1, width,
+# block_size)``, a block's columns minor (``ops.attention.
+# latent_leaf_shape``): a column is a key of ``width`` values whose first
+# ``value_width`` are also its value. All of a layer's query heads read it
+# (the absorbed form of latent attention), so a block is moved once and
+# scored by every head, for key and value alike.
+
+# VMEM the latent chunk kernel may take: a chunk of 2,048 queries holds
+# 4 MB of scores a head and as much again of weights beside its operands,
+# past Mosaic's default scoped limit and well inside a v5e's 128 MiB.
+_LATENT_CHUNK_VMEM = 64 << 20
+
+
+def _latent_fold(q, blocks, live, value_width, sm_scale, m_ref, l_ref, acc_ref):
+    """Fold a step's ``blocks`` (each (width, block_size), columns minor)
+    into the running softmax of the rows of ``q`` (rows, width): the scores
+    of every block side by side, dead where ``live(column index)`` is false
+    (every column live where it is None), one softmax update, then each
+    block's weights against its own first ``value_width`` rows."""
+    s = jnp.concatenate(
+        [jax.lax.dot_general(q, k, (((1,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+         for k in blocks], axis=1) * sm_scale
+    if live is not None:
+        s = jnp.where(live(jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)),
+                      s, _NEG_INF)
+    m = m_ref[...]
+    m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m - m_new)
+    l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
+    block_size = blocks[0].shape[1]
+    acc = alpha * acc_ref[...]
+    for b, k in enumerate(blocks):
+        acc = acc + jax.lax.dot_general(
+            p[:, b * block_size:(b + 1) * block_size].astype(k.dtype),
+            k[:value_width], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    acc_ref[...] = acc
+    m_ref[...] = m_new
+
+
+def _latent_decode_kernel(
+    lane_ref, first_ref, table_ref, idx_ref, steps_ref,  # scalar prefetch
+    q_ref,  # (1, heads, width): every head's query of one lane
+    new_ref,  # (1, width, 1): the lane's new column
+    k_hbm,  # the pool, left where it is
+    o_ref, ko_hbm,  # (1, heads, value_width); the pool again (aliased)
+    k_buf,  # (2, blocks, width, block_size): a step's blocks, twice
+    sem, tail_sem,  # DMAs in flight: (buffer, block); the tail's
+    m_ref, l_ref, acc_ref,
+    *, blocks: int, blocks_per_slot: int, value_width: int, sm_scale: float,
+):
+    """``_paged_decode_kernel`` for a latent pool: grid step ``w`` folds
+    ``blocks`` consecutive blocks of one lane, from its block
+    ``first_ref[w]`` on, into the running softmax of all of the lane's
+    heads at once; the step that holds the lane's last block, its tail,
+    puts the new column into it before it is scored and sends it back to
+    the pool. The next step's live blocks are on their way while this one
+    is scored; a block with no live column is not moved, and what its place
+    in the buffer holds is scored dead."""
+    w = pl.program_id(0)
+    block_size = k_buf.shape[3]
+
+    def last_block(lane):
+        return jnp.minimum(idx_ref[lane] // block_size, blocks_per_slot - 1)
+
+    def each_live_block(step, buf, act):
+        lane, first = lane_ref[step], first_ref[step]
+
+        def body(b, carry):
+            phys = table_ref[lane * blocks_per_slot + first + b]
+            act(pltpu.make_async_copy(k_hbm.at[phys, 0], k_buf.at[buf, b],
+                                      sem.at[buf, b]))
+            return carry
+
+        live = jnp.minimum(blocks, last_block(lane) - first + 1)
+        jax.lax.fori_loop(0, live, body, 0)
+
+    buf = jax.lax.rem(w, 2)
+
+    @pl.when(w == 0)
+    def _first():
+        # a place no copy has filled is scored dead: it has to be finite
+        k_buf[...] = jnp.zeros_like(k_buf)
+        each_live_block(0, 0, lambda copy: copy.start())
+
+    @pl.when(w + 1 < steps_ref[0])
+    def _ahead():
+        each_live_block(w + 1, 1 - buf, lambda copy: copy.start())
+
+    each_live_block(w, buf, lambda copy: copy.wait())
+
+    lane, first = lane_ref[w], first_ref[w]
+    idx = idx_ref[lane]
+    last = last_block(lane)
+    is_tail = first + blocks > last
+
+    @pl.when(first == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _MASKED)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def tail_copy():
+        phys = table_ref[lane * blocks_per_slot + last]
+        return pltpu.make_async_copy(k_buf.at[buf, last - first],
+                                     ko_hbm.at[phys, 0], tail_sem.at[0])
+
+    @pl.when(is_tail)
+    def _write():
+        b = last - first
+        here = jax.lax.broadcasted_iota(
+            jnp.int32, k_buf.shape[2:], 1) == idx - last * block_size
+        k_buf[buf, b] = jnp.where(here, new_ref[0], k_buf[buf, b])
+        tail_copy().start()
+
+    _latent_fold(q_ref[0], [k_buf[buf, b] for b in range(blocks)],
+                 lambda col: col <= idx - first * block_size,
+                 value_width, sm_scale, m_ref, l_ref, acc_ref)
+
+    @pl.when(is_tail)
+    def _finalize():
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                    ).astype(o_ref.dtype)
+        # the next step's copies may land in this half only after it left
+        tail_copy().wait()
+
+
+@functools.partial(jax.jit, static_argnames=("value_width", "scale", "blocks",
+                                             "interpret"))
+def pallas_latent_decode_attention(q, new, pool, table, idx, active,
+                                   value_width: int, scale=None,
+                                   blocks: int | None = None,
+                                   interpret: bool = False):
+    """One decode step of one layer on a latent paged pool, in place.
+
+    ``q``: (slots, q_heads, width); ``new``: (slots, 1, width), the step's
+    column a lane; ``pool``: (num_blocks, 1, width, block_size), aliased to
+    the returned pool; ``table``, ``idx``, ``active`` as
+    ``pallas_paged_decode_attention`` has them. Returns ``(out, pool)``,
+    ``out`` (slots, q_heads, value_width) and zeros for an inactive lane.
+    The grid runs over the active lanes' steps only, a run-time count; the
+    pool stays in HBM and of it only each active lane's tail block is
+    written back."""
+    from elephas_tpu.ops.attention import paged_decode_blocks
+
+    slots, q_heads, width = q.shape
+    num_blocks, _, _, block_size = pool.shape
+    blocks_per_slot = table.shape[1]
+    if blocks is None:
+        blocks = paged_decode_blocks(pool.shape, pool.dtype, width, q_heads,
+                                     blocks_per_slot, latent=True)
+    dtype = pool.dtype
+    idx = idx.astype(jnp.int32)
+    table = jnp.clip(table.astype(jnp.int32), 0, num_blocks - 1)
+    last = jnp.clip(idx // block_size, 0, blocks_per_slot - 1)
+    most = -(-blocks_per_slot // blocks)
+    live = active[:, None] & (jnp.arange(most)[None] * blocks <= last[:, None])
+    (work,) = jnp.nonzero(live.reshape(-1), size=live.size, fill_value=0)
+    work = work.astype(jnp.int32)
+    lane, first = work // most, work % most * blocks
+    steps = live.sum().astype(jnp.int32)
+
+    kernel = functools.partial(
+        _latent_decode_kernel, blocks=blocks, blocks_per_slot=blocks_per_slot,
+        value_width=value_width,
+        sm_scale=float(scale) if scale is not None else 1.0 / (width ** 0.5),
+    )
+    any_space = pl.BlockSpec(memory_space=pl.ANY)
+    out, pool = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(steps,),
+            in_specs=[
+                pl.BlockSpec((1, q_heads, width),
+                             lambda w, lane, *_: (lane[w], 0, 0)),
+                pl.BlockSpec((1, width, 1),
+                             lambda w, lane, *_: (lane[w], 0, 0)),
+                any_space,
+            ],
+            out_specs=[
+                pl.BlockSpec((1, q_heads, value_width),
+                             lambda w, lane, *_: (lane[w], 0, 0)),
+                any_space,
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((2, blocks, width, block_size), dtype),
+                pltpu.SemaphoreType.DMA((2, blocks)),
+                pltpu.SemaphoreType.DMA((1,)),
+                pltpu.VMEM((q_heads, 1), jnp.float32),
+                pltpu.VMEM((q_heads, 1), jnp.float32),
+                pltpu.VMEM((q_heads, value_width), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((slots, q_heads, value_width), q.dtype),
+            jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        ],
+        # operands count the scalar-prefetch arguments: the pool is 7
+        input_output_aliases={7: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name="latent_decode_attention",
+    )(lane, first, table.reshape(-1), idx, steps.reshape(1), q.astype(dtype),
+      jnp.swapaxes(new.astype(dtype), 1, 2), pool)
+    # a lane the grid never visited holds whatever its output rows held
+    return jnp.where(active[:, None, None], out, 0), pool
+
+
+def _latent_chunk_kernel(
+    phys_ref, steps_ref, start_ref,  # scalar prefetch (SMEM)
+    qn_ref, qp_ref,  # (heads a step, C, nope), (.., C, pe): scaled queries
+    uk_ref, uv_ref,  # (heads a step, nope, rank), (.., v_head, rank)
+    bound_ref,  # (C, 1): the last live column of each query
+    *refs,  # the step's blocks, (rank + pe, block_size) each; out; scratch
+    blocks: int, rank: int,
+):
+    """Grid step (head group, ``j``): fold ``blocks`` blocks of the slot's
+    row, from block ``j * blocks`` on, into the running softmax of the
+    chunk's queries, for each head of the group. A block is latents,
+    columns minor; for the head at hand its first ``rank`` rows go through
+    ``W_uk`` to the head's keys and through ``W_uv`` to its values, both
+    still columns minor, and the last rows are the rotary key all heads
+    share. A step wholly before the chunk's first query is scored unmasked."""
+    del phys_ref  # read by the index maps only
+    k_refs = refs[:blocks]
+    o_ref, m_ref, l_ref, acc_ref = refs[blocks:]
+    j = pl.program_id(1)
+    block_size = k_refs[0].shape[1]
+    columns = blocks * block_size
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _MASKED)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def product(a, b, contract):
+        return jax.lax.dot_general(a, b, (contract, ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+    def fold(masked: bool):
+        latents = [ref[:rank] for ref in k_refs]
+        rotary = [ref[rank:] for ref in k_refs]
+        for h in range(qn_ref.shape[0]):
+            dtype = qn_ref.dtype
+            s = jnp.concatenate([
+                product(qn_ref[h], product(uk_ref[h], c, ((1,), (0,))).astype(dtype),
+                        ((1,), (0,)))
+                + product(qp_ref[h], r, ((1,), (0,)))
+                for c, r in zip(latents, rotary)], axis=1)
+            if masked:
+                col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                s = jnp.where(col <= bound_ref[...] - j * columns, s, _NEG_INF)
+            m = m_ref[h]
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l_ref[h] = alpha * l_ref[h] + p.sum(axis=-1, keepdims=True)
+            acc = alpha * acc_ref[h]
+            for b, c in enumerate(latents):
+                values = product(uv_ref[h], c, ((1,), (0,))).astype(dtype)
+                acc = acc + product(
+                    p[:, b * block_size:(b + 1) * block_size].astype(dtype),
+                    values, ((1,), (1,)))
+            acc_ref[h] = acc
+            m_ref[h] = m_new
+
+    whole = (j + 1) * columns - 1 <= start_ref[0]  # every column live
+    pl.when(whole)(lambda: fold(False))
+    pl.when(jnp.logical_not(whole))(lambda: fold(True))
+
+    @pl.when(j == steps_ref[0] - 1)
+    def _finalize():
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                      ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def pallas_latent_chunk_attention(q, kv_b, pool, row, start, scale=None,
+                                  interpret: bool = False):
+    """One prefill chunk of one layer of ONE slot against a latent paged
+    pool, which already holds the chunk's own columns: query ``i`` of every
+    head attends columns ``<= start + i`` of the slot's blocks, keys and
+    values a head expanded from each block as it is read.
+
+    ``q``: (q_heads, C, nope + pe), a head's own queries, the rotary part
+    rotated; ``kv_b``: (rank, q_heads, nope + v_head); ``pool``:
+    (num_blocks, 1, rank + pe, block_size), read only; ``row``:
+    (blocks_per_slot,) int32; ``start``: scalar. Returns (q_heads, C,
+    v_head). The grid is (head groups, steps over the row's blocks), the
+    steps a run-time count, as ``pallas_paged_chunk_attention`` has them;
+    the softmax scale is folded into the queries."""
+    from elephas_tpu.ops.attention import _latent_chunk_tiles
+
+    q_heads, chunk, q_width = q.shape
+    num_blocks, _, width, block_size = pool.shape
+    rank = kv_b.shape[0]
+    pe = width - rank
+    nope = q_width - pe
+    v_head = kv_b.shape[2] - nope
+    group, blocks = _latent_chunk_tiles(q_heads, block_size)
+    dtype = pool.dtype
+    scale = float(scale) if scale is not None else 1.0 / (q_width ** 0.5)
+    q = (q.astype(jnp.float32) * scale).astype(dtype)
+    uk = jnp.transpose(kv_b[..., :nope], (1, 2, 0)).astype(dtype)  # (h, nope, rank)
+    uv = jnp.transpose(kv_b[..., nope:], (1, 2, 0)).astype(dtype)  # (h, v, rank)
+    start = start.astype(jnp.int32)
+    bound = (start + jnp.arange(chunk, dtype=jnp.int32))[:, None]
+
+    most = -(-row.shape[0] // blocks)
+    phys = jnp.clip(row.astype(jnp.int32), 0, num_blocks - 1)
+    phys = jnp.pad(phys, (0, most * blocks - row.shape[0]), mode="edge")
+    steps = jnp.clip((start + chunk - 1) // (blocks * block_size) + 1, 1, most)
+
+    def by_head(*shape):
+        return pl.BlockSpec((group,) + shape, lambda g, j, *_: (g,) + (0,) * len(shape))
+
+    live_blocks = [
+        pl.BlockSpec((None, None, width, block_size),
+                     lambda g, j, phys, *_, b=b: (phys[j * blocks + b], 0, 0, 0))
+        for b in range(blocks)
+    ]
+    return pl.pallas_call(
+        functools.partial(_latent_chunk_kernel, blocks=blocks, rank=rank),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(q_heads // group, steps),
+            in_specs=[by_head(chunk, nope), by_head(chunk, pe),
+                      by_head(nope, rank), by_head(v_head, rank),
+                      pl.BlockSpec((chunk, 1), lambda g, j, *_: (0, 0)),
+                      *live_blocks],
+            out_specs=by_head(chunk, v_head),
+            scratch_shapes=[
+                pltpu.VMEM((group, chunk, 1), jnp.float32),
+                pltpu.VMEM((group, chunk, 1), jnp.float32),
+                pltpu.VMEM((group, chunk, v_head), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((q_heads, chunk, v_head), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_LATENT_CHUNK_VMEM,
+        ),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name="latent_chunk_attention",
+    )(phys, steps.reshape(1), start.reshape(1), q[..., :nope], q[..., nope:],
+      uk, uv, bound, *([pool] * blocks))

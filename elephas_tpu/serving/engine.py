@@ -57,10 +57,11 @@ for connect (``parameter.client._RETRY_DELAYS``).
 from __future__ import annotations
 
 import dataclasses
+import inspect
 import itertools
 import threading
 import time
-from typing import Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -72,6 +73,7 @@ from elephas_tpu.models.decode_cache import (
     KV,
     PagedDecode,
     first_index,
+    has_latent,
     has_state,
     leaf_kind,
     leaves_of_kind,
@@ -245,7 +247,23 @@ class InferenceEngine:
         # What the engine has to know of the model it reads from the cache
         # tree: a leaf that is neither K/V nor an index is per-slot state.
         self.stateful = has_state(self.pool.cache)
+        # ... and a K/V leaf may be a latent: key and value in one, one head.
+        self.latent = has_latent(self.pool.cache)
+        # A module that takes ``valid`` is told a chunk's real tokens: it
+        # keeps padding out of what padding is not invisible to (a
+        # recurrence, a router) and returns the one sampled row of logits.
+        self.takes_valid = "valid" in inspect.signature(
+            type(module).__call__).parameters
+        # Per-step counters a module's layers sow (``counters`` collection):
+        # their names, fixed when the programs are traced.
+        self._counter_names: Tuple[str, ...] = ()
         self.spec = None
+        if speculative and self.latent:
+            raise NotImplementedError(
+                "speculative decoding is not built for a latent cache: a "
+                "draft or verify window attends a gathered contiguous row of "
+                "keys and values a head, and a latent pool holds neither"
+            )
         if speculative:
             from elephas_tpu.serving.spec import (
                 SelfDraftSource,
@@ -394,7 +412,7 @@ class InferenceEngine:
             return jnp.where(start == 0, jnp.zeros_like(own), own)
 
         row_cache = jax.tree_util.tree_map_with_path(to_row, cache)
-        told = {"valid": valid[None]} if self.stateful else {}
+        told = {"valid": valid[None]} if self.takes_valid else {}
         logits, mutated = self.decode_module.apply(
             {"params": params, "cache": row_cache},
             tokens,
@@ -403,12 +421,12 @@ class InferenceEngine:
             paged=PagedDecode(
                 row, self.prefill_attention,
                 aligned=self.prefill_chunk % self.pool.block_size == 0),
-            mutable=["cache"],
+            mutable=["cache", "counters"],
             **told,
         )
         # The chunk's LAST VALID position predicts the first new token
         # (only the final chunk's sample is ever read).
-        last = logits[:, 0] if self.stateful else jax.lax.dynamic_slice_in_dim(
+        last = logits[:, 0] if self.takes_valid else jax.lax.dynamic_slice_in_dim(
             logits, valid - 1, 1, axis=1)[:, 0]
         # Position-keyed sampling: rows are never left-padded, so the
         # sampled token's stream position is the prefilled depth start +
@@ -437,7 +455,7 @@ class InferenceEngine:
         new_cache = jax.tree_util.tree_map_with_path(
             back, cache, mutated["cache"]
         )
-        return first[0], new_cache
+        return (first[0], new_cache) + self._counted(mutated)
 
     def _paged_decode_impl(self, params, cache, table, prev_tokens,
                            override_vals, override_mask, active_mask,
@@ -468,14 +486,30 @@ class InferenceEngine:
             pad_offset=pad,
             active=active_mask,
             paged=PagedDecode(table, self.decode_attention),
-            mutable=["cache"],
+            mutable=["cache", "counters"],
         )
         with jax.named_scope("sample"):
             nxt = sample_tokens_at(
                 logits[:, -1], rng, idx - pad + 1, self._greedy, self.top_k,
                 self.temperature,
             )
-        return nxt, mutated["cache"]
+        return (nxt, mutated["cache"]) + self._counted(mutated)
+
+    def _counted(self, mutated) -> tuple:
+        """What the module's layers sowed into ``counters`` in this apply,
+        each name summed over the layers, as one float32 vector in the
+        names' order: ``(vector,)``, or ``()`` for a module that counts
+        nothing (its programs return what they always did)."""
+        sown = mutated.get("counters")
+        if not sown:
+            return ()
+        totals: Dict[str, Any] = {}
+        for path, leaf in jax.tree_util.tree_flatten_with_path(sown)[0]:
+            name = next(k.key for k in reversed(path) if hasattr(k, "key"))
+            totals[name] = totals.get(name, 0.0) + leaf
+        self._counter_names = tuple(sorted(totals))
+        return (jnp.stack([totals[n] for n in self._counter_names]).astype(
+            jnp.float32),)
 
     def _kv_layout(self):
         """The pool's own K/V leaf and the head width its rows pack: the
@@ -495,7 +529,8 @@ class InferenceEngine:
 
         leaf, head_dim = self._kv_layout()
         return paged_decode_body(leaf.shape, leaf.dtype, head_dim, self.mesh,
-                                 q_heads=self.decode_module.num_heads)
+                                 q_heads=self.decode_module.num_heads,
+                                 latent=self.latent)
 
     @property
     def decode_kernel_blocks(self) -> Optional[int]:
@@ -509,7 +544,7 @@ class InferenceEngine:
         leaf, head_dim = self._kv_layout()
         return paged_decode_blocks(
             leaf.shape, leaf.dtype, head_dim, self.decode_module.num_heads,
-            self.pool.blocks_per_slot)
+            self.pool.blocks_per_slot, latent=self.latent)
 
     @property
     def prefill_attention(self) -> str:
@@ -520,7 +555,9 @@ class InferenceEngine:
 
         leaf, head_dim = self._kv_layout()
         return paged_chunk_body(leaf.shape, leaf.dtype, head_dim,
-                                self.prefill_chunk, self.mesh)
+                                self.prefill_chunk, self.mesh,
+                                q_heads=self.decode_module.num_heads,
+                                latent=self.latent)
 
     def _name_attention(self):
         """The bodies both programs will be traced with, and the decode
@@ -544,7 +581,7 @@ class InferenceEngine:
         """Scheduler-facing chunk closure: runs one compiled chunk and
         swaps the donated pool; returns the device token sampled at the
         chunk's last valid position (read only for the final chunk)."""
-        first, new_cache = self._jit_prefill(
+        first, new_cache, *counted = self._jit_prefill(
             self.params, self.pool.cache, self.pool.device_table(),
             tokens, slot, start, valid, self._next_rng(),
         )
@@ -553,6 +590,8 @@ class InferenceEngine:
             # Model draft sources mirror every prompt chunk into their
             # own cache (no-op for self-draft, which reads the pool).
             self.spec.prefill_chunk(tokens, slot, start, valid)
+        if counted:
+            return first, (self._counter_names, counted[0])
         return first
 
     def _decode(self, cache, prev_tokens, override_vals, override_mask,
@@ -563,11 +602,14 @@ class InferenceEngine:
             # mesh. Placed alike, both hit one trace of the program.
             prev_tokens = jax.device_put(
                 prev_tokens, NamedSharding(self.mesh, P()))
-        return self._jit_decode(
+        nxt, new_cache, *counted = self._jit_decode(
             self.params, cache, self.pool.device_table(), prev_tokens,
             override_vals, override_mask, active_mask, pad,
             self._next_rng(),
         )
+        if counted:
+            return nxt, new_cache, (self._counter_names, counted[0])
+        return nxt, new_cache
 
     # -- tensor-parallel serving -------------------------------------------
 
@@ -597,6 +639,14 @@ class InferenceEngine:
                 "shard_serving is not built for a model with per-slot "
                 "state: the state rows have no sharding rule, and the "
                 "scan's kernel is not partitioned by a mesh"
+            )
+        if getattr(self.decode_module, "experts_held", None) is not None:
+            raise NotImplementedError(
+                "shard_serving is not built for a routed expert layer: the "
+                "sharding rules place no expert over a mesh, and the "
+                "exchange of tokens between the devices that hold them is "
+                "not built; the module is told which experts it holds "
+                "(experts_held) and serves its share on one device"
             )
         if self._prefill_traces or self._decode_traces or \
                 self.pool.admitted_total:
@@ -1018,6 +1068,7 @@ class InferenceEngine:
             "pool_free": self.pool.free_count,
             "kv_blocks_free": self.pool.free_blocks,
             "kv_blocks_total": self.pool.num_blocks,
+            "kv_bytes_per_token": self.pool.kv_bytes_per_token,
             **self.pool.prefix_stats(),
             **self.pool.state_signals(),
             "state_resets": self.pool.state_resets,

@@ -62,3 +62,23 @@ def fetch_lanes(tokens, lanes: Sequence[int]) -> List[Tuple[int, int]]:
     """
     host = np.asarray(fetch(tokens))  # host-ok: sanctioned sync point
     return [(lane, int(host[lane])) for lane in lanes]  # host-ok: numpy
+
+
+def _lists(rider):
+    return jax.tree_util.tree_map(
+        lambda a: np.asarray(a).tolist(), rider)  # host-ok: numpy
+
+
+def fetch_lanes_and(tokens, lanes: Sequence[int], extra):
+    """``fetch_lanes`` with a rider: ``extra`` (a device vector or a list
+    of them: a step's counters) comes back in the SAME transfer as the
+    lanes' tokens, as plain lists of floats. Returns ``(pairs, extra)``."""
+    host, rider = fetch((tokens, extra))
+    host = np.asarray(host)  # host-ok: sanctioned sync point
+    return [(lane, int(host[lane])) for lane in lanes], _lists(rider)  # host-ok: numpy
+
+
+def fetch_scalar_and(value, extra):
+    """``fetch_scalar`` with the same rider."""
+    host, rider = fetch((value, extra))
+    return int(host), _lists(rider)  # host-ok: sanctioned sync point

@@ -242,8 +242,10 @@ class PrefixCache:
         references through ``decref``. Returns it (None when empty)."""
         if not self._entries:
             return None
-        key = min(self._entries, key=lambda k: self._entries[k].recency)
-        entry = self._entries.pop(key)
+        # over the entries, not the keys: a lookup by key hashes the whole
+        # token chain again, 16k tokens deep for a long document's
+        entry = min(self._entries.values(), key=lambda e: e.recency)
+        del self._entries[entry.tokens]
         for b in entry.blocks:
             decref(b)
         self.evictions_total += 1
@@ -547,6 +549,15 @@ class PagedKVPool:
                 "the recurrent and convolution state of the other layers "
                 "would be left behind"
             )
+
+    @property
+    def kv_bytes_per_token(self) -> int:
+        """Bytes one cached token holds over every K/V leaf: a block's
+        bytes over the columns it holds, all layers. A latent leaf counts
+        once, as key and value in one."""
+        return sum(leaf.size // leaf.shape[0] * leaf.dtype.itemsize
+                   for _, leaf in leaves_of_kind(self._cache, KV)
+                   ) // self.block_size
 
     def state_signals(self) -> dict:
         """What the ``step`` event says of the state rows: a row is in use

@@ -230,7 +230,7 @@ class ServingMetrics:
                     kv_blocks_in_use: Optional[int] = None,
                     kv_blocks_total: Optional[int] = None,
                     state_slots_in_use: int = 0, state_slots_total: int = 0,
-                    state_bytes: int = 0) -> None:
+                    state_bytes: int = 0, counters=None) -> None:
         self.steps += 1
         self.tokens_emitted_total += tokens
         self.tokens_prefilled_total += prefill_tokens
@@ -269,6 +269,10 @@ class ServingMetrics:
                 decode_attention=self.decode_attention,
                 prefill_attention=self.prefill_attention,
                 decode_kernel_blocks=self.decode_kernel_blocks,
+                # what the model's layers counted in the decode step that
+                # this step harvested (a routed layer's assignments and
+                # loads): nothing for a model that counts nothing
+                **(counters or {}),
             )
 
     # -- aggregates --------------------------------------------------------

@@ -45,7 +45,7 @@ closures.
 from __future__ import annotations
 
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -197,6 +197,9 @@ class _Prefilling:
     t_pop: float
     t_pre0: Optional[float] = None
     first_dev: Any = None
+    # chunks whose span waits for the counters of its program:
+    # (t0, t1, start, valid, device counters), read at finalize
+    counted: List[Tuple] = field(default_factory=list)
 
 
 @dataclass
@@ -212,6 +215,9 @@ class _Inflight:
     spec: bool = False
     spec_emitted: Any = None             # (max_slots, gamma + 1) device
     spec_accepted: Any = None            # (max_slots,) device
+    # What the model's layers counted in this step: (names, device vector)
+    # or None. It comes back with the lanes' tokens, in the one fetch.
+    counted: Any = None
 
 
 class ContinuousBatchingScheduler:
@@ -321,6 +327,7 @@ class ContinuousBatchingScheduler:
         self._prefill_s = self._dispatch_s = 0.0
         self._prefill_tokens = self._prefill_chunks = 0
         self._lane_lengths: List[int] = []
+        self._step_counters: Dict[str, float] = {}
 
     # -- introspection -----------------------------------------------------
 
@@ -547,18 +554,27 @@ class ContinuousBatchingScheduler:
         tokens = jnp.asarray(  # host list → device upload
             [chunk], jnp.int32
         )
-        pf.first_dev = self.chunk_prefill_fn(
+        out = self.chunk_prefill_fn(
             tokens, jnp.int32(pf.slot), jnp.int32(start), jnp.int32(valid),
         )
+        # A model whose layers count (a routed layer's assignments) hands
+        # back ``(first, (names, device vector))``.
+        pf.first_dev, counted = out if isinstance(out, tuple) else (out, None)
         pf.next_col = start + valid
         self._prefill_tokens += valid
         self._prefill_chunks += 1
         if self.tracer.enabled:
-            self.tracer.record(
-                "step/prefill_chunk", t0, self.clock(),
-                parent_id=self._step_id, slot=pf.slot, start=start,
-                valid=valid,
-            )
+            if counted is not None:
+                # the span carries the chunk's counters, and they ride the
+                # prompt's one fetch: it is recorded at finalize
+                pf.counted.append((t0, self.clock(), self._step_id, start,
+                                   valid, counted))
+            else:
+                self.tracer.record(
+                    "step/prefill_chunk", t0, self.clock(),
+                    parent_id=self._step_id, slot=pf.slot, start=start,
+                    valid=valid,
+                )
         if pf.next_col >= plen:
             self._finalize_prefill(pf)
 
@@ -568,7 +584,18 @@ class ContinuousBatchingScheduler:
         prefix cache, and join the decode batch."""
         req = pf.request
         t_sync0 = self.clock()
-        first = host_sync.fetch_scalar(pf.first_dev)
+        if pf.counted:
+            first, counts = host_sync.fetch_scalar_and(
+                pf.first_dev, [values for *_, (_, values) in pf.counted])
+            for (t0, t1, step_id, start, valid, (names, _)), values in zip(
+                    pf.counted, counts):
+                self.tracer.record(
+                    "step/prefill_chunk", t0, t1, parent_id=step_id,
+                    slot=pf.slot, start=start, valid=valid,
+                    **dict(zip(names, values)),
+                )
+        else:
+            first = host_sync.fetch_scalar(pf.first_dev)
         t_pre1 = self.clock()
         self.tracer.record("step/prefill_sync", t_sync0, t_pre1,
                            parent_id=self._step_id, slot=pf.slot)
@@ -825,7 +852,9 @@ class ContinuousBatchingScheduler:
             # block table.
             self.pool.ensure_decode_col(slot, entry.next_col)
             entry.next_col += 1
-        nxt, new_cache = self.decode_fn(
+        # A model whose layers count hands back a third value, ``(names,
+        # device vector)``: it is fetched with the lanes' tokens.
+        nxt, new_cache, *counted = self.decode_fn(
             self.pool.cache, prev_tokens, override_vals, override_mask,
             active_mask, self.pool.pad,
         )
@@ -837,7 +866,8 @@ class ContinuousBatchingScheduler:
             lanes=len(lanes),
         )
         return _Inflight(tokens=nxt, lanes=lanes,
-                         dispatched_at=dispatched_at)
+                         dispatched_at=dispatched_at,
+                         counted=counted[0] if counted else None)
 
     def _host_prev_tokens(self):
         """Previous-token vector built host-side — the cold-start path
@@ -864,9 +894,16 @@ class ContinuousBatchingScheduler:
         if not live:
             return 0
         t_wait0 = self.clock()
-        fetched = host_sync.fetch_lanes(
-            inflight.tokens, [slot for slot, _ in live]
-        )
+        slots = [slot for slot, _ in live]
+        if inflight.counted is None:
+            fetched = host_sync.fetch_lanes(inflight.tokens, slots)
+        else:
+            names, values = inflight.counted
+            fetched, values = host_sync.fetch_lanes_and(
+                inflight.tokens, slots, values)
+            for name, value in zip(names, values):
+                self._step_counters[name] = \
+                    self._step_counters.get(name, 0.0) + value
         now = self.clock()
         self._harvest_wait_s += now - t_wait0
         if self.metrics is not None:
@@ -1042,6 +1079,7 @@ class ContinuousBatchingScheduler:
         self._prefill_s = self._dispatch_s = 0.0
         self._prefill_tokens = self._prefill_chunks = 0
         self._lane_lengths = []
+        self._step_counters = {}
         emitted = (
             self._step_pipelined() if self.pipeline else self._step_sync()
         )
@@ -1063,6 +1101,7 @@ class ContinuousBatchingScheduler:
                 kv_blocks_in_use=self.pool.blocks_in_use,
                 kv_blocks_total=self.pool.num_blocks,
                 **self.pool.state_signals(),
+                counters=self._step_counters,
             )
         if self.load is not None:
             # BLOCK-granular KV pressure (free blocks beat free slots

@@ -294,6 +294,75 @@ def test_grouped_paged_decode_kernel_compiles_for_v5e(one_chip):
     assert compiled.memory_analysis().alias_size_in_bytes >= 2 * 2 * np.prod(pool)
 
 
+# The latent long-document cell (benchmark/configs/deepseek-v2.json): 128
+# query heads on one latent of 512 + 64 values, blocks of 128 columns, 16
+# slots of 144 blocks, chunks of 2,048 tokens; 40 held experts of 5,120 x
+# 1,536 under 2,048 x 6 assignments a chunk and 16 x 6 a decode step.
+_LATENT = dict(q_heads=128, rank=512, pe=64, nope=128, v_head=128, block_size=128,
+               slots=16, blocks_per_slot=144, chunk=2048, experts=40, d=5120, f=1536)
+
+
+def test_latent_kernels_compile_for_v5e(one_chip):
+    """Both latent attention kernels at the cell's shapes. The leaf is dense
+    and row-major on the device (a block's columns minor), the decode kernel
+    moves the pool's blocks itself and writes the pool in place, copying
+    nothing of it; the chunk kernel only reads it."""
+    from elephas_tpu.ops import attention
+    from elephas_tpu.ops.attention_pallas import (
+        pallas_latent_chunk_attention,
+        pallas_latent_decode_attention,
+    )
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    c = _LATENT
+    width = c["rank"] + c["pe"]
+    pool = attention.latent_leaf_shape(c["slots"] * c["blocks_per_slot"],
+                                       c["block_size"], width)
+    blocks = attention.paged_decode_blocks(pool, jnp.bfloat16, width, c["q_heads"],
+                                           c["blocks_per_slot"], latent=True)
+    assert blocks == -(-attention._DECODE_STEP_BYTES // (2 * width * c["block_size"]))
+    assert attention._latent_fits(pool, jnp.bfloat16, width, c["q_heads"], blocks)
+    decode = jax.jit(pallas_latent_decode_attention, donate_argnums=(2,),
+                     static_argnames=("value_width", "scale")).lower(
+        arg((c["slots"], c["q_heads"], width), jnp.bfloat16),
+        arg((c["slots"], 1, width), jnp.bfloat16), arg(pool, jnp.bfloat16),
+        arg((c["slots"], c["blocks_per_slot"]), jnp.int32),
+        arg((c["slots"],), jnp.int32), arg((c["slots"],), jnp.bool_),
+        value_width=c["rank"], scale=0.11472).compile()
+    assert "tpu_custom_call" in decode.as_text()
+    memory = decode.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * np.prod(pool)
+    assert memory.temp_size_in_bytes < 16 << 20  # nothing of the pool copied
+    chunk = jax.jit(pallas_latent_chunk_attention, static_argnames=("scale",)).lower(
+        arg((c["q_heads"], c["chunk"], c["nope"] + c["pe"]), jnp.bfloat16),
+        arg((c["rank"], c["q_heads"], c["nope"] + c["v_head"]), jnp.bfloat16),
+        arg(pool, jnp.bfloat16), arg((c["blocks_per_slot"],), jnp.int32),
+        arg((), jnp.int32), scale=0.11472).compile()
+    assert "tpu_custom_call" in chunk.as_text()
+    # the scaled queries and the transposed expansion: nothing a column
+    assert chunk.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+@pytest.mark.parametrize("rows", [2048 * 6, 16 * 6])
+def test_grouped_matmul_kernel_compiles_for_v5e(one_chip, rows):
+    """The routed layer's grouped product at a chunk's and a decode step's
+    rows, both orientations of an expert's matrices."""
+    from elephas_tpu.ops.routed_experts import _pallas_fits, pallas_grouped_matmul
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    c = _LATENT
+    for depth, cols in ((c["d"], c["f"]), (c["f"], c["d"])):
+        assert _pallas_fits(rows, depth, cols, jnp.bfloat16)
+        compiled = jax.jit(pallas_grouped_matmul).lower(
+            arg((rows, depth), jnp.bfloat16), arg((c["experts"], depth, cols), jnp.bfloat16),
+            arg((c["experts"],), jnp.int32)).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_selective_scan_kernel_compiles_for_v5e(one_chip):
     """The scan's kernel at a chunk of the cell: the state in registers,
     `B` and `C` in SMEM, and nothing of `(T, d_inner, d_state)` anywhere:

@@ -1,0 +1,641 @@
+"""The latent-attention, routed-expert family (`models/latent_moe.py`)
+through the serving engine: a latent paged cache with rotary positions on
+the decode path, and a routed layer that is told which experts it holds.
+Small sizes, seeded weights from the benchmark's family file, compared with
+the benchmark's plain reference (`benchmark/references/deepseek_v2.py`:
+float32, `highest` precision, keys and values a head, a full causal
+softmax, nothing of the program) or with plain numpy."""
+
+import importlib.util
+import math
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from elephas_tpu import InferenceEngine, compile_model
+from elephas_tpu.models import latent_moe
+from elephas_tpu.models.decode_cache import KV, has_latent, leaves_of_kind
+from elephas_tpu.ops import attention, routed_experts
+from elephas_tpu.ops.attention_pallas import (
+    pallas_latent_chunk_attention,
+    pallas_latent_decode_attention,
+)
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                     "benchmark")
+SEED = 2147483659
+YARN = dict(beta_fast=32, beta_slow=1, factor=40, mscale=0.707, mscale_all_dim=0.707,
+            original_max_position_embeddings=4096, type="yarn")
+PUBLISHED = dict(
+    num_hidden_layers=60, hidden_size=5120, intermediate_size=12288,
+    num_attention_heads=128, q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+    qk_rope_head_dim=64, v_head_dim=128, vocab_size=102400, first_k_dense_replace=1,
+    n_routed_experts=160, router_experts=160, experts_first=0, n_shared_experts=2,
+    moe_intermediate_size=1536, num_experts_per_tok=6, n_group=8, topk_group=3,
+    routed_scaling_factor=16, rope_theta=10000, rope_scaling=YARN, rms_norm_eps=1e-6,
+    max_position_embeddings=163840, topk_method="group_limited_greedy",
+    scoring_func="softmax", norm_topk_prob=False, moe_layer_freq=1,
+    tie_word_embeddings=False, attention_bias=False)
+SMALL = dict(PUBLISHED, num_hidden_layers=3, hidden_size=64, intermediate_size=128,
+             num_attention_heads=4, q_lora_rank=16, kv_lora_rank=8, qk_nope_head_dim=16,
+             qk_rope_head_dim=8, v_head_dim=16, vocab_size=211, n_routed_experts=4,
+             router_experts=8, moe_intermediate_size=32, num_experts_per_tok=2,
+             n_group=4, topk_group=2)
+
+
+def _bench_module(kind, name):
+    sys.path.insert(0, BENCH)
+    try:
+        spec = importlib.util.spec_from_file_location(
+            f"bench_{kind}_{name}", os.path.join(BENCH, kind, name + ".py"))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+    finally:
+        sys.path.remove(BENCH)
+
+
+@pytest.fixture(scope="module")
+def family():
+    return _bench_module("models", "deepseek_v2")
+
+
+@pytest.fixture(scope="module")
+def reference():
+    return _bench_module("references", "deepseek_v2")
+
+
+def _compiled(family, sizes=SMALL):
+    cfg = family.shape(sizes)
+    params = family.params(SEED, cfg, jnp.float32)
+    module = family.flax_module(cfg, "float32")
+    return module, params, compile_model(
+        module, params=params, optimizer="sgd", loss="sparse_categorical_crossentropy",
+        metrics=[], input_shape=(30,), input_dtype=jnp.int32)
+
+
+@pytest.fixture(scope="module")
+def served(family):
+    """(module, params, compiled) at the small size, weights from the seed."""
+    return _compiled(family)
+
+
+def _engine(compiled, **kw):
+    sizes = dict(max_slots=3, max_prompt_len=30, max_len=48, kv_block_size=8,
+                 prefill_chunk=8, queue_depth=16)
+    sizes.update(kw)
+    return InferenceEngine(compiled, **sizes)
+
+
+def _prompts(lengths, seed=1):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, SMALL["vocab_size"], n).tolist() for n in lengths]
+
+
+def _reference_logits(reference, family, tokens, rows, sizes=SMALL):
+    cfg = family.shape(sizes)
+    return reference.logits_at(
+        jnp.asarray(tokens, jnp.int32), jnp.asarray(rows, jnp.int32),
+        family.top_at(SEED, cfg, jnp.float32),
+        lambda layer: family.block_at(SEED, layer, cfg, jnp.float32),
+        family.layers(cfg))
+
+
+def _served_gaps(reference, family, prompts, results):
+    """For every served token, how far its reference logit lies below the
+    reference's best at its position."""
+    gaps = []
+    for prompt, res in zip(prompts, results):
+        seq = prompt + res.tokens[:-1]
+        rows = np.arange(len(prompt) - 1, len(seq))
+        logits = np.asarray(_reference_logits(reference, family, [seq], [rows]))[0]
+        gaps += list(logits.max(-1) - logits[np.arange(len(rows)), res.tokens])
+    return np.asarray(gaps)
+
+
+def _serve(eng, prompts, new=8):
+    ids = [eng.submit(p, max_new_tokens=new, stop_token=None) for p in prompts]
+    return [eng.result(i, timeout_s=300) for i in ids]
+
+
+# -- (a) the module's full forward ------------------------------------------
+
+
+def test_full_forward_matches_the_plain_reference(served, family, reference):
+    module, params, _ = served
+    tokens = np.asarray(_prompts([21, 21], seed=3))
+    got = module.apply({"params": params}, jnp.asarray(tokens))
+    rows = np.tile(np.arange(21), (2, 1))
+    want = _reference_logits(reference, family, tokens, rows)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
+    # the reference stands alone, and is the published form
+    source = open(os.path.join(BENCH, "references", "deepseek_v2.py")).read()
+    code = source.split('"""', 2)[2]
+    assert "elephas_tpu" not in code and "ragged_dot" not in code
+    assert 'default_matmul_precision("highest")' in code
+    assert "k_nope, v = expanded" in code  # keys and values a head, not absorbed
+
+
+# -- (b) through the engine: chunks, a ragged last one, then decode ----------
+
+
+def test_chunked_prefill_and_decode_match_the_reference(served, family, reference):
+    _, _, compiled = served
+    eng = _engine(compiled)
+    assert has_latent(eng.pool.cache) and not eng.stateful and eng.takes_valid
+    prompts = _prompts([5, 13, 19, 29, 9, 17])  # none a multiple of the chunk of 8
+    results = _serve(eng, prompts)
+    assert all(r.status == "completed" and len(r.tokens) == 8 for r in results)
+    assert _served_gaps(reference, family, prompts, results).max() < 1e-5
+    stats = eng.stats()
+    assert stats["prefill_traces"] == stats["decode_traces"] == 1
+    assert stats["decode_attention"] == stats["prefill_attention"] == "paged_xla"
+
+
+@pytest.mark.parametrize("fault", ["a chunk's tokens at the chunk's offset",
+                                   "every lane at the first lane's position"])
+def test_a_wrong_rotary_position_is_seen(served, family, reference, monkeypatch, fault):
+    """The faults the comparison has to see: every token of a chunk rotated
+    at the chunk's first position, and every lane of a decode step rotated
+    at one lane's position (lanes differ in length)."""
+    _, _, compiled = served
+    rotate = latent_moe.rotate
+
+    def wrong(x, positions, rope):
+        if fault.startswith("a chunk") and positions.shape[1] > 1 and positions.shape[0] == 1:
+            positions = jnp.broadcast_to(positions[:, :1], positions.shape)
+        if fault.startswith("every lane") and positions.shape[1] == 1:
+            positions = jnp.broadcast_to(positions[:1], positions.shape)
+        return rotate(x, positions, rope)
+
+    monkeypatch.setattr(latent_moe, "rotate", wrong)
+    prompts = _prompts([13, 19, 29])
+    results = _serve(_engine(compiled), prompts)
+    assert _served_gaps(reference, family, prompts, results).max() > 1e-5
+
+
+@pytest.mark.parametrize("chunk", [1, 5, 16])
+def test_every_chunk_width_gives_the_one_shot_prefills_tokens(served, chunk):
+    _, _, compiled = served
+    prompts = _prompts([5, 13, 29, 17])
+    whole = [r.tokens for r in _serve(_engine(compiled, prefill_chunk=30), prompts)]
+    assert [r.tokens for r in _serve(_engine(compiled, prefill_chunk=chunk), prompts)] == whole
+
+
+def test_a_reused_slot_gives_what_it_gives_alone(served):
+    _, _, compiled = served
+    first, second = _prompts([29, 11], seed=5)
+    alone = _serve(_engine(compiled, max_slots=1), [second])[0].tokens
+    eng = _engine(compiled, max_slots=1, prefix_cache=False)
+    reused = _serve(eng, [first, second])[1].tokens
+    assert eng.pool.admitted_total == 2 and reused == alone
+
+
+def test_the_chunk_program_builds_no_row_and_no_key_a_head(served):
+    _, _, compiled = served
+    eng = _engine(compiled)
+    program = str(jax.make_jaxpr(eng._chunk_prefill_impl)(
+        eng.params, eng.pool.cache, eng.pool.device_table(),
+        jnp.zeros((1, 8), jnp.int32), jnp.int32(0), jnp.int32(0), jnp.int32(8),
+        eng._next_rng()))
+    assert program.count("name=paged_chunk_attention") == SMALL["num_hidden_layers"]
+    assert "paged_to_contiguous" not in program
+
+
+# -- (c) the share -----------------------------------------------------------
+
+
+def test_the_four_shares_add_up_to_the_uncut_layer(family, reference):
+    """Four devices that each hold a quarter of the experts, each told
+    which, and the shared experts counted once, give the uncut routed layer
+    of the reference."""
+    uncut = dict(SMALL, n_routed_experts=8)
+    cfg = family.shape(uncut)
+    w = family.block_at(SEED, 1, cfg, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 11, cfg["hidden_size"]))
+    hyper = {**family.hyper(cfg), "count": 8}
+    static = tuple(sorted((k, v) for k, v in hyper.items() if k != "rope"))
+    ids, weights, load = reference._route(
+        {"ffn_norm": w["ffn_norm"], "experts": {"router": w["experts"]["router"]}},
+        x, 1e-6, static, reference.identity)
+    want = reference._routed({k: w[k] for k in ("ffn_norm", "shared", "experts")}, x, ids,
+                             weights, 1e-6, static, 64, reference.identity)
+    y = reference.rms_norm(x, w["ffn_norm"], 1e-6).reshape(-1, cfg["hidden_size"])
+    total = x.reshape(y.shape) + reference.gated(w["shared"], y, reference.identity)
+    for first in (0, 2, 4, 6):
+        layer = latent_moe.RoutedExperts(
+            n_routed_experts=8, experts_held=(first, 2), moe_d_ff=32, top_k=2, n_group=4,
+            topk_group=2, routed_scaling_factor=16.0)
+        held = {"router": w["experts"]["router"],
+                **{k: w["experts"][k][first:first + 2] for k in ("gate", "up", "down")}}
+        part, counted = layer.apply({"params": held}, y, mutable=["counters"])
+        total = total + part
+        sown = counted["counters"]
+        assert float(sown["moe_assignments"][0]) == y.shape[0] * 2
+        assert float(sown["moe_assignments_held"][0]) == float(load[first:first + 2].sum())
+    assert int(load.sum()) == y.shape[0] * 2  # held by one of the four, each
+    np.testing.assert_allclose(np.asarray(total).reshape(want.shape), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+# -- (d) the router ----------------------------------------------------------
+
+
+def _numpy_group_limited_greedy(p, n_group, topk_group, top_k):
+    ids = np.zeros((p.shape[0], top_k), np.int64)
+    for t, row in enumerate(p):
+        groups = row.reshape(n_group, -1)
+        best = np.argsort(-groups.max(-1), kind="stable")[:topk_group]
+        limited = np.zeros_like(row).reshape(n_group, -1)
+        limited[best] = groups[best]
+        ids[t] = np.argsort(-limited.reshape(-1), kind="stable")[:top_k]
+    return ids
+
+
+def test_the_router_is_group_limited_greedy_ties_included():
+    rng = np.random.default_rng(0)
+    logits = rng.standard_normal((64, 160)).astype(np.float32)
+    logits[:8] = np.round(logits[:8])  # ties, within a group and across groups
+    p = np.asarray(jax.nn.softmax(jnp.asarray(logits), -1))
+    ids, values = routed_experts.group_limited_top_k(jnp.asarray(p), 8, 3, 6)
+    want = _numpy_group_limited_greedy(p, 8, 3, 6)
+    np.testing.assert_array_equal(np.asarray(ids), want)
+    assert all(len(set(row // 20)) <= 3 and len(set(row)) == 6 for row in want)
+    np.testing.assert_array_equal(np.asarray(values), np.take_along_axis(p, want, 1))
+    # the layer weighs 16 p, not renormalised: the six do not add up to 16
+    assert not np.allclose(16 * np.asarray(values).sum(-1), 16.0)
+
+
+# -- (e) the grouped product --------------------------------------------------
+
+
+def _dense_routed(y, ids, weights, gate, up, down, first):
+    out = np.zeros(y.shape, np.float64)
+    for t in range(y.shape[0]):
+        for e, w in zip(ids[t], weights[t]):
+            if first <= e < first + gate.shape[0]:
+                h = y[t] @ gate[e - first]
+                h = h / (1 + np.exp(-h)) * (y[t] @ up[e - first])
+                out[t] += w * (h @ down[e - first])
+    return out
+
+
+@pytest.mark.parametrize("case", ["an expert with no token", "every assignment on one expert",
+                                  "no assignment on a held expert", "mixed"])
+def test_the_routed_product_is_exact_whatever_the_routing(case):
+    rng = np.random.default_rng(1)
+    tokens, d, f, top_k, first, count = 24, 16, 8, 3, 4, 4
+    y = rng.standard_normal((tokens, d)).astype(np.float32)
+    gate, up = (rng.standard_normal((count, d, f)).astype(np.float32) for _ in range(2))
+    down = rng.standard_normal((count, f, d)).astype(np.float32)
+    weights = rng.uniform(0.1, 2.0, (tokens, top_k)).astype(np.float32)
+    ids = {"an expert with no token": rng.choice([0, 4, 5, 7, 9], (tokens, top_k)),
+           "every assignment on one expert": np.full((tokens, top_k), 6),
+           "no assignment on a held expert": rng.choice([0, 1, 2, 3, 8, 9], (tokens, top_k)),
+           "mixed": rng.integers(0, 12, (tokens, top_k))}[case]
+    out, load = routed_experts.routed_experts(
+        jnp.asarray(y), jnp.asarray(ids), jnp.asarray(weights), jnp.asarray(gate),
+        jnp.asarray(up), jnp.asarray(down), first)
+    np.testing.assert_allclose(np.asarray(out), _dense_routed(
+        y, ids, weights, gate, up, down, first), atol=1e-4, rtol=1e-4)
+    held = (ids >= first) & (ids < first + count)
+    np.testing.assert_array_equal(
+        np.asarray(load), np.bincount(ids[held] - first, minlength=count))  # nothing dropped
+
+
+@pytest.mark.parametrize("sizes", [[100, 0, 50, 7, 0, 0, 200, 1], [0, 512, 0, 0],
+                                   [0, 0, 0, 0], [0, 0, 0, 3], [256, 256]])
+def test_the_grouped_kernel_matches_a_loop_of_products(sizes):
+    rng = np.random.default_rng(2)
+    lhs = rng.standard_normal((512, 256)).astype(np.float32)
+    rhs = rng.standard_normal((len(sizes), 256, 128)).astype(np.float32)
+    want, at = np.zeros((512, 128), np.float32), 0
+    for g, n in enumerate(sizes):
+        want[at:at + n] = lhs[at:at + n] @ rhs[g]
+        at += n
+    for got in (routed_experts.pallas_grouped_matmul(
+                    jnp.asarray(lhs), jnp.asarray(rhs), jnp.asarray(sizes, jnp.int32),
+                    interpret=True),
+                routed_experts.grouped_matmul(
+                    jnp.asarray(lhs), jnp.asarray(rhs), jnp.asarray(sizes, jnp.int32))):
+        np.testing.assert_allclose(np.asarray(got), want, atol=2e-4, rtol=2e-4)
+
+
+# -- (f) rotary ----------------------------------------------------------------
+
+
+def test_yarn_frequencies_and_the_softmax_scale(reference):
+    rope = (10000.0, 40.0, 4096.0, 32.0, 1.0, 0.707, 0.707)
+    got = latent_moe.rotary_frequencies(64, rope)
+    plain = 10000.0 ** (-np.arange(0, 64, 2) / 64)
+    low = math.floor(64 * math.log(4096 / (32 * 2 * math.pi)) / (2 * math.log(10000)))
+    high = math.ceil(64 * math.log(4096 / (1 * 2 * math.pi)) / (2 * math.log(10000)))
+    assert (low, high) == (10, 23) and got.shape == (32,)
+    np.testing.assert_allclose(got[:low + 1], plain[:low + 1])       # fast: as they are
+    np.testing.assert_allclose(got[high:], plain[high:] / 40)        # slow: stretched
+    ramp = (np.arange(low + 1, high) - low) / (high - low)
+    np.testing.assert_allclose(got[low + 1:high],
+                               plain[low + 1:high] * (1 - ramp) + plain[low + 1:high] / 40 * ramp)
+    np.testing.assert_allclose(got, reference.rotary_frequencies(64, rope))
+    assert abs(latent_moe.softmax_scale(192, rope) - 0.11472) < 5e-6
+    assert abs(reference.softmax_scale(192, rope) - 0.11472) < 5e-6
+    assert latent_moe.softmax_scale(192, None) == 192 ** -0.5
+    # pairs (2i, 2i + 1): position 0 turns nothing, a pair keeps its norm
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 3, 64))
+    np.testing.assert_allclose(latent_moe.rotate(x, jnp.zeros((2, 3), jnp.int32), rope), x,
+                               atol=1e-6)
+    turned = np.asarray(latent_moe.rotate(x, jnp.asarray([[5, 6, 7], [90, 91, 92]]), rope))
+    np.testing.assert_allclose((turned.reshape(2, 3, 32, 2) ** 2).sum(-1),
+                               (np.asarray(x).reshape(2, 3, 32, 2) ** 2).sum(-1), rtol=1e-5)
+
+
+# -- (g) both paged bodies on a latent pool ----------------------------------
+
+
+def _latent_case(heads=8, rank=128, pe=64, nope=32, v_head=32, bs=128, bps=3, slots=4):
+    width, nb = rank + pe, slots * bps
+    keys = jax.random.split(jax.random.PRNGKey(7), 5)
+    pool = jax.random.normal(keys[0], attention.latent_leaf_shape(nb, bs, width))
+    table = jnp.asarray(np.random.default_rng(0).permutation(nb).reshape(slots, bps),
+                        jnp.int32)
+    kv_b = jax.random.normal(keys[1], (rank, heads, nope + v_head)) * 0.1
+    return dict(pool=pool, table=table, kv_b=kv_b, keys=keys, heads=heads, rank=rank,
+                pe=pe, nope=nope, v_head=v_head, bs=bs, width=width, slots=slots)
+
+
+def _dense_latent_attention(c, q, latents, last, scale):
+    """Keys and values a head expanded from ``latents`` (L, width); ``q``:
+    (heads, Q, nope + pe); ``last``: (Q,) the last column each attends."""
+    k_nope = jnp.einsum("lr,rhf->hlf", latents[:, :c["rank"]], c["kv_b"][..., :c["nope"]])
+    v = jnp.einsum("lr,rhf->hlf", latents[:, :c["rank"]], c["kv_b"][..., c["nope"]:])
+    s = (jnp.einsum("hqf,hlf->hql", q[..., :c["nope"]], k_nope)
+         + jnp.einsum("hqf,lf->hql", q[..., c["nope"]:], latents[:, c["rank"]:])) * scale
+    s = jnp.where(jnp.arange(latents.shape[0])[None, :] <= last[:, None], s, -1e30)
+    return jnp.einsum("hql,hlf->hqf", jax.nn.softmax(s, -1), v)
+
+
+def _row(pool, table_row):
+    """A slot's latents, contiguous: (blocks * block_size, width)."""
+    return jnp.swapaxes(pool[table_row, 0], -1, -2).reshape(-1, pool.shape[2])
+
+
+@pytest.mark.parametrize("body", attention.PAGED_BODIES)
+def test_latent_decode_attention_matches_dense(body):
+    c = _latent_case()
+    idx = jnp.asarray([0, 130, 383, 40], jnp.int32)
+    active = jnp.asarray([True, True, True, False])
+    q = jax.random.normal(c["keys"][2], (c["slots"], c["heads"], c["nope"] + c["pe"]))
+    new = jax.random.normal(c["keys"][3], (c["slots"], 1, c["width"]))
+    if body == "paged_xla":
+        out, pool, none = attention.paged_decode_attention(
+            q, new, None, c["pool"], None, c["table"], idx, active, body, scale=0.11,
+            kv_b=c["kv_b"])
+        assert none is None
+    else:  # the kernel itself, absorbed by hand, in interpret mode
+        absorbed = attention._absorb(q[:, :, None], c["kv_b"], c["width"])[:, :, 0]
+        out, pool = pallas_latent_decode_attention(
+            absorbed, new, c["pool"], c["table"], idx, active, c["rank"], 0.11, blocks=2,
+            interpret=True)
+        out = attention._expand_values(out[:, :, None], c["kv_b"], c["v_head"])[:, :, 0]
+    for s in range(c["slots"]):
+        row = _row(pool, c["table"][s])
+        if not bool(active[s]):
+            np.testing.assert_array_equal(np.asarray(row), np.asarray(_row(c["pool"], c["table"][s])))
+            continue
+        np.testing.assert_allclose(np.asarray(row[int(idx[s])]), np.asarray(new[s, 0]), atol=1e-6)
+        want = _dense_latent_attention(c, q[s][:, None], row, idx[s][None], 0.11)[:, 0]
+        np.testing.assert_allclose(np.asarray(out[s]), np.asarray(want), atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("body", attention.PAGED_BODIES)
+@pytest.mark.parametrize("start", [0, 128, 37])
+def test_latent_chunk_attention_matches_dense(body, start):
+    c = _latent_case()
+    chunk = 128
+    q = jax.random.normal(c["keys"][2], (c["heads"], chunk, c["nope"] + c["pe"]))
+    new = jax.random.normal(c["keys"][3], (1, chunk, c["width"]))
+    out, pool, _ = attention.paged_chunk_attention(
+        q, new, None, c["pool"], None, c["table"][1], jnp.int32(start), "paged_xla",
+        start % c["bs"] == 0, scale=0.11, kv_b=c["kv_b"])
+    if body == "paged_pallas":
+        out = pallas_latent_chunk_attention(q, c["kv_b"], pool, c["table"][1],
+                                            jnp.int32(start), 0.11, interpret=True)
+    row = _row(pool, c["table"][1])
+    np.testing.assert_allclose(np.asarray(row[start:start + chunk]), np.asarray(new[0]), atol=1e-6)
+    want = _dense_latent_attention(c, q, row, start + jnp.arange(chunk), 0.11)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5, rtol=2e-5)
+
+
+def test_the_pool_holds_the_latent_and_nothing_a_head(served):
+    _, _, compiled = served
+    eng = _engine(compiled)
+    width = SMALL["kv_lora_rank"] + SMALL["qk_rope_head_dim"]
+    leaves = leaves_of_kind(eng.pool.cache, KV)
+    assert len(leaves) == SMALL["num_hidden_layers"]  # one a layer: no value leaf
+    assert all(leaf.shape == (eng.pool.num_blocks, 1, width, 8) for _, leaf in leaves)
+    assert eng.stats()["kv_bytes_per_token"] == SMALL["num_hidden_layers"] * width * 4
+    assert eng._kv_layout()[1] == width
+    # at the published sizes: 576 values a token a layer, dense on the device
+    assert attention.latent_leaf_shape(10, 128, 576) == (10, 1, 576, 128)
+    assert attention._latent_fits((10, 1, 576, 128), jnp.bfloat16, 576, 128, blocks=11)
+    assert not attention._latent_fits((10, 1, 576, 64), jnp.bfloat16, 576, 128)
+
+
+# -- (h) what rests on "a block is all there is to a prefix" ------------------
+
+
+def test_prefix_adoption_over_latent_blocks(served):
+    _, _, compiled = served
+    shared = _prompts([16], seed=8)[0]  # two whole blocks of 8
+    prompts = [shared + [1, 2, 3], shared + [4, 5], shared + [1, 2, 3]]
+    alone = [_serve(_engine(compiled), [p])[0].tokens for p in prompts]
+    eng = _engine(compiled)
+    assert eng.pool.prefix is not None
+    got = [_serve(eng, [p])[0].tokens for p in prompts]
+    assert got == alone
+    stats = eng.stats()
+    assert stats["prefix_hits"] == 2 and stats["prefix_tokens_saved"] == 32
+    eng.pool.assert_block_invariants()
+
+
+def test_fork_and_handoff_over_latent_blocks(served):
+    _, _, compiled = served
+    eng, other = _engine(compiled), _engine(compiled)
+    prompt = _prompts([19], seed=9)[0]
+    _serve(eng, [prompt], new=2)
+    pool = eng.pool
+    parent = pool.acquire()
+    pool.ensure_cols(parent, 16)
+    block = int(pool.table.rows[parent, 0])
+    pool.swap(jax.tree_util.tree_map(
+        lambda leaf: leaf.at[block].set(2.5) if leaf.ndim == 4 else leaf, pool.cache))
+    child = pool.fork_slot(parent)
+    assert int(pool.table.rows[child, 0]) == block and pool._ref[block] == 2
+    fresh = pool.ensure_writable(child, 0)
+    for _, leaf in leaves_of_kind(pool.cache, KV):
+        np.testing.assert_array_equal(np.asarray(leaf[fresh]), np.asarray(leaf[block]))
+    export = pool.export_blocks(parent)
+    assert len(export["arrays"]) == SMALL["num_hidden_layers"]
+    slot = other.pool.acquire()
+    other.pool.import_blocks(slot, list(range(16)), export["arrays"],
+                             leaf_names=export["leaves"])
+    landed = int(other.pool.table.rows[slot, 0])
+    for (_, got), (_, sent) in zip(leaves_of_kind(other.pool.cache, KV),
+                                   leaves_of_kind(pool.cache, KV)):
+        np.testing.assert_array_equal(np.asarray(got[landed]), np.asarray(sent[block]))
+    pool.assert_block_invariants()
+
+
+def test_speculation_and_a_mesh_are_refused_by_mechanism(served):
+    _, _, compiled = served
+    with pytest.raises(NotImplementedError, match="latent cache"):
+        _engine(compiled, speculative=True)
+    from elephas_tpu.parallel.mesh import build_mesh
+    from elephas_tpu.serving import shard_serving
+
+    with pytest.raises(NotImplementedError, match="routed expert layer"):
+        shard_serving(_engine(compiled), build_mesh(num_data=2, num_model=4))
+    # and the cache has no contiguous form to fall back to
+    module = served[0].clone(decode=True)
+    cache = module.init(jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))["cache"]
+    with pytest.raises(NotImplementedError, match="block table"):
+        module.apply({"params": served[1], "cache": cache}, jnp.zeros((1, 1), jnp.int32),
+                     mutable=["cache"])
+
+
+# -- counters ride the step's one fetch ---------------------------------------
+
+
+def test_routing_counters_ride_the_lanes_fetch(served, monkeypatch):
+    from elephas_tpu.serving import host_sync
+
+    _, _, compiled = served
+
+    class Sink:
+        rows = []
+
+        def log(self, step, **fields):
+            self.rows.append(fields)
+
+    fetches = []
+    fetch = host_sync.fetch
+    monkeypatch.setattr(host_sync, "fetch", lambda v: fetches.append(v) or fetch(v))
+    eng = _engine(compiled, sink=Sink())
+    _serve(eng, _prompts([13, 19, 9]))
+    steps = [r for r in Sink.rows if r.get("event") == "step" and "moe_assignments" in r]
+    assert steps
+    routed_layers = SMALL["num_hidden_layers"] - SMALL["first_k_dense_replace"]
+    for s in steps:
+        lanes = len(s["lane_lengths"]) or s["moe_assignments"] / (2 * routed_layers)
+        assert s["moe_assignments"] == lanes * 2 * routed_layers
+        assert s["moe_experts_held"] == 4 * routed_layers
+        assert s["moe_assignments_held"] <= s["moe_assignments"]
+        assert s["moe_experts_touched"] <= min(s["moe_experts_held"], s["moe_assignments_held"])
+        assert s["moe_load_max"] * 4 >= s["moe_load_mean"] * 4 >= s["moe_assignments_held"] / routed_layers
+    # every fetch that brought counters brought tokens with them: none of their own
+    assert all(isinstance(v, tuple) and len(v) == 2 for v in fetches
+               if not hasattr(v, "shape"))
+
+
+# -- (i) the count --------------------------------------------------------------
+
+
+def test_param_count_published_cut_and_drawn(family):
+    published = {k: PUBLISHED[k] for k in ("num_hidden_layers", "n_routed_experts", "vocab_size")}
+    cut = family.shape(dict(PUBLISHED, num_hidden_layers=5, n_routed_experts=40,
+                            vocab_size=25600))
+    assert family.param_count(cut, published) == 235_741_434_880
+    assert family.param_count(cut) == 5_163_975_680
+    assert family.kv_bytes_per_token(cut) == 5 * 576 * 2 == 5_760
+    small = family.shape(SMALL)
+    drawn = family.params(SEED, small, jnp.float32)
+    assert family.param_count(small) == sum(
+        leaf.size for leaf in jax.tree_util.tree_leaves(drawn))
+    # a decode step's routed bytes are the touched experts', and none without the counter
+    base = family.decode_cost(cut, [4096] * 16)[1]
+    assert family.decode_cost(cut, [4096] * 16, touched=18 * 4)[1] - base == \
+        18 * 4 * 3 * 5120 * 1536 * 2
+    assert family.chunk_cost(cut, 0, 2048)[0] > family.mla_chunk_attention_cost(cut, 0, 2048)[0] > 0
+
+
+# -- (j) against the published implementation ---------------------------------
+
+
+def test_reference_agrees_with_the_transformers_port(family, reference):
+    torch = pytest.importorskip("torch")
+    transformers = pytest.importorskip("transformers")
+    if not hasattr(transformers, "DeepseekV2ForCausalLM"):
+        pytest.skip("this transformers has no DeepseekV2ForCausalLM")
+    sizes = dict(SMALL, n_routed_experts=8, rope_scaling=None)  # the port's scale is plain
+    cfg = family.shape(sizes)
+    config = transformers.DeepseekV2Config(
+        vocab_size=211, hidden_size=64, intermediate_size=128, moe_intermediate_size=32,
+        num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=4,
+        n_shared_experts=2, n_routed_experts=8, routed_scaling_factor=16.0, kv_lora_rank=8,
+        q_lora_rank=16, qk_rope_head_dim=8, v_head_dim=16, qk_nope_head_dim=16, n_group=4,
+        topk_group=2, num_experts_per_tok=2, first_k_dense_replace=1, norm_topk_prob=False,
+        topk_method="group_limited_greedy", rms_norm_eps=1e-6, rope_theta=10000.0,
+        rope_scaling=None, attention_bias=False, tie_word_embeddings=False,
+        max_position_embeddings=4096, attn_implementation="eager")
+    model = transformers.DeepseekV2ForCausalLM(config).eval().to(torch.float32)
+
+    def t(a):  # (in, out) -> torch's (out, in)
+        return torch.tensor(np.asarray(a, np.float32).reshape(a.shape[0], -1).T.copy())
+
+    top = family.top_at(SEED, cfg, jnp.float32)
+    state = {"model.embed_tokens.weight": torch.tensor(np.asarray(top["tok_embed"]["embedding"])),
+             "model.norm.weight": torch.tensor(np.asarray(top["final_norm"]["scale"])),
+             "lm_head.weight": t(top["lm_head"]["kernel"])}
+    for i in range(3):
+        w, pre = family.block_at(SEED, i, cfg, jnp.float32), f"model.layers.{i}."
+        a = w["attention"]
+        state.update({
+            pre + "input_layernorm.weight": torch.tensor(np.asarray(w["attn_norm"]["scale"])),
+            pre + "post_attention_layernorm.weight": torch.tensor(np.asarray(w["ffn_norm"]["scale"])),
+            pre + "self_attn.q_a_proj.weight": t(a["q_a"]["kernel"]),
+            pre + "self_attn.q_a_layernorm.weight": torch.tensor(np.asarray(a["q_a_norm"]["scale"])),
+            pre + "self_attn.q_b_proj.weight": t(a["q_b"]["kernel"]),
+            pre + "self_attn.kv_a_proj_with_mqa.weight": t(a["kv_a"]["kernel"]),
+            pre + "self_attn.kv_a_layernorm.weight": torch.tensor(np.asarray(a["kv_a_norm"]["scale"])),
+            pre + "self_attn.kv_b_proj.weight": t(a["kv_b"]),
+            pre + "self_attn.o_proj.weight": torch.tensor(
+                np.asarray(a["out"]["kernel"]).reshape(-1, 64).T.copy())})
+        gated = {"gate": "gate_proj", "up": "up_proj", "down": "down_proj"}
+        if "experts" not in w:
+            state.update({pre + f"mlp.{theirs}.weight": t(w[ours]["kernel"])
+                          for ours, theirs in gated.items()})
+            continue
+        state[pre + "mlp.gate.weight"] = t(w["experts"]["router"]["kernel"])
+        for ours, theirs in gated.items():
+            state[pre + f"mlp.shared_experts.{theirs}.weight"] = t(w["shared"][ours]["kernel"])
+            for e in range(8):
+                state[pre + f"mlp.experts.{e}.{theirs}.weight"] = t(w["experts"][ours][e])
+    missing, unexpected = model.load_state_dict(state, strict=False)
+    assert not unexpected and not [m for m in missing if "rotary" not in m]
+    tokens = np.asarray(_prompts([17, 17], seed=11))
+    with torch.no_grad():
+        theirs = model(torch.tensor(tokens)).logits.numpy()
+    ours = _reference_logits(reference, family, tokens, np.tile(np.arange(17), (2, 1)), sizes)
+    np.testing.assert_allclose(np.asarray(ours), theirs, atol=1e-4, rtol=1e-4)
+
+
+def test_a_chunks_span_carries_its_routing_counters(served):
+    from elephas_tpu.obs import Tracer
+
+    _, _, compiled = served
+    tracer = Tracer(annotate_device=False)
+    eng = _engine(compiled, tracer=tracer)
+    prompts = _prompts([13, 19])
+    _serve(eng, prompts)
+    chunks = [e for e in tracer.events() if e.name == "step/prefill_chunk"]
+    assert sorted((e.args["start"], e.args["valid"]) for e in chunks) == sorted(
+        (start, min(8, n - start)) for n in (13, 19) for start in range(0, n, 8))
+    routed_layers = SMALL["num_hidden_layers"] - SMALL["first_k_dense_replace"]
+    for e in chunks:  # padding is routed nowhere: a ragged chunk counts its own tokens
+        assert e.args["moe_assignments"] == e.args["valid"] * 2 * routed_layers
+        assert 0 <= e.args["moe_assignments_held"] <= e.args["moe_assignments"]
+        assert e.begin_s <= e.end_s
