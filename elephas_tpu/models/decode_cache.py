@@ -77,11 +77,14 @@ class PagedDecode(NamedTuple):
     chunk), the name of the attention ``body``
     (``ops.attention.PAGED_BODIES``) and, for a chunk, whether it is
     ``aligned``: it starts at a block's first column and is whole blocks
-    wide, so its columns are written as blocks."""
+    wide, so its columns are written as blocks; and how many of its tokens
+    are ``valid``, the others right-padding whose queries nobody reads (a
+    traced scalar; None where every token counts)."""
 
     table: Any
     body: str
     aligned: bool = False
+    valid: Any = None
 
 
 def attend_paged(q, k, v, cached_key, cached_value, cache_index, active,
@@ -127,7 +130,8 @@ def attend_paged(q, k, v, cached_key, cached_value, cache_index, active,
                              f"slot's; got {rows} rows")
         out, *pools = paged_chunk_attention(
             q[0], k[0], None if latent else v[0], *pools, paged.table[0],
-            idx[0], paged.body, paged.aligned, scale=scale, kv_b=kv_b)
+            idx[0], paged.body, paged.aligned, scale=scale, kv_b=kv_b,
+            valid=paged.valid)
         cache_index.value = idx + T
         out = out[None]
     cached_key.value = pools[0]

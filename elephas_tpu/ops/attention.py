@@ -478,6 +478,11 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, table, idx,
 # over columns ``<= start + i``. The same two bodies, chosen the same way.
 
 
+def _widest_divisor(n: int, limit: int) -> int:
+    """The largest divisor of ``n`` that is no more than ``limit``."""
+    return max(d for d in range(1, min(n, limit) + 1) if n % d == 0)
+
+
 def _chunk_tiles(heads, block_size, chunk):
     """Tile sizes of the chunk kernel, from the shapes alone: K/V heads a
     grid step, queries a tile, blocks a grid step. A step covers as many
@@ -488,8 +493,8 @@ def _chunk_tiles(heads, block_size, chunk):
     a step 24 / 60, 512 32 / 51, 128 23 / 99; chunks of 512 on one K/V
     head of 20 query heads, 512 columns 56 / 319, 256 88 / 568)."""
     head_group = max(n for n in range(1, 9) if heads % n == 0)
-    tile = max(n for n in range(1, min(chunk, 512) + 1) if chunk % n == 0)
-    return head_group, tile, max(1, max(256, min(chunk, 512)) // block_size)
+    return (head_group, _widest_divisor(chunk, 512),
+            max(1, max(256, min(chunk, 512)) // block_size))
 
 
 def _paged_chunk_fits(pool_shape, dtype, head_dim, chunk) -> bool:
@@ -513,12 +518,37 @@ def _paged_chunk_fits(pool_shape, dtype, head_dim, chunk) -> bool:
     return 2 * (moved + bounds) + held + scores <= _PAGED_VMEM_BUDGET
 
 
-def _latent_chunk_tiles(q_heads, block_size):
-    """Tile sizes of the latent chunk kernel: heads a grid step (each with
-    all of the chunk's queries; a block is moved once for them) and blocks
-    a grid step (512 columns, or a block)."""
+# Queries a tile of the latent chunk kernel, at the most.
+_LATENT_QUERY_TILE = 512
+
+
+def _latent_chunk_tiles(q_heads, block_size, chunk):
+    """Tile sizes of the latent chunk kernel, from the shapes alone: heads a
+    grid step (each with all of the chunk's queries resident; a block is
+    moved once for them, and a head's keys and values are expanded once a
+    step), blocks a grid step (512 columns, or a block), and the queries a
+    tile inside a step: the widest divisor of the chunk up to
+    ``_LATENT_QUERY_TILE``. A step walks the tiles that hold a live (query,
+    column) pair and no other (``latent_chunk_tiles_visited`` counts
+    them)."""
     group = max(n for n in range(1, 5) if q_heads % n == 0)
-    return group, max(1, 512 // block_size)
+    return (group, max(1, 512 // block_size),
+            _widest_divisor(chunk, _LATENT_QUERY_TILE))
+
+
+def latent_chunk_tiles_visited(start: int, valid: int, chunk: int, tile: int,
+                               columns: int):
+    """``(visited, dense)`` of one chunk call of the latent chunk kernel,
+    from the host's integers: the (query tile, column step) pairs its grid
+    steps walk, a tile ``tile`` queries and a step ``columns`` columns, and
+    what one tile of the whole chunk a step up to column ``start + chunk -
+    1`` amounts to in the same units (the kernel before it tiled its
+    queries or knew ``valid``). The kernel's own arithmetic, step by
+    step."""
+    end = (valid - 1) // tile + 1
+    visited = sum(end - max(j * columns - start, 0) // tile
+                  for j in range((start + valid - 1) // columns + 1))
+    return visited, (chunk // tile) * ((start + chunk - 1) // columns + 1)
 
 
 def paged_chunk_body(pool_shape, dtype, head_dim, chunk, mesh=None,
@@ -559,7 +589,7 @@ def scatter_prefill_blocks(pool_leaf, row_table, start, chunk, latent=False):
 @functools.partial(jax.jit, static_argnames=("body", "aligned", "scale"))
 def paged_chunk_attention(q, k_new, v_new, k_pool, v_pool, row, start,
                           body: str, aligned: bool = False, scale=None,
-                          kv_b=None):
+                          kv_b=None, valid=None):
     """One prefill chunk's attention of one layer of ONE slot over the
     paged pool.
 
@@ -577,20 +607,28 @@ def paged_chunk_attention(q, k_new, v_new, k_pool, v_pool, row, start,
     (``paged_chunk_body`` picks it). ``aligned`` is the caller's promise
     that ``start`` is a multiple of the block size, as ``C`` then has to
     be: the chunk is written as whole blocks, where otherwise the blocks
-    it touches are read, updated and set back.
+    it touches are read, updated and set back. ``valid``: a scalar, the
+    chunk's real tokens, the rest right-padding (None: all of them). Only
+    the latent kernel reads it: there the rows at or past ``valid`` come
+    back as zeros it never scored. The K/V kernel and the XLA body ignore
+    it and score every row, whose padding rows nobody reads either way.
 
     A latent pool: ``v_new`` and ``v_pool`` None, ``kv_b`` and ``scale`` as
     ``paged_decode_attention`` has them, ``q`` (q_heads, C, nope + pe) and
     ``k_new`` (1, C, rank + pe); ``out`` is (q_heads, C, v_head). The kernel
-    runs a chunk EXPANDED: each block of latents it reads goes through
-    ``W_uk_h`` and ``W_uv_h`` for the head at hand and is scored as keys and
-    values of that head. With thousands of queries to a column, the
-    expansion (``rank`` products a column and head) is a fifth of the
-    scores it feeds, and those cost ``nope + pe + v_head`` products a
-    (query, column, head) where the absorbed form costs ``2 rank + pe``,
-    3.4 times as many at the published sizes (on the chip the absorbed
-    kernel ran at three quarters of the MXU's peak and was still three
-    fifths of a chunk's time). The XLA body runs absorbed, as a decode step.
+    runs a chunk EXPANDED: the latents of a grid step's blocks go through
+    ``W_uk_h`` and ``W_uv_h`` once a step and head, and are scored as keys
+    and values of that head by the chunk's queries in tiles
+    (``_latent_chunk_tiles``), of which a step visits those that hold a live
+    (query, column) pair: none wholly above the diagonal, none past
+    ``valid``, and no step past column ``start + valid - 1``. With
+    thousands of queries to a column, the expansion (``rank`` products a
+    column and head) is a fifth of the scores it feeds, and those cost
+    ``nope + pe + v_head`` products a (query, column, head) where the
+    absorbed form costs ``2 rank + pe``, 3.4 times as many at the published
+    sizes (on the chip the absorbed kernel ran at three quarters of the
+    MXU's peak and was still three fifths of a chunk's time). The XLA body
+    runs absorbed, as a decode step.
     """
     if body not in PAGED_BODIES:
         raise ValueError(f"unknown paged body {body!r}; expected one of "
@@ -611,7 +649,7 @@ def paged_chunk_attention(q, k_new, v_new, k_pool, v_pool, row, start,
 
             if v_pool is None:
                 out = pallas_latent_chunk_attention(q, kv_b, k_pool, row,
-                                                    start, scale)
+                                                    start, scale, valid)
             else:
                 out = pallas_paged_chunk_attention(q, k_pool, v_pool, row,
                                                    start)

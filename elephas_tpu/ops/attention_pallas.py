@@ -940,9 +940,11 @@ def pallas_paged_chunk_attention(q, k_pool, v_pool, row, start,
 # (the absorbed form of latent attention), so a block is moved once and
 # scored by every head, for key and value alike.
 
-# VMEM the latent chunk kernel may take: a chunk of 2,048 queries holds
-# 4 MB of scores a head and as much again of weights beside its operands,
-# past Mosaic's default scoped limit and well inside a v5e's 128 MiB.
+# VMEM the latent chunk kernel may take: all of a chunk's queries stay
+# resident for a group of heads (at 2,048 queries and 4 heads 12 MB of
+# queries and output, buffered twice, and 12 MB of accumulator, maximum
+# and sum) beside a tile's scores and weights, past Mosaic's default
+# scoped limit and well inside a v5e's 128 MiB.
 _LATENT_CHUNK_VMEM = 64 << 20
 
 
@@ -1147,26 +1149,32 @@ def pallas_latent_decode_attention(q, new, pool, table, idx, active,
 
 
 def _latent_chunk_kernel(
-    phys_ref, steps_ref, start_ref,  # scalar prefetch (SMEM)
+    phys_ref, steps_ref, start_ref, valid_ref,  # scalar prefetch (SMEM)
     qn_ref, qp_ref,  # (heads a step, C, nope), (.., C, pe): scaled queries
     uk_ref, uv_ref,  # (heads a step, nope, rank), (.., v_head, rank)
-    bound_ref,  # (C, 1): the last live column of each query
     *refs,  # the step's blocks, (rank + pe, block_size) each; out; scratch
-    blocks: int, rank: int,
+    blocks: int, rank: int, tq: int,
 ):
     """Grid step (head group, ``j``): fold ``blocks`` blocks of the slot's
     row, from block ``j * blocks`` on, into the running softmax of the
     chunk's queries, for each head of the group. A block is latents,
-    columns minor; for the head at hand its first ``rank`` rows go through
-    ``W_uk`` to the head's keys and through ``W_uv`` to its values, both
-    still columns minor, and the last rows are the rotary key all heads
-    share. A step wholly before the chunk's first query is scored unmasked."""
+    columns minor; for each head its first ``rank`` rows go through ``W_uk``
+    to the head's keys and through ``W_uv`` to its values, both still
+    columns minor and both made ONCE a step, and the last rows are the
+    rotary key all heads share. The queries are walked in tiles of ``tq``
+    rows, and of them only the tiles that hold a live score of this step:
+    from the first whose last query reaches the step's first column to the
+    last that holds a row below ``valid``. A tile whose first query
+    already sees the step's last column is scored unmasked; the diagonal
+    crosses the others. A tile past ``valid`` is never touched: its rows
+    come back zero."""
     del phys_ref  # read by the index maps only
     k_refs = refs[:blocks]
-    o_ref, m_ref, l_ref, acc_ref = refs[blocks:]
+    o_ref, m_ref, l_ref, acc_ref, key_ref, value_ref = refs[blocks:]
     j = pl.program_id(1)
     block_size = k_refs[0].shape[1]
     columns = blocks * block_size
+    heads, dtype = qn_ref.shape[0], qn_ref.dtype
 
     @pl.when(j == 0)
     def _init():
@@ -1178,36 +1186,46 @@ def _latent_chunk_kernel(
         return jax.lax.dot_general(a, b, (contract, ((), ())),
                                    preferred_element_type=jnp.float32)
 
-    def fold(masked: bool):
-        latents = [ref[:rank] for ref in k_refs]
-        rotary = [ref[rank:] for ref in k_refs]
-        for h in range(qn_ref.shape[0]):
-            dtype = qn_ref.dtype
-            s = jnp.concatenate([
-                product(qn_ref[h], product(uk_ref[h], c, ((1,), (0,))).astype(dtype),
-                        ((1,), (0,)))
-                + product(qp_ref[h], r, ((1,), (0,)))
-                for c, r in zip(latents, rotary)], axis=1)
-            if masked:
-                col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-                s = jnp.where(col <= bound_ref[...] - j * columns, s, _NEG_INF)
-            m = m_ref[h]
-            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m - m_new)
-            l_ref[h] = alpha * l_ref[h] + p.sum(axis=-1, keepdims=True)
-            acc = alpha * acc_ref[h]
-            for b, c in enumerate(latents):
-                values = product(uv_ref[h], c, ((1,), (0,))).astype(dtype)
-                acc = acc + product(
-                    p[:, b * block_size:(b + 1) * block_size].astype(dtype),
-                    values, ((1,), (1,)))
-            acc_ref[h] = acc
-            m_ref[h] = m_new
+    latents = jnp.concatenate([ref[:rank] for ref in k_refs], axis=1)
+    for h in range(heads):
+        key_ref[h] = product(uk_ref[h], latents, ((1,), (0,))).astype(dtype)
+        value_ref[h] = product(uv_ref[h], latents, ((1,), (0,))).astype(dtype)
 
-    whole = (j + 1) * columns - 1 <= start_ref[0]  # every column live
-    pl.when(whole)(lambda: fold(False))
-    pl.when(jnp.logical_not(whole))(lambda: fold(True))
+    start = start_ref[0]
+    first_col = j * columns  # of this step; its last is first_col + columns - 1
+
+    def fold(masked: bool):
+        def tile(t, carry):
+            rows = pl.ds(pl.multiple_of(t * tq, tq), tq)
+            rotary = jnp.concatenate([ref[rank:] for ref in k_refs], axis=1)
+            if masked:  # column c of the step is live for row r of the tile
+                shape = (tq, columns)
+                live = (jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+                        - jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+                        <= start + t * tq - first_col)
+            for h in range(heads):
+                s = (product(qn_ref[h, rows, :], key_ref[h], ((1,), (0,)))
+                     + product(qp_ref[h, rows, :], rotary, ((1,), (0,))))
+                if masked:
+                    s = jnp.where(live, s, _NEG_INF)
+                m = m_ref[h, rows, :]
+                m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                alpha = jnp.exp(m - m_new)
+                l_ref[h, rows, :] = (alpha * l_ref[h, rows, :]
+                                     + p.sum(axis=-1, keepdims=True))
+                acc_ref[h, rows, :] = alpha * acc_ref[h, rows, :] + product(
+                    p.astype(dtype), value_ref[h], ((1,), (1,)))
+                m_ref[h, rows, :] = m_new
+            return carry
+        return tile
+
+    # tile t holds queries start + t * tq .. start + (t + 1) * tq - 1
+    first = jnp.maximum(first_col - start, 0) // tq
+    whole = jnp.maximum(first_col + columns - 1 - start + tq - 1, 0) // tq
+    end = (valid_ref[0] - 1) // tq + 1
+    jax.lax.fori_loop(first, jnp.minimum(whole, end), fold(True), 0)
+    jax.lax.fori_loop(whole, end, fold(False), 0)
 
     @pl.when(j == steps_ref[0] - 1)
     def _finalize():
@@ -1215,21 +1233,26 @@ def _latent_chunk_kernel(
                       ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "tq", "interpret"))
 def pallas_latent_chunk_attention(q, kv_b, pool, row, start, scale=None,
+                                  valid=None, tq: int | None = None,
                                   interpret: bool = False):
     """One prefill chunk of one layer of ONE slot against a latent paged
     pool, which already holds the chunk's own columns: query ``i`` of every
     head attends columns ``<= start + i`` of the slot's blocks, keys and
-    values a head expanded from each block as it is read.
+    values a head expanded from each step's blocks as they are read.
 
     ``q``: (q_heads, C, nope + pe), a head's own queries, the rotary part
     rotated; ``kv_b``: (rank, q_heads, nope + v_head); ``pool``:
     (num_blocks, 1, rank + pe, block_size), read only; ``row``:
-    (blocks_per_slot,) int32; ``start``: scalar. Returns (q_heads, C,
+    (blocks_per_slot,) int32; ``start``: scalar; ``valid``: scalar, the
+    chunk's real queries (all of them where it is None): the rows at or
+    past it come back finite and mean nothing. Returns (q_heads, C,
     v_head). The grid is (head groups, steps over the row's blocks), the
-    steps a run-time count, as ``pallas_paged_chunk_attention`` has them;
-    the softmax scale is folded into the queries."""
+    steps a run-time count that ends with the last valid query's column;
+    inside a step the queries go in tiles of ``tq`` rows
+    (``ops.attention._latent_chunk_tiles``; tests pass their own), the
+    live ones only. The softmax scale is folded into the queries."""
     from elephas_tpu.ops.attention import _latent_chunk_tiles
 
     q_heads, chunk, q_width = q.shape
@@ -1238,19 +1261,22 @@ def pallas_latent_chunk_attention(q, kv_b, pool, row, start, scale=None,
     pe = width - rank
     nope = q_width - pe
     v_head = kv_b.shape[2] - nope
-    group, blocks = _latent_chunk_tiles(q_heads, block_size)
+    group, blocks, tile = _latent_chunk_tiles(q_heads, block_size, chunk)
+    tq = tile if tq is None else tq
+    columns = blocks * block_size
     dtype = pool.dtype
     scale = float(scale) if scale is not None else 1.0 / (q_width ** 0.5)
     q = (q.astype(jnp.float32) * scale).astype(dtype)
     uk = jnp.transpose(kv_b[..., :nope], (1, 2, 0)).astype(dtype)  # (h, nope, rank)
     uv = jnp.transpose(kv_b[..., nope:], (1, 2, 0)).astype(dtype)  # (h, v, rank)
     start = start.astype(jnp.int32)
-    bound = (start + jnp.arange(chunk, dtype=jnp.int32))[:, None]
+    valid = (jnp.int32(chunk) if valid is None
+             else jnp.clip(valid.astype(jnp.int32), 1, chunk))
 
     most = -(-row.shape[0] // blocks)
     phys = jnp.clip(row.astype(jnp.int32), 0, num_blocks - 1)
     phys = jnp.pad(phys, (0, most * blocks - row.shape[0]), mode="edge")
-    steps = jnp.clip((start + chunk - 1) // (blocks * block_size) + 1, 1, most)
+    steps = jnp.clip((start + valid - 1) // columns + 1, 1, most)
 
     def by_head(*shape):
         return pl.BlockSpec((group,) + shape, lambda g, j, *_: (g,) + (0,) * len(shape))
@@ -1261,19 +1287,20 @@ def pallas_latent_chunk_attention(q, kv_b, pool, row, start, scale=None,
         for b in range(blocks)
     ]
     return pl.pallas_call(
-        functools.partial(_latent_chunk_kernel, blocks=blocks, rank=rank),
+        functools.partial(_latent_chunk_kernel, blocks=blocks, rank=rank, tq=tq),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(q_heads // group, steps),
             in_specs=[by_head(chunk, nope), by_head(chunk, pe),
                       by_head(nope, rank), by_head(v_head, rank),
-                      pl.BlockSpec((chunk, 1), lambda g, j, *_: (0, 0)),
                       *live_blocks],
             out_specs=by_head(chunk, v_head),
             scratch_shapes=[
                 pltpu.VMEM((group, chunk, 1), jnp.float32),
                 pltpu.VMEM((group, chunk, 1), jnp.float32),
                 pltpu.VMEM((group, chunk, v_head), jnp.float32),
+                pltpu.VMEM((group, nope, columns), dtype),
+                pltpu.VMEM((group, v_head, columns), dtype),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((q_heads, chunk, v_head), q.dtype),
@@ -1283,5 +1310,5 @@ def pallas_latent_chunk_attention(q, kv_b, pool, row, start, scale=None,
         ),
         interpret=pltpu.InterpretParams() if interpret else False,
         name="latent_chunk_attention",
-    )(phys, steps.reshape(1), start.reshape(1), q[..., :nope], q[..., nope:],
-      uk, uv, bound, *([pool] * blocks))
+    )(phys, steps.reshape(1), start.reshape(1), valid.reshape(1),
+      q[..., :nope], q[..., nope:], uk, uv, *([pool] * blocks))

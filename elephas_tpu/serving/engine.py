@@ -420,7 +420,8 @@ class InferenceEngine:
             # of an adopted prefix end, every later one a chunk further
             paged=PagedDecode(
                 row, self.prefill_attention,
-                aligned=self.prefill_chunk % self.pool.block_size == 0),
+                aligned=self.prefill_chunk % self.pool.block_size == 0,
+                valid=valid),
             mutable=["cache", "counters"],
             **told,
         )
@@ -559,13 +560,35 @@ class InferenceEngine:
                                 q_heads=self.decode_module.num_heads,
                                 latent=self.latent)
 
+    @property
+    def prefill_query_tile(self) -> Optional[int]:
+        """Queries a tile of the latent chunk kernel, derived from the
+        shapes as the kernel derives it; ``None`` where the chunk program
+        runs another body (a K/V pool's kernel, or XLA's)."""
+        return self._latent_chunk_tiles()[2]
+
+    def _latent_chunk_tiles(self):
+        """``(heads, blocks, queries)`` of the latent chunk kernel's tiles,
+        or Nones where the chunk program's body is not that kernel."""
+        from elephas_tpu.ops.attention import _latent_chunk_tiles
+
+        if not self.latent or self.prefill_attention != "paged_pallas":
+            return None, None, None
+        return _latent_chunk_tiles(self.decode_module.num_heads,
+                                   self.pool.block_size, self.prefill_chunk)
+
     def _name_attention(self):
-        """The bodies both programs will be traced with, and the decode
-        kernel's blocks a grid step, onto every ``step`` event."""
+        """The bodies both programs will be traced with and their kernels'
+        tiles (the decode kernel's blocks a grid step, the latent chunk
+        kernel's queries a tile), onto every ``step`` event."""
         self.metrics.decode_attention = self.decode_attention
         self.metrics.prefill_attention = self.prefill_attention
         self.metrics.decode_kernel_blocks = self.decode_kernel_blocks
         self.metrics.kv_block_size = self.pool.block_size
+        _, blocks, tile = self._latent_chunk_tiles()
+        self.metrics.prefill_query_tile = tile
+        self.metrics.prefill_step_columns = blocks and blocks * self.pool.block_size
+        self.metrics.prefill_chunk = self.prefill_chunk
 
     def _next_rng(self):
         # Sampling keys derive from (base key, pad-free stream position)
@@ -1063,6 +1086,7 @@ class InferenceEngine:
             "decode_attention": self.decode_attention,
             "decode_kernel_blocks": self.decode_kernel_blocks,
             "prefill_attention": self.prefill_attention,
+            "prefill_query_tile": self.prefill_query_tile,
             "pool_admitted_total": self.pool.admitted_total,
             "pool_active": self.pool.active_count,
             "pool_free": self.pool.free_count,

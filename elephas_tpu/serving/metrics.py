@@ -24,7 +24,9 @@ Two record streams share the sink, tagged by ``event``:
   decode program and the prefill program were traced with:
   ``paged_pallas`` or ``paged_xla``), ``decode_kernel_blocks`` (the blocks
   of a lane that a grid step of the decode kernel folds; ``None`` under
-  ``paged_xla``), and ``dispatch_to_fetch_s`` — the
+  ``paged_xla``), ``prefill_query_tile`` (the queries a tile of the latent
+  chunk kernel; ``None`` where the chunk program's body is another), and
+  ``dispatch_to_fetch_s`` — the
   device-overlap gauge: wall seconds between a decode step's dispatch
   and the harvest of its tokens. On the pipelined path all host
   bookkeeping for the previous step happens inside this window, so the
@@ -63,6 +65,11 @@ class ServingMetrics:
         self.prefill_attention: Optional[str] = None
         self.decode_kernel_blocks: Optional[int] = None
         self.kv_block_size: Optional[int] = None
+        # the latent chunk kernel's geometry: queries a tile, columns a
+        # grid step, queries a chunk (None where the body is another)
+        self.prefill_query_tile: Optional[int] = None
+        self.prefill_step_columns: Optional[int] = None
+        self.prefill_chunk: Optional[int] = None
         self.requests_submitted = 0
         self.requests_completed = 0
         self.requests_timed_out = 0
@@ -79,6 +86,13 @@ class ServingMetrics:
         # (scored dead, not moved). Zero under ``paged_xla``.
         self.decode_live_blocks = 0
         self.decode_dead_blocks = 0
+        # (query tile, column step) pairs the latent chunk kernel's grid
+        # steps walked, chunk by chunk from the host's ``start`` and
+        # ``valid``, and what one tile of the whole chunk a step up to the
+        # chunk's last column amounts to in the same units
+        # (``ops.attention.latent_chunk_tiles_visited``).
+        self.prefill_tiles_visited = 0
+        self.prefill_tiles_dense = 0
         self.steps = 0
         self.max_concurrent = 0
         self.ttft_s: list = []
@@ -116,6 +130,8 @@ class ServingMetrics:
         self.tokens_prefilled_total = 0
         self.decode_live_blocks = 0
         self.decode_dead_blocks = 0
+        self.prefill_tiles_visited = 0
+        self.prefill_tiles_dense = 0
         self.steps = 0
         self.max_concurrent = 0
         self.ttft_s = []
@@ -226,7 +242,8 @@ class ServingMetrics:
                     step_seconds: float, *, harvest_wait_s: float = 0.0,
                     admit_s: float = 0.0, prefill_s: float = 0.0,
                     dispatch_s: float = 0.0, prefill_tokens: int = 0,
-                    prefill_chunks: int = 0, lane_lengths=(),
+                    prefill_chunks: int = 0, prefill_spans=(),
+                    lane_lengths=(),
                     kv_blocks_in_use: Optional[int] = None,
                     kv_blocks_total: Optional[int] = None,
                     state_slots_in_use: int = 0, state_slots_total: int = 0,
@@ -240,6 +257,15 @@ class ServingMetrics:
             self.decode_live_blocks += sum(live)
             self.decode_dead_blocks += sum(
                 -n % self.decode_kernel_blocks for n in live)
+        if self.prefill_query_tile:
+            from elephas_tpu.ops.attention import latent_chunk_tiles_visited
+
+            for start, valid in prefill_spans:  # each chunk's, as launched
+                visited, dense = latent_chunk_tiles_visited(
+                    start, valid, self.prefill_chunk, self.prefill_query_tile,
+                    self.prefill_step_columns)
+                self.prefill_tiles_visited += visited
+                self.prefill_tiles_dense += dense
         self.max_concurrent = max(self.max_concurrent, active)
         overlap, self._last_overlap = self._last_overlap, None
         if self.sink is not None and self.steps % self.step_log_every == 0:
@@ -269,6 +295,7 @@ class ServingMetrics:
                 decode_attention=self.decode_attention,
                 prefill_attention=self.prefill_attention,
                 decode_kernel_blocks=self.decode_kernel_blocks,
+                prefill_query_tile=self.prefill_query_tile,
                 # what the model's layers counted in the decode step that
                 # this step harvested (a routed layer's assignments and
                 # loads): nothing for a model that counts nothing
@@ -294,6 +321,8 @@ class ServingMetrics:
             "tokens_prefilled_total": self.tokens_prefilled_total,
             "decode_live_blocks": self.decode_live_blocks,
             "decode_dead_blocks": self.decode_dead_blocks,
+            "prefill_tiles_visited": self.prefill_tiles_visited,
+            "prefill_tiles_dense": self.prefill_tiles_dense,
             "steps": self.steps,
             "max_concurrent": self.max_concurrent,
             "ttft_s_avg": mean(self.ttft_s),

@@ -326,6 +326,7 @@ class ContinuousBatchingScheduler:
         self._harvest_wait_s = self._admit_s = 0.0
         self._prefill_s = self._dispatch_s = 0.0
         self._prefill_tokens = self._prefill_chunks = 0
+        self._prefill_spans = []
         self._lane_lengths: List[int] = []
         self._step_counters: Dict[str, float] = {}
 
@@ -563,6 +564,7 @@ class ContinuousBatchingScheduler:
         pf.next_col = start + valid
         self._prefill_tokens += valid
         self._prefill_chunks += 1
+        self._prefill_spans.append((start, valid))
         if self.tracer.enabled:
             if counted is not None:
                 # the span carries the chunk's counters, and they ride the
@@ -1078,6 +1080,7 @@ class ContinuousBatchingScheduler:
         self._harvest_wait_s = self._admit_s = 0.0
         self._prefill_s = self._dispatch_s = 0.0
         self._prefill_tokens = self._prefill_chunks = 0
+        self._prefill_spans = []
         self._lane_lengths = []
         self._step_counters = {}
         emitted = (
@@ -1097,6 +1100,7 @@ class ContinuousBatchingScheduler:
                 dispatch_s=self._dispatch_s,
                 prefill_tokens=self._prefill_tokens,
                 prefill_chunks=self._prefill_chunks,
+                prefill_spans=self._prefill_spans,
                 lane_lengths=self._lane_lengths,
                 kv_blocks_in_use=self.pool.blocks_in_use,
                 kv_blocks_total=self.pool.num_blocks,

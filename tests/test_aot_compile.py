@@ -345,6 +345,33 @@ def test_latent_kernels_compile_for_v5e(one_chip):
     assert chunk.memory_analysis().temp_size_in_bytes < 256 << 20
 
 
+@pytest.mark.parametrize("tq", [None, 128, 1024])
+def test_latent_chunk_kernel_tiles_compile_for_v5e(one_chip, tq):
+    """The latent chunk kernel told ``valid``, a run-time scalar, at the
+    cell's sizes: the query tile the shapes give (512 of the chunk's 2,048
+    rows), and the narrowest and a wide one that the kernel's chain on the
+    chip was run at. The loops over a step's live tiles have run-time bounds
+    and slice the queries, the running maximum, sum and accumulator by tile."""
+    from elephas_tpu.ops import attention
+    from elephas_tpu.ops.attention_pallas import pallas_latent_chunk_attention
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    c = _LATENT
+    pool = attention.latent_leaf_shape(c["slots"] * c["blocks_per_slot"],
+                                       c["block_size"], c["rank"] + c["pe"])
+    assert attention._latent_chunk_tiles(c["q_heads"], c["block_size"], c["chunk"]) == (
+        4, 4, 512)
+    chunk = jax.jit(pallas_latent_chunk_attention, static_argnames=("scale", "tq")).lower(
+        arg((c["q_heads"], c["chunk"], c["nope"] + c["pe"]), jnp.bfloat16),
+        arg((c["rank"], c["q_heads"], c["nope"] + c["v_head"]), jnp.bfloat16),
+        arg(pool, jnp.bfloat16), arg((c["blocks_per_slot"],), jnp.int32),
+        arg((), jnp.int32), scale=0.11472, valid=arg((), jnp.int32), tq=tq).compile()
+    assert "tpu_custom_call" in chunk.as_text()
+    assert chunk.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
 @pytest.mark.parametrize("rows", [2048 * 6, 16 * 6])
 def test_grouped_matmul_kernel_compiles_for_v5e(one_chip, rows):
     """The routed layer's grouped product at a chunk's and a decode step's

@@ -53,6 +53,7 @@ def _bench_module(kind, name):
         spec = importlib.util.spec_from_file_location(
             f"bench_{kind}_{name}", os.path.join(BENCH, kind, name + ".py"))
         mod = importlib.util.module_from_spec(spec)
+        sys.modules[spec.name] = mod  # a dataclass looks its module up there
         spec.loader.exec_module(mod)
         return mod
     finally:
@@ -411,23 +412,117 @@ def test_latent_decode_attention_matches_dense(body):
         np.testing.assert_allclose(np.asarray(out[s]), np.asarray(want), atol=2e-5, rtol=2e-5)
 
 
+# A chunk of one query tile and one column step (the three cases PR 34
+# wrote), then one of four tiles of 128 queries over steps of 512 columns:
+# ``start`` at 0, unaligned, a whole number of steps and past a step's
+# middle, ``valid`` around a tile's edge and the whole chunk.
+_CHUNK_CASES = [(128, s, None) for s in (0, 128, 37)] + [
+    (512, s, v) for s in (0, 37, 512, 700) for v in (1, 127, 128, 129, 512)]
+
+
 @pytest.mark.parametrize("body", attention.PAGED_BODIES)
-@pytest.mark.parametrize("start", [0, 128, 37])
-def test_latent_chunk_attention_matches_dense(body, start):
-    c = _latent_case()
-    chunk = 128
+@pytest.mark.parametrize("chunk,start,valid", _CHUNK_CASES)
+def test_latent_chunk_attention_matches_dense(body, chunk, start, valid):
+    """Rows below ``valid`` are dense attention's; the kernel's rows at or
+    past it are finite, and zeros in the tiles it never visited."""
+    c = _latent_case(bps=3 if chunk == 128 else 10, slots=4 if chunk == 128 else 2)
     q = jax.random.normal(c["keys"][2], (c["heads"], chunk, c["nope"] + c["pe"]))
     new = jax.random.normal(c["keys"][3], (1, chunk, c["width"]))
+    told = None if valid is None else jnp.int32(valid)
     out, pool, _ = attention.paged_chunk_attention(
         q, new, None, c["pool"], None, c["table"][1], jnp.int32(start), "paged_xla",
-        start % c["bs"] == 0, scale=0.11, kv_b=c["kv_b"])
+        start % c["bs"] == 0, scale=0.11, kv_b=c["kv_b"], valid=told)
     if body == "paged_pallas":
-        out = pallas_latent_chunk_attention(q, c["kv_b"], pool, c["table"][1],
-                                            jnp.int32(start), 0.11, interpret=True)
+        # the interpreter's callbacks and the host's own ops share one
+        # client: nothing else is dispatched while the kernel runs
+        out = jax.block_until_ready(pallas_latent_chunk_attention(
+            q, c["kv_b"], pool, c["table"][1], jnp.int32(start), 0.11, valid=told,
+            tq=128, interpret=True))
     row = _row(pool, c["table"][1])
     np.testing.assert_allclose(np.asarray(row[start:start + chunk]), np.asarray(new[0]), atol=1e-6)
     want = _dense_latent_attention(c, q, row, start + jnp.arange(chunk), 0.11)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5, rtol=2e-5)
+    live = chunk if valid is None else valid
+    np.testing.assert_allclose(np.asarray(out[:, :live]), np.asarray(want[:, :live]),
+                               atol=2e-5, rtol=2e-5)
+    assert np.isfinite(np.asarray(out)).all()
+    if body == "paged_pallas":
+        assert not np.asarray(out[:, -(-live // 128) * 128:]).any()
+
+
+def test_the_tiles_a_job_visits_follow_from_its_prompt_lengths():
+    """``latent_chunk_tiles_visited`` over the long-document cell's job: the
+    traffic file's 16 prompt lengths, chunks of 2,048, steps of 512 columns.
+    Against the one 2,048-row tile a step up to the chunk's last column,
+    tiles of 512 rows visit 0.789 of the (query, column) pairs and tiles of
+    256 rows 0.771 (ISSUE 35's closed form), and each tile size's count is
+    what a plain walk over every (tile, step) pair finds live."""
+    import json
+
+    with open(os.path.join(BENCH, "traffic", "doc-batch-16k.json")) as f:
+        traffic = json.load(f)
+    prompts = [p for p, _ in _bench_module("lib", "traffic").length_pairs(traffic)]
+    assert prompts == [4480 + 768 * i for i in range(16)]
+    chunk, columns = 2048, 512
+    assert attention._latent_chunk_tiles(128, 128, chunk) == (
+        4, columns // 128, attention._LATENT_QUERY_TILE)
+    spans = [(start, min(chunk, p - start)) for p in prompts for start in range(0, p, chunk)]
+    assert len(spans) == 88
+    live_pairs = sum(v * s + v * (v + 1) // 2 for s, v in spans)
+    share = {}
+    for tile in (128, 256, 512, 1024, 2048):
+        visited = dense = 0
+        for start, valid in spans:
+            got, whole = attention.latent_chunk_tiles_visited(start, valid, chunk, tile, columns)
+            walked = sum(
+                1 for j in range(-(-(start + chunk) // columns)) for t in range(chunk // tile)
+                if t * tile < valid and j * columns <= start + min((t + 1) * tile, valid) - 1)
+            assert got == walked <= whole
+            visited, dense = visited + got, dense + whole
+        assert dense * tile * columns == sum(
+            chunk * columns * ((s + chunk - 1) // columns + 1) for s, _ in spans)
+        share[tile] = visited / dense
+        assert visited * tile * columns >= live_pairs
+    assert round(share[512], 3) == 0.789 and round(share[256], 3) == 0.771
+    assert sorted(share.values()) == [share[t] for t in (128, 256, 512, 1024, 2048)]
+    dense_pairs = sum(chunk * columns * ((s + chunk - 1) // columns + 1) for s, _ in spans)
+    assert round(dense_pairs / live_pairs, 3) == 1.384
+    assert round(share[512] * dense_pairs / live_pairs, 3) == 1.092
+
+
+def test_the_chunk_kernels_tiles_are_counted_on_stats_and_step_events(served):
+    """``stats()`` and every ``step`` event carry the latent chunk kernel's
+    query tile (None on the CPU, where the chunk program runs XLA's body, and
+    nothing is counted); given the kernel's geometry, the metrics count each
+    launched chunk's tiles from its ``start`` and ``valid``."""
+    from elephas_tpu.serving.metrics import ServingMetrics
+
+    events = []
+
+    class Sink:
+        def log(self, step, **fields):
+            events.append(fields)
+
+    eng = _engine(served[2], sink=Sink())
+    _serve(eng, _prompts([19, 8]), new=3)
+    stats = eng.stats()
+    assert stats["prefill_query_tile"] is None and eng.prefill_query_tile is None
+    assert stats["prefill_tiles_visited"] == stats["prefill_tiles_dense"] == 0
+    steps = [e for e in events if e.get("event") == "step"]
+    assert steps and all(e["prefill_query_tile"] is None for e in steps)
+
+    metrics = ServingMetrics(sink=Sink())
+    metrics.prefill_query_tile, metrics.prefill_step_columns = 512, 512
+    metrics.prefill_chunk = 2048
+    # a document of 4,480 tokens: two whole chunks and a tail of 384
+    metrics.record_step(0, 1, 0, 0.01, prefill_chunks=2, prefill_spans=[(0, 2048), (2048, 2048)])
+    metrics.record_step(0, 1, 0, 0.01, prefill_chunks=1, prefill_spans=[(4096, 384)])
+    assert events[-1]["prefill_query_tile"] == 512
+    # steps x tiles: 4+3+2+1, then 4 steps of 4 tiles and 4+3+2+1, then 9 steps of 1
+    assert metrics.summary()["prefill_tiles_visited"] == 10 + 16 + 10 + 9
+    assert metrics.summary()["prefill_tiles_dense"] == 4 * (4 + 8 + 12)
+    metrics.reset()
+    assert metrics.summary()["prefill_tiles_visited"] == 0
+    assert metrics.prefill_query_tile == 512  # kept, as the bodies' names
 
 
 def test_the_pool_holds_the_latent_and_nothing_a_head(served):
