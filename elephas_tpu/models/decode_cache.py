@@ -1,7 +1,7 @@
 """What kind of thing a leaf of a decode ``cache`` collection is.
 
 A decode module's ``cache`` tree is the whole of what the serving pool
-holds for it, and every program that touches the pool has to treat three
+holds for it, and every program that touches the pool has to treat four
 kinds of leaf differently. This is the one place that tells them apart,
 by the leaf's name:
 
@@ -10,7 +10,19 @@ by the leaf's name:
   and, in their first part, value at once, with no value leaf beside it.
   In the paged pool they are physical blocks shared through the block table
   (``ops.attention.pool_leaf_shape``), in a gathered row ``(batch, heads,
-  len, head_dim)``.
+  len, head_dim)``. ``cached_index_key`` is a K/V leaf too: the one key a
+  token that a learned sparse attention's indexer scores (``ops.
+  sparse_index``), a second paged leaf beside the latent of the same layer,
+  written through the same table at the same column and laid out as the
+  latent is (``COLUMN_MINOR``).
+- ``WINDOW``: ``cached_window_latent``, the latent of a layer whose queries
+  see the last ``window`` columns only. What a slot keeps of it is bounded
+  by the window, not by the sequence: a RING of blocks a slot,
+  ``(slots, ring_blocks, 1, width, block_size)``, column ``c`` in block
+  ``c // block_size % ring_blocks``, with room for the window's columns
+  behind a prefill chunk's first and the chunk itself (``ring_blocks``). It
+  is a slot's own as a state row is: never shared through the block table,
+  cleared at release, and a resident prefix says nothing about it.
 - ``INDEX``: ``cache_index`` / ``pos_index``, the column a row writes
   next: a scalar in a fresh module cache, a ``(slots,)`` vector in a pool.
 - ``STATE``: anything else: a recurrence's or a convolution's state.
@@ -26,10 +38,15 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-KV, INDEX, STATE = "kv", "index", "state"
+KV, INDEX, STATE, WINDOW = "kv", "index", "state", "window"
 
 _KINDS = {"cached_key": KV, "cached_value": KV, "cached_latent": KV,
+          "cached_index_key": KV, "cached_window_latent": WINDOW,
           "cache_index": INDEX, "pos_index": INDEX}
+
+# leaves of one head whose blocks keep their columns minor
+# (``ops.attention.latent_leaf_shape``)
+COLUMN_MINOR = ("cached_latent", "cached_index_key", "cached_window_latent")
 
 
 def leaf_name(path) -> str:
@@ -49,6 +66,13 @@ def leaves_of_kind(cache, kind: str):
 
 def has_state(cache) -> bool:
     return bool(leaves_of_kind(cache, STATE))
+
+
+def ring_blocks(window: int, chunk: int, block_size: int) -> int:
+    """Blocks a slot's ring of a window layer holds: the ``window - 1``
+    columns behind a prefill chunk's first and the chunk's own, rounded up
+    to blocks, and one more where a chunk need not start on a block."""
+    return -(-(window - 1 + chunk) // block_size) + (chunk % block_size != 0)
 
 
 def has_latent(cache) -> bool:
@@ -87,8 +111,31 @@ class PagedDecode(NamedTuple):
     valid: Any = None
 
 
+class Indexer(NamedTuple):
+    """What a learned sparse attention hands ``attend_paged`` beside its
+    queries: the index queries ``q`` (rows, index heads, T, width) and their
+    weights ``w`` (rows, T, index heads), the new tokens' index keys ``k``
+    (rows, 1, T, width), the ``cached`` variable that holds the pool's index
+    keys, and ``top_k``, how many columns a query attends."""
+
+    q: Any
+    w: Any
+    k: Any
+    cached: Any
+    top_k: int
+
+
+def _ring_rows(ring_blocks: int, block_size: int, window: int, first, count: int):
+    """Of a ring, the ``count`` blocks from the one that holds column
+    ``first - (window - 1)`` on, in column order, and that block's first
+    column: the ring read as the short sequence a window layer attends."""
+    j0 = jnp.maximum(first - (window - 1), 0) // block_size
+    return (j0[..., None] + jnp.arange(count)) % ring_blocks, j0 * block_size
+
+
 def attend_paged(q, k, v, cached_key, cached_value, cache_index, active,
-                 paged: PagedDecode, scale=None, kv_b=None):
+                 paged: PagedDecode, scale=None, kv_b=None, window=None,
+                 indexer: Indexer = None):
     """A decode module's attention where its K/V variables ARE the pool's
     physical blocks: the new columns go into their blocks and the queries
     attend through the block table, no contiguous row is ever built.
@@ -105,7 +152,20 @@ def attend_paged(q, k, v, cached_key, cached_value, cache_index, active,
     (``rank`` values, then the shared rotary key), ``q`` a head's own query
     (``nope`` values, then its rotary part), and the result (rows, q_heads,
     T, v_head). ``scale`` is the softmax scale where it is not ``head_dim
-    ** -0.5``."""
+    ** -0.5``.
+
+    With a ``window`` (a latent attention's) ``cached_key`` is the layer's
+    ring (``WINDOW`` above), every lane's in a decode step and the slot's own
+    in a chunk: the block table is not read; the ring's blocks from the one
+    that holds the oldest column a query still sees are attended in column
+    order, under the window's bound.
+
+    With an ``indexer`` (a latent attention's) the new index keys go into
+    their leaf through the table, the queries' index scores over the slot's
+    live index keys choose ``top_k`` columns a query (``ops.sparse_index``),
+    and attention runs over those alone. Returns ``(out, (live,
+    selected))`` then: the live columns of the apply's real queries, summed,
+    and how many of them were attended."""
     from elephas_tpu.ops.attention import (
         paged_chunk_attention,
         paged_decode_attention,
@@ -115,26 +175,81 @@ def attend_paged(q, k, v, cached_key, cached_value, cache_index, active,
     idx = cache_index.value
     latent = cached_value is None
     pools = (cached_key.value, None if latent else cached_value.value)
+    table, at, told, counts = paged.table, idx, {}, None
+    if window is not None:
+        ring = pools[0]  # (rows, ring blocks, 1, width, block_size)
+        blocks, block_size = ring.shape[1], ring.shape[-1]
+        pools = (ring.reshape((-1,) + ring.shape[2:]), None)
+        # a lane's window touches a few blocks, a chunk's every one
+        count = min(blocks, (window - 2) // block_size + 2) if active is not None \
+            else blocks
+        table, first = _ring_rows(blocks, block_size, window, idx, count)
+        table = table + jnp.arange(rows)[:, None] * blocks
+        at, told = idx - first, {"window": window}
     if active is not None:
         if T != 1:
             raise ValueError("a paged decode step attends one token per lane; "
                              "speculative windows gather their rows")
+        if indexer is not None:
+            told["selected"], counts = _select(
+                indexer, table, idx, paged.body, active=active)
         out, *pools = paged_decode_attention(
             q[:, :, 0], k[:, :, 0], None if latent else v[:, :, 0], *pools,
-            paged.table, idx, active, paged.body, scale=scale, kv_b=kv_b)
+            table, at, active, paged.body, scale=scale, kv_b=kv_b, **told)
         cache_index.value = jnp.where(active, idx + 1, idx)
         out = out[:, :, None, :]
     else:
         if rows != 1:
             raise ValueError("a prefill chunk over the paged pool is one "
                              f"slot's; got {rows} rows")
+        if indexer is not None:
+            told["selected"], counts = _select(
+                indexer, table[0], idx[0], paged.body, valid=paged.valid,
+                aligned=paged.aligned)
         out, *pools = paged_chunk_attention(
-            q[0], k[0], None if latent else v[0], *pools, paged.table[0],
-            idx[0], paged.body, paged.aligned, scale=scale, kv_b=kv_b,
-            valid=paged.valid)
+            q[0], k[0], None if latent else v[0], *pools, table[0],
+            at[0], paged.body, paged.aligned, scale=scale, kv_b=kv_b,
+            valid=paged.valid, **told)
         cache_index.value = idx + T
         out = out[None]
-    cached_key.value = pools[0]
+    cached_key.value = pools[0] if window is None else pools[0].reshape(ring.shape)
     if not latent:
         cached_value.value = pools[1]
-    return out
+    return out if indexer is None else (out, counts)
+
+
+def _select(indexer: Indexer, table, idx, body: str, active=None, valid=None,
+            aligned: bool = False):
+    """Write the new index keys, score the live ones and select: the mask
+    (queries, S) of what each query attends, and ``(live, selected)``. Under
+    ``active`` a decode step (``table`` every lane's row, ``idx`` the column
+    each lane writes), else one slot's chunk from column ``idx`` on."""
+    from elephas_tpu.ops.attention import (
+        _write_windows,
+        scatter_prefill_blocks,
+        scatter_prefill_columns,
+    )
+    from elephas_tpu.ops.sparse_index import index_scores, select_columns
+
+    pool = indexer.cached.value
+    if active is not None:
+        with jax.named_scope("kv_write"):
+            pool = _write_windows(pool, table, idx, indexer.k[:, :, :1], active,
+                                  True)
+        scores = index_scores(indexer.q[:, :, 0], indexer.w[:, 0], pool, table,
+                              idx, body, active=active)
+        last, real = idx, active
+    else:
+        write = scatter_prefill_blocks if aligned else scatter_prefill_columns
+        with jax.named_scope("kv_write"):
+            pool = write(pool, table, idx, indexer.k[0], latent=True)
+        scores = index_scores(indexer.q[0], indexer.w[0], pool, table, idx, body,
+                              valid=valid)
+        steps = jnp.arange(scores.shape[0])
+        last = idx + steps
+        real = steps < (scores.shape[0] if valid is None else valid)
+    indexer.cached.value = pool
+    mask = select_columns(scores, last, indexer.top_k, body)
+    live = jnp.where(real, last + 1, 0).sum()
+    selected = jnp.where(real[:, None], mask, 0).astype(jnp.int32).sum()
+    return mask, (live.astype(jnp.float32), selected.astype(jnp.float32))

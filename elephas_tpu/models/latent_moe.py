@@ -1,5 +1,9 @@
 """Decoder LM with latent attention and routed experts: the block of the
-DeepSeek-V2 family.
+DeepSeek-V2 family, and of its descendants that mix FULL layers (with a
+learned sparse attention's indexer) and WINDOW layers (their own latent
+geometry, a bounded cache), gate their heads, rescale their latents and
+route by a sigmoid with a selection bias. DeepSeek-V2 is the case of no
+window layer, no indexer, no gate, no rescale and softmax scoring.
 
 Every layer is ``x + attn(rms(x))`` then ``x + ffn(rms(x))`` with RMSNorm,
 no bias anywhere, an untied head. The first ``first_dense`` layers have a
@@ -47,6 +51,44 @@ position ``valid - 1`` alone and routes no padding token. Each routed layer
 sows its counters (assignments, those on held experts, experts touched and
 held, the busiest and the mean expert's load) into the ``counters``
 collection.
+
+The descendants' parts, each off by default. Layer ``i`` is **full** or
+**window** by ``layer_types[i]`` (``"full_attention"`` /
+``"sliding_attention"``).
+
+A full layer (the sizes above; ``rope`` its rotary's parameters)::
+
+    c_q            = rms(W_qa y) * (d / q_lora_rank)^0.5      latent_rescale
+    [c_kv, k_pe]   = split(W_kva y);  c_kv = rms(c_kv) * (d / kv_lora_rank)^0.5
+    indexer:  qI_j = W_qI c_q      (index heads x index width)
+              kI   = layernorm(W_kI y)      ONE a token, scale only
+              rope on the first qk_rope values of qI_j and kI
+              w    = W_w y * heads^-0.5 * width^-0.5
+              I[t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s])     s <= t
+              S_t  = the index_topk columns s <= t with the largest I[t, s];
+                     every s <= t while t < index_topk; the lower column
+                     wins a tie
+    score_h[t, s]  = (q_nope_h . k_nope_h + q_pe_h . k_pe) * scale   s in S_t
+    o_h            = softmax_s(score_h) v_h
+    g              = sigmoid(W_g y)         one a head        head_gate
+    out            = W_o concat_h(g_h * o_h)
+
+A window layer: the same latent attention at ``window_geometry``'s sizes (its
+own heads, ranks, head widths and rotary), no indexer, the mask ``t -
+sliding_window < s <= t``, its own gate and rescale.
+
+The router of such a model (``scoring="sigmoid"``): ``s = sigmoid(W_r y)``
+in float32 over all experts; the ``top_k`` largest of ``s + b`` are taken
+(``b`` a learned bias, ``selection_bias``, used to select only); weights
+``s_e / sum over the chosen of s`` (``norm_topk_prob``) times
+``routed_scaling_factor``.
+
+On the serving path a full layer caches, a token, its latent and its index
+key after norm and rotation (``cached_index_key``, a second paged leaf), a
+window layer its latent in a ring a slot bounded by the window
+(``cached_window_latent``; ``models.decode_cache``). A full layer sows
+``sparse_columns_live`` (the sum over its real queries of ``t + 1``) and
+``sparse_columns_selected`` (the sum of ``|S_t|``) into ``counters``.
 """
 
 from __future__ import annotations
@@ -60,7 +102,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from elephas_tpu.models import register_model
-from elephas_tpu.models.decode_cache import attend_paged
+from elephas_tpu.models.decode_cache import Indexer, attend_paged
 from elephas_tpu.models.jamba import RMSNorm, _rows, gated_feed_forward
 
 Rope = Optional[Tuple[float, ...]]
@@ -119,6 +161,10 @@ class LatentAttention(nn.Module):
     qk_rope_head_dim: int
     v_head_dim: int
     rope: Rope = None
+    window: Optional[int] = None  # a query sees this many columns, its own too
+    indexer: Optional[Tuple[int, int, int]] = None  # (heads, width, top_k)
+    head_gate: bool = False
+    latent_rescale: bool = False
     eps: float = 1e-6
     dtype: Any = jnp.float32
     decode: bool = False
@@ -129,22 +175,32 @@ class LatentAttention(nn.Module):
         heads, rank = self.num_heads, self.kv_lora_rank
         nope, pe = self.qk_nope_head_dim, self.qk_rope_head_dim
         scale = softmax_scale(nope + pe, self.rope)
+        if self.window is not None and self.indexer is not None:
+            raise ValueError("a window layer has no indexer")
 
         c_q = RMSNorm(self.eps, name="q_a_norm")(
             nn.Dense(self.q_lora_rank, use_bias=False, dtype=self.dtype, name="q_a")(x))
+        if self.latent_rescale:
+            c_q = c_q * (d_model / self.q_lora_rank) ** 0.5
         q = nn.DenseGeneral((heads, nope + pe), use_bias=False, dtype=self.dtype,
                             name="q_b")(c_q.astype(self.dtype))  # (b, T, h, f)
         kv = nn.Dense(rank + pe, use_bias=False, dtype=self.dtype, name="kv_a")(x)
         c_kv = RMSNorm(self.eps, name="kv_a_norm")(kv[..., :rank])
+        if self.latent_rescale:
+            c_kv = c_kv * (d_model / rank) ** 0.5
         # W_uk and W_uv side by side, by head: (rank, heads, nope + v)
         kv_b = self.param("kv_b", nn.initializers.lecun_normal(),
                           (rank, heads, nope + self.v_head_dim))
         kv_b = kv_b.astype(self.dtype)
 
-        carried = self.decode and self.has_variable("cache", "cached_latent")
+        leaf = "cached_latent" if self.window is None else "cached_window_latent"
+        carried = self.decode and self.has_variable("cache", leaf)
         if self.decode:
-            cached_latent = self.variable("cache", "cached_latent", jnp.zeros,
-                                          (b, 1, T, rank + pe), self.dtype)
+            # a window layer's leaf says by its length what a slot keeps of
+            # it: the window's columns behind the ``T`` at hand
+            kept = T if self.window is None else self.window - 1 + T
+            cached_latent = self.variable("cache", leaf, jnp.zeros,
+                                          (b, 1, kept, rank + pe), self.dtype)
             cache_index = self.variable("cache", "cache_index",
                                         lambda: jnp.array(0, jnp.int32))
         if carried:
@@ -154,6 +210,31 @@ class LatentAttention(nn.Module):
         positions = start[:, None] + jnp.arange(T)[None, :]
         q_pe = rotate(q[..., nope:], positions, self.rope)
         k_pe = rotate(kv[..., rank:], positions, self.rope)
+
+        index = None
+        if self.indexer is not None:
+            i_heads, i_width, top_k = self.indexer
+            with jax.named_scope("indexer"):
+                qi = nn.DenseGeneral((i_heads, i_width), use_bias=False,
+                                     dtype=self.dtype, name="index_q")(
+                                         c_q.astype(self.dtype))
+                ki = nn.LayerNorm(epsilon=self.eps, use_bias=False, name="index_k_norm")(
+                    nn.Dense(i_width, use_bias=False, dtype=self.dtype,
+                             name="index_k")(x).astype(jnp.float32))
+                wi = nn.Dense(i_heads, use_bias=False, dtype=jnp.float32,
+                              name="index_w")(x.astype(jnp.float32)) * (
+                                  i_heads ** -0.5 * i_width ** -0.5)
+                qi = jnp.concatenate([rotate(qi[..., :pe], positions, self.rope),
+                                      qi[..., pe:].astype(jnp.float32)], -1)
+                ki = jnp.concatenate([rotate(ki[..., :pe], positions, self.rope),
+                                      ki[..., pe:]], -1)
+                qi, ki = qi.astype(self.dtype), ki.astype(self.dtype)
+            if self.decode:
+                cached_index_key = self.variable(
+                    "cache", "cached_index_key", jnp.zeros, (b, 1, T, i_width),
+                    self.dtype)
+                index = Indexer(jnp.moveaxis(qi, 2, 1), wi, ki[:, None],
+                                cached_index_key, top_k)
 
         if carried:
             if paged is None:
@@ -166,7 +247,12 @@ class LatentAttention(nn.Module):
             latent = jnp.concatenate([c_kv, k_pe], -1).astype(self.dtype)[:, None]
             out = attend_paged(jnp.moveaxis(q_all, 2, 1), latent, None,
                                cached_latent, None, cache_index, active, paged,
-                               scale=scale, kv_b=kv_b)  # (b, h, T, v)
+                               scale=scale, kv_b=kv_b, window=self.window,
+                               indexer=index)  # (b, h, T, v)
+            if index is not None:
+                out, (live, selected) = out
+                self.sow("counters", "sparse_columns_live", live)
+                self.sow("counters", "sparse_columns_selected", selected)
             out = jnp.moveaxis(out, 1, 2)
         else:
             # the published form: keys and values a head, expanded
@@ -176,9 +262,25 @@ class LatentAttention(nn.Module):
                                  expanded[..., :nope])
                       + jnp.einsum("bqhf,bkf->bhqk", q_pe, k_pe)) * scale
             mask = jnp.tril(jnp.ones((T, T), bool))
+            if self.window is not None:
+                mask &= ~jnp.tril(jnp.ones((T, T), bool), -self.window)
+            if self.indexer is not None:
+                from elephas_tpu.ops.sparse_index import select_columns
+
+                index_scores = (jax.nn.relu(jnp.einsum(
+                    "bqhd,bkd->bqhk", qi, ki, preferred_element_type=jnp.float32))
+                    * wi[..., None]).sum(2)  # (b, T, T)
+                chosen = select_columns(
+                    index_scores.reshape(b * T, T), jnp.tile(jnp.arange(T), b),
+                    self.indexer[2], "paged_xla").reshape(b, 1, T, T) > 0
+                scores = jnp.where(chosen, scores, jnp.finfo(jnp.float32).min)
             scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
             out = jnp.einsum("bhqk,bkhf->bqhf", nn.softmax(scores, -1),
                              expanded[..., nope:])
+        if self.head_gate:  # one gate a head, from the layer's normed input
+            gate = nn.Dense(heads, use_bias=False, dtype=self.dtype, name="gate")(x)
+            out = out.astype(jnp.float32) * jax.nn.sigmoid(
+                gate.astype(jnp.float32))[..., None]
         return nn.DenseGeneral(d_model, axis=(-2, -1), use_bias=False,
                                dtype=self.dtype, name="out")(out.astype(self.dtype))
 
@@ -214,6 +316,9 @@ class RoutedExperts(nn.Module):
     n_group: int
     topk_group: int
     routed_scaling_factor: float
+    scoring: str = "softmax"  # or "sigmoid"
+    norm_topk_prob: bool = False
+    selection_bias: bool = False
     dtype: Any = jnp.float32
 
     @nn.compact
@@ -233,8 +338,20 @@ class RoutedExperts(nn.Module):
         down = self.param("down", init, (count, f, d)).astype(self.dtype)
         logits = nn.Dense(self.n_routed_experts, use_bias=False, dtype=jnp.float32,
                           name="router")(y.astype(jnp.float32))
-        ids, p = group_limited_top_k(nn.softmax(logits, -1), self.n_group,
-                                     self.topk_group, self.top_k)
+        if self.scoring == "softmax":
+            score = nn.softmax(logits, -1)
+        elif self.scoring == "sigmoid":
+            score = jax.nn.sigmoid(logits)
+        else:
+            raise ValueError(f"unknown router scoring {self.scoring!r}")
+        by = None
+        if self.selection_bias:  # chooses the experts, weighs none
+            by = score + self.param("bias", nn.initializers.zeros,
+                                    (self.n_routed_experts,)).astype(jnp.float32)
+        ids, p = group_limited_top_k(score, self.n_group, self.topk_group,
+                                     self.top_k, by=by)
+        if self.norm_topk_prob:
+            p = p / p.sum(-1, keepdims=True)
         if live is not None:  # an id past every expert is held nowhere
             ids = jnp.where(live[:, None], ids, self.n_routed_experts)
         out, load = routed_experts(y, ids, p * self.routed_scaling_factor,
@@ -303,6 +420,18 @@ class LatentMoELM(nn.Module):
     rope: Rope = None
     rms_eps: float = 1e-6
     max_seq_len: int = 163840
+    # layer i's kind, "full_attention" or "sliding_attention" (all full: None)
+    layer_types: Optional[Tuple[str, ...]] = None
+    sliding_window: Optional[int] = None
+    # a window layer's (num_heads, q_lora_rank, kv_lora_rank, qk_nope_head_dim,
+    # qk_rope_head_dim, v_head_dim, rope)
+    window_geometry: Optional[Tuple] = None
+    indexer: Optional[Tuple[int, int, int]] = None  # full layers': (heads, width, top_k)
+    head_gate: bool = False
+    latent_rescale: bool = False
+    scoring: str = "softmax"
+    norm_topk_prob: bool = False
+    selection_bias: bool = False
     dtype: Any = jnp.float32
     attention: str = "dense"  # the one full-sequence form; the engine sets it
     decode: bool = False
@@ -322,6 +451,16 @@ class LatentMoELM(nn.Module):
             kv_lora_rank=self.kv_lora_rank, qk_nope_head_dim=self.qk_nope_head_dim,
             qk_rope_head_dim=self.qk_rope_head_dim, v_head_dim=self.v_head_dim,
             rope=self.rope)
+        if self.indexer is not None or self.head_gate or self.latent_rescale:
+            attention.update(indexer=self.indexer, head_gate=self.head_gate,
+                             latent_rescale=self.latent_rescale)
+        windowed = None
+        if self.layer_types is not None and "sliding_attention" in self.layer_types:
+            names = ("num_heads", "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                     "qk_rope_head_dim", "v_head_dim", "rope")
+            windowed = dict(zip(names, self.window_geometry),
+                            window=self.sliding_window, head_gate=self.head_gate,
+                            latent_rescale=self.latent_rescale)
         experts = dict(
             n_routed_experts=self.n_routed_experts,
             experts_held=tuple(self.experts_held or (0, self.n_routed_experts)),
@@ -329,13 +468,18 @@ class LatentMoELM(nn.Module):
             topk_group=self.topk_group,
             routed_scaling_factor=self.routed_scaling_factor,
             shared_d_ff=self.n_shared_experts * self.moe_d_ff)
+        if self.scoring != "softmax" or self.norm_topk_prob or self.selection_bias:
+            experts.update(scoring=self.scoring, norm_topk_prob=self.norm_topk_prob,
+                           selection_bias=self.selection_bias)
         live = None
         if valid is not None:
             live = jnp.arange(T)[None, :] < valid[:, None]
         elif active is not None:
             live = jnp.broadcast_to(active[:, None], (b, T))
         for i in range(self.num_layers):
-            x = LatentMoELayer(i >= self.first_dense, self.d_ff, attention, experts,
+            slides = windowed is not None and self.layer_types[i] == "sliding_attention"
+            x = LatentMoELayer(i >= self.first_dense, self.d_ff,
+                               windowed if slides else attention, experts,
                                eps=self.rms_eps, dtype=self.dtype, decode=self.decode,
                                name=f"Layer_{i}")(x, active=active, paged=paged, live=live)
         if valid is not None:  # the one row a prefill chunk samples from
@@ -352,7 +496,11 @@ def build_latent_moe_lm(vocab_size=102400, d_model=256, num_layers=3, num_heads=
                         n_routed_experts=8, experts_held=None, n_shared_experts=2,
                         moe_d_ff=64, top_k=2, n_group=4, topk_group=2,
                         routed_scaling_factor=1.0, rope=None, rms_eps=1e-6,
-                        max_seq_len=163840, dtype="float32"):
+                        max_seq_len=163840, layer_types=None, sliding_window=None,
+                        window_geometry=None, indexer=None, head_gate=False,
+                        latent_rescale=False, scoring="softmax",
+                        norm_topk_prob=False, selection_bias=False,
+                        dtype="float32"):
     first, count = experts_held or (0, n_routed_experts)
     if n_routed_experts % n_group or not 0 < topk_group <= n_group:
         raise ValueError(f"{n_routed_experts} experts do not divide into "
@@ -364,6 +512,27 @@ def build_latent_moe_lm(vocab_size=102400, d_model=256, num_layers=3, num_heads=
                          f"{n_routed_experts} routed experts")
     if qk_rope_head_dim % 2:
         raise ValueError("the rotary width is a number of pairs")
+    if layer_types is not None:
+        layer_types = tuple(layer_types)
+        if len(layer_types) != num_layers or set(layer_types) - {
+                "full_attention", "sliding_attention"}:
+            raise ValueError(f"layer_types names {num_layers} layers, each "
+                             "full_attention or sliding_attention")
+        if "sliding_attention" in layer_types:
+            if not sliding_window or sliding_window < 1 or window_geometry is None \
+                    or len(window_geometry) != 7:
+                raise ValueError("a sliding_attention layer needs sliding_window "
+                                 "and window_geometry (heads, q rank, kv rank, "
+                                 "nope, rope, v head, rope parameters)")
+            window_geometry = tuple(window_geometry[:6]) + (
+                None if window_geometry[6] is None
+                else tuple(float(v) for v in window_geometry[6]),)
+    if indexer is not None:
+        indexer = tuple(int(v) for v in indexer)
+        if len(indexer) != 3 or indexer[1] < qk_rope_head_dim or indexer[2] < 1:
+            raise ValueError("indexer is (heads, width >= qk_rope_head_dim, top_k)")
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"unknown router scoring {scoring!r}")
     return LatentMoELM(
         vocab_size=vocab_size, d_model=d_model, num_layers=num_layers,
         num_heads=num_heads, q_lora_rank=q_lora_rank, kv_lora_rank=kv_lora_rank,
@@ -374,4 +543,8 @@ def build_latent_moe_lm(vocab_size=102400, d_model=256, num_layers=3, num_heads=
         n_group=n_group, topk_group=topk_group,
         routed_scaling_factor=routed_scaling_factor,
         rope=None if rope is None else tuple(float(v) for v in rope),
-        rms_eps=rms_eps, max_seq_len=max_seq_len, dtype=jnp.dtype(dtype))
+        rms_eps=rms_eps, max_seq_len=max_seq_len, layer_types=layer_types,
+        sliding_window=sliding_window, window_geometry=window_geometry,
+        indexer=indexer, head_gate=head_gate, latent_rescale=latent_rescale,
+        scoring=scoring, norm_topk_prob=norm_topk_prob,
+        selection_bias=selection_bias, dtype=jnp.dtype(dtype))

@@ -489,7 +489,7 @@ def make_decode_cache(decode_module, batch: int, total_len: int):
 
 
 def make_paged_decode_cache(decode_module, max_slots: int, num_blocks: int,
-                            block_size: int):
+                            block_size: int, prefill_chunk: int = 1):
     """Zeroed PAGED decode cache: the same pytree structure as
     ``make_decode_cache`` but with every K/V leaf laid out as physical
     blocks of ``block_size`` columns (``ops.attention.pool_leaf_shape``:
@@ -506,9 +506,22 @@ def make_paged_decode_cache(decode_module, max_slots: int, num_blocks: int,
     speculative windows) or over the blocks themselves (``paged=``).
     A latent leaf (``cached_latent``: one head, key and value in one) is
     laid out ``ops.attention.latent_leaf_shape``, a block's columns minor.
+    An indexer's key (``cached_index_key``) is laid out as the latent is.
     A state leaf (``models.decode_cache``: a recurrence's or a
-    convolution's) is one row a slot, ``(max_slots, ...)``, never paged."""
-    from elephas_tpu.models.decode_cache import INDEX, KV, leaf_kind, leaf_name
+    convolution's) is one row a slot, ``(max_slots, ...)``, never paged. A
+    window layer's latent (``cached_window_latent``) is a ring of blocks a
+    slot, ``(max_slots, ring_blocks, 1, width, block_size)``: its length in
+    the module's own cache is the window, and ``prefill_chunk`` the widest
+    chunk that will be written into it at once."""
+    from elephas_tpu.models.decode_cache import (
+        COLUMN_MINOR,
+        INDEX,
+        KV,
+        WINDOW,
+        leaf_kind,
+        leaf_name,
+        ring_blocks,
+    )
     from elephas_tpu.ops.attention import latent_leaf_shape, pool_leaf_shape
 
     cache_shapes = jax.eval_shape(
@@ -521,7 +534,7 @@ def make_paged_decode_cache(decode_module, max_slots: int, num_blocks: int,
         kind = leaf_kind(path)
         if kind == KV:
             _, heads, _, head_dim = s.shape
-            if leaf_name(path) == "cached_latent":
+            if leaf_name(path) in COLUMN_MINOR:
                 return jnp.zeros(
                     latent_leaf_shape(num_blocks, block_size, head_dim),
                     s.dtype)
@@ -531,6 +544,11 @@ def make_paged_decode_cache(decode_module, max_slots: int, num_blocks: int,
             )
         if kind == INDEX:
             return jnp.zeros((max_slots,), jnp.int32)
+        if kind == WINDOW:
+            _, _, window, width = s.shape
+            blocks = ring_blocks(window, prefill_chunk, block_size)
+            return jnp.zeros(
+                (max_slots,) + latent_leaf_shape(blocks, block_size, width), s.dtype)
         return jnp.zeros((max_slots,) + s.shape[1:], s.dtype)
 
     return jax.tree_util.tree_map_with_path(build, cache_shapes)

@@ -335,7 +335,7 @@ def paged_decode_body(pool_shape, dtype, head_dim, mesh=None,
 
 
 def _paged_attend_xla(q, k_pool, v_pool, table, last, scale=None,
-                      kv_b=None):
+                      kv_b=None, selected=None, window=None):
     """Plain body of both paged attentions: gather one layer's blocks
     through the table and score them in the block layout ``(rows, blocks,
     heads, bs, head_dim)``. ``q``: (rows, q_heads, Q, head_dim), ``Q``
@@ -343,7 +343,10 @@ def _paged_attend_xla(q, k_pool, v_pool, table, last, scale=None,
     multi-head); ``last``: (rows, Q) the last column each query attends.
     Without a ``v_pool`` the pool is a latent one and ``kv_b`` expands it
     (``paged_decode_attention``): the queries are absorbed, a column's first
-    ``rank`` values are its value, and the result goes through ``W_uv``."""
+    ``rank`` values are its value, and the result goes through ``W_uv``.
+    ``selected``: (rows, Q, S) of 0 and 1, the columns each query attends of
+    its live ones (all of them where None); ``window``: a query attends the
+    ``window`` columns that end with its ``last`` only."""
     if v_pool is None:
         nope = _nope_width(q, kv_b, k_pool.shape[2])
         q = _absorb(q, kv_b, k_pool.shape[2])
@@ -364,6 +367,10 @@ def _paged_attend_xla(q, k_pool, v_pool, table, last, scale=None,
     ) * (1.0 / (head_dim ** 0.5) if scale is None else scale)
     cols = (jnp.arange(bps) * bs)[:, None] + jnp.arange(bs)[None, :]
     valid = cols <= last[:, :, None, None]  # (rows, Q, bps, bs)
+    if window is not None:
+        valid &= cols > last[:, :, None, None] - window
+    if selected is not None:
+        valid &= selected[..., :bps * bs].reshape(rows, count, bps, bs) > 0
     scores = jnp.where(valid[:, None, None], scores,
                        jnp.finfo(jnp.float32).min)
     flat = scores.reshape(*scores.shape[:4], bps * bs)
@@ -401,9 +408,10 @@ def _expand_values(out, kv_b, nope: int):
                       preferred_element_type=jnp.float32).astype(out.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("body", "scale"))
+@functools.partial(jax.jit, static_argnames=("body", "scale", "window"))
 def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, table, idx,
-                           active, body: str, scale=None, kv_b=None):
+                           active, body: str, scale=None, kv_b=None,
+                           selected=None, window=None):
     """One decode step's attention of one layer over the paged pool.
 
     ``q``: (slots, q_heads, head_dim); ``k_new``/``v_new``: (slots,
@@ -432,11 +440,18 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, table, idx,
     expanding its thousands of columns would cost ``rank`` products a
     column where absorbing costs them once). ``out`` is (slots, q_heads,
     v_head) and the returned ``v_pool`` None. ``scale`` is the softmax
-    scale where it is not ``head_dim ** -0.5``.
+    scale where it is not ``head_dim ** -0.5``. A latent step may also be
+    given ``selected``, (slots, S) of 0 and 1: the columns a lane's query
+    attends of its live ones (``ops.sparse_index``; both bodies do the dense
+    arithmetic over the live blocks and give the exact result over the set),
+    and a static ``window``: lane ``s`` attends columns ``idx[s] - window <
+    c <= idx[s]`` alone.
     """
     if body not in PAGED_BODIES:
         raise ValueError(f"unknown paged body {body!r}; expected one of "
                          f"{PAGED_BODIES}")
+    if (selected is not None or window is not None) and v_pool is not None:
+        raise NotImplementedError("a selection or a window is a latent pool's")
     if body == "paged_pallas":
         from elephas_tpu.ops.attention_pallas import (
             pallas_latent_decode_attention,
@@ -450,7 +465,8 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, table, idx,
                 width = k_pool.shape[2]
                 out, k_pool = pallas_latent_decode_attention(
                     _absorb(q[:, :, None], kv_b, width)[:, :, 0], k_new, k_pool,
-                    table, idx, active, kv_b.shape[0], scale)
+                    table, idx, active, kv_b.shape[0], scale,
+                    selected=selected, window=window)
                 out = _expand_values(out[:, :, None], kv_b,
                                      _nope_width(q, kv_b, width))[:, :, 0]
                 return out, k_pool, None
@@ -464,8 +480,9 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, table, idx,
             v_pool = _write_windows(v_pool, table, idx, v_new[:, :, None],
                                     active)
     with jax.named_scope("paged_attention"):
-        out = _paged_attend_xla(q[:, :, None], k_pool, v_pool, table,
-                                idx[:, None], scale, kv_b)[:, :, 0]
+        out = _paged_attend_xla(
+            q[:, :, None], k_pool, v_pool, table, idx[:, None], scale, kv_b,
+            None if selected is None else selected[:, None], window)[:, :, 0]
     return out, k_pool, v_pool
 
 
@@ -586,10 +603,11 @@ def scatter_prefill_blocks(pool_leaf, row_table, start, chunk, latent=False):
     return pool_leaf.at[ids].set(jnp.moveaxis(blocks, 1, 0), mode="drop")
 
 
-@functools.partial(jax.jit, static_argnames=("body", "aligned", "scale"))
+@functools.partial(jax.jit, static_argnames=("body", "aligned", "scale",
+                                             "window"))
 def paged_chunk_attention(q, k_new, v_new, k_pool, v_pool, row, start,
                           body: str, aligned: bool = False, scale=None,
-                          kv_b=None, valid=None):
+                          kv_b=None, valid=None, selected=None, window=None):
     """One prefill chunk's attention of one layer of ONE slot over the
     paged pool.
 
@@ -628,11 +646,18 @@ def paged_chunk_attention(q, k_new, v_new, k_pool, v_pool, row, start,
     absorbed form costs ``2 rank + pe``, 3.4 times as many at the published
     sizes (on the chip the absorbed kernel ran at three quarters of the
     MXU's peak and was still three fifths of a chunk's time). The XLA body
-    runs absorbed, as a decode step.
+    runs absorbed, as a decode step. ``selected``, (C, S) of 0 and 1, and a
+    static ``window`` bound what query ``i`` attends as they bound a decode
+    step's lane: its row of the selection, and columns ``start + i - window
+    < c <= start + i``; the kernel then scores every tile it visits masked,
+    and under a window visits no tile whose queries have all left a step's
+    columns behind.
     """
     if body not in PAGED_BODIES:
         raise ValueError(f"unknown paged body {body!r}; expected one of "
                          f"{PAGED_BODIES}")
+    if (selected is not None or window is not None) and v_pool is not None:
+        raise NotImplementedError("a selection or a window is a latent pool's")
     write = scatter_prefill_blocks if aligned else scatter_prefill_columns
     with jax.named_scope("kv_write"):
         if v_pool is None:
@@ -648,15 +673,17 @@ def paged_chunk_attention(q, k_new, v_new, k_pool, v_pool, row, start,
             )
 
             if v_pool is None:
-                out = pallas_latent_chunk_attention(q, kv_b, k_pool, row,
-                                                    start, scale, valid)
+                out = pallas_latent_chunk_attention(
+                    q, kv_b, k_pool, row, start, scale, valid,
+                    selected=selected, window=window)
             else:
                 out = pallas_paged_chunk_attention(q, k_pool, v_pool, row,
                                                    start)
         else:
             out = _paged_attend_xla(
                 q[None], k_pool, v_pool, row[None],
-                (start + jnp.arange(q.shape[1]))[None], scale, kv_b)[0]
+                (start + jnp.arange(q.shape[1]))[None], scale, kv_b,
+                None if selected is None else selected[None], window)[0]
     return out, k_pool, v_pool
 
 

@@ -981,12 +981,9 @@ def _latent_decode_kernel(
     lane_ref, first_ref, table_ref, idx_ref, steps_ref,  # scalar prefetch
     q_ref,  # (1, heads, width): every head's query of one lane
     new_ref,  # (1, width, 1): the lane's new column
-    k_hbm,  # the pool, left where it is
-    o_ref, ko_hbm,  # (1, heads, value_width); the pool again (aliased)
-    k_buf,  # (2, blocks, width, block_size): a step's blocks, twice
-    sem, tail_sem,  # DMAs in flight: (buffer, block); the tail's
-    m_ref, l_ref, acc_ref,
-    *, blocks: int, blocks_per_slot: int, value_width: int, sm_scale: float,
+    *refs,  # [the step's selection, (1, 1, columns)]; the pool; outputs; scratch
+    blocks: int, blocks_per_slot: int, value_width: int, sm_scale: float,
+    window=None, sparse: bool = False,
 ):
     """``_paged_decode_kernel`` for a latent pool: grid step ``w`` folds
     ``blocks`` consecutive blocks of one lane, from its block
@@ -995,7 +992,16 @@ def _latent_decode_kernel(
     puts the new column into it before it is scored and sends it back to
     the pool. The next step's live blocks are on their way while this one
     is scored; a block with no live column is not moved, and what its place
-    in the buffer holds is scored dead."""
+    in the buffer holds is scored dead. Under a ``window`` a column is live
+    only within ``window`` of the lane's own (the token itself counted);
+    ``sparse``, only where the step's selection reads 1."""
+    sel_ref = refs[0] if sparse else None
+    # k_hbm: the pool, left where it is; o_ref: (1, heads, value_width);
+    # ko_hbm: the pool again (aliased); k_buf: (2, blocks, width, block_size),
+    # a step's blocks, twice; sem, tail_sem: DMAs in flight, (buffer, block)
+    # and the tail's
+    (k_hbm, o_ref, ko_hbm, k_buf, sem, tail_sem,
+     m_ref, l_ref, acc_ref) = refs[1 if sparse else 0:]
     w = pl.program_id(0)
     block_size = k_buf.shape[3]
 
@@ -1052,8 +1058,15 @@ def _latent_decode_kernel(
         k_buf[buf, b] = jnp.where(here, new_ref[0], k_buf[buf, b])
         tail_copy().start()
 
-    _latent_fold(q_ref[0], [k_buf[buf, b] for b in range(blocks)],
-                 lambda col: col <= idx - first * block_size,
+    def live(col):
+        seen = col <= idx - first * block_size
+        if window is not None:
+            seen &= col > idx - first * block_size - window
+        if sparse:
+            seen &= sel_ref[0] > 0
+        return seen
+
+    _latent_fold(q_ref[0], [k_buf[buf, b] for b in range(blocks)], live,
                  value_width, sm_scale, m_ref, l_ref, acc_ref)
 
     @pl.when(is_tail)
@@ -1065,11 +1078,12 @@ def _latent_decode_kernel(
 
 
 @functools.partial(jax.jit, static_argnames=("value_width", "scale", "blocks",
-                                             "interpret"))
+                                             "window", "interpret"))
 def pallas_latent_decode_attention(q, new, pool, table, idx, active,
                                    value_width: int, scale=None,
                                    blocks: int | None = None,
-                                   interpret: bool = False):
+                                   interpret: bool = False, selected=None,
+                                   window: int | None = None):
     """One decode step of one layer on a latent paged pool, in place.
 
     ``q``: (slots, q_heads, width); ``new``: (slots, 1, width), the step's
@@ -1079,7 +1093,10 @@ def pallas_latent_decode_attention(q, new, pool, table, idx, active,
     ``out`` (slots, q_heads, value_width) and zeros for an inactive lane.
     The grid runs over the active lanes' steps only, a run-time count; the
     pool stays in HBM and of it only each active lane's tail block is
-    written back."""
+    written back. ``selected``: (slots, S) of 0 and 1, the columns a lane's
+    query attends (a learned sparse attention's; every live one where it is
+    None; dense arithmetic over the live blocks either way); ``window``:
+    lane ``s`` attends columns ``idx[s] - window < c <= idx[s]`` only."""
     from elephas_tpu.ops.attention import paged_decode_blocks
 
     slots, q_heads, width = q.shape
@@ -1099,12 +1116,23 @@ def pallas_latent_decode_attention(q, new, pool, table, idx, active,
     lane, first = work // most, work % most * blocks
     steps = live.sum().astype(jnp.int32)
 
+    sparse = selected is not None
     kernel = functools.partial(
         _latent_decode_kernel, blocks=blocks, blocks_per_slot=blocks_per_slot,
         value_width=value_width,
         sm_scale=float(scale) if scale is not None else 1.0 / (width ** 0.5),
+        window=window, sparse=sparse,
     )
     any_space = pl.BlockSpec(memory_space=pl.ANY)
+    columns = blocks * block_size
+    chosen, chosen_spec = [], []
+    if sparse:  # float32 rows of a step's columns: one row is one tile's
+        take = min(selected.shape[1], most * columns)
+        chosen = [jnp.pad(selected[:, :take].astype(jnp.float32),
+                          ((0, 0), (0, most * columns - take)))[:, None]]
+        chosen_spec = [pl.BlockSpec(
+            (1, 1, columns),
+            lambda w, lane, first, *_: (lane[w], 0, first[w] // blocks))]
     out, pool = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1115,6 +1143,7 @@ def pallas_latent_decode_attention(q, new, pool, table, idx, active,
                              lambda w, lane, *_: (lane[w], 0, 0)),
                 pl.BlockSpec((1, width, 1),
                              lambda w, lane, *_: (lane[w], 0, 0)),
+                *chosen_spec,
                 any_space,
             ],
             out_specs=[
@@ -1135,15 +1164,16 @@ def pallas_latent_decode_attention(q, new, pool, table, idx, active,
             jax.ShapeDtypeStruct((slots, q_heads, value_width), q.dtype),
             jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         ],
-        # operands count the scalar-prefetch arguments: the pool is 7
-        input_output_aliases={7: 1},
+        # operands count the scalar-prefetch arguments: the pool is 7, or 8
+        input_output_aliases={8 if sparse else 7: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)
         ),
         interpret=pltpu.InterpretParams() if interpret else False,
-        name="latent_decode_attention",
+        name=("sparse_latent_decode_attention" if sparse
+              else "latent_decode_attention"),
     )(lane, first, table.reshape(-1), idx, steps.reshape(1), q.astype(dtype),
-      jnp.swapaxes(new.astype(dtype), 1, 2), pool)
+      jnp.swapaxes(new.astype(dtype), 1, 2), *chosen, pool)
     # a lane the grid never visited holds whatever its output rows held
     return jnp.where(active[:, None, None], out, 0), pool
 
@@ -1152,8 +1182,9 @@ def _latent_chunk_kernel(
     phys_ref, steps_ref, start_ref, valid_ref,  # scalar prefetch (SMEM)
     qn_ref, qp_ref,  # (heads a step, C, nope), (.., C, pe): scaled queries
     uk_ref, uv_ref,  # (heads a step, nope, rank), (.., v_head, rank)
-    *refs,  # the step's blocks, (rank + pe, block_size) each; out; scratch
-    blocks: int, rank: int, tq: int,
+    *refs,  # the step's blocks, (rank + pe, block_size) each;
+    # [the selection of the step's columns, (C, columns)]; out; scratch
+    blocks: int, rank: int, tq: int, window=None, sparse: bool = False,
 ):
     """Grid step (head group, ``j``): fold ``blocks`` blocks of the slot's
     row, from block ``j * blocks`` on, into the running softmax of the
@@ -1167,10 +1198,16 @@ def _latent_chunk_kernel(
     last that holds a row below ``valid``. A tile whose first query
     already sees the step's last column is scored unmasked; the diagonal
     crosses the others. A tile past ``valid`` is never touched: its rows
-    come back zero."""
+    come back zero.
+
+    Under a ``window`` a query sees the ``window`` columns that end with its
+    own and a step visits no tile whose every query has left its last column
+    behind; ``sparse``, a query sees of its live columns those its row of
+    the selection marks 1. Every tile is then scored masked."""
     del phys_ref  # read by the index maps only
     k_refs = refs[:blocks]
-    o_ref, m_ref, l_ref, acc_ref, key_ref, value_ref = refs[blocks:]
+    sel_ref = refs[blocks] if sparse else None
+    o_ref, m_ref, l_ref, acc_ref, key_ref, value_ref = refs[blocks + sparse:]
     j = pl.program_id(1)
     block_size = k_refs[0].shape[1]
     columns = blocks * block_size
@@ -1200,9 +1237,13 @@ def _latent_chunk_kernel(
             rotary = jnp.concatenate([ref[rank:] for ref in k_refs], axis=1)
             if masked:  # column c of the step is live for row r of the tile
                 shape = (tq, columns)
-                live = (jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-                        - jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-                        <= start + t * tq - first_col)
+                ahead = (jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+                         - jax.lax.broadcasted_iota(jnp.int32, shape, 0))
+                live = ahead <= start + t * tq - first_col
+                if window is not None:
+                    live &= ahead > start + t * tq - first_col - window
+                if sparse:
+                    live &= sel_ref[rows, :].astype(jnp.int32) > 0
             for h in range(heads):
                 s = (product(qn_ref[h, rows, :], key_ref[h], ((1,), (0,)))
                      + product(qp_ref[h, rows, :], rotary, ((1,), (0,))))
@@ -1224,8 +1265,14 @@ def _latent_chunk_kernel(
     first = jnp.maximum(first_col - start, 0) // tq
     whole = jnp.maximum(first_col + columns - 1 - start + tq - 1, 0) // tq
     end = (valid_ref[0] - 1) // tq + 1
-    jax.lax.fori_loop(first, jnp.minimum(whole, end), fold(True), 0)
-    jax.lax.fori_loop(whole, end, fold(False), 0)
+    if window is not None:  # the last tile with a query this near the step
+        end = jnp.minimum(end, (first_col + columns - 1 + window - 1 - start)
+                          // tq + 1)
+    if window is not None or sparse:
+        jax.lax.fori_loop(first, end, fold(True), 0)
+    else:
+        jax.lax.fori_loop(first, jnp.minimum(whole, end), fold(True), 0)
+        jax.lax.fori_loop(whole, end, fold(False), 0)
 
     @pl.when(j == steps_ref[0] - 1)
     def _finalize():
@@ -1233,10 +1280,12 @@ def _latent_chunk_kernel(
                       ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "tq", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "tq", "interpret",
+                                             "window"))
 def pallas_latent_chunk_attention(q, kv_b, pool, row, start, scale=None,
                                   valid=None, tq: int | None = None,
-                                  interpret: bool = False):
+                                  interpret: bool = False, selected=None,
+                                  window: int | None = None):
     """One prefill chunk of one layer of ONE slot against a latent paged
     pool, which already holds the chunk's own columns: query ``i`` of every
     head attends columns ``<= start + i`` of the slot's blocks, keys and
@@ -1252,7 +1301,13 @@ def pallas_latent_chunk_attention(q, kv_b, pool, row, start, scale=None,
     steps a run-time count that ends with the last valid query's column;
     inside a step the queries go in tiles of ``tq`` rows
     (``ops.attention._latent_chunk_tiles``; tests pass their own), the
-    live ones only. The softmax scale is folded into the queries."""
+    live ones only. The softmax scale is folded into the queries.
+
+    ``selected``: (C, S) int8 of 0 and 1, ``S`` at least the row's columns
+    in whole steps: query ``i`` attends of its live columns those marked 1 (a
+    learned sparse attention's selection: dense arithmetic over every live
+    step, the exact result over the set). ``window``: query ``i`` attends
+    columns ``start + i - window < c <= start + i`` only."""
     from elephas_tpu.ops.attention import _latent_chunk_tiles
 
     q_heads, chunk, q_width = q.shape
@@ -1286,14 +1341,22 @@ def pallas_latent_chunk_attention(q, kv_b, pool, row, start, scale=None,
                      lambda g, j, phys, *_, b=b: (phys[j * blocks + b], 0, 0, 0))
         for b in range(blocks)
     ]
+    sparse = selected is not None
+    chosen, chosen_spec = [], []
+    if sparse:
+        take = min(selected.shape[1], most * columns)
+        chosen = [jnp.pad(selected[:, :take].astype(jnp.int8),
+                          ((0, 0), (0, most * columns - take)))]
+        chosen_spec = [pl.BlockSpec((chunk, columns), lambda g, j, *_: (0, j))]
     return pl.pallas_call(
-        functools.partial(_latent_chunk_kernel, blocks=blocks, rank=rank, tq=tq),
+        functools.partial(_latent_chunk_kernel, blocks=blocks, rank=rank, tq=tq,
+                          window=window, sparse=sparse),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(q_heads // group, steps),
             in_specs=[by_head(chunk, nope), by_head(chunk, pe),
                       by_head(nope, rank), by_head(v_head, rank),
-                      *live_blocks],
+                      *live_blocks, *chosen_spec],
             out_specs=by_head(chunk, v_head),
             scratch_shapes=[
                 pltpu.VMEM((group, chunk, 1), jnp.float32),
@@ -1309,6 +1372,7 @@ def pallas_latent_chunk_attention(q, kv_b, pool, row, start, scale=None,
             vmem_limit_bytes=_LATENT_CHUNK_VMEM,
         ),
         interpret=pltpu.InterpretParams() if interpret else False,
-        name="latent_chunk_attention",
+        name=("sparse_latent_chunk_attention" if sparse
+              else "latent_chunk_attention"),
     )(phys, steps.reshape(1), start.reshape(1), valid.reshape(1),
-      q[..., :nope], q[..., nope:], uk, uv, *([pool] * blocks))
+      q[..., :nope], q[..., nope:], uk, uv, *([pool] * blocks), *chosen)

@@ -71,19 +71,29 @@ def grouped_body(rows: int, depth: int, cols: int, dtype, mesh=None) -> str:
 # -- the router ---------------------------------------------------------------
 
 
-def group_limited_top_k(p, n_group: int, topk_group: int, top_k: int):
+def group_limited_top_k(p, n_group: int, topk_group: int, top_k: int, by=None):
     """``group_limited_greedy``: of ``p`` (tokens, experts) keep the
     ``topk_group`` groups whose largest entry is largest, read 0 elsewhere,
     and take the ``top_k`` largest entries; the lower index wins a tie.
     Returns ``(ids, values)``, (tokens, top_k) each; the values are ``p``'s
-    own, not renormalised."""
+    own, not renormalised.
+
+    With ``by`` (tokens, experts) the experts are SELECTED by ``by`` (its
+    groups, its ``top_k`` largest: a score plus a selection bias, say) and
+    WEIGHED by ``p``: the values returned are ``p`` at the chosen ids. A
+    dropped group then reads minus infinity, since ``by`` may lie below
+    0."""
     tokens, experts = p.shape
-    by_group = p.reshape(tokens, n_group, experts // n_group)
+    chosen = p if by is None else by
+    by_group = chosen.reshape(tokens, n_group, experts // n_group)
     _, best = jax.lax.top_k(by_group.max(-1), topk_group)
     kept = jnp.zeros((tokens, n_group), bool).at[
         jnp.arange(tokens)[:, None], best].set(True)
-    limited = jnp.where(kept[:, :, None], by_group, 0.0).reshape(p.shape)
+    limited = jnp.where(kept[:, :, None], by_group,
+                        0.0 if by is None else -jnp.inf).reshape(p.shape)
     values, ids = jax.lax.top_k(limited, top_k)
+    if by is not None:
+        values = jnp.take_along_axis(p, ids, axis=1)
     return ids, values
 
 

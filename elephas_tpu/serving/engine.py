@@ -71,11 +71,13 @@ from elephas_tpu import obs
 from elephas_tpu.models.decode_cache import (
     INDEX,
     KV,
+    WINDOW,
     PagedDecode,
     first_index,
     has_latent,
     has_state,
     leaf_kind,
+    leaf_name,
     leaves_of_kind,
 )
 from elephas_tpu.serving.kv_pool import PagedKVPool
@@ -243,6 +245,7 @@ class InferenceEngine:
             num_blocks=kv_blocks,
             prefix_cache=prefix_cache,
             virtual_len=virtual_len,
+            prefill_chunk=chunk,
         )
         # What the engine has to know of the model it reads from the cache
         # tree: a leaf that is neither K/V nor an index is per-slot state.
@@ -263,6 +266,9 @@ class InferenceEngine:
                 "speculative decoding is not built for a latent cache: a "
                 "draft or verify window attends a gathered contiguous row of "
                 "keys and values a head, and a latent pool holds neither"
+                + ("; nor for a window layer's ring, which a rejected draft "
+                   "token would have overwritten a live column of"
+                   if self.pool.windowed else "")
             )
         if speculative:
             from elephas_tpu.serving.spec import (
@@ -409,6 +415,8 @@ class InferenceEngine:
             if kind == INDEX:
                 return jnp.full((1,), start, jnp.int32)
             own = jax.lax.dynamic_index_in_dim(leaf, slot, axis=0)
+            if kind == WINDOW:  # the slot's ring: what it holds past the
+                return own      # window's reach is never read
             return jnp.where(start == 0, jnp.zeros_like(own), own)
 
         row_cache = jax.tree_util.tree_map_with_path(to_row, cache)
@@ -449,7 +457,8 @@ class InferenceEngine:
                 # start + chunk — the right-pad tail is garbage); every
                 # other slot's entry is untouched.
                 return pool_leaf.at[slot].set(start + valid)
-            # State: this slot's row alone, as the module left it.
+            # State, or a window layer's ring: this slot's row alone, as the
+            # module left it.
             return jax.lax.dynamic_update_slice_in_dim(
                 pool_leaf, mut_leaf.astype(pool_leaf.dtype), slot, 0)
 
@@ -516,10 +525,29 @@ class InferenceEngine:
         """The pool's own K/V leaf and the head width its rows pack: the
         leaf's heads are the model's K/V heads, which grouped attention
         has fewer of than query heads; a block is ``block_size`` columns
-        a head."""
-        leaf = leaves_of_kind(self.pool.cache, KV)[0][1]
+        a head. Of a latent pool, the latent's leaf (an indexer's key beside
+        it is a narrower leaf of the same layout)."""
+        found = leaves_of_kind(self.pool.cache, KV)
+        leaf = next((leaf for path, leaf in found
+                     if leaf_name(path) == "cached_latent"), found[0][1])
         _, _, rows, lanes = leaf.shape
         return leaf, rows * lanes // self.pool.block_size
+
+    def _every_latent_fits(self) -> bool:
+        """Whether the latent kernels' tiles lower for EVERY column-minor
+        leaf the pool holds, a window layer's ring read as the small pool it
+        is: one body name serves all of a program's layers."""
+        from elephas_tpu.ops.attention import _latent_fits
+
+        heads = self.decode_module.num_heads
+        cache = self.pool.cache
+        for path, leaf in leaves_of_kind(cache, KV) + leaves_of_kind(cache, WINDOW):
+            # an index key is scored by kernels of its own (``ops.sparse_index``)
+            if leaf_name(path) in ("cached_latent", "cached_window_latent"):
+                shape = (-1,) + leaf.shape[-3:]  # a ring: (slots, blocks, ...)
+                if not _latent_fits(shape, leaf.dtype, shape[2], heads):
+                    return False
+        return True
 
     @property
     def decode_attention(self) -> str:
@@ -529,6 +557,8 @@ class InferenceEngine:
         from elephas_tpu.ops.attention import paged_decode_body
 
         leaf, head_dim = self._kv_layout()
+        if self.latent and not self._every_latent_fits():
+            return "paged_xla"
         return paged_decode_body(leaf.shape, leaf.dtype, head_dim, self.mesh,
                                  q_heads=self.decode_module.num_heads,
                                  latent=self.latent)
@@ -555,6 +585,8 @@ class InferenceEngine:
         from elephas_tpu.ops.attention import paged_chunk_body
 
         leaf, head_dim = self._kv_layout()
+        if self.latent and not self._every_latent_fits():
+            return "paged_xla"
         return paged_chunk_body(leaf.shape, leaf.dtype, head_dim,
                                 self.prefill_chunk, self.mesh,
                                 q_heads=self.decode_module.num_heads,
@@ -662,6 +694,13 @@ class InferenceEngine:
                 "shard_serving is not built for a model with per-slot "
                 "state: the state rows have no sharding rule, and the "
                 "scan's kernel is not partitioned by a mesh"
+            )
+        if self.pool.windowed:
+            raise NotImplementedError(
+                "shard_serving is not built for a model with window layers: "
+                "a slot's ring of a window layer's latent has no sharding "
+                "rule (one latent head: there is no head axis to divide), "
+                "and the latent kernels are not partitioned by a mesh"
             )
         if getattr(self.decode_module, "experts_held", None) is not None:
             raise NotImplementedError(

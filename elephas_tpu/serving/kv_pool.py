@@ -5,7 +5,9 @@ programs' operand shapes never change as sequences come and go: per
 layer, K/V leaves of ``num_blocks`` physical blocks of ``block_size``
 columns (the vLLM layout) and per-slot ``cache_index``/``pos_index``
 ``(max_slots,)`` vectors; a model with per-slot state (a recurrence's, a
-convolution's) keeps one row a slot in the same tree. Slots reach their
+convolution's) keeps one row a slot in the same tree, and a window layer's
+latent a ring of blocks a slot, bounded by the window and not by the
+sequence (``models.decode_cache``: four kinds of leaf). Slots reach their
 blocks through a reference-counted ``BlockTable``; a ``PrefixCache``
 admits already-resident prompt prefixes by bumping refcounts instead of
 re-prefilling, evicts unreferenced prefixes LRU-first under allocation
@@ -49,6 +51,7 @@ from elephas_tpu.models.decode_cache import (
     INDEX,
     KV,
     STATE,
+    WINDOW,
     has_state,
     leaf_kind,
     leaves_of_kind,
@@ -76,11 +79,12 @@ def _copy_block(cache, src, dst):
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _clear_state_row(cache, slot):
-    """Zero ``slot``'s row of every state leaf, in place on the donated
-    cache: a released slot keeps nothing of the request it served."""
+    """Zero ``slot``'s row of every state leaf and its ring of every window
+    leaf, in place on the donated cache: a released slot keeps nothing of
+    the request it served."""
 
     def clear(path, leaf):
-        if leaf_kind(path) == STATE:
+        if leaf_kind(path) in (STATE, WINDOW):
             return jax.lax.dynamic_update_slice_in_dim(
                 leaf, jnp.zeros((1,) + leaf.shape[1:], leaf.dtype), slot, 0)
         return leaf
@@ -304,7 +308,8 @@ class PagedKVPool:
     def __init__(self, decode_module, max_slots: int, max_len: int,
                  block_size: int, num_blocks: Optional[int] = None,
                  prefix_cache: bool = True,
-                 virtual_len: Optional[int] = None):
+                 virtual_len: Optional[int] = None,
+                 prefill_chunk: Optional[int] = None):
         from elephas_tpu.models.transformer import make_paged_decode_cache
 
         if max_slots < 1:
@@ -329,8 +334,11 @@ class PagedKVPool:
                 f"num_blocks ({self.num_blocks}) cannot back even one "
                 f"slot ({self.blocks_per_slot} blocks per slot)"
             )
+        # ``prefill_chunk``: the widest chunk a program writes at once, which
+        # a window layer's ring has to hold beside the window behind it
         self._cache = make_paged_decode_cache(
-            decode_module, max_slots, self.num_blocks, block_size
+            decode_module, max_slots, self.num_blocks, block_size,
+            prefill_chunk=prefill_chunk or max_len,
         )
         # A model with per-slot state (a recurrence's, a convolution's):
         # the state rows ride in the same cache tree, one a slot, and what
@@ -339,6 +347,16 @@ class PagedKVPool:
         self.stateful = has_state(self._cache)
         self.state_bytes = state_bytes(self._cache)
         self.state_resets = 0  # rows zeroed: at admission and at release
+        # A window layer's latent: a ring of blocks a slot, outside the block
+        # table. What a slot holds of it never passes ``window_columns``,
+        # however long its sequence; like state, it is the slot's own, so a
+        # resident prefix says nothing of it (``_refuse_state``).
+        rings = [leaf for _, leaf in leaves_of_kind(self._cache, WINDOW)]
+        self.windowed = bool(rings)
+        self.window_columns = max(
+            (leaf.shape[1] * leaf.shape[-1] for leaf in rings), default=0)
+        self.window_bytes = sum(leaf.size * leaf.dtype.itemsize for leaf in rings)
+        self._cols: Dict[int, int] = {}  # columns each live slot has backed
         # Prompts are never left-padded (shared prefixes must land at
         # identical cache columns in every slot): the pad vector stays
         # zero, an operand the decode and speculation programs still take.
@@ -352,7 +370,8 @@ class PagedKVPool:
         # With state, a resident block of K/V says nothing of the state the
         # other layers held at its last column: nothing is adopted.
         self.prefix = (PrefixCache(block_size)
-                       if prefix_cache and not self.stateful else None)
+                       if prefix_cache and not self.stateful
+                       and not self.windowed else None)
         # Lazy process-registry mirror (same latch-False idiom as
         # ServingMetrics): the fleet aggregator federates these from
         # /metrics scrapes without the pool knowing it's being watched.
@@ -549,6 +568,14 @@ class PagedKVPool:
                 "the recurrent and convolution state of the other layers "
                 "would be left behind"
             )
+        if self.windowed:
+            raise NotImplementedError(
+                f"{what} is not built for a model with window layers: the "
+                "pool's blocks hold the latents and index keys of its full "
+                "layers only, and a window layer's last columns live in a "
+                "ring of the slot's own, outside the block table, which "
+                "would be left behind"
+            )
 
     @property
     def kv_bytes_per_token(self) -> int:
@@ -562,18 +589,28 @@ class PagedKVPool:
     def state_signals(self) -> dict:
         """What the ``step`` event says of the state rows: a row is in use
         from its slot's admission to its release."""
-        return {
+        out = {
             "state_slots_in_use": self.active_count if self.stateful else 0,
             "state_slots_total": self.max_slots if self.stateful else 0,
             "state_bytes": self.state_bytes,
         }
+        if self.windowed:
+            # of each window layer: the columns the live slots hold, against
+            # the bound (a slot's ring, times the slots)
+            out["window_columns_resident"] = sum(
+                min(cols, self.window_columns) for cols in self._cols.values())
+            out["window_columns_bound"] = self.max_slots * self.window_columns
+            out["window_columns_per_slot"] = self.window_columns
+            out["window_bytes"] = self.window_bytes
+        return out
 
     def admit_prefix(self, slot: int, prompt: Sequence[int]) -> int:
         """Bind the longest resident prefix of ``prompt`` to ``slot``
         (bump refcounts, no device work, no prefill compute). Returns
         the matched token count — prefill resumes at that column.
         A stateful pool matches nothing: prefill starts at column 0, where
-        the chunk program hands the module a zeroed state row."""
+        the chunk program hands the module a zeroed state row. A pool with
+        window layers matches nothing either: the ring is the slot's own."""
         if self.stateful:
             self.state_resets += 1
         if self.prefix is None:
@@ -610,6 +647,8 @@ class PagedKVPool:
                 f"{self.virtual_len} columns"
             )
         self._bill_slot(slot)  # per-decode-step occupancy integration
+        if self.windowed:
+            self._cols[slot] = max(self._cols.get(slot, 0), upto)
         row = self.table.rows[slot]
         for i in range(-(-upto // self.block_size)):
             if row[i] < 0:
@@ -702,7 +741,8 @@ class PagedKVPool:
                 self._decref(int(b))  # host-ok: numpy table
         self.table.clear_row(slot)
         self._free.append(slot)
-        if self.stateful:
+        self._cols.pop(slot, None)
+        if self.stateful or self.windowed:
             self.swap(_clear_state_row(self.cache, jnp.int32(slot)))
             self.state_resets += 1
         self._mirror_push()
@@ -883,8 +923,10 @@ class PagedKVPool:
             return {"prefix_hits": 0, "prefix_lookups": 0,
                     "prefix_hit_rate": None, "prefix_tokens_saved": 0,
                     "prefix_evictions": 0, "prefix_resident": 0,
-                    "prefix_cache": ("off: per-slot state" if self.stateful
-                                     else "off")}
+                    "prefix_cache": (
+                        "off: per-slot state" if self.stateful
+                        else "off: window layers keep a ring a slot"
+                        if self.windowed else "off")}
         return {
             "prefix_cache": "on",
             "prefix_hits": self.prefix.hits_total,

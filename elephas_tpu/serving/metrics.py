@@ -247,7 +247,7 @@ class ServingMetrics:
                     kv_blocks_in_use: Optional[int] = None,
                     kv_blocks_total: Optional[int] = None,
                     state_slots_in_use: int = 0, state_slots_total: int = 0,
-                    state_bytes: int = 0, counters=None) -> None:
+                    state_bytes: int = 0, counters=None, **pool_signals) -> None:
         self.steps += 1
         self.tokens_emitted_total += tokens
         self.tokens_prefilled_total += prefill_tokens
@@ -292,6 +292,9 @@ class ServingMetrics:
                 state_slots_in_use=state_slots_in_use,
                 state_slots_total=state_slots_total,
                 state_bytes=state_bytes,
+                # what else the pool says of itself (``state_signals``): the
+                # window layers' resident columns against their bound
+                **pool_signals,
                 decode_attention=self.decode_attention,
                 prefill_attention=self.prefill_attention,
                 decode_kernel_blocks=self.decode_kernel_blocks,

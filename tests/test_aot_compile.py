@@ -372,6 +372,168 @@ def test_latent_chunk_kernel_tiles_compile_for_v5e(one_chip, tq):
     assert chunk.memory_analysis().temp_size_in_bytes < 256 << 20
 
 
+# dots3-note-prev.doc-mix-32k: a full layer's latent (576) and index key (128)
+# paged over 16 slots of 272 blocks, a window layer's ring of 20 blocks a slot
+# (1,088 wide, 64 heads), chunks of 2,048, 2,048 columns a query.
+_SPARSE = dict(q_heads=128, rank=512, pe=64, nope=128, v_head=128, block_size=128,
+               slots=16, blocks_per_slot=272, chunk=2048, index_heads=64,
+               index_width=128, top_k=2048, window=513, ring_blocks=20,
+               w_heads=64, w_rank=1024, w_nope=192)
+
+
+def _sparse_arg(one_chip):
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return arg
+
+
+@pytest.mark.parametrize("step", ["chunk", "decode"])
+def test_index_scores_kernels_compile_for_v5e(one_chip, step):
+    """The indexer's scores at the cell's shapes: a chunk's 2,048 queries of
+    64 heads against the slot's index keys in steps of 512 columns, and a
+    decode step's one query a lane; float32 out, padded to whole steps."""
+    from elephas_tpu.ops import attention, sparse_index
+
+    arg, c = _sparse_arg(one_chip), _SPARSE
+    pool = attention.latent_leaf_shape(c["slots"] * c["blocks_per_slot"],
+                                       c["block_size"], c["index_width"])
+    columns = sparse_index.padded_columns(c["blocks_per_slot"], c["block_size"])
+    assert columns == 34816
+    if step == "chunk":
+        got = jax.jit(sparse_index.pallas_index_scores).lower(
+            arg((c["index_heads"], c["chunk"], c["index_width"]), jnp.bfloat16),
+            arg((c["chunk"], c["index_heads"]), jnp.float32), arg(pool, jnp.bfloat16),
+            arg((c["blocks_per_slot"],), jnp.int32), arg((), jnp.int32),
+            arg((), jnp.int32)).compile()
+        assert got.memory_analysis().output_size_in_bytes == 4 * c["chunk"] * columns
+    else:
+        got = jax.jit(sparse_index.pallas_index_decode_scores).lower(
+            arg((c["slots"], c["index_heads"], c["index_width"]), jnp.bfloat16),
+            arg((c["slots"], c["index_heads"]), jnp.float32), arg(pool, jnp.bfloat16),
+            arg((c["slots"], c["blocks_per_slot"]), jnp.int32),
+            arg((c["slots"],), jnp.int32), arg((c["slots"],), jnp.bool_)).compile()
+    assert "tpu_custom_call" in got.as_text()
+    assert got.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("rows", [2048, 16])
+def test_select_columns_kernel_compiles_for_v5e(one_chip, rows):
+    """The exact selection of 2,048 of 34,816 columns a row, a chunk's rows
+    and a decode step's: 32 rows a grid step, resident while their k-th key
+    is built bit by bit; an int8 mask out."""
+    from elephas_tpu.ops import sparse_index
+
+    arg = _sparse_arg(one_chip)
+    got = jax.jit(sparse_index.pallas_select_columns, static_argnames=("k",)).lower(
+        arg((rows, 34816), jnp.float32), arg((rows,), jnp.int32),
+        k=_SPARSE["top_k"]).compile()
+    assert "tpu_custom_call" in got.as_text()
+
+
+@pytest.mark.parametrize("layer", ["full", "window"])
+def test_sparse_and_window_latent_kernels_compile_for_v5e(one_chip, layer):
+    """Both latent kernels as the drawn model's two kinds of layer run them:
+    a full layer's under the selection's mask (int8 a chunk, float32 rows a
+    decode step), a window layer's over its ring read as a short sequence
+    under the window's bound, at the second geometry (64 heads on a latent
+    of 1,088)."""
+    from elephas_tpu.ops import attention
+    from elephas_tpu.ops.attention_pallas import (
+        pallas_latent_chunk_attention,
+        pallas_latent_decode_attention,
+    )
+
+    arg, c = _sparse_arg(one_chip), _SPARSE
+    if layer == "full":
+        heads, rank, nope, told = c["q_heads"], c["rank"], c["nope"], {}
+        blocks, rows = c["slots"] * c["blocks_per_slot"], c["blocks_per_slot"]
+        chosen = dict(selected=arg((c["chunk"], 34816), jnp.int8))
+        lanes = dict(selected=arg((c["slots"], 34816), jnp.int8))
+    else:
+        heads, rank, nope = c["w_heads"], c["w_rank"], c["w_nope"]
+        told = dict(window=c["window"])
+        blocks, rows, chosen, lanes = c["slots"] * c["ring_blocks"], c["ring_blocks"], {}, {}
+    width = rank + c["pe"]
+    pool = attention.latent_leaf_shape(blocks, c["block_size"], width)
+    assert attention._latent_fits(pool, jnp.bfloat16, width, heads)
+    chunk = jax.jit(pallas_latent_chunk_attention,
+                    static_argnames=("scale", "window")).lower(
+        arg((heads, c["chunk"], nope + c["pe"]), jnp.bfloat16),
+        arg((rank, heads, nope + c["v_head"]), jnp.bfloat16), arg(pool, jnp.bfloat16),
+        arg((rows,), jnp.int32), arg((), jnp.int32), scale=0.0722,
+        valid=arg((), jnp.int32), **chosen, **told).compile()
+    assert "tpu_custom_call" in chunk.as_text()
+    assert chunk.memory_analysis().temp_size_in_bytes < 512 << 20
+    lane_rows = rows if layer == "full" else 5
+    decode = jax.jit(pallas_latent_decode_attention, donate_argnums=(2,),
+                     static_argnames=("value_width", "scale", "window")).lower(
+        arg((c["slots"], heads, width), jnp.bfloat16),
+        arg((c["slots"], 1, width), jnp.bfloat16), arg(pool, jnp.bfloat16),
+        arg((c["slots"], lane_rows), jnp.int32), arg((c["slots"],), jnp.int32),
+        arg((c["slots"],), jnp.bool_), value_width=rank, scale=0.0722, **lanes,
+        **told).compile()
+    assert "tpu_custom_call" in decode.as_text()
+    assert decode.memory_analysis().alias_size_in_bytes >= 2 * np.prod(pool)
+    assert decode.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+def test_both_programs_of_the_sparse_latent_cell_compile_for_v5e(one_chip, monkeypatch):
+    """`dots3-note-prev.doc-mix-32k` whole, at the published widths and the
+    cell's serving sizes (16 slots of 272 blocks, chunks of 2,048), through
+    `benchmark/aot_compile.py::serving` as the harness builds the engine,
+    with every kernel steered on: both programs fit the chip beside their
+    temporaries, the pool comes back in place, and the configuration file's
+    `memory_reckoning` quotes what this compile reports. The pool is built
+    on the host (2.6 GB of zeros); the compile takes half a minute here."""
+    import importlib.util
+    import json
+    import sys
+
+    from elephas_tpu.ops import attention, routed_experts
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                         "benchmark")
+    monkeypatch.setattr(sys, "path", list(sys.path))  # the script adds its own
+    spec = importlib.util.spec_from_file_location(
+        "bench_aot_compile", os.path.join(bench, "aot_compile.py"))
+    aot = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(aot)
+    # the described chip is not the default backend: steer the choice here
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(routed_experts, "_on_tpu", lambda: True)
+    programs = {}
+    monkeypatch.setattr(aot, "report", lambda name, compiled: programs.update(
+        {name.split()[-1]: compiled}))
+
+    class Described:
+        devices = [next(iter(one_chip.device_set))]
+
+    aot.serving(Described, ["dots3-note-prev.doc-mix-32k"])
+    chunk, decode = programs["jit__chunk_prefill_impl"], programs["jit__paged_decode_impl"]
+    for kernel in ("index_scores", "select_columns", "sparse_latent_chunk_attention",
+                   "latent_chunk_attention", "grouped_matmul"):
+        assert kernel in chunk.as_text()
+    for kernel in ("index_scores", "select_columns", "sparse_latent_decode_attention",
+                   "latent_decode_attention", "grouped_matmul"):
+        assert kernel in decode.as_text()
+    sizes = {name: program.memory_analysis() for name, program in programs.items()}
+    for name, m in sizes.items():
+        print(f"dots3-note-prev {name}, 16 slots, v5e:", m)
+        # weights 10.02 GB, paged latents and index keys 2.35, rings 0.27
+        assert 12.6e9 < m.argument_size_in_bytes < 12.7e9
+        assert m.alias_size_in_bytes >= 2.35e9 + 0.26e9  # no leaf of the pool copied
+    held = 16 * 2 ** 30  # a v5e chip's HBM
+    arguments = sizes["jit__chunk_prefill_impl"].argument_size_in_bytes
+    assert arguments + sizes["jit__chunk_prefill_impl"].temp_size_in_bytes < 0.85 * held
+    assert sizes["jit__paged_decode_impl"].temp_size_in_bytes < 64 << 20
+    with open(os.path.join(bench, "configs", "dots3-note-prev.json")) as f:
+        reckoning = json.load(f)["memory_reckoning"]
+    for said in (f"{arguments / 1e9:.2f} GB of arguments",
+                 f"{sizes['jit__chunk_prefill_impl'].temp_size_in_bytes / 1e9:.2f} GB of temporaries",
+                 f"{sizes['jit__paged_decode_impl'].temp_size_in_bytes / 1e9:.3f} GB in the decode"):
+        assert said in reckoning, said
+
+
 @pytest.mark.parametrize("rows", [2048 * 6, 16 * 6])
 def test_grouped_matmul_kernel_compiles_for_v5e(one_chip, rows):
     """The routed layer's grouped product at a chunk's and a decode step's
