@@ -49,7 +49,8 @@ cache index: a lane's its own length, a chunk's ``start .. start + T - 1``.
 Given ``valid`` (a chunk's real tokens), the apply returns the logits of
 position ``valid - 1`` alone and routes no padding token. Each routed layer
 sows its counters (assignments, those on held experts, experts touched and
-held, the busiest and the mean expert's load) into the ``counters``
+held, the passes over an expert's matrices that the grouped kernel makes of
+them, the busiest and the mean expert's load) into the ``counters``
 collection.
 
 The descendants' parts, each off by default. Layer ``i`` is **full** or
@@ -328,6 +329,7 @@ class RoutedExperts(nn.Module):
         from elephas_tpu.ops.routed_experts import (
             group_limited_top_k,
             routed_experts,
+            weight_passes,
         )
 
         first, count = self.experts_held
@@ -361,6 +363,7 @@ class RoutedExperts(nn.Module):
                 ("moe_assignments", tokens * self.top_k),
                 ("moe_assignments_held", load.sum()),
                 ("moe_experts_touched", (load > 0).sum()),
+                ("moe_weight_passes", weight_passes(load, y.shape[0] * self.top_k)),
                 ("moe_experts_held", count),
                 ("moe_load_max", load.max()),
                 ("moe_load_mean", load.sum() / count)):

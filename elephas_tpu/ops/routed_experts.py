@@ -18,11 +18,29 @@ there is no capacity, so nothing is dropped whatever the routing, and an
 expert with no token, or every assignment on one expert, is exact.
 
 Two bodies with the same arithmetic (operands as given, float32
-accumulation). ``grouped_xla`` is ``jax.lax.ragged_dot``: the CPU's body and
+accumulation, the depth walked in the same order). ``grouped_xla`` is
+``jax.lax.ragged_dot`` over the packed rows: the CPU's body, a mesh's and
 the kernel's reference. ``grouped_pallas`` is a kernel for one TPU whose
-grid runs over the (group, row tile) pairs that hold a row, a run-time
-count, so that the weights of an expert with no token are never read and
-the rows past the last held assignment cost nothing.
+work items are the (group, row tile of that group) pairs that hold a row, a
+run-time count, so that the weights of an expert with no token are never
+read. What it is fed and how its grid runs follows from the rows, a shape:
+
+- More assignments than one row tile (``_ROW_TILE``, a chunk's): the held
+  assignments are laid out with every group starting at a multiple of
+  ``_SUB_TILE`` (``laid_out_rows``, ``group_starts``: the gather out of the
+  tokens and the gather back take other indices, the rows no further pass),
+  so a group owns its row tiles. The grid is (work item, column tile, depth
+  step). The kernel copies an item's rows itself, the sub-tiles that hold a
+  row of the group and no other, once and at full depth, while the item
+  before is multiplied; the expert's matrix streams past them block by
+  block, once an item; a sub-tile past the group's last row is neither
+  pushed through the MXU nor written. A product reads each touched expert's
+  matrix once a row tile of its group (``weight_passes``: once, for all but
+  the largest groups) and each row once.
+- One row tile at most (a decode step's): the rows lie packed and all
+  groups share the tile. The grid is (column tile, work item, depth step),
+  the whole tile stays in VMEM at full depth from the first step on, and an
+  item's result is stored under a mask of its group's rows.
 """
 
 from __future__ import annotations
@@ -36,9 +54,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 GROUPED_BODIES = ("grouped_pallas", "grouped_xla")
 
-_ROW_TILE = 256   # rows a grid step, where there are as many
+_ROW_TILE = 256   # rows a work item, where there are as many
+_SUB_TILE = 128   # rows a push through the MXU; what a group of many tiles starts at
 _COL_TILE = 512   # output columns a grid step
 _DEPTH_TILE = 1024  # of the contraction a grid step
+_VMEM_BUDGET = 12 << 20  # of the 16 MiB a v5e kernel has by default
 
 
 def _on_tpu() -> bool:
@@ -52,11 +72,24 @@ def _divisor(n: int, most: int, unit: int = 128) -> int:
                default=0)
 
 
+def _vmem_bytes(rows: int, depth: int, cols: int, dtype) -> int:
+    """What the kernel keeps in VMEM: a work item's rows at full depth and a
+    block of a matrix, each twice (one in flight), the accumulator, and a
+    column tile of results twice."""
+    item, tile = jnp.dtype(dtype).itemsize, min(rows, _ROW_TILE)
+    col_tile, depth_tile = _divisor(cols, _COL_TILE), _divisor(depth, _DEPTH_TILE)
+    return (2 * item * (tile * depth + depth_tile * col_tile + tile * col_tile)
+            + 4 * tile * col_tile)
+
+
 def _pallas_fits(rows: int, depth: int, cols: int, dtype) -> bool:
+    """``rows`` assignments: one tile's lie packed and fill whole sublanes;
+    more are laid out anew (``laid_out_rows``) and may be any number."""
     sublanes = 32 // jnp.dtype(dtype).itemsize
-    return (jnp.dtype(dtype).itemsize in (2, 4) and rows % sublanes == 0
-            and (rows <= _ROW_TILE or rows % _ROW_TILE == 0)
-            and _divisor(depth, _DEPTH_TILE) > 0 and _divisor(cols, _COL_TILE) > 0)
+    return (jnp.dtype(dtype).itemsize in (2, 4)
+            and (rows > _ROW_TILE or rows % sublanes == 0)
+            and _divisor(depth, _DEPTH_TILE) > 0 and _divisor(cols, _COL_TILE) > 0
+            and _vmem_bytes(rows, depth, cols, dtype) <= _VMEM_BUDGET)
 
 
 def grouped_body(rows: int, depth: int, cols: int, dtype, mesh=None) -> str:
@@ -100,93 +133,236 @@ def group_limited_top_k(p, n_group: int, topk_group: int, top_k: int, by=None):
 # -- the grouped product ------------------------------------------------------
 
 
-def _grouped_kernel(group_ref, tile_ref, offsets_ref, lhs_ref, rhs_ref, out_ref,
-                    acc_ref, *, row_tile: int, depth_steps: int):
-    """Grid step (column tile, work item ``w``, depth step): rows of row
-    tile ``tile_ref[w]`` against matrix ``group_ref[w]``; at the last depth
-    step the rows that belong to the group go to the output tile, the others
-    keep what an earlier work item of the same tile left there."""
+def laid_out_rows(rows: int, groups: int) -> int:
+    """Rows of the layout ``pallas_grouped_matmul`` takes for ``rows`` rows
+    in ``groups`` groups. One tile's worth lie packed. More lie with every
+    group at a multiple of ``_SUB_TILE``, which no routing needs more than
+    ``groups`` sub-tiles beyond the rows' own for."""
+    if rows <= _ROW_TILE:
+        return rows
+    return (rows // _SUB_TILE + groups) * _SUB_TILE
+
+
+def group_starts(group_sizes, rows: int):
+    """The row each group starts at among ``rows`` laid-out rows: one tile's
+    groups packed, more rounded up to ``_SUB_TILE`` each."""
+    sizes = group_sizes.astype(jnp.int32)
+    if rows > _ROW_TILE:
+        sizes = -(-sizes // _SUB_TILE) * _SUB_TILE
+    return jnp.cumsum(sizes) - sizes
+
+
+def _row_tiles(group_sizes, rows: int):
+    """Row tiles each group takes where the assignments are ``rows``: one
+    for a group within a tile, none for an empty one."""
+    return -(-group_sizes.astype(jnp.int32) // min(rows, _ROW_TILE))
+
+
+def weight_passes(group_sizes, rows: int):
+    """The (group, row tile) work items the kernel makes of ``group_sizes``
+    where the assignments are ``rows``: each streams one matrix of its group
+    once."""
+    return _row_tiles(group_sizes, rows).sum()
+
+
+def _work_items(group_sizes, rows: int):
+    """``(group, tile, count)``: the group of each work item and which of its
+    group's row tiles it is, group-major, and how many are real; the rest
+    repeat the last (the grid never reaches them)."""
+    groups = group_sizes.shape[0]
+    n_tiles = _row_tiles(group_sizes, rows)
+    ends = jnp.cumsum(n_tiles)
+    count = ends[-1]
+    item = jnp.arange(-(-rows // _ROW_TILE) + groups, dtype=jnp.int32)  # at most
+    item = jnp.minimum(item, jnp.maximum(count - 1, 0))
+    group = jnp.minimum(jnp.searchsorted(ends, item, side="right"), groups - 1)
+    tile = item - (ends[group] - n_tiles[group])
+    return group.astype(jnp.int32), tile.astype(jnp.int32), count.astype(jnp.int32)
+
+
+def _one_tile_kernel(group_ref, offsets_ref, lhs_ref, rhs_ref, out_ref, acc_ref, *,
+                     depth_tile: int, depth_steps: int):
+    """Grid step (column tile, work item ``w``, depth step): every row, held
+    in VMEM at full depth since the kernel's first step, against matrix
+    ``group_ref[w]``; at the last depth step the rows that belong to the
+    group go to the output tile, the others keep what an earlier work item
+    left there."""
     w, depth = pl.program_id(1), pl.program_id(2)
 
     @pl.when(depth == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    at = pl.multiple_of(depth * depth_tile, depth_tile)
     acc_ref[...] += jax.lax.dot_general(
-        lhs_ref[...], rhs_ref[...], (((1,), (0,)), ((), ())),
+        lhs_ref[:, pl.ds(at, depth_tile)], rhs_ref[...], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     @pl.when(depth == depth_steps - 1)
     def _store():
         group = group_ref[w]
-        row = tile_ref[w] * row_tile + jax.lax.broadcasted_iota(
-            jnp.int32, acc_ref.shape, 0)
+        row = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 0)
         mine = (row >= offsets_ref[group]) & (row < offsets_ref[group + 1])
         out_ref[...] = jnp.where(mine, acc_ref[...],
                                  out_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
 
 
-def _work_items(offsets, rows: int, row_tile: int):
-    """The (group, row tile) pairs that hold a row, group-major, and their
-    count. At most ``tiles + groups - 1`` of them are real; the rest repeat
-    the last (the grid never reaches them)."""
-    groups = offsets.shape[0] - 1
-    tiles = rows // row_tile
-    most = tiles + groups - 1
-    start, end = offsets[:-1], offsets[1:]
-    first_tile = start // row_tile
-    n_tiles = jnp.where(end > start, (end - 1) // row_tile - first_tile + 1, 0)
-    ends = jnp.cumsum(n_tiles)
-    count = ends[-1]
-    item = jnp.arange(most, dtype=jnp.int32)
-    group = jnp.minimum(jnp.searchsorted(ends, item, side="right"), groups - 1)
-    tile = first_tile[group] + item - (ends[group] - n_tiles[group])
-    last = jnp.maximum(count - 1, 0)
-    group = jnp.where(item < count, group, group[last]).astype(jnp.int32)
-    tile = jnp.where(item < count, tile, tile[last]).astype(jnp.int32)
-    return group, jnp.clip(tile, 0, tiles - 1), count.astype(jnp.int32)
+def _tiles_kernel(group_ref, sub_ref, live_ref, count_ref, lhs_hbm, rhs_ref, out_hbm,
+                  rows_ref, acc_ref, res_ref, rows_sem, res_sem, *,
+                  depth_tile: int, depth_steps: int, col_tile: int, col_steps: int):
+    """Grid step (work item ``w``, column tile, depth step). The item's rows
+    are the sub-tiles ``sub_ref[w] .. sub_ref[w] + live_ref[w] - 1`` of
+    ``lhs_hbm``, its group's alone: copied to ``rows_ref`` once, at full
+    depth, while the item before it is multiplied; pushed through the MXU
+    sub-tile by sub-tile against the depth step's block of matrix
+    ``group_ref[w]``; and copied out of ``res_ref`` to their own rows of
+    ``out_hbm`` a column tile at a time, behind the next column tile's
+    products. A sub-tile past the group's last row is not copied, not
+    multiplied and not written."""
+    w, col, depth = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    subs = rows_ref.shape[1] // _SUB_TILE
+    slot = w % 2
+
+    def live(item, do):
+        for s in range(subs):
+            pl.when(s < live_ref[item])(functools.partial(do, s))
+
+    def its_rows(item, s):  # where sub-tile ``s`` of the item lies in HBM
+        return pl.ds(pl.multiple_of((sub_ref[item] + s) * _SUB_TILE, _SUB_TILE), _SUB_TILE)
+
+    def rows_copy(item, slot, s):
+        return pltpu.make_async_copy(
+            lhs_hbm.at[its_rows(item, s)],
+            rows_ref.at[slot, pl.ds(s * _SUB_TILE, _SUB_TILE)], rows_sem.at[slot, s])
+
+    def res_copy(item, col, slot, s):
+        return pltpu.make_async_copy(
+            res_ref.at[slot, pl.ds(s * _SUB_TILE, _SUB_TILE)],
+            out_hbm.at[its_rows(item, s),
+                       pl.ds(pl.multiple_of(col * col_tile, col_tile), col_tile)],
+            res_sem.at[slot, s])
+
+    @pl.when((col == 0) & (depth == 0))
+    def _rows():
+        @pl.when(w == 0)
+        def _first():
+            live(0, lambda s: rows_copy(0, 0, s).start())
+
+        @pl.when(w + 1 < count_ref[0])
+        def _next():
+            live(w + 1, lambda s: rows_copy(w + 1, 1 - slot, s).start())
+
+        live(w, lambda s: rows_copy(w, slot, s).wait())
+
+    def push(s):
+        rows = pl.ds(s * _SUB_TILE, _SUB_TILE)
+        at = pl.multiple_of(depth * depth_tile, depth_tile)
+        part = jax.lax.dot_general(
+            rows_ref[slot, rows, pl.ds(at, depth_tile)], rhs_ref[...],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+        @pl.when(depth == 0)
+        def _set():
+            acc_ref[rows, :] = part
+
+        @pl.when(depth > 0)
+        def _add():
+            acc_ref[rows, :] += part
+
+    live(w, push)
+
+    @pl.when(depth == depth_steps - 1)
+    def _store():
+        step = w * col_steps + col  # the results' two buffers take turns
+        mine = step % 2
+
+        @pl.when(step >= 2)
+        def _free():
+            before = (step - 2) // col_steps
+            live(before, lambda s: res_copy(before, 0, mine, s).wait())
+
+        def out(s):
+            rows = pl.ds(s * _SUB_TILE, _SUB_TILE)
+            res_ref[mine, rows, :] = acc_ref[rows, :].astype(res_ref.dtype)
+            res_copy(w, col, mine, s).start()
+
+        live(w, out)
+
+        @pl.when((w == count_ref[0] - 1) & (col == col_steps - 1))
+        def _drain():
+            @pl.when(step >= 1)
+            def _other():
+                before = (step - 1) // col_steps
+                live(before, lambda s: res_copy(before, 0, 1 - mine, s).wait())
+
+            live(w, lambda s: res_copy(w, 0, mine, s).wait())
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def pallas_grouped_matmul(lhs, rhs, group_sizes, interpret: bool = False):
-    """``lhs``: (rows, depth), the rows of group ``g`` together and the
-    groups in order; ``rhs``: (groups, depth, cols); ``group_sizes``:
-    (groups,) int32, their sum at most ``rows``. Returns (rows, cols) in
-    ``lhs``'s type: row ``i`` of group ``g`` is ``lhs[i] @ rhs[g]``; the rows
-    past the last group read 0."""
+    """``lhs``: (rows, depth), the rows of group ``g`` together from row
+    ``group_starts(group_sizes, rows)[g]`` on; ``rhs``: (groups, depth,
+    cols); ``group_sizes``: (groups,) int32. Returns (rows, cols) in
+    ``lhs``'s type: row ``i`` of group ``g`` is ``lhs[i] @ rhs[g]``. Of the
+    rows no group owns, one tile's (``rows <= _ROW_TILE``) read 0 and more
+    hold anything."""
     rows, depth = lhs.shape
     groups, _, cols = rhs.shape
-    row_tile = rows if rows <= _ROW_TILE else _ROW_TILE
     col_tile, depth_tile = _divisor(cols, _COL_TILE), _divisor(depth, _DEPTH_TILE)
-    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                               jnp.cumsum(group_sizes.astype(jnp.int32))])
-    group, tile, count = _work_items(offsets, rows, row_tile)
-    kernel = functools.partial(_grouped_kernel, row_tile=row_tile,
-                               depth_steps=depth // depth_tile)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(cols // col_tile, count, depth // depth_tile),
-            in_specs=[
-                pl.BlockSpec((row_tile, depth_tile),
-                             lambda n, w, k, group, tile, offsets: (tile[w], k)),
-                pl.BlockSpec((None, depth_tile, col_tile),
-                             lambda n, w, k, group, tile, offsets: (group[w], k, n)),
-            ],
-            out_specs=pl.BlockSpec(
-                (row_tile, col_tile),
-                lambda n, w, k, group, tile, offsets: (tile[w], n)),
-            scratch_shapes=[pltpu.VMEM((row_tile, col_tile), jnp.float32)],
-        ),
+    sizes = group_sizes.astype(jnp.int32)
+    group, tile, count = _work_items(sizes, rows)
+    steps = dict(depth_tile=depth_tile, depth_steps=depth // depth_tile)
+    params = dict(
         out_shape=jax.ShapeDtypeStruct((rows, cols), lhs.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=pltpu.InterpretParams() if interpret else False,
-        name="grouped_matmul",
-    )(group, tile, offsets, lhs, rhs)
-    # a row no group owns holds whatever its tile held
-    return jnp.where((jnp.arange(rows) < offsets[-1])[:, None], out, 0)
+        name="grouped_matmul")
+    if rows <= _ROW_TILE:
+        offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes)])
+        out = pl.pallas_call(
+            functools.partial(_one_tile_kernel, **steps),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(cols // col_tile, count, depth // depth_tile),
+                in_specs=[
+                    pl.BlockSpec((rows, depth), lambda n, w, k, group, offsets: (0, 0)),
+                    pl.BlockSpec((None, depth_tile, col_tile),
+                                 lambda n, w, k, group, offsets: (group[w], k, n)),
+                ],
+                out_specs=pl.BlockSpec((rows, col_tile),
+                                       lambda n, w, k, group, offsets: (0, n)),
+                scratch_shapes=[pltpu.VMEM((rows, col_tile), jnp.float32)],
+            ),
+            **params,
+        )(group, offsets, lhs, rhs)
+        # a row no group owns holds whatever its tile held
+        return jnp.where((jnp.arange(rows) < offsets[-1])[:, None], out, 0)
+    subs = _ROW_TILE // _SUB_TILE
+    sub = group_starts(sizes, rows)[group] // _SUB_TILE + tile * subs
+    live = jnp.clip(-(-(sizes[group] - tile * _ROW_TILE) // _SUB_TILE), 0, subs)
+    return pl.pallas_call(
+        functools.partial(_tiles_kernel, col_tile=col_tile, col_steps=cols // col_tile,
+                          **steps),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(count, cols // col_tile, depth // depth_tile),
+            in_specs=[
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec((None, depth_tile, col_tile),
+                             lambda w, n, k, group, sub, live, count: (group[w], k, n)),
+            ],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[
+                pltpu.VMEM((2, _ROW_TILE, depth), lhs.dtype),
+                pltpu.VMEM((_ROW_TILE, col_tile), jnp.float32),
+                pltpu.VMEM((2, _ROW_TILE, col_tile), lhs.dtype),
+                pltpu.SemaphoreType.DMA((2, subs)),
+                pltpu.SemaphoreType.DMA((2, subs)),
+            ],
+        ),
+        **params,
+    )(group, sub, live, count[None], lhs, rhs)
 
 
 @functools.partial(jax.jit, static_argnames=("body",))
@@ -227,15 +403,33 @@ def routed_experts(y, ids, weights, gate, up, down, first: int, body=None):
     local = jnp.where((flat >= first) & (flat < first + count), flat - first, count)
     order = jnp.argsort(local, stable=True)  # held assignments first, by expert
     load = jnp.zeros((count,), jnp.int32).at[local].add(1, mode="drop")
-    token_of = order // top_k
-    x = y[token_of]
     if body is None:  # the kernel where all three products fit it
         f = gate.shape[-1]
         body = max(grouped_body(rows, d, f, y.dtype), grouped_body(rows, f, d, y.dtype),
                    key=GROUPED_BODIES.index)
-    h = (jax.nn.silu(grouped_matmul(x, gate, load, body).astype(jnp.float32))
-         * grouped_matmul(x, up, load, body).astype(jnp.float32)).astype(y.dtype)
-    part = grouped_matmul(h, down, load, body).astype(jnp.float32)
+
+    def expert(x):
+        h = (jax.nn.silu(grouped_matmul(x, gate, load, body).astype(jnp.float32))
+             * grouped_matmul(x, up, load, body).astype(jnp.float32)).astype(y.dtype)
+        return grouped_matmul(h, down, load, body)
+
+    if body == "grouped_pallas" and rows > _ROW_TILE:
+        # the kernel's layout of many tiles: a group's rows from its own start
+        laid = laid_out_rows(rows, count)
+        group = local[order]
+        held = group < count
+        of = jnp.minimum(group, count - 1)
+        offsets = jnp.cumsum(load) - load
+        row = jnp.where(held, group_starts(load, laid)[of]
+                        + jnp.arange(rows, dtype=jnp.int32) - offsets[of], laid)
+        token_of = jnp.zeros((laid,), jnp.int32).at[row].set(order // top_k, mode="drop")
+        back = jnp.zeros((rows,), jnp.int32).at[order].set(row)
+        # an assignment held elsewhere has no row: it adds 0
+        part = expert(y[token_of])[jnp.minimum(back, laid - 1)].astype(jnp.float32)
+        part = jnp.where((back < laid)[:, None],
+                         part * weights.reshape(-1, 1).astype(jnp.float32), 0)
+        return part.reshape(tokens, top_k, d).sum(1), load
+    part = expert(y[order // top_k]).astype(jnp.float32)
     # an assignment held elsewhere sorts past every group: its row reads 0
     part = part * weights.reshape(-1)[order, None].astype(jnp.float32)
     # back in the assignments' own order: a token's top_k rows are adjacent

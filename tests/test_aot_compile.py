@@ -10,6 +10,7 @@ back without a chip.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -528,27 +529,44 @@ def test_both_programs_of_the_sparse_latent_cell_compile_for_v5e(one_chip, monke
     assert sizes["jit__paged_decode_impl"].temp_size_in_bytes < 64 << 20
     with open(os.path.join(bench, "configs", "dots3-note-prev.json")) as f:
         reckoning = json.load(f)["memory_reckoning"]
-    for said in (f"{arguments / 1e9:.2f} GB of arguments",
-                 f"{sizes['jit__chunk_prefill_impl'].temp_size_in_bytes / 1e9:.2f} GB of temporaries",
-                 f"{sizes['jit__paged_decode_impl'].temp_size_in_bytes / 1e9:.3f} GB in the decode"):
-        assert said in reckoning, said
+    assert f"{arguments / 1e9:.2f} GB of arguments" in reckoning
+    # the temporaries it states are PR 36's compile; the benchmark's file is not a
+    # perf PR's to edit, and the programs may need less (PR 37: the routed layer's
+    # way back writes no float32 copy of every row), never more
+    stated = re.search(r"\+ ([0-9.]+) GB of temporaries in the chunk program.* and "
+                       r"([0-9.]+) GB in the decode program", reckoning)
+    for name, most in zip(("jit__chunk_prefill_impl", "jit__paged_decode_impl"),
+                          stated.groups()):
+        assert sizes[name].temp_size_in_bytes / 1e9 < float(most) + 0.0005, name
 
 
-@pytest.mark.parametrize("rows", [2048 * 6, 16 * 6])
-def test_grouped_matmul_kernel_compiles_for_v5e(one_chip, rows):
+@pytest.mark.parametrize("rows,experts", [(2048 * 6, 40), (2048 * 8, 32), (16 * 6, 40),
+                                          (16 * 8, 32)])
+def test_grouped_matmul_kernel_compiles_for_v5e(one_chip, rows, experts):
     """The routed layer's grouped product at a chunk's and a decode step's
-    rows, both orientations of an expert's matrices."""
-    from elephas_tpu.ops.routed_experts import _pallas_fits, pallas_grouped_matmul
+    rows of both routed cells, both orientations of an expert's matrices, in
+    the layout the kernel takes for as many rows. No limit of VMEM is asked
+    for: the compiler holds the kernel to the chip's default."""
+    from elephas_tpu.ops.routed_experts import (
+        _VMEM_BUDGET,
+        _pallas_fits,
+        _vmem_bytes,
+        laid_out_rows,
+        pallas_grouped_matmul,
+    )
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     c = _LATENT
+    laid = laid_out_rows(rows, experts)
+    assert laid == (rows if rows <= 256 else rows + 128 * experts)
     for depth, cols in ((c["d"], c["f"]), (c["f"], c["d"])):
         assert _pallas_fits(rows, depth, cols, jnp.bfloat16)
+        assert _vmem_bytes(rows, depth, cols, jnp.bfloat16) <= _VMEM_BUDGET < 16 << 20
         compiled = jax.jit(pallas_grouped_matmul).lower(
-            arg((rows, depth), jnp.bfloat16), arg((c["experts"], depth, cols), jnp.bfloat16),
-            arg((c["experts"],), jnp.int32)).compile()
+            arg((laid, depth), jnp.bfloat16), arg((experts, depth, cols), jnp.bfloat16),
+            arg((experts,), jnp.int32)).compile()
         assert "tpu_custom_call" in compiled.as_text()
 
 

@@ -308,22 +308,104 @@ def test_the_routed_product_is_exact_whatever_the_routing(case):
         np.asarray(load), np.bincount(ids[held] - first, minlength=count))  # nothing dropped
 
 
-@pytest.mark.parametrize("sizes", [[100, 0, 50, 7, 0, 0, 200, 1], [0, 512, 0, 0],
-                                   [0, 0, 0, 0], [0, 0, 0, 3], [256, 256]])
-def test_the_grouped_kernel_matches_a_loop_of_products(sizes):
+_LAYOUT_CASES = {
+    # (tokens, top_k, the experts the ids are drawn from): of 12 experts, 4 .. 7 are held
+    "an expert with no token": (200, 3, [0, 4, 5, 7, 9]),
+    "every assignment on one expert": (200, 3, [6]),
+    "no assignment on a held expert": (200, 3, [0, 1, 2, 3, 8, 9]),
+    "mixed": (200, 3, list(range(12))),
+    "every assignment held": (100, 3, [4, 5, 6, 7]),
+    "a decode step's one tile": (32, 3, list(range(12))),
+}
+
+
+@pytest.mark.parametrize("case", list(_LAYOUT_CASES))
+def test_the_routed_product_is_exact_in_the_kernels_layout(case, monkeypatch):
+    """``routed_experts()`` with the kernel for its body, interpreted here:
+    over one tile's assignments (600, 300) the rows are laid out anew, a
+    group from its own start, and gathered back; 96 lie packed."""
+    import functools
+
+    monkeypatch.setattr(routed_experts, "pallas_grouped_matmul", functools.partial(
+        routed_experts.pallas_grouped_matmul, interpret=True))
+    tokens, top_k, drawn = _LAYOUT_CASES[case]
+    rng = np.random.default_rng(3)
+    d, f, first, count = 256, 128, 4, 4
+    y = rng.standard_normal((tokens, d)).astype(np.float32)
+    gate, up = (rng.standard_normal((count, d, f)).astype(np.float32) / 16 for _ in range(2))
+    down = rng.standard_normal((count, f, d)).astype(np.float32) / 16
+    weights = rng.uniform(0.1, 2.0, (tokens, top_k)).astype(np.float32)
+    ids = rng.choice(drawn, (tokens, top_k))
+    assert routed_experts._pallas_fits(tokens * top_k, d, f, jnp.float32)
+    args = [jnp.asarray(a) for a in (y, ids, weights, gate, up, down)]
+    out, load = routed_experts.routed_experts(*args, first, body="grouped_pallas")
+    np.testing.assert_allclose(np.asarray(out), _dense_routed(
+        y, ids, weights, gate, up, down, first), atol=2e-4, rtol=2e-4)
+    held = (ids >= first) & (ids < first + count)
+    np.testing.assert_array_equal(
+        np.asarray(load), np.bincount(ids[held] - first, minlength=count))  # nothing dropped
+    # the same products in the same order as the packed rows' body: the same sums
+    packed, _ = routed_experts.routed_experts(*args, first, body="grouped_xla")
+    np.testing.assert_allclose(np.asarray(out), np.asarray(packed), atol=1e-5, rtol=1e-5)
+
+
+def _laid_out(packed, sizes, fill):
+    """``packed``'s rows (group after group) in the layout the kernel takes
+    for as many, every other row ``fill``; and where each row went."""
+    rows = routed_experts.laid_out_rows(packed.shape[0], len(sizes))
+    starts = np.asarray(routed_experts.group_starts(jnp.asarray(sizes, jnp.int32), rows))
+    where = np.concatenate([starts[g] + np.arange(n) for g, n in enumerate(sizes)]
+                           + [np.zeros((0,), np.int64)]).astype(np.int64)
+    laid = np.full((rows,) + packed.shape[1:], fill, packed.dtype)
+    laid[where] = packed[:len(where)]
+    return laid, where
+
+
+_KERNEL_CASES = {
+    # (rows, sizes, depth, cols, dtype); the first five are PR 34's
+    "empty groups between": (512, [100, 0, 50, 7, 0, 0, 200, 1], 256, 128, np.float32),
+    "every row on one expert": (512, [0, 512, 0, 0], 256, 128, np.float32),
+    "no row at all": (512, [0, 0, 0, 0], 256, 128, np.float32),
+    "three rows on the last expert": (512, [0, 0, 0, 3], 256, 128, np.float32),
+    "two groups of a tile each": (512, [256, 256], 256, 128, np.float32),
+    "a group over several row tiles": (1024, [3, 700, 0, 130], 2048, 1536, np.float32),
+    "groups smaller than a sub-tile": (512, [5, 17, 100, 1, 0, 90, 127, 2], 256, 1024,
+                                       np.float32),
+    "a group that ends on a tile's edge": (1024, [256, 128, 512, 1], 256, 128, np.float32),
+    "every row held, none to spare": (512, [129, 129, 129, 125], 256, 128, np.float32),
+    "a decode step's tile of 96 rows": (96, [5, 0, 31, 1, 0, 40, 0, 6], 2048, 1024,
+                                        np.float32),
+    "a decode step's tile of 128 rows": (128, [0, 128, 0, 0], 256, 128, np.float32),
+    "bfloat16 over several tiles": (768, [300, 0, 20, 256, 1], 2048, 512, jnp.bfloat16),
+    "bfloat16 in one tile": (128, [64, 0, 3, 50], 256, 128, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", list(_KERNEL_CASES))
+def test_the_grouped_kernel_matches_a_loop_of_products(case):
+    rows, sizes, depth, cols, dtype = _KERNEL_CASES[case]
     rng = np.random.default_rng(2)
-    lhs = rng.standard_normal((512, 256)).astype(np.float32)
-    rhs = rng.standard_normal((len(sizes), 256, 128)).astype(np.float32)
-    want, at = np.zeros((512, 128), np.float32), 0
+    lhs = np.asarray(jnp.asarray(rng.standard_normal((rows, depth)), dtype))
+    rhs = np.asarray(jnp.asarray(rng.standard_normal((len(sizes), depth, cols)), dtype))
+    want, at = np.zeros((rows, cols), np.float32), 0
     for g, n in enumerate(sizes):
-        want[at:at + n] = lhs[at:at + n] @ rhs[g]
+        want[at:at + n] = lhs[at:at + n].astype(np.float32) @ rhs[g].astype(np.float32)
         at += n
-    for got in (routed_experts.pallas_grouped_matmul(
-                    jnp.asarray(lhs), jnp.asarray(rhs), jnp.asarray(sizes, jnp.int32),
-                    interpret=True),
-                routed_experts.grouped_matmul(
-                    jnp.asarray(lhs), jnp.asarray(rhs), jnp.asarray(sizes, jnp.int32))):
-        np.testing.assert_allclose(np.asarray(got), want, atol=2e-4, rtol=2e-4)
+    close = dict(atol=2e-4 * depth ** 0.5, rtol=2e-4) if dtype is np.float32 else \
+        dict(atol=0.02 * depth ** 0.5, rtol=0.02)
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    # the kernel's layout; a row no group owns must reach no row that one does
+    laid, where = _laid_out(lhs, sizes, np.nan)
+    got = np.asarray(routed_experts.pallas_grouped_matmul(
+        jnp.asarray(laid), jnp.asarray(rhs), group_sizes, interpret=True), np.float32)
+    np.testing.assert_allclose(got[where], want[:at], **close)
+    if rows <= routed_experts._ROW_TILE:  # one tile lies packed, and its other rows read 0
+        np.testing.assert_array_equal(where, np.arange(at))
+        assert not got[at:].any()
+    else:
+        assert (where % routed_experts._SUB_TILE == 0).sum() >= np.count_nonzero(sizes)
+    xla = routed_experts.grouped_matmul(jnp.asarray(lhs), jnp.asarray(rhs), group_sizes)
+    np.testing.assert_allclose(np.asarray(xla, np.float32), want, **close)
 
 
 # -- (f) rotary ----------------------------------------------------------------
@@ -716,6 +798,50 @@ def test_reference_agrees_with_the_transformers_port(family, reference):
         theirs = model(torch.tensor(tokens)).logits.numpy()
     ours = _reference_logits(reference, family, tokens, np.tile(np.arange(17), (2, 1)), sizes)
     np.testing.assert_allclose(np.asarray(ours), theirs, atol=1e-4, rtol=1e-4)
+
+
+def test_weight_passes_are_the_kernels_work_items(served):
+    """``moe_weight_passes``: a group within a row tile is one pass over its
+    expert's matrices, a larger one as many as it has tiles; on the ``step``
+    event and the chunk's span it is the layers' sum."""
+    from elephas_tpu.obs import Tracer
+
+    # (a) the layer alone: every token the same, so two experts hold 300 rows each
+    layer = latent_moe.RoutedExperts(
+        n_routed_experts=8, experts_held=(0, 8), moe_d_ff=32, top_k=2, n_group=4,
+        topk_group=2, routed_scaling_factor=1.0)
+    one = jax.random.normal(jax.random.PRNGKey(0), (1, 64))
+    for tokens, passes in ((300, 4), (256, 2), (128, 2), (129, 2)):
+        y = jnp.tile(one, (tokens, 1))
+        params = layer.init(jax.random.PRNGKey(1), y)["params"]
+        sown = layer.apply({"params": params}, y, mutable=["counters"])[1]["counters"]
+        assert float(sown["moe_experts_touched"][0]) == 2
+        assert float(sown["moe_weight_passes"][0]) == passes, tokens
+    sizes = jnp.asarray([0, 1, 256, 257, 700], jnp.int32)
+    assert int(routed_experts.weight_passes(sizes, 4096)) == 0 + 1 + 1 + 2 + 3
+    assert int(routed_experts.weight_passes(sizes[:3], 256)) == 2  # one tile: the touched
+    group, tile, count = routed_experts._work_items(sizes, 4096)
+    assert int(count) == 7
+    assert np.asarray(group)[:7].tolist() == [1, 2, 3, 3, 4, 4, 4]
+    assert np.asarray(tile)[:7].tolist() == [0, 0, 0, 1, 0, 1, 2]
+
+    # (b) through the engine, chunks and steps of a tile at most: the touched experts
+    _, _, compiled = served
+
+    class Sink:
+        rows = []
+
+        def log(self, step, **fields):
+            self.rows.append(fields)
+
+    tracer = Tracer(annotate_device=False)
+    eng = _engine(compiled, sink=Sink(), tracer=tracer)
+    _serve(eng, _prompts([13, 19, 9]))
+    steps = [r for r in Sink.rows if r.get("event") == "step" and "moe_weight_passes" in r]
+    chunks = [e.args for e in tracer.events() if e.name == "step/prefill_chunk"]
+    assert steps and len(chunks) == 2 + 3 + 2
+    for counted in steps + chunks:
+        assert counted["moe_weight_passes"] == counted["moe_experts_touched"] > 0
 
 
 def test_a_chunks_span_carries_its_routing_counters(served):

@@ -529,12 +529,7 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(family, reference):
 # -- (g) the first generation lowers as it did --------------------------------
 
 
-def test_deepseek_v2_lowers_to_the_parents_two_programs():
-    """The family's first configuration is the case of no window layer, no
-    indexer, no gate, no rescale and softmax scoring: no new operand reaches
-    its two programs. The digests are of `str(jax.make_jaxpr(...))` of the
-    small engine's chunk and decode programs on the CPU, taken on the parent
-    commit (6f0661e) with this installation's JAX."""
+def _program_digests():
     import test_latent_moe as first
 
     family = first._bench_module("models", "deepseek_v2")
@@ -547,12 +542,36 @@ def test_deepseek_v2_lowers_to_the_parents_two_programs():
     decode = jax.make_jaxpr(eng._paged_decode_impl)(
         params, cache, table, lanes, lanes, jnp.zeros((3,), bool), jnp.ones((3,), bool),
         lanes, rng)
-    digests = [hashlib.sha256(str(j).encode()).hexdigest() for j in (chunk, decode)]
-    assert digests == [
-        "2e1ff27e407d11c5d551576d9b70f33bd2b6de6ce5a06ea3d77c6b5381f11607",
-        "1632385df362187c13b6c0b86924723fd1e1fc92a77cf158c987877d8aa4d2dc"]
     assert not any(name in str(chunk) + str(decode) for name in (
         "index_scores", "select_columns", "cached_index_key"))
+    return [hashlib.sha256(str(j).encode()).hexdigest() for j in (chunk, decode)]
+
+
+def test_deepseek_v2_lowers_to_the_parents_two_programs(monkeypatch):
+    """The family's first configuration is the case of no window layer, no
+    indexer, no gate, no rescale and softmax scoring: no new operand reaches
+    its two programs. The digests are of `str(jax.make_jaxpr(...))` of the
+    small engine's chunk and decode programs on the CPU with this
+    installation's JAX. The second pair was taken on PR 36's parent
+    (6f0661e) and holds with the one counter PR 37 sows left out
+    (`moe_weight_passes`, a sum over `load` and one more entry of the
+    counters' vector): the rows of the CPU's body lie as they lay. The first
+    pair is PR 37's, with the counter."""
+    from flax import linen as nn
+
+    from elephas_tpu.models import latent_moe
+    from elephas_tpu.ops import routed_experts
+
+    assert _program_digests() == [
+        "6ea61899e6ff120e304d48839845ef5c75e975f4fddab4c9504763da365da394",
+        "f7dbe5af01b873e2c7ed490d2dd93f90bfaf5505dc1d47e261850268343a2b28"]
+    monkeypatch.setattr(routed_experts, "weight_passes", lambda load, rows: 0.0)
+    monkeypatch.setattr(
+        latent_moe.RoutedExperts, "sow", lambda self, col, name, value, **kw:
+        name != "moe_weight_passes" and nn.Module.sow(self, col, name, value, **kw))
+    assert _program_digests() == [
+        "2e1ff27e407d11c5d551576d9b70f33bd2b6de6ce5a06ea3d77c6b5381f11607",
+        "1632385df362187c13b6c0b86924723fd1e1fc92a77cf158c987877d8aa4d2dc"]
 
 
 # -- (h) the counters ride the step's one fetch and the chunk's span ----------
