@@ -167,6 +167,10 @@ class SyncTrainer:
         # Monotonic stamp of each epoch's end (metrics on the host, before
         # validation and callbacks), filled by every ``fit``.
         self.epoch_end_times: List[float] = []
+        # ``program_report``'s: the epoch program's argument shapes as a
+        # traced ``fit`` last saw them, and the report built from shapes
+        self._epoch_shapes = None
+        self._program_report = None
 
     # -- observability ---------------------------------------------------------
 
@@ -183,7 +187,7 @@ class SyncTrainer:
         if self.ops is not None:
             return self.ops
         from elephas_tpu import obs
-        from elephas_tpu.obs.devprof import record_device_memory
+        from elephas_tpu.obs.devprof import DeviceProfiler, record_device_memory
         from elephas_tpu.obs.history import HistorySampler
         from elephas_tpu.obs.opsd import OpsServer
 
@@ -211,6 +215,8 @@ class SyncTrainer:
             },
             incidents_fn=(self.store.doc if self.store is not None
                           else None),
+            # ``/profile?action=stop`` answers the capture by model part
+            profiler=DeviceProfiler(reports=lambda: [self.program_report()]),
         ).start()
         return self.ops
 
@@ -229,6 +235,33 @@ class SyncTrainer:
             self.store = None
 
     # -- compiled bodies -------------------------------------------------------
+
+    def program_report(self, state=None, xs=None, ys=None):
+        """``obs.programs.ProgramReport`` of the epoch program
+        (``jit_epoch_fn``): which part of the step (``forward``,
+        ``backward``, ``update`` and the model's own scopes inside them)
+        issued each of its instructions. The SAME jitted function is
+        lowered over the shapes of ``state``, ``xs`` and ``ys`` as ``fit``
+        hands them to it (arrays or ``jax.ShapeDtypeStruct``s; left out:
+        those of the last ``fit`` that ran under an enabled tracer) and
+        compiled once more, a load where the persistent compile cache is
+        on. Built when asked for and kept until the shapes change."""
+        from elephas_tpu.obs.programs import ProgramReport, shapes_of
+
+        given = (state, xs, ys)
+        if any(a is None for a in given):
+            if self._epoch_shapes is None:
+                raise ValueError(
+                    "program_report() needs state, xs and ys, or a fit under "
+                    "obs.enable_tracing() before it")
+            shapes = self._epoch_shapes
+        else:
+            shapes = shapes_of(given)
+        if self._program_report is None or self._program_report[0] != shapes:
+            epoch = jax.ShapeDtypeStruct((), jnp.int32)
+            self._program_report = (shapes, ProgramReport.from_compiled(
+                self._epoch_fn.lower(*shapes, epoch).compile()))
+        return self._program_report[1]
 
     def _local_shuffle(self, rng, xs, ys):
         """Per-shard reshuffle of local rows across batches (the reference's
@@ -377,6 +410,9 @@ class SyncTrainer:
                 # upload's own time; untraced, the transfer overlaps the
                 # first epoch's trace and lowering as it always did.
                 jax.block_until_ready((xs, ys))
+                from elephas_tpu.obs.programs import shapes_of
+
+                self._epoch_shapes = shapes_of((state, xs, ys))
 
         if self.autotune and self.autotune_choice is None:
             if self.frequency == _PER_FIT:

@@ -128,9 +128,10 @@ class MambaMixer(nn.Module):
         dt = RMSNorm(self.eps, name="dt_norm")(dbc[..., :self.dt_rank])
         B = RMSNorm(self.eps, name="b_norm")(dbc[..., self.dt_rank:self.dt_rank + n])
         C = RMSNorm(self.eps, name="c_norm")(dbc[..., self.dt_rank + n:])
-        delta = nn.softplus(nn.Dense(d, dtype=self.dtype, name="dt_proj")(
-            dt.astype(self.dtype)).astype(jnp.float32))
-        A = -jnp.exp(a_log.astype(jnp.float32))
+        dt = nn.Dense(d, dtype=self.dtype, name="dt_proj")(dt.astype(self.dtype))
+        with jax.named_scope("softplus"):
+            delta = nn.softplus(dt.astype(jnp.float32))
+            A = -jnp.exp(a_log.astype(jnp.float32))
 
         if carried and T == 1 and valid is None:
             y, h = selective_scan_step(u[:, 0], delta[:, 0], A, B[:, 0], C[:, 0],
@@ -142,14 +143,16 @@ class MambaMixer(nn.Module):
             body = scan_body(b, T, d, n) if self.decode else "scan_xla"
             y, h = selective_scan(u, delta, A, B, C, skip, h0, valid, body=body)
         if carried:
-            # the convolution's state: the last inputs ending at valid - 1
-            at = jnp.full((b,), T, jnp.int32) if valid is None else valid
-            taps_in = _rows(xs, at, taps - 1).astype(self.dtype)
-            if active is not None:
-                taps_in = jnp.where(active[:, None, None], taps_in, history)
-                h = jnp.where(active[:, None, None], h, h0)
-            conv_state.value, ssm_state.value = taps_in, h
-        gated = (y * nn.silu(z.astype(jnp.float32))).astype(self.dtype)
+            with jax.named_scope("state_write"):
+                # the convolution's state: the last inputs ending at valid - 1
+                at = jnp.full((b,), T, jnp.int32) if valid is None else valid
+                taps_in = _rows(xs, at, taps - 1).astype(self.dtype)
+                if active is not None:
+                    taps_in = jnp.where(active[:, None, None], taps_in, history)
+                    h = jnp.where(active[:, None, None], h, h0)
+                conv_state.value, ssm_state.value = taps_in, h
+        with jax.named_scope("gate"):
+            gated = (y * nn.silu(z.astype(jnp.float32))).astype(self.dtype)
         return nn.Dense(x.shape[-1], use_bias=False, dtype=self.dtype,
                         name="out_proj")(gated)
 

@@ -209,8 +209,9 @@ class LatentAttention(nn.Module):
         else:  # a full sequence, or the pass that shapes the cache
             start = jnp.zeros((b,), jnp.int32)
         positions = start[:, None] + jnp.arange(T)[None, :]
-        q_pe = rotate(q[..., nope:], positions, self.rope)
-        k_pe = rotate(kv[..., rank:], positions, self.rope)
+        with jax.named_scope("rope"):
+            q_pe = rotate(q[..., nope:], positions, self.rope)
+            k_pe = rotate(kv[..., rank:], positions, self.rope)
 
         index = None
         if self.indexer is not None:
@@ -243,9 +244,10 @@ class LatentAttention(nn.Module):
                     "a latent cache is attended through the serving pool's "
                     "block table only: there is no contiguous row of keys "
                     "and values a head to gather")
-            q_all = jnp.concatenate(
-                [q[..., :nope].astype(jnp.float32), q_pe], -1).astype(self.dtype)
-            latent = jnp.concatenate([c_kv, k_pe], -1).astype(self.dtype)[:, None]
+            with jax.named_scope("assemble"):  # a query's and a cache row's halves
+                q_all = jnp.concatenate(
+                    [q[..., :nope].astype(jnp.float32), q_pe], -1).astype(self.dtype)
+                latent = jnp.concatenate([c_kv, k_pe], -1).astype(self.dtype)[:, None]
             out = attend_paged(jnp.moveaxis(q_all, 2, 1), latent, None,
                                cached_latent, None, cache_index, active, paged,
                                scale=scale, kv_b=kv_b, window=self.window,
@@ -280,8 +282,9 @@ class LatentAttention(nn.Module):
                              expanded[..., nope:])
         if self.head_gate:  # one gate a head, from the layer's normed input
             gate = nn.Dense(heads, use_bias=False, dtype=self.dtype, name="gate")(x)
-            out = out.astype(jnp.float32) * jax.nn.sigmoid(
-                gate.astype(jnp.float32))[..., None]
+            with jax.named_scope("head_gate"):
+                out = out.astype(jnp.float32) * jax.nn.sigmoid(
+                    gate.astype(jnp.float32))[..., None]
         return nn.DenseGeneral(d_model, axis=(-2, -1), use_bias=False,
                                dtype=self.dtype, name="out")(out.astype(self.dtype))
 
@@ -340,22 +343,21 @@ class RoutedExperts(nn.Module):
         down = self.param("down", init, (count, f, d)).astype(self.dtype)
         logits = nn.Dense(self.n_routed_experts, use_bias=False, dtype=jnp.float32,
                           name="router")(y.astype(jnp.float32))
-        if self.scoring == "softmax":
-            score = nn.softmax(logits, -1)
-        elif self.scoring == "sigmoid":
-            score = jax.nn.sigmoid(logits)
-        else:
+        if self.scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown router scoring {self.scoring!r}")
-        by = None
-        if self.selection_bias:  # chooses the experts, weighs none
-            by = score + self.param("bias", nn.initializers.zeros,
-                                    (self.n_routed_experts,)).astype(jnp.float32)
-        ids, p = group_limited_top_k(score, self.n_group, self.topk_group,
-                                     self.top_k, by=by)
-        if self.norm_topk_prob:
-            p = p / p.sum(-1, keepdims=True)
-        if live is not None:  # an id past every expert is held nowhere
-            ids = jnp.where(live[:, None], ids, self.n_routed_experts)
+        with jax.named_scope("route"):
+            score = nn.softmax(logits, -1) if self.scoring == "softmax" \
+                else jax.nn.sigmoid(logits)
+            by = None
+            if self.selection_bias:  # chooses the experts, weighs none
+                by = score + self.param("bias", nn.initializers.zeros,
+                                        (self.n_routed_experts,)).astype(jnp.float32)
+            ids, p = group_limited_top_k(score, self.n_group, self.topk_group,
+                                         self.top_k, by=by)
+            if self.norm_topk_prob:
+                p = p / p.sum(-1, keepdims=True)
+            if live is not None:  # an id past every expert is held nowhere
+                ids = jnp.where(live[:, None], ids, self.n_routed_experts)
         out, load = routed_experts(y, ids, p * self.routed_scaling_factor,
                                    gate, up, down, first)
         tokens = y.shape[0] if live is None else live.sum()

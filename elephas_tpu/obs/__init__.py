@@ -76,8 +76,10 @@ from elephas_tpu.obs.history import (  # noqa: F401
 from elephas_tpu.obs.devprof import (  # noqa: F401
     DeviceProfiler,
     device_memory_snapshot,
+    device_seconds_by_part,
     record_device_memory,
 )
+from elephas_tpu.obs.programs import ProgramReport  # noqa: F401
 from elephas_tpu.obs.fleet import (  # noqa: F401
     FleetAggregator,
     ProcessRegistry,

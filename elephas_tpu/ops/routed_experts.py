@@ -399,39 +399,53 @@ def routed_experts(y, ids, weights, gate, up, down, first: int, body=None):
     top_k = ids.shape[1]
     count = gate.shape[0]
     rows = tokens * top_k
-    flat = ids.reshape(-1)
-    local = jnp.where((flat >= first) & (flat < first + count), flat - first, count)
-    order = jnp.argsort(local, stable=True)  # held assignments first, by expert
-    load = jnp.zeros((count,), jnp.int32).at[local].add(1, mode="drop")
+    with jax.named_scope("sort_rows"):
+        flat = ids.reshape(-1)
+        local = jnp.where((flat >= first) & (flat < first + count), flat - first, count)
+        order = jnp.argsort(local, stable=True)  # held assignments first, by expert
+        load = jnp.zeros((count,), jnp.int32).at[local].add(1, mode="drop")
     if body is None:  # the kernel where all three products fit it
         f = gate.shape[-1]
         body = max(grouped_body(rows, d, f, y.dtype), grouped_body(rows, f, d, y.dtype),
                    key=GROUPED_BODIES.index)
 
     def expert(x):
-        h = (jax.nn.silu(grouped_matmul(x, gate, load, body).astype(jnp.float32))
-             * grouped_matmul(x, up, load, body).astype(jnp.float32)).astype(y.dtype)
+        g = grouped_matmul(x, gate, load, body)
+        with jax.named_scope("silu_mul"):
+            g = jax.nn.silu(g.astype(jnp.float32))
+        u = grouped_matmul(x, up, load, body)
+        with jax.named_scope("silu_mul"):
+            h = (g * u.astype(jnp.float32)).astype(y.dtype)
         return grouped_matmul(h, down, load, body)
 
     if body == "grouped_pallas" and rows > _ROW_TILE:
         # the kernel's layout of many tiles: a group's rows from its own start
-        laid = laid_out_rows(rows, count)
-        group = local[order]
-        held = group < count
-        of = jnp.minimum(group, count - 1)
-        offsets = jnp.cumsum(load) - load
-        row = jnp.where(held, group_starts(load, laid)[of]
-                        + jnp.arange(rows, dtype=jnp.int32) - offsets[of], laid)
-        token_of = jnp.zeros((laid,), jnp.int32).at[row].set(order // top_k, mode="drop")
-        back = jnp.zeros((rows,), jnp.int32).at[order].set(row)
-        # an assignment held elsewhere has no row: it adds 0
-        part = expert(y[token_of])[jnp.minimum(back, laid - 1)].astype(jnp.float32)
-        part = jnp.where((back < laid)[:, None],
-                         part * weights.reshape(-1, 1).astype(jnp.float32), 0)
-        return part.reshape(tokens, top_k, d).sum(1), load
-    part = expert(y[order // top_k]).astype(jnp.float32)
-    # an assignment held elsewhere sorts past every group: its row reads 0
-    part = part * weights.reshape(-1)[order, None].astype(jnp.float32)
-    # back in the assignments' own order: a token's top_k rows are adjacent
-    back = jnp.zeros((rows,), jnp.int32).at[order].set(jnp.arange(rows, dtype=jnp.int32))
-    return part[back].reshape(tokens, top_k, d).sum(1), load
+        with jax.named_scope("gather_rows"):
+            laid = laid_out_rows(rows, count)
+            group = local[order]
+            held = group < count
+            of = jnp.minimum(group, count - 1)
+            offsets = jnp.cumsum(load) - load
+            row = jnp.where(held, group_starts(load, laid)[of]
+                            + jnp.arange(rows, dtype=jnp.int32) - offsets[of], laid)
+            token_of = jnp.zeros((laid,), jnp.int32).at[row].set(order // top_k,
+                                                                 mode="drop")
+            back = jnp.zeros((rows,), jnp.int32).at[order].set(row)
+            x = y[token_of]
+        out = expert(x)
+        with jax.named_scope("combine_rows"):
+            # an assignment held elsewhere has no row: it adds 0
+            part = out[jnp.minimum(back, laid - 1)].astype(jnp.float32)
+            part = jnp.where((back < laid)[:, None],
+                             part * weights.reshape(-1, 1).astype(jnp.float32), 0)
+            return part.reshape(tokens, top_k, d).sum(1), load
+    with jax.named_scope("gather_rows"):
+        x = y[order // top_k]
+    out = expert(x)
+    with jax.named_scope("combine_rows"):
+        # an assignment held elsewhere sorts past every group: its row reads 0
+        part = out.astype(jnp.float32) * weights.reshape(-1)[order, None].astype(jnp.float32)
+        # back in the assignments' own order: a token's top_k rows are adjacent
+        back = jnp.zeros((rows,), jnp.int32).at[order].set(
+            jnp.arange(rows, dtype=jnp.int32))
+        return part[back].reshape(tokens, top_k, d).sum(1), load

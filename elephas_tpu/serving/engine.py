@@ -368,6 +368,10 @@ class InferenceEngine:
             self._paged_decode_impl, donate_argnums=(1,),
             in_shardings=dec_in, out_shardings=dec_out,
         )
+        # ``program_report``'s, built when asked for and kept: new programs,
+        # new reports
+        self._program_reports: Dict[str, Any] = {}
+        self._report_lowering = threading.local()
 
     # -- compiled bodies ---------------------------------------------------
 
@@ -400,10 +404,11 @@ class InferenceEngine:
         # and the obs hook makes a surprise retrace (a silent 10×
         # regression if it happened per request) a visible counter +
         # trace marker.
-        self._prefill_traces += 1
-        from elephas_tpu.utils.compiler import note_retrace
+        if not self._lowering_a_report():
+            self._prefill_traces += 1
+            from elephas_tpu.utils.compiler import note_retrace
 
-        note_retrace("serving_prefill", count=self._prefill_traces)
+            note_retrace("serving_prefill", count=self._prefill_traces)
         from elephas_tpu.models.transformer import sample_tokens_at
 
         row = jax.lax.dynamic_index_in_dim(table, slot, axis=0)
@@ -476,10 +481,11 @@ class InferenceEngine:
         physical block and attends through the table
         (``ops.attention.paged_decode_attention``); no per-slot
         contiguous cache is built and nothing else of the pool moves."""
-        self._decode_traces += 1
-        from elephas_tpu.utils.compiler import note_retrace
+        if not self._lowering_a_report():
+            self._decode_traces += 1
+            from elephas_tpu.utils.compiler import note_retrace
 
-        note_retrace("serving_decode", count=self._decode_traces)
+            note_retrace("serving_decode", count=self._decode_traces)
         from elephas_tpu.models.transformer import sample_tokens_at
 
         # Pre-advance write column per lane (every layer advances in
@@ -520,6 +526,67 @@ class InferenceEngine:
         self._counter_names = tuple(sorted(totals))
         return (jnp.stack([totals[n] for n in self._counter_names]).astype(
             jnp.float32),)
+
+    # -- what the two programs are made of ----------------------------------
+
+    _PROGRAMS = {"prefill": "_jit_prefill", "decode": "_jit_decode"}
+
+    def _lowering_a_report(self) -> bool:
+        """True on the thread that is lowering a program again for
+        ``program_report``: the bodies run, and it is no retrace."""
+        return getattr(self._report_lowering, "on", False)
+
+    def program_args(self, which: str, sharding=None) -> tuple:
+        """The abstract arguments (shapes, types, placement) with which the
+        live engine calls its ``"prefill"`` or ``"decode"`` program. With
+        ``sharding``, every one placed there instead: a described chip's,
+        to compile for it without the chip."""
+        if which not in self._PROGRAMS:
+            raise ValueError(f"program is one of {sorted(self._PROGRAMS)}, got {which!r}")
+
+        from elephas_tpu.obs.programs import shapes_of
+
+        def arg(shape, dtype, placed=None):
+            return jax.ShapeDtypeStruct(
+                shape, dtype, sharding=placed if sharding is None else sharding)
+
+        with self._step_lock:  # between two steps the pool is whole
+            params, cache, rng, pad = shapes_of(
+                (self.params, self.pool.cache, self._rng, self.pool.pad), sharding)
+        table = arg(self.pool.table.rows.shape, jnp.int32, self.pool.table.sharding)
+        S = self.pool.max_slots
+        if which == "prefill":
+            return (params, cache, table, arg((1, self.prefill_chunk), jnp.int32),
+                    arg((), jnp.int32), arg((), jnp.int32), arg((), jnp.int32), rng)
+        # ``prev_tokens`` is the previous step's output, as the pool's leaves
+        # are: committed where they are (``_decode`` places it on a mesh)
+        placed = jax.tree_util.tree_leaves(cache)[0].sharding if self.mesh is None \
+            else NamedSharding(self.mesh, P())
+        return (params, cache, table, arg((S,), jnp.int32, placed),
+                arg((S,), jnp.int32), arg((S,), jnp.bool_), arg((S,), jnp.bool_),
+                pad, rng)
+
+    def program_report(self, which: str):
+        """``obs.programs.ProgramReport`` of the ``"prefill"`` or
+        ``"decode"`` program: which model part issued each of its
+        instructions, and what its copies move. Built on first use and
+        kept. The SAME jitted function is lowered with
+        ``program_args(which)`` and compiled once more — a load where the
+        persistent compile cache is on, a compile of the program's own
+        length where it is not. Lowering runs the program's Python body
+        again; that is no retrace, and ``prefill_traces``,
+        ``decode_traces`` and ``retrace_total`` stay what they were."""
+        from elephas_tpu.obs.programs import ProgramReport
+
+        if which not in self._program_reports:
+            args = self.program_args(which)
+            self._report_lowering.on = True
+            try:
+                lowered = getattr(self, self._PROGRAMS[which]).lower(*args)
+            finally:
+                self._report_lowering.on = False
+            self._program_reports[which] = ProgramReport.from_compiled(lowered.compile())
+        return self._program_reports[which]
 
     def _kv_layout(self):
         """The pool's own K/V leaf and the head width its rows pack: the
@@ -808,6 +875,8 @@ class InferenceEngine:
         requeue-on-death replays unchanged. The request also roots (or
         adopts) a trace context here: the scheduler re-activates it at
         finish so histogram exemplars latch THIS request's trace id."""
+        # what the calling thread spends here is a span on its own track
+        t_in = self.clock() if self.tracer.enabled else None
         prompt = [int(t) for t in prompt]  # host-ok: caller-supplied ints
         if not 1 <= len(prompt) <= self.max_prompt_len:
             raise ValueError(
@@ -855,6 +924,8 @@ class InferenceEngine:
             req_id=req.req_id, prompt_tokens=len(prompt),
             tenant=tenant or obs.DEFAULT_TENANT,
         )
+        if t_in is not None:
+            self.tracer.record("submit", t_in, self.clock(), req_id=req.req_id)
         return req.req_id
 
     def submit_with_retry(self, prompt, **kwargs) -> int:
@@ -983,6 +1054,10 @@ class InferenceEngine:
         never evaluated into the real-traffic SLO ledger."""
         if not finished:
             return
+        # ``step/publish``: what follows a scheduler step on the thread that
+        # stepped, where it finished a request: the results made claimable,
+        # the goodput ledger's windows read again for each of them
+        t_in = self.clock() if self.tracer.enabled else None
         with self._cond:
             # Preempted results are deferrals, not failures: the router
             # redispatches them under fair-share, and only the eventual
@@ -999,6 +1074,10 @@ class InferenceEngine:
             # Same canary-blindness as the fleet ledger: per-tenant
             # goodput/burn must agree with the aggregate SLO view.
             self.costs.record_goodput(r)
+        if t_in is not None:
+            self.tracer.record("step/publish", t_in, self.clock(),
+                               parent_id=self.scheduler._step_id,
+                               finished=len(finished))
 
     def halt(self) -> None:
         """Simulate process death for chaos harnesses: after any
@@ -1159,7 +1238,9 @@ class InferenceEngine:
         ``/load`` (EWMA load score), ``/slo`` (windowed goodput +
         burn), ``/canary`` (blackbox probe SLIs when a driver is
         attached), ``/tenants`` (per-tenant cost ledger + burn/KV-share
-        alerts). Loopback-bound by default; port 0 picks a free one
+        alerts), and ``/profile``, whose ``?action=stop`` answers the
+        capture reduced by model part (``by_part``) against this engine's
+        two ``program_report``s. Loopback-bound by default; port 0 picks a free one
         (read ``engine.ops.port``). Idempotent.
 
         ``store_dir`` additionally mounts the durable telemetry journal
@@ -1170,7 +1251,7 @@ class InferenceEngine:
         if self.ops is not None:
             return self.ops
         from elephas_tpu import obs
-        from elephas_tpu.obs.devprof import record_device_memory
+        from elephas_tpu.obs.devprof import DeviceProfiler, record_device_memory
         from elephas_tpu.obs.opsd import OpsServer
 
         if getattr(self, "_alert_engine", None) is None:
@@ -1208,6 +1289,10 @@ class InferenceEngine:
             tenants_fn=self._tenants_doc,
             incidents_fn=(self.store.doc if self.store is not None
                           else None),
+            # ``/profile?action=stop`` answers the capture by model part: the
+            # two reports are built then, on first use, never before
+            profiler=DeviceProfiler(reports=lambda: [
+                self.program_report("prefill"), self.program_report("decode")]),
         ).start()
         return self.ops
 

@@ -321,8 +321,10 @@ class ContinuousBatchingScheduler:
         # One step's accounting, zeroed at the top of ``step()``: host
         # seconds by phase, the prompt work dispatched, the columns each
         # decoding lane held — what the ``step`` event carries whether or
-        # not a tracer is on. ``_step_id`` parents the ``step/*`` spans.
+        # not a tracer is on. ``_step_id`` parents the ``step/*`` spans,
+        # ``_prefill_id`` (``step/prefill``) the chunks and syncs among them.
         self._step_id: Optional[str] = None
+        self._prefill_id: Optional[str] = None
         self._harvest_wait_s = self._admit_s = 0.0
         self._prefill_s = self._dispatch_s = 0.0
         self._prefill_tokens = self._prefill_chunks = 0
@@ -569,12 +571,12 @@ class ContinuousBatchingScheduler:
             if counted is not None:
                 # the span carries the chunk's counters, and they ride the
                 # prompt's one fetch: it is recorded at finalize
-                pf.counted.append((t0, self.clock(), self._step_id, start,
+                pf.counted.append((t0, self.clock(), self._prefill_id, start,
                                    valid, counted))
             else:
                 self.tracer.record(
                     "step/prefill_chunk", t0, self.clock(),
-                    parent_id=self._step_id, slot=pf.slot, start=start,
+                    parent_id=self._prefill_id, slot=pf.slot, start=start,
                     valid=valid,
                 )
         if pf.next_col >= plen:
@@ -600,7 +602,7 @@ class ContinuousBatchingScheduler:
             first = host_sync.fetch_scalar(pf.first_dev)
         t_pre1 = self.clock()
         self.tracer.record("step/prefill_sync", t_sync0, t_pre1,
-                           parent_id=self._step_id, slot=pf.slot)
+                           parent_id=self._prefill_id, slot=pf.slot)
         del self._prefilling[pf.slot]
         self.pool.commit_prefix(pf.slot, req.prompt)
         self.pool.admitted_total += 1
@@ -1038,11 +1040,19 @@ class ContinuousBatchingScheduler:
         t1 = self.clock()
         self._admit_from_queue()
         t2 = self.clock()
+        # ``step/prefill`` covers what of ``_advance_prefills`` its chunks
+        # and syncs do not: they are its children by id
+        traced = self.tracer.enabled and bool(self._prefilling)
+        self._prefill_id = obs.new_span_id() if traced else None
         self._advance_prefills()
+        t3 = self.clock()
         self._admit_s += t2 - t1
-        self._prefill_s += self.clock() - t2
+        self._prefill_s += t3 - t2
         self.tracer.record("step/evict", t0, t1, parent_id=self._step_id)
         self.tracer.record("step/admit", t1, t2, parent_id=self._step_id)
+        if traced:
+            self.tracer.record("step/prefill", t2, t3, span_id=self._prefill_id,
+                               parent_id=self._step_id)
 
     def _step_pipelined(self) -> int:
         """Dispatch N+1, then do ALL host work overlapped with it."""
@@ -1141,6 +1151,9 @@ class ContinuousBatchingScheduler:
                     and self.spec_decode_fn is not None else None
                 ),
             )
+        if self.tracer.enabled:  # what follows the step's close, by its id
+            self.tracer.record("step/record", t1, self.clock(),
+                               parent_id=self._step_id)
         return self._results[before:]
 
     def run_until_drained(self, max_steps: int = 100_000) -> None:

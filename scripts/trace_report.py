@@ -29,13 +29,25 @@ trips) vs lock (PS apply under the buffer lock) vs train — with the
 straggler unit first, plus a replay-stable digest over the set of
 completed units (seeded ``FaultPlan`` chaos runs reproduce it).
 
+Device mode (``--xplane PATH --reports FILE...``) reads a profiler
+capture (an ``.xplane.pb``, or the directory it lies under) made anywhere
+— ``/profile?action=start|stop`` on a live engine, ``jax.profiler`` by
+hand — and prints its device time by model part: every operation joined,
+by its instruction's name, to the ``ProgramReport`` of the program call
+that covers it (``obs.devprof.device_seconds_by_part``). A reports file
+is what ``obs.programs.save_reports`` wrote from
+``engine.program_report("prefill")`` / ``("decode")`` or a trainer's
+``program_report()``, or a compiled program's own text
+(``compiled.as_text()``).
+
 Usage:
     python scripts/trace_report.py TRACE.json [--tree-req ID]
         [--tenant ID]
     python scripts/trace_report.py --merge D1.json D2.json...
         [--out MERGED.json]
+    python scripts/trace_report.py --xplane CAPTURE --reports R.json...
 (importable: ``report(path) -> str``, ``merge_dumps``, ``unit_table``,
-``unit_chain_digest``, and ``main(argv)``).
+``unit_chain_digest``, ``device_report`` and ``main(argv)``).
 """
 
 from __future__ import annotations
@@ -430,13 +442,36 @@ def merge_report(dumps: List[str], out: Optional[str] = None) -> str:
     return "\n".join(lines) + "\n"
 
 
+def device_report(xplane: str, report_files: List[str], top: int = 40) -> str:
+    """A capture's device time by model part, as text."""
+    import os
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    from elephas_tpu.obs import devprof
+    from elephas_tpu.obs.programs import load_reports
+
+    reports = [r for path in report_files for r in load_reports(path)]
+    if os.path.isdir(xplane):
+        xplane = devprof.find_xplane(xplane)
+    return devprof.format_by_part(
+        devprof.device_seconds_by_part(xplane, reports), top)
+
+
 def main(argv: Optional[List[str]] = None) -> str:
     parser = argparse.ArgumentParser(
         description="Per-phase percentiles + request tree from a trace, "
                     "or a clock-aligned multi-process merge (--merge)"
     )
-    parser.add_argument("trace", nargs="+",
+    parser.add_argument("trace", nargs="*",
                         help="Chrome trace_event JSON file(s)")
+    parser.add_argument("--xplane", default=None,
+                        help="a profiler capture (.xplane.pb or its "
+                             "directory): print device time by model part")
+    parser.add_argument("--reports", nargs="+", default=[],
+                        help="with --xplane: the programs' reports "
+                             "(obs.programs.save_reports) or compiled texts")
+    parser.add_argument("--top", type=int, default=40,
+                        help="with --xplane: rows a program")
     parser.add_argument("--merge", action="store_true",
                         help="merge per-process dumps (clockSync-aligned) "
                              "and print the per-unit critical-path table")
@@ -450,6 +485,17 @@ def main(argv: Optional[List[str]] = None) -> str:
                         help="write the merged trace (--merge) or the "
                              "report text to this file")
     args = parser.parse_args(argv)
+    if args.xplane:
+        if not args.reports:
+            parser.error("--xplane needs --reports")
+        text = device_report(args.xplane, args.reports, args.top)
+        if args.out:
+            with open(args.out, "w") as f:
+                f.write(text)
+        print(text, end="")
+        return text
+    if not args.trace:
+        parser.error("a trace file, or --xplane with --reports")
     if args.merge:
         text = merge_report(args.trace, out=args.out)
         print(text, end="")

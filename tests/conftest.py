@@ -51,6 +51,53 @@ def blobs():
     return make_blobs()
 
 
+def rehearsal_engine(cell: str):
+    """The serving engine of one of the benchmark's cells at its files'
+    `rehearsal` sizes, as `benchmark/lib/serve.py` builds it (weights from
+    a seed)."""
+    import os
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                         "benchmark")
+    for path in (bench, os.path.dirname(bench)):
+        if path not in sys.path:
+            sys.path.insert(0, path)
+    from lib import cells, serve
+
+    return serve.build(cells.Cell(cell), 1, True, False).engine
+
+
+def assert_report_is_whole(report, layers=None):
+    """What holds of every `obs.programs.ProgramReport`: each instruction
+    that issues device work has a part, nearly all of them a scope's; every
+    `-done` finds its `-start` and carries its part; a mixed fusion lists its
+    parts; `copy_bytes()` is what the data-moving instructions write. With
+    `layers` > 1, the model's layers have collapsed into one row."""
+    import re
+
+    from elephas_tpu.obs.programs import _FREE, UNSCOPED
+
+    ins = report.instructions
+    working = [i for i in ins.values() if i.opcode not in _FREE]
+    assert working and all(i.part for i in ins.values())
+    unscoped = [i.name for i in working if i.part == UNSCOPED]
+    assert len(unscoped) <= max(3, 0.03 * len(working)), unscoped
+    for i in ins.values():
+        if i.opcode.endswith("-done"):
+            assert i.start in ins, i
+            assert (i.part, i.operand) == (ins[i.start].part, ins[i.start].operand), i
+        assert not i.mixed or (i.opcode == "fusion" and len(i.parts) >= 2), i
+    copies = report.copies()
+    assert all(i.moves_data and not i.opcode.endswith("-start") for i in copies)
+    assert report.copy_bytes() == sum(i.out_bytes for i in copies)
+    assert sum(row[1] for row in report.copies_by_part()) == report.copy_bytes()
+    if layers and layers > 1:
+        parts = set(report.parts())
+        assert not [p for p in parts if re.search(r"(^|/)(Layer|Block|blocks)_\d+(/|$)", p)], parts
+        assert any(re.search(r"(^|/)(Layer|Block|blocks)_\*/", p) for p in parts), parts
+
+
 # -- runtime lock sanitizer ---------------------------------------------------
 
 #: Concurrency suites run with the lock sanitizer ON: every

@@ -517,6 +517,17 @@ def test_both_programs_of_the_sparse_latent_cell_compile_for_v5e(one_chip, monke
     for kernel in ("index_scores", "select_columns", "sparse_latent_decode_attention",
                    "latent_decode_attention", "grouped_matmul"):
         assert kernel in decode.as_text()
+    from conftest import assert_report_is_whole
+    from elephas_tpu.obs.programs import ProgramReport
+
+    for name, program in programs.items():  # which part issued each instruction
+        report = ProgramReport.from_compiled(program)
+        assert report.program == name
+        assert_report_is_whole(report, layers=6)
+        parts = set(report.parts())
+        for scope in ("indexer", "select_columns", "index_scores", "route", "gather_rows",
+                      "combine_rows", "rope", "head_gate"):
+            assert any(scope in p.split("/") for p in parts), (name, scope)
     sizes = {name: program.memory_analysis() for name, program in programs.items()}
     for name, m in sizes.items():
         print(f"dots3-note-prev {name}, 16 slots, v5e:", m)
@@ -538,6 +549,88 @@ def test_both_programs_of_the_sparse_latent_cell_compile_for_v5e(one_chip, monke
     for name, most in zip(("jit__chunk_prefill_impl", "jit__paged_decode_impl"),
                           stated.groups()):
         assert sizes[name].temp_size_in_bytes / 1e9 < float(most) + 0.0005, name
+
+
+# -- which model part issued each instruction (obs.programs.ProgramReport) -----
+
+
+def _cell_engine(monkeypatch, cell_name, config, serving):
+    """A cell's engine at its published widths over parameters that are
+    shapes, as `benchmark/aot_compile.py` builds it, with `config` (a depth
+    cut for the compile's sake) and `serving` laid over the files, and every
+    kernel steered on for the described chip."""
+    import sys
+
+    from elephas_tpu import InferenceEngine, compile_model
+    from elephas_tpu.ops import attention, routed_experts, selective_scan
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                         "benchmark")
+    monkeypatch.setattr(sys, "path", [bench, os.path.dirname(bench)] + list(sys.path))
+    from lib.cells import Cell
+
+    for ops in (attention, routed_experts, selective_scan):
+        monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    cell = Cell(cell_name)
+    cfg_file = {**cell.config, **config}
+    family = cell.module("models", cfg_file["model"])
+    cfg = family.shape(cfg_file)
+    s = {**cfg_file["serving"], **cell.traffic["engine"], **serving}
+    params = jax.eval_shape(lambda: family.params(0, cfg, jnp.dtype(cfg_file["dtype"])))
+    compiled = compile_model(
+        family.flax_module(cfg, cfg_file["dtype"]), params=params, optimizer="sgd",
+        loss="sparse_categorical_crossentropy", metrics=[],
+        input_shape=(s["max_prompt_len"],), input_dtype=jnp.int32)
+    return InferenceEngine(
+        compiled, max_slots=s["max_slots"], max_prompt_len=s["max_prompt_len"],
+        max_len=s["max_len"], kv_block_size=s["kv_block_size"],
+        prefill_chunk=s["prefill_chunk"])
+
+
+@pytest.mark.parametrize("cell,config,serving,kernels", [
+    ("gpt2-xl.doc-batch", {"n_layer": 2}, {},
+     {"prefill": ["paged_chunk_attention"], "decode": ["paged_decode_attention"]}),
+    ("jamba2-3b.doc-batch-4k",
+     {"num_hidden_layers": 4, "attn_layer_period": 2, "attn_layer_offset": 1},
+     {"max_slots": 8},
+     {"prefill": ["selective_scan", "paged_chunk_attention"],
+      "decode": ["paged_decode_attention"]}),
+    ("deepseek-v2.doc-batch-16k", {"num_hidden_layers": 3}, {"max_slots": 2},
+     {"prefill": ["latent_chunk_attention", "grouped_matmul"],
+      "decode": ["latent_decode_attention"]}),
+], ids=["transformer", "jamba", "latent_moe"])
+def test_program_reports_of_the_serving_programs_for_v5e(
+        one_chip, monkeypatch, cell, config, serving, kernels):
+    """Both serving programs of each family at the published widths (the
+    depth cut to a few layers), compiled for the described chip with the
+    kernels in, through the engine's own `program_args`: every instruction
+    that works has a part, every `-done` its `-start`, the layers are one
+    row, each kernel lies under the scope that called it, and the bytes the
+    copies move are what their shapes say."""
+    from conftest import assert_report_is_whole
+    from elephas_tpu.obs.programs import ProgramReport
+
+    engine = _cell_engine(monkeypatch, cell, config, serving)
+    for which, jitted in (("prefill", engine._jit_prefill), ("decode", engine._jit_decode)):
+        text = jitted.lower(*engine.program_args(which, sharding=one_chip)).compile().as_text()
+        report = ProgramReport.from_text(text)
+        assert_report_is_whole(report, layers=2)
+        found = {i.kind: i for i in report.instructions.values()
+                 if i.opcode == "custom-call"}
+        for kernel in kernels[which]:
+            assert kernel in found, (which, sorted(found))
+            assert {"paged_attention", "selective_scan", "grouped_matmul"} & set(
+                found[kernel].part.split("/")), found[kernel]
+        # the TPU compiler prefetches and relays out beside the kernels: the
+        # table has rows, and a weight's copy names the weight
+        copies = report.copies()
+        assert any(i.opcode == "copy-done" for i in copies)
+        assert any(i.part.startswith("params/") for i in copies)
+        assert report.copy_bytes() > 0
+        # every instruction name the text gives a device event is in the report
+        for name in re.findall(r"^  (?:ROOT )?%([\w.\-]+) = ", text.split("ENTRY", 1)[1],
+                               re.M):
+            assert name in report.instructions, name
 
 
 @pytest.mark.parametrize("rows,experts", [(2048 * 6, 40), (2048 * 8, 32), (16 * 6, 40),

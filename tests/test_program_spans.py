@@ -91,27 +91,54 @@ def traced(compiled):
                            events=tracer.events())
 
 
+def _tiled(parent, kids):
+    """`kids`, in order, lie inside `parent` and do not overlap."""
+    for kid in kids:
+        assert parent.begin_s <= kid.begin_s <= kid.end_s <= parent.end_s, (parent, kid)
+    for a, b in zip(kids, kids[1:]):
+        assert a.end_s <= b.begin_s, (a, b)
+    assert sum(k.duration_s for k in kids) <= parent.duration_s + 1e-12
+
+
 def test_step_spans_tile_their_step(traced):
+    """A step's phases are its children by id and tile it; `step/prefill`
+    is one of them, and the chunks and syncs it ran are ITS children;
+    `step/record` (what follows the step's close: the `step` event, the
+    load tracker) is the step's child by id and begins where it ends, and
+    `step/publish` (the engine's, where the step finished a request)
+    follows that."""
     steps = [e for e in traced.events if e.name == "sched_step"]
     assert len(steps) == len(traced.sink.steps) > 5
     by_parent = {}
     for e in traced.events:
         if e.name.startswith("step/") or e.name == "dispatch":
             by_parent.setdefault(e.parent_id, []).append(e)
-    assert set(by_parent) <= {s.span_id for s in steps}
+    prefills = {e.span_id: e for e in traced.events if e.name == "step/prefill"}
+    assert set(by_parent) <= {s.span_id for s in steps} | set(prefills)
     seen = set()
     for step in steps:
         kids = sorted(by_parent.get(step.span_id, []), key=lambda e: e.begin_s)
         assert kids, "a step with work records its phases"
-        for kid in kids:
-            assert step.begin_s <= kid.begin_s <= kid.end_s <= step.end_s
-            seen.add(kid.name)
-        for a, b in zip(kids, kids[1:]):
-            assert a.end_s <= b.begin_s, (a, b)
-        assert sum(k.duration_s for k in kids) <= step.duration_s + 1e-12
+        seen.update(kid.name for kid in kids)
+        inside = [k for k in kids if k.name not in ("step/record", "step/publish")]
+        after = kids[len(inside):]
+        assert [k.name for k in after] in (["step/record"],
+                                           ["step/record", "step/publish"])
+        assert after[0].begin_s == step.end_s
+        assert all(a.end_s <= b.begin_s for a, b in zip(after, after[1:]))
+        _tiled(step, inside)
     assert seen == {"dispatch", "step/harvest_wait", "step/harvest_book",
-                    "step/evict", "step/admit", "step/prefill_chunk",
-                    "step/prefill_sync"}
+                    "step/evict", "step/admit", "step/prefill", "step/record",
+                    "step/publish"}
+    published = [e for e in traced.events if e.name == "step/publish"]
+    assert sum(e.args["finished"] for e in published) == len(PROMPTS)
+    assert prefills
+    for span_id, prefill in prefills.items():
+        kids = sorted(by_parent[span_id], key=lambda e: e.begin_s)
+        assert {k.name for k in kids} <= {"step/prefill_chunk", "step/prefill_sync"}
+        _tiled(prefill, kids)
+    assert sum(len(by_parent[i]) for i in prefills) == sum(
+        e.name in ("step/prefill_chunk", "step/prefill_sync") for e in traced.events)
     chunk = next(e for e in traced.events if e.name == "step/prefill_chunk")
     assert {k: type(v) for k, v in chunk.args.items()} == {
         "slot": int, "start": int, "valid": int}
