@@ -331,7 +331,9 @@ class RoutedExperts(nn.Module):
         no padding and no idle lane (all of them where it is None)."""
         from elephas_tpu.ops.routed_experts import (
             group_limited_top_k,
+            routed_body,
             routed_experts,
+            rows_combined,
             weight_passes,
         )
 
@@ -358,14 +360,17 @@ class RoutedExperts(nn.Module):
                 p = p / p.sum(-1, keepdims=True)
             if live is not None:  # an id past every expert is held nowhere
                 ids = jnp.where(live[:, None], ids, self.n_routed_experts)
+        body = routed_body(y.shape[0], self.top_k, d, f, y.dtype)
         out, load = routed_experts(y, ids, p * self.routed_scaling_factor,
-                                   gate, up, down, first)
+                                   gate, up, down, first, body=body)
         tokens = y.shape[0] if live is None else live.sum()
+        rows, assignments = y.shape[0] * self.top_k, tokens * self.top_k
         for name, value in (
-                ("moe_assignments", tokens * self.top_k),
+                ("moe_assignments", assignments),
                 ("moe_assignments_held", load.sum()),
                 ("moe_experts_touched", (load > 0).sum()),
-                ("moe_weight_passes", weight_passes(load, y.shape[0] * self.top_k)),
+                ("moe_weight_passes", weight_passes(load, rows)),
+                ("moe_rows_combined", rows_combined(load, assignments, rows, body)),
                 ("moe_experts_held", count),
                 ("moe_load_max", load.max()),
                 ("moe_load_mean", load.sum() / count)):

@@ -41,6 +41,12 @@ read. What it is fed and how its grid runs follows from the rows, a shape:
   groups share the tile. The grid is (column tile, work item, depth step),
   the whole tile stays in VMEM at full depth from the first step on, and an
   item's result is stored under a mask of its group's rows.
+
+The way back follows the same shape. Over many tiles a kernel
+(``pallas_combine_rows``) reads the held rows alone, once, in their laid-out
+order, and adds each, weighed, to its token's float32 sum in VMEM, which it
+writes once; over one tile the packed rows are weighed and gathered back
+into the assignments' order and summed in XLA.
 """
 
 from __future__ import annotations
@@ -59,6 +65,9 @@ _SUB_TILE = 128   # rows a push through the MXU; what a group of many tiles star
 _COL_TILE = 512   # output columns a grid step
 _DEPTH_TILE = 1024  # of the contraction a grid step
 _VMEM_BUDGET = 12 << 20  # of the 16 MiB a v5e kernel has by default
+_PIECE = 16  # rows a copy of the way back: a sub-tile's live rows, rounded up
+_COMBINE_VMEM_BUDGET = 64 << 20  # of a v5e core's 128 MiB, asked for by the call
+_COMBINE_SMEM_BUDGET = 512 << 10  # of a v5e core's 1 MiB
 
 
 def _on_tpu() -> bool:
@@ -90,6 +99,25 @@ def _pallas_fits(rows: int, depth: int, cols: int, dtype) -> bool:
             and (rows > _ROW_TILE or rows % sublanes == 0)
             and _divisor(depth, _DEPTH_TILE) > 0 and _divisor(cols, _COL_TILE) > 0
             and _vmem_bytes(rows, depth, cols, dtype) <= _VMEM_BUDGET)
+
+
+def _combine_tile(tokens: int, d: int, dtype) -> int:
+    """The widest column tile (a multiple of 128 dividing ``d``) whose
+    sums, ``tokens`` rows of float32, fit ``_COMBINE_VMEM_BUDGET`` beside a
+    sub-tile of rows twice and once in float32; 0 where none does."""
+    return max((c for c in range(128, d + 1, 128) if d % c == 0
+                and _combine_vmem_bytes(tokens, c, dtype) <= _COMBINE_VMEM_BUDGET),
+               default=0)
+
+
+def _combine_vmem_bytes(tokens: int, col_tile: int, dtype) -> int:
+    return col_tile * (4 * tokens + (2 * jnp.dtype(dtype).itemsize + 4) * _SUB_TILE)
+
+
+def _combine_fits(tokens: int, top_k: int, d: int, dtype) -> bool:
+    """The way back's kernel holds each assignment's place and weight in
+    SMEM and a column tile of every token's sums in VMEM."""
+    return 8 * tokens * top_k <= _COMBINE_SMEM_BUDGET and _combine_tile(tokens, d, dtype) > 0
 
 
 def grouped_body(rows: int, depth: int, cols: int, dtype, mesh=None) -> str:
@@ -382,7 +410,143 @@ def grouped_matmul(lhs, rhs, group_sizes, body: str = "grouped_xla"):
         return jnp.where((jnp.arange(lhs.shape[0]) < group_sizes.sum())[:, None], out, 0)
 
 
+# -- the way back ---------------------------------------------------------------
+
+
+def _combine_kernel(sizes_ref, order_ref, weight_ref, rows_hbm, out_hbm, start_ref, end_ref,
+                    sums_ref, rows_ref, wide_ref, rows_sem, out_sem, *, top_k: int,
+                    col_tile: int):
+    """Grid step: a column tile. The live sub-tiles of ``rows_hbm`` are the
+    groups' own, one after another from the first; ``start_ref`` takes the
+    sorted position of each one's first row and ``end_ref`` its rows. Each
+    sub-tile's live pieces are copied in while the one before is added,
+    each row weighed and added to its token's row of ``sums_ref``, and the
+    sums are written out once."""
+    cols = pl.ds(pl.multiple_of(pl.program_id(0) * col_tile, col_tile), col_tile)
+
+    def group(g, carry):
+        sub, first = carry
+        size = sizes_ref[g]
+
+        def each(k, sub):
+            start_ref[sub] = first + k * _SUB_TILE
+            end_ref[sub] = jnp.minimum(size - k * _SUB_TILE, _SUB_TILE)
+            return sub + 1
+
+        return jax.lax.fori_loop(0, (size + _SUB_TILE - 1) // _SUB_TILE, each, sub), first + size
+
+    subs, _ = jax.lax.fori_loop(0, sizes_ref.shape[0], group, (0, 0))
+
+    def live(sub, do):
+        for q in range(_SUB_TILE // _PIECE):
+            pl.when(q * _PIECE < end_ref[sub])(functools.partial(do, q))
+
+    def rows_copy(sub, slot, q):
+        return pltpu.make_async_copy(
+            rows_hbm.at[pl.ds(pl.multiple_of(sub * _SUB_TILE + q * _PIECE, _PIECE), _PIECE), cols],
+            rows_ref.at[slot, pl.ds(q * _PIECE, _PIECE)], rows_sem.at[slot, q])
+
+    sums_ref[...] = jnp.zeros_like(sums_ref)
+
+    @pl.when(subs > 0)
+    def _first():
+        live(0, lambda q: rows_copy(0, 0, q).start())
+
+    def sub_tile(sub, carry):
+        slot = sub % 2
+
+        @pl.when(sub + 1 < subs)
+        def _next():
+            live(sub + 1, lambda q: rows_copy(sub + 1, 1 - slot, q).start())
+
+        def widen(q):
+            rows_copy(sub, slot, q).wait()
+            piece = pl.ds(q * _PIECE, _PIECE)
+            wide_ref[piece, :] = rows_ref[slot, piece, :].astype(jnp.float32)
+
+        live(sub, widen)
+        first = start_ref[sub]
+
+        def add(r, carry):
+            a = order_ref[first + r]  # the assignment: its token, its weight
+            sums_ref[pl.ds(a // top_k, 1), :] += weight_ref[a] * wide_ref[pl.ds(r, 1), :]
+            return carry
+
+        return jax.lax.fori_loop(0, end_ref[sub], add, carry)
+
+    jax.lax.fori_loop(0, subs, sub_tile, 0)
+    out = pltpu.make_async_copy(sums_ref, out_hbm.at[:, cols], out_sem)
+    out.start()
+    out.wait()
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def pallas_combine_rows(rows, order, weights, group_sizes, interpret: bool = False):
+    """The way back from the kernel's layout of many row tiles. ``rows``:
+    (laid, d), the held assignments' rows as ``laid_out_rows`` /
+    ``group_starts`` lay them out for ``group_sizes``; ``order``: (tokens *
+    top_k,), the assignments sorted by group, the held ones first, as
+    ``rows`` holds them; ``weights``: (tokens, top_k). Returns (tokens, d)
+    float32: each token's sum of ``weights[t, k] * rows[its row]`` over its
+    held assignments. A row no group owns is never read, whatever it holds,
+    and a token with no held assignment reads 0."""
+    laid, d = rows.shape
+    tokens, top_k = weights.shape
+    col_tile = _combine_tile(tokens, d, rows.dtype)
+    subs = laid // _SUB_TILE
+    return pl.pallas_call(
+        functools.partial(_combine_kernel, top_k=top_k, col_tile=col_tile),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(d // col_tile,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[
+                pltpu.SMEM((subs,), jnp.int32),
+                pltpu.SMEM((subs,), jnp.int32),
+                pltpu.VMEM((tokens, col_tile), jnp.float32),
+                pltpu.VMEM((2, _SUB_TILE, col_tile), rows.dtype),
+                pltpu.VMEM((_SUB_TILE, col_tile), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, _SUB_TILE // _PIECE)),
+                pltpu.SemaphoreType.DMA(()),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((tokens, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_combine_vmem_bytes(tokens, col_tile, rows.dtype) + (4 << 20)),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name="combine_rows",
+    )(group_sizes.astype(jnp.int32), order.astype(jnp.int32),
+      weights.reshape(-1).astype(jnp.float32), rows)
+
+
 # -- the layer's routed part --------------------------------------------------
+
+
+def routed_body(tokens: int, top_k: int, d: int, f: int, dtype) -> str:
+    """The body ``routed_experts`` runs for ``tokens`` of ``top_k``
+    assignments over experts of widths ``d`` and ``f``: the kernel where all
+    three products fit it and, over more than one row tile, the way back."""
+    rows = tokens * top_k
+    body = max(grouped_body(rows, d, f, dtype), grouped_body(rows, f, d, dtype),
+               key=GROUPED_BODIES.index)
+    if rows > _ROW_TILE and not _combine_fits(tokens, top_k, d, dtype):
+        return "grouped_xla"
+    return body
+
+
+def combines_held_rows(rows: int, body: str) -> bool:
+    """Whether the way back reads the held rows alone (the kernel's layout
+    of many row tiles) or a row for every assignment."""
+    return body == "grouped_pallas" and rows > _ROW_TILE
+
+
+def rows_combined(load, assignments, rows: int, body: str):
+    """The rows the way back reads for ``rows`` assignments in ``body``:
+    the held ones, ``load``'s sum, where it reads them alone; else
+    ``assignments``, those that count (padding and idle lanes left out)."""
+    return load.sum() if combines_held_rows(rows, body) else assignments
 
 
 def routed_experts(y, ids, weights, gate, up, down, first: int, body=None):
@@ -404,10 +568,8 @@ def routed_experts(y, ids, weights, gate, up, down, first: int, body=None):
         local = jnp.where((flat >= first) & (flat < first + count), flat - first, count)
         order = jnp.argsort(local, stable=True)  # held assignments first, by expert
         load = jnp.zeros((count,), jnp.int32).at[local].add(1, mode="drop")
-    if body is None:  # the kernel where all three products fit it
-        f = gate.shape[-1]
-        body = max(grouped_body(rows, d, f, y.dtype), grouped_body(rows, f, d, y.dtype),
-                   key=GROUPED_BODIES.index)
+    if body is None:
+        body = routed_body(tokens, top_k, d, gate.shape[-1], y.dtype)
 
     def expert(x):
         g = grouped_matmul(x, gate, load, body)
@@ -418,7 +580,7 @@ def routed_experts(y, ids, weights, gate, up, down, first: int, body=None):
             h = (g * u.astype(jnp.float32)).astype(y.dtype)
         return grouped_matmul(h, down, load, body)
 
-    if body == "grouped_pallas" and rows > _ROW_TILE:
+    if combines_held_rows(rows, body):
         # the kernel's layout of many tiles: a group's rows from its own start
         with jax.named_scope("gather_rows"):
             laid = laid_out_rows(rows, count)
@@ -430,15 +592,11 @@ def routed_experts(y, ids, weights, gate, up, down, first: int, body=None):
                             + jnp.arange(rows, dtype=jnp.int32) - offsets[of], laid)
             token_of = jnp.zeros((laid,), jnp.int32).at[row].set(order // top_k,
                                                                  mode="drop")
-            back = jnp.zeros((rows,), jnp.int32).at[order].set(row)
             x = y[token_of]
         out = expert(x)
         with jax.named_scope("combine_rows"):
-            # an assignment held elsewhere has no row: it adds 0
-            part = out[jnp.minimum(back, laid - 1)].astype(jnp.float32)
-            part = jnp.where((back < laid)[:, None],
-                             part * weights.reshape(-1, 1).astype(jnp.float32), 0)
-            return part.reshape(tokens, top_k, d).sum(1), load
+            # the held rows alone, each once: an assignment held elsewhere adds 0
+            return pallas_combine_rows(out, order, weights, load), load
     with jax.named_scope("gather_rows"):
         x = y[order // top_k]
     out = expert(x)

@@ -596,7 +596,7 @@ def _cell_engine(monkeypatch, cell_name, config, serving):
      {"prefill": ["selective_scan", "paged_chunk_attention"],
       "decode": ["paged_decode_attention"]}),
     ("deepseek-v2.doc-batch-16k", {"num_hidden_layers": 3}, {"max_slots": 2},
-     {"prefill": ["latent_chunk_attention", "grouped_matmul"],
+     {"prefill": ["latent_chunk_attention", "grouped_matmul", "combine_rows"],
       "decode": ["latent_decode_attention"]}),
 ], ids=["transformer", "jamba", "latent_moe"])
 def test_program_reports_of_the_serving_programs_for_v5e(
@@ -619,8 +619,15 @@ def test_program_reports_of_the_serving_programs_for_v5e(
                  if i.opcode == "custom-call"}
         for kernel in kernels[which]:
             assert kernel in found, (which, sorted(found))
-            assert {"paged_attention", "selective_scan", "grouped_matmul"} & set(
+            assert {"paged_attention", "selective_scan", "grouped_matmul", "combine_rows"} & set(
                 found[kernel].part.split("/")), found[kernel]
+        if "latent_decode_attention" in kernels["decode"]:
+            # the routed layer's way back: a kernel over a chunk's rows, which
+            # leaves no (tokens, top_k, d) float32 copy; none over a step's
+            assert ("combine_rows" in found) == (which == "prefill"), sorted(found)
+            chunk, top_k, d = engine.prefill_chunk, 6, 5120
+            assert not [i for i in report.copies() if "combine_rows" in i.part.split("/")
+                        and i.out_bytes >= chunk * top_k * d * 4], report.copies_by_part(8)
         # the TPU compiler prefetches and relays out beside the kernels: the
         # table has rows, and a weight's copy names the weight
         copies = report.copies()
@@ -661,6 +668,34 @@ def test_grouped_matmul_kernel_compiles_for_v5e(one_chip, rows, experts):
             arg((laid, depth), jnp.bfloat16), arg((experts, depth, cols), jnp.bfloat16),
             arg((experts,), jnp.int32)).compile()
         assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("tokens,top_k,experts", [(2048, 6, 40), (2048, 8, 32)])
+def test_combine_rows_kernel_compiles_for_v5e(one_chip, tokens, top_k, experts):
+    """The routed layer's way back at a chunk of both routed cells: every
+    token's float32 sums over the whole width in VMEM (42 MB, asked for by
+    the call), each assignment's place and weight in SMEM."""
+    from elephas_tpu.ops.routed_experts import (
+        _COMBINE_VMEM_BUDGET,
+        _combine_fits,
+        _combine_tile,
+        _combine_vmem_bytes,
+        laid_out_rows,
+        pallas_combine_rows,
+    )
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    d = _LATENT["d"]
+    assert _combine_fits(tokens, top_k, d, jnp.bfloat16)
+    assert _combine_tile(tokens, d, jnp.bfloat16) == d
+    assert _combine_vmem_bytes(tokens, d, jnp.bfloat16) <= _COMBINE_VMEM_BUDGET
+    compiled = jax.jit(pallas_combine_rows).lower(
+        arg((laid_out_rows(tokens * top_k, experts), d), jnp.bfloat16),
+        arg((tokens * top_k,), jnp.int32), arg((tokens, top_k), jnp.float32),
+        arg((experts,), jnp.int32)).compile()
+    assert "combine_rows" in compiled.as_text()
 
 
 def test_selective_scan_kernel_compiles_for_v5e(one_chip):

@@ -316,18 +316,22 @@ _LAYOUT_CASES = {
     "mixed": (200, 3, list(range(12))),
     "every assignment held": (100, 3, [4, 5, 6, 7]),
     "a decode step's one tile": (32, 3, list(range(12))),
+    "top_k 6, a quarter of the experts held": (100, 6, list(range(16))),
+    "top_k 8, an eighth of the experts held": (64, 8, list(range(32))),
 }
 
 
 @pytest.mark.parametrize("case", list(_LAYOUT_CASES))
 def test_the_routed_product_is_exact_in_the_kernels_layout(case, monkeypatch):
-    """``routed_experts()`` with the kernel for its body, interpreted here:
-    over one tile's assignments (600, 300) the rows are laid out anew, a
-    group from its own start, and gathered back; 96 lie packed."""
+    """``routed_experts()`` with the kernels for its body, interpreted here:
+    over one tile's assignments (600, 300, 512) the rows are laid out anew, a
+    group from its own start, and the way back's kernel reads the held ones
+    alone; 96 lie packed and are gathered back."""
     import functools
 
-    monkeypatch.setattr(routed_experts, "pallas_grouped_matmul", functools.partial(
-        routed_experts.pallas_grouped_matmul, interpret=True))
+    for kernel in ("pallas_grouped_matmul", "pallas_combine_rows"):
+        monkeypatch.setattr(routed_experts, kernel, functools.partial(
+            getattr(routed_experts, kernel), interpret=True))
     tokens, top_k, drawn = _LAYOUT_CASES[case]
     rng = np.random.default_rng(3)
     d, f, first, count = 256, 128, 4, 4
@@ -337,8 +341,12 @@ def test_the_routed_product_is_exact_in_the_kernels_layout(case, monkeypatch):
     weights = rng.uniform(0.1, 2.0, (tokens, top_k)).astype(np.float32)
     ids = rng.choice(drawn, (tokens, top_k))
     assert routed_experts._pallas_fits(tokens * top_k, d, f, jnp.float32)
+    assert routed_experts._combine_fits(tokens, top_k, d, jnp.float32)
     args = [jnp.asarray(a) for a in (y, ids, weights, gate, up, down)]
-    out, load = routed_experts.routed_experts(*args, first, body="grouped_pallas")
+    # one program: no host op is dispatched beside the interpreter's callbacks
+    out, load = jax.block_until_ready(jax.jit(
+        routed_experts.routed_experts, static_argnames=("first", "body"))(
+        *args, first=first, body="grouped_pallas"))
     np.testing.assert_allclose(np.asarray(out), _dense_routed(
         y, ids, weights, gate, up, down, first), atol=2e-4, rtol=2e-4)
     held = (ids >= first) & (ids < first + count)
@@ -406,6 +414,67 @@ def test_the_grouped_kernel_matches_a_loop_of_products(case):
         assert (where % routed_experts._SUB_TILE == 0).sum() >= np.count_nonzero(sizes)
     xla = routed_experts.grouped_matmul(jnp.asarray(lhs), jnp.asarray(rhs), group_sizes)
     np.testing.assert_allclose(np.asarray(xla, np.float32), want, **close)
+
+
+def _gather_and_sum(rows, order, weights, sizes):
+    """PR 37's way back, the XLA body the kernel replaced: a row gathered
+    for every assignment, held or not (one held elsewhere reads the last
+    row), weighed under a mask of the held ones and summed a token."""
+    laid, d = rows.shape
+    tokens, top_k = weights.shape
+    sizes = jnp.asarray(sizes, jnp.int32)
+    starts, ends = routed_experts.group_starts(sizes, laid), jnp.cumsum(sizes)
+    at = jnp.arange(tokens * top_k)
+    group = jnp.minimum(jnp.searchsorted(ends, at, side="right"), len(sizes) - 1)
+    row = jnp.where(at < ends[-1], starts[group] + at - (ends - sizes)[group], laid)
+    back = jnp.zeros((tokens * top_k,), jnp.int32).at[jnp.asarray(order)].set(row)
+    part = jnp.asarray(rows)[jnp.minimum(back, laid - 1)].astype(jnp.float32)
+    part = jnp.where((back < laid)[:, None],
+                     part * jnp.asarray(weights).reshape(-1, 1).astype(jnp.float32), 0)
+    return np.asarray(part.reshape(tokens, top_k, d).sum(1))
+
+
+_COMBINE_CASES = {
+    # (tokens, top_k, the experts the ids are drawn from, d, dtype): 0 .. 7 are held
+    "top_k 6, a quarter of the experts held": (256, 6, range(32), 256, np.float32),
+    "top_k 8, an eighth of the experts held": (256, 8, range(64), 256, np.float32),
+    "no assignment held": (100, 6, range(8, 20), 256, np.float32),
+    "every assignment held": (100, 6, range(8), 128, np.float32),
+    "one group over several row tiles": (300, 2, [3, 11], 128, np.float32),
+    "a token count that fills no tile": (203, 6, range(24), 384, np.float32),
+    "bfloat16 rows over the whole width": (256, 6, range(32), 1024, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", list(_COMBINE_CASES))
+def test_the_way_back_matches_the_gather_and_sum(case):
+    """``pallas_combine_rows`` against the body it replaced, over the layout
+    of many row tiles in which every row no group owns holds NaN, the last
+    one among them (where the old body pointed an assignment held elsewhere):
+    the held rows are read alone, and every token comes back finite."""
+    tokens, top_k, drawn, d, dtype = _COMBINE_CASES[case]
+    rng = np.random.default_rng(4)
+    count = 8
+    ids = np.stack([rng.choice(list(drawn), top_k, replace=False) for _ in range(tokens)])
+    if case == "one group over several row tiles":
+        ids[:] = [3, 11]  # every token on expert 3: a group of 300 rows
+    local = np.where(ids.reshape(-1) < count, ids.reshape(-1), count)
+    order = np.argsort(local, kind="stable").astype(np.int32)
+    sizes = np.bincount(local, minlength=count + 1)[:count]
+    packed = np.asarray(jnp.asarray(rng.standard_normal((tokens * top_k, d)), dtype))
+    laid, _ = _laid_out(packed, sizes, np.nan)
+    assert laid.shape[0] > routed_experts._ROW_TILE
+    assert np.isnan(np.asarray(laid[-1], np.float32)).all()
+    weights = rng.uniform(0.1, 2.0, (tokens, top_k)).astype(np.float32)
+    got = np.asarray(routed_experts.pallas_combine_rows(
+        jnp.asarray(laid), jnp.asarray(order), jnp.asarray(weights), jnp.asarray(sizes),
+        interpret=True))
+    assert got.shape == (tokens, d) and got.dtype == np.float32
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, _gather_and_sum(laid, order, weights, sizes),
+                               atol=1e-5, rtol=1e-6)
+    if not sizes.any():
+        assert not got.any()
 
 
 # -- (f) rotary ----------------------------------------------------------------
@@ -711,6 +780,7 @@ def test_routing_counters_ride_the_lanes_fetch(served, monkeypatch):
         assert s["moe_assignments"] == lanes * 2 * routed_layers
         assert s["moe_experts_held"] == 4 * routed_layers
         assert s["moe_assignments_held"] <= s["moe_assignments"]
+        assert s["moe_rows_combined"] == s["moe_assignments"]  # a step's rows lie packed
         assert s["moe_experts_touched"] <= min(s["moe_experts_held"], s["moe_assignments_held"])
         assert s["moe_load_max"] * 4 >= s["moe_load_mean"] * 4 >= s["moe_assignments_held"] / routed_layers
     # every fetch that brought counters brought tokens with them: none of their own
@@ -859,4 +929,36 @@ def test_a_chunks_span_carries_its_routing_counters(served):
     for e in chunks:  # padding is routed nowhere: a ragged chunk counts its own tokens
         assert e.args["moe_assignments"] == e.args["valid"] * 2 * routed_layers
         assert 0 <= e.args["moe_assignments_held"] <= e.args["moe_assignments"]
+        # the CPU's body: a row combined for every assignment that counts
+        assert e.args["moe_rows_combined"] == e.args["moe_assignments"]
         assert e.begin_s <= e.end_s
+
+
+@pytest.mark.parametrize("tokens", [200, 100])
+def test_the_rows_combined_are_the_rows_the_way_back_reads(tokens, monkeypatch):
+    """``moe_rows_combined`` with the kernels steered on and interpreted:
+    over more than one row tile (200 tokens of 2, a chunk's case) the way
+    back reads the held rows alone, ``moe_assignments_held``; over one (100,
+    a decode step's) a row for every assignment, ``moe_assignments``. The
+    sums are the XLA body's."""
+    import functools
+
+    monkeypatch.setattr(routed_experts, "_on_tpu", lambda: True)
+    for kernel in ("pallas_grouped_matmul", "pallas_combine_rows"):
+        monkeypatch.setattr(routed_experts, kernel, functools.partial(
+            getattr(routed_experts, kernel), interpret=True))
+    layer = latent_moe.RoutedExperts(
+        n_routed_experts=8, experts_held=(0, 4), moe_d_ff=128, top_k=2, n_group=4,
+        topk_group=2, routed_scaling_factor=1.0)
+    y = jax.random.normal(jax.random.PRNGKey(0), (tokens, 128))
+    params = layer.init(jax.random.PRNGKey(1), y)["params"]
+    assert routed_experts.routed_body(tokens, 2, 128, 128, y.dtype) == "grouped_pallas"
+    apply = jax.jit(functools.partial(layer.apply, mutable=["counters"]))
+    out, sown = jax.block_until_ready(apply({"params": params}, y))
+    count = {k: float(v[0]) for k, v in sown["counters"].items()}
+    assert 0 < count["moe_assignments_held"] < count["moe_assignments"] == 2 * tokens
+    assert count["moe_rows_combined"] == count[
+        "moe_assignments_held" if 2 * tokens > routed_experts._ROW_TILE else "moe_assignments"]
+    monkeypatch.setattr(routed_experts, "_on_tpu", lambda: False)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(jax.jit(layer.apply)(
+        {"params": params}, y)), atol=1e-5, rtol=1e-5)

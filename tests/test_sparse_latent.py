@@ -552,23 +552,32 @@ def test_deepseek_v2_lowers_to_the_parents_two_programs(monkeypatch):
     indexer, no gate, no rescale and softmax scoring: no new operand reaches
     its two programs. The digests are of `str(jax.make_jaxpr(...))` of the
     small engine's chunk and decode programs on the CPU with this
-    installation's JAX. The second pair was taken on PR 36's parent
-    (6f0661e) and holds with the one counter PR 37 sows left out
-    (`moe_weight_passes`, a sum over `load` and one more entry of the
-    counters' vector): the rows of the CPU's body lie as they lay. The first
-    pair is PR 37's, with the counter."""
+    installation's JAX. Whose each pair is: the first is PR 39's, with every
+    counter; the second PR 37's and PR 38's (PR 39's parent, 2f05d14), which
+    holds with the counter PR 39 sows left out (`moe_rows_combined`: on the
+    CPU's packed body it is `moe_assignments` again, one more entry of the
+    counters' vector), since the CPU's way back is as it was; the third PR
+    36's parent's (6f0661e), which holds with PR 37's counter left out too
+    (`moe_weight_passes`, a sum over `load`): the rows of the CPU's body lie
+    as they lay."""
     from flax import linen as nn
 
     from elephas_tpu.models import latent_moe
     from elephas_tpu.ops import routed_experts
 
     assert _program_digests() == [
-        "6ea61899e6ff120e304d48839845ef5c75e975f4fddab4c9504763da365da394",
-        "f7dbe5af01b873e2c7ed490d2dd93f90bfaf5505dc1d47e261850268343a2b28"]
-    monkeypatch.setattr(routed_experts, "weight_passes", lambda load, rows: 0.0)
+        "b5e4ed1472b2ef720e723af6fd00d2cb05b9be0b1d108cc6777019c6010a3f25",
+        "7b4a171b84596d29ce4b84cf9fbd286d00492f45b416b1ee0be3ee7c310fc820"]
+    left_out = {"moe_rows_combined"}
+    monkeypatch.setattr(routed_experts, "rows_combined", lambda *args: 0.0)
     monkeypatch.setattr(
         latent_moe.RoutedExperts, "sow", lambda self, col, name, value, **kw:
-        name != "moe_weight_passes" and nn.Module.sow(self, col, name, value, **kw))
+        name not in left_out and nn.Module.sow(self, col, name, value, **kw))
+    assert _program_digests() == [
+        "6ea61899e6ff120e304d48839845ef5c75e975f4fddab4c9504763da365da394",
+        "f7dbe5af01b873e2c7ed490d2dd93f90bfaf5505dc1d47e261850268343a2b28"]
+    left_out.add("moe_weight_passes")
+    monkeypatch.setattr(routed_experts, "weight_passes", lambda load, rows: 0.0)
     assert _program_digests() == [
         "2e1ff27e407d11c5d551576d9b70f33bd2b6de6ce5a06ea3d77c6b5381f11607",
         "1632385df362187c13b6c0b86924723fd1e1fc92a77cf158c987877d8aa4d2dc"]
