@@ -15,7 +15,10 @@ handoff, speculation and ``shard_serving``) and ``latent_moe_lm`` (latent
 attention with rotary positions and a routed expert layer; it takes
 ``experts_held=(first, count)`` beside ``n_routed_experts`` and computes
 the held experts' part, ``(0, n_routed_experts)`` being the uncut layer;
-the engine refuses it speculation and ``shard_serving``).
+the engine refuses it speculation and ``shard_serving``), and
+``minicpm_sala_lm`` (lightning linear-attention layers with a matrix state a
+slot beside block-sparse attention that selects whole pages of the pool;
+per-slot state, so refused as ``jamba_lm`` is).
 """
 
 from __future__ import annotations
@@ -54,7 +57,7 @@ def registered_models():
 
 # Import for side effect: populate the registry.
 from elephas_tpu.models import (  # noqa: E402,F401
-    mlp, cnn, resnet, lstm, transformer, jamba, latent_moe,
+    mlp, cnn, resnet, lstm, transformer, jamba, latent_moe, minicpm_sala,
 )
 from elephas_tpu.models.mlp import MLP  # noqa: E402,F401
 from elephas_tpu.models.cnn import SimpleCNN  # noqa: E402,F401
@@ -62,6 +65,7 @@ from elephas_tpu.models.resnet import ResNet18  # noqa: E402,F401
 from elephas_tpu.models.lstm import LSTMClassifier  # noqa: E402,F401
 from elephas_tpu.models.jamba import JambaLM  # noqa: E402,F401
 from elephas_tpu.models.latent_moe import LatentMoELM  # noqa: E402,F401
+from elephas_tpu.models.minicpm_sala import MiniCPMSALA  # noqa: E402,F401
 from elephas_tpu.models.transformer import (  # noqa: E402,F401
     TransformerLM,
     generate,

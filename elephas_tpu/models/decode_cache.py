@@ -14,7 +14,13 @@ by the leaf's name:
   token that a learned sparse attention's indexer scores (``ops.
   sparse_index``), a second paged leaf beside the latent of the same layer,
   written through the same table at the same column and laid out as the
-  latent is (``COLUMN_MINOR``).
+  latent is (``COLUMN_MINOR``). ``cached_compressed_key`` is a K/V leaf too:
+  a block-sparse attention's compressed keys (``ops.sparse_index``:
+  selection by blocks), ``block_size / stride`` of them a block and K/V
+  head, ``(num_blocks, heads, block_size // stride, width)``, written
+  through the same table as the columns that complete them are. In the
+  module's own cache it is ``(batch, heads, stride, width)``: the third
+  dimension says how many columns a key stands for, not a length.
 - ``WINDOW``: ``cached_window_latent``, the latent of a layer whose queries
   see the last ``window`` columns only. What a slot keeps of it is bounded
   by the window, not by the sequence: a RING of blocks a slot,
@@ -41,7 +47,8 @@ import jax.numpy as jnp
 KV, INDEX, STATE, WINDOW = "kv", "index", "state", "window"
 
 _KINDS = {"cached_key": KV, "cached_value": KV, "cached_latent": KV,
-          "cached_index_key": KV, "cached_window_latent": WINDOW,
+          "cached_index_key": KV, "cached_compressed_key": KV,
+          "cached_window_latent": WINDOW,
           "cache_index": INDEX, "pos_index": INDEX}
 
 # leaves of one head whose blocks keep their columns minor
@@ -253,3 +260,103 @@ def _select(indexer: Indexer, table, idx, body: str, active=None, valid=None,
     live = jnp.where(real, last + 1, 0).sum()
     selected = jnp.where(real[:, None], mask, 0).astype(jnp.int32).sum()
     return mask, (live.astype(jnp.float32), selected.astype(jnp.float32))
+
+
+def attend_block_sparse(q, k, v, cached_key, cached_value, cached_compressed,
+                        cache_index, active, paged: PagedDecode, sel, scale: float):
+    """A block-sparse attention's layer over the paged pool
+    (``ops.sparse_index``: selection by blocks, ``sel`` its sizes): the new
+    columns go into their blocks and the compressed keys they complete into
+    theirs, each query scores its K/V head's compressed keys, selects whole
+    blocks, and attends those pages alone. ``q``: (rows, q_heads, T,
+    head_dim), ``k``/``v``: (rows, heads, T, head_dim); a decode step under
+    ``active``, one slot's chunk without it, as ``attend_paged`` has them.
+
+    Returns ``(out, (live, selected, scored))``: (rows, q_heads, T,
+    head_dim), and over the apply's real queries and K/V heads the columns
+    each could see, the columns it attended and the blocks it scored (none
+    for a query that attends every column)."""
+    from elephas_tpu.ops.attention import (
+        _write_windows,
+        block_sparse_chunk_attention,
+        block_sparse_decode_attention,
+        scatter_prefill_blocks,
+        scatter_prefill_columns,
+    )
+    from elephas_tpu.ops.sparse_index import (
+        block_scores,
+        chosen_blocks,
+        pallas_block_scores,
+        row_keys,
+        select_blocks,
+        write_chunk_keys,
+        write_step_keys,
+    )
+
+    rows, q_heads, T, D = q.shape
+    heads = k.shape[1]
+    group = q_heads // heads
+    idx, table = cache_index.value, paged.table
+    nb = table.shape[1]
+    if active is not None:
+        if T != 1:
+            raise ValueError("a paged decode step attends one token per lane")
+        with jax.named_scope("kv_write"):
+            k_pool = _write_windows(cached_key.value, table, idx, k[:, :, :1], active)
+            v_pool = _write_windows(cached_value.value, table, idx, v[:, :, :1], active)
+            comp = write_step_keys(cached_compressed.value, k_pool, table, idx, active,
+                                   sel, D)
+        qg = q[:, :, 0].reshape(rows, heads, group, D)
+        scores = block_scores(qg, row_keys(comp, table), idx, sel, scale)
+        last = jnp.repeat(idx, heads)
+        mask = select_blocks(scores.reshape(rows * heads, nb), last, sel, paged.body)
+        chosen = chosen_blocks(mask, sel.table_width).reshape(rows, heads, -1)
+        out = block_sparse_decode_attention(qg, k_pool, v_pool, table, chosen, idx, D,
+                                            scale).reshape(rows, q_heads, 1, D)
+        cache_index.value = jnp.where(active, idx + 1, idx)
+        real = jnp.repeat(active, heads)
+    else:
+        if rows != 1:
+            raise ValueError(f"a prefill chunk over the paged pool is one slot's; got {rows} rows")
+        row, start = table[0], idx[0]
+        valid = T if paged.valid is None else paged.valid
+        write = scatter_prefill_blocks if paged.aligned else scatter_prefill_columns
+        with jax.named_scope("kv_write"):
+            k_pool = write(cached_key.value, row, start, k[0])
+            v_pool = write(cached_value.value, row, start, v[0])
+            comp = write_chunk_keys(cached_compressed.value, k_pool, row, start, valid,
+                                    k[0], sel, D)
+        keys = row_keys(comp, row[None])[0]
+        qt = jnp.moveaxis(q[0], 0, 1).reshape(T, heads, group, D)
+        at = start + jnp.arange(T)
+        if paged.body == "paged_pallas":
+            scores = jnp.moveaxis(pallas_block_scores(
+                jnp.moveaxis(qt, 0, 2), keys, start, sel, scale), 0, 1)
+        else:
+            tile = max(d for d in range(1, min(T, 256) + 1) if T % d == 0)
+            scores = jax.lax.map(
+                lambda i: block_scores(jax.lax.dynamic_slice_in_dim(qt, i * tile, tile, 0),
+                                       keys, start + i * tile + jnp.arange(tile), sel,
+                                       scale), jnp.arange(T // tile)).reshape(T, heads, -1)
+        last = jnp.repeat(at, heads)
+        mask = select_blocks(scores[..., :nb].reshape(T * heads, nb), last, sel, paged.body)
+        picked = jnp.moveaxis(mask.reshape(T, heads, nb), 0, 2)  # (heads, nb, T)
+        out = block_sparse_chunk_attention(jnp.moveaxis(qt, 0, 2), k_pool, v_pool, row,
+                                           start, picked, D, scale, paged.body)
+        out = out.reshape(q_heads, T, D)[None]
+        cache_index.value = idx + T
+        real = jnp.repeat(jnp.arange(T) < valid, heads)
+    cached_key.value, cached_value.value, cached_compressed.value = k_pool, v_pool, comp
+    return out, _block_counts(mask, last, real, sel)
+
+
+def _block_counts(mask, last, real, sel):
+    """Over the (query, K/V head) rows that are ``real``: the columns each
+    could see, those its blocks hold up to its own, and the blocks it
+    scored (every live one, for a query that selects)."""
+    first = jnp.arange(mask.shape[-1]) * sel.block
+    held = jnp.clip(last[:, None] - first[None] + 1, 0, sel.block)
+    selected = jnp.where(mask > 0, held, 0).sum(-1)
+    scored = jnp.where(last >= sel.dense_len, last // sel.block + 1, 0)
+    return tuple(jnp.where(real, x, 0).sum().astype(jnp.float32)
+                 for x in (last + 1, selected, scored))

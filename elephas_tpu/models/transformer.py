@@ -507,6 +507,9 @@ def make_paged_decode_cache(decode_module, max_slots: int, num_blocks: int,
     A latent leaf (``cached_latent``: one head, key and value in one) is
     laid out ``ops.attention.latent_leaf_shape``, a block's columns minor.
     An indexer's key (``cached_index_key``) is laid out as the latent is.
+    A block-sparse attention's compressed keys (``cached_compressed_key``)
+    are ``block_size / stride`` keys a block and head
+    (``ops.sparse_index.compressed_leaf_shape``).
     A state leaf (``models.decode_cache``: a recurrence's or a
     convolution's) is one row a slot, ``(max_slots, ...)``, never paged. A
     window layer's latent (``cached_window_latent``) is a ring of blocks a
@@ -523,6 +526,7 @@ def make_paged_decode_cache(decode_module, max_slots: int, num_blocks: int,
         ring_blocks,
     )
     from elephas_tpu.ops.attention import latent_leaf_shape, pool_leaf_shape
+    from elephas_tpu.ops.sparse_index import compressed_leaf_shape
 
     cache_shapes = jax.eval_shape(
         lambda: decode_module.init(
@@ -534,6 +538,9 @@ def make_paged_decode_cache(decode_module, max_slots: int, num_blocks: int,
         kind = leaf_kind(path)
         if kind == KV:
             _, heads, _, head_dim = s.shape
+            if leaf_name(path) == "cached_compressed_key":
+                return jnp.zeros(compressed_leaf_shape(
+                    num_blocks, heads, block_size, s.shape[2], head_dim), s.dtype)
             if leaf_name(path) in COLUMN_MINOR:
                 return jnp.zeros(
                     latent_leaf_shape(num_blocks, block_size, head_dim),

@@ -687,6 +687,229 @@ def paged_chunk_attention(q, k_new, v_new, k_pool, v_pool, row, start,
     return out, k_pool, v_pool
 
 
+# -- attention over selected blocks: a block-sparse layer's pages -------------
+#
+# A block-sparse attention (``ops.sparse_index``: selection by blocks) picks,
+# for each query and K/V head, whole blocks of the pool. A decode step reads
+# a lane's chosen pages alone, through a table of them. A chunk walks the
+# slot's live blocks a step of ``_SPARSE_CHUNK_STEP`` at a time, each query
+# scoring the blocks it chose (every one up to its own column, before
+# ``dense_len``) with an online softmax: on one TPU a kernel keeps a tile's
+# scores and running sums in VMEM; elsewhere the XLA body does the same
+# arithmetic a step at a time.
+
+# Blocks a step of the chunk's walk covers.
+_SPARSE_CHUNK_STEP = 8
+# Queries a tile of the chunk kernel.
+_SPARSE_QUERY_TILE = 256
+
+
+def block_sparse_decode_attention(q, k_pool, v_pool, table, chosen, idx,
+                                  head_dim: int, scale: float):
+    """One decode step's attention over each lane's chosen blocks.
+
+    ``q``: (slots, heads, group, D), a lane's query heads by its K/V head;
+    ``k_pool``/``v_pool``: pool leaves holding the step's columns;
+    ``table``: (slots, blocks_per_slot); ``chosen``: (slots, heads, W) the
+    blocks of the lane's row each K/V head attends, out-of-range numbers
+    past them (``sparse_index.chosen_blocks``); ``idx``: (slots,) the column
+    each lane just wrote. Only the chosen pages of the lane's own K/V head
+    are read. Returns (slots, heads, group, D)."""
+    bps = table.shape[1]
+    bs = k_pool.shape[2] * k_pool.shape[3] // head_dim
+    heads = jnp.arange(q.shape[1])[None, :, None]
+    with jax.named_scope("block_sparse_attention"):
+        phys = jnp.take_along_axis(table[:, None, :], jnp.clip(chosen, 0, bps - 1), 2)
+        kb = _unpack(k_pool[phys, heads], head_dim)  # (slots, heads, W, bs, D)
+        vb = _unpack(v_pool[phys, heads], head_dim)
+        cols = chosen[..., None] * bs + jnp.arange(bs)
+        ok = (chosen[..., None] < bps) & (cols <= idx[:, None, None, None])
+        s = jnp.einsum("shgd,shwbd->shgwb", q, kb,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(ok[:, :, None], s, jnp.finfo(jnp.float32).min)
+        p = jax.nn.softmax(s.reshape(*s.shape[:3], -1), axis=-1).reshape(s.shape)
+        out = jnp.einsum("shgwb,shwbd->shgd", p.astype(vb.dtype), vb,
+                         preferred_element_type=jnp.float32)
+    return out.astype(q.dtype)
+
+
+def _chunk_walk_xla(q, k_pool, v_pool, row, start, picked, head_dim: int,
+                    scale: float):
+    """The XLA body of ``block_sparse_chunk_attention``: the slot's live
+    blocks a step at a time, every query's scores under its mask, an online
+    softmax. Returns (H, g, T, D) float32."""
+    H, g, T, D = q.shape
+    bps = row.shape[0]
+    bs = k_pool.shape[2] * k_pool.shape[3] // head_dim
+    G = min(_SPARSE_CHUNK_STEP, bps)
+    last = start + jnp.arange(T)
+    floor = jnp.finfo(jnp.float32).min
+
+    def step(j, carry):
+        acc, m, l = carry
+        blocks = j * G + jnp.arange(G)
+        ids = jnp.clip(blocks, 0, bps - 1)
+        kb = _rows_of(_unpack(k_pool[row[ids]], head_dim))  # (H, G * bs, D)
+        vb = _rows_of(_unpack(v_pool[row[ids]], head_dim))
+        s = jnp.einsum("hgtd,hcd->hgtc", q, kb, preferred_element_type=jnp.float32) * scale
+        cols = (blocks[:, None] * bs + jnp.arange(bs)).reshape(-1)
+        chose = jnp.repeat(jnp.take(picked, ids, axis=1) > 0, bs, axis=1)  # (H, G * bs, T)
+        ok = jnp.swapaxes(chose, 1, 2) & (cols[None] <= last[:, None])[None] & \
+            jnp.repeat(blocks < bps, bs)[None, None]
+        s = jnp.where(ok[:, None], s, floor)
+        m_new = jnp.maximum(m, s.max(-1))
+        p = jnp.where(ok[:, None], jnp.exp(s - m_new[..., None]), 0.0)
+        fix = jnp.exp(m - m_new)
+        acc = acc * fix[..., None] + jnp.einsum(
+            "hgtc,hcd->hgtd", p.astype(vb.dtype), vb, preferred_element_type=jnp.float32)
+        return acc, m_new, l * fix + p.sum(-1)
+
+    acc, _, l = jax.lax.fori_loop(
+        0, (start + T - 1) // (bs * G) + 1, step,
+        (jnp.zeros((H, g, T, D), jnp.float32), jnp.full((H, g, T), floor),
+         jnp.zeros((H, g, T), jnp.float32)))
+    return acc / jnp.maximum(l, jnp.finfo(jnp.float32).tiny)[..., None]
+
+
+def _sparse_chunk_kernel(phys_ref, start_ref,  # scalar prefetch
+                         q_ref,                # (1, g, tq, D)
+                         m_ref,                # (1, G, tq) int32: a block of the step chosen
+                         *refs, G: int, scale: float):
+    """Grid step (K/V head ``h``, query tile ``i``, step ``j``): the tile's
+    queries of the group's heads against the step's ``G`` blocks, where the
+    step holds a column no later than the tile's last query."""
+    from jax.experimental import pallas as pl
+
+    k_refs, v_refs = refs[:G], refs[G:2 * G]
+    o_ref, acc_ref, max_ref, sum_ref = refs[2 * G:]
+    i, j = pl.program_id(1), pl.program_id(2)
+    g, tq = q_ref.shape[1], q_ref.shape[2]
+    bs = k_refs[0].shape[0]
+    floor = jnp.finfo(jnp.float32).min
+    start = start_ref[0]
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+        max_ref[...] = jnp.full(max_ref.shape, floor, jnp.float32)
+        sum_ref[...] = jnp.zeros(sum_ref.shape, jnp.float32)
+
+    @pl.when(j * G * bs <= start + (i + 1) * tq - 1)
+    def _step():
+        keys = jnp.concatenate([ref[...] for ref in k_refs], axis=0)  # (G * bs, D)
+        vals = jnp.concatenate([ref[...] for ref in v_refs], axis=0)
+        # a block's choice spread over its columns: (tq, G) through a 0/1 matrix
+        chose = jnp.transpose(m_ref[0].astype(jnp.float32))
+        spread = (jax.lax.broadcasted_iota(jnp.int32, (G, G * bs), 1) // bs ==
+                  jax.lax.broadcasted_iota(jnp.int32, (G, G * bs), 0)).astype(jnp.float32)
+        chose = jnp.dot(chose, spread, preferred_element_type=jnp.float32) > 0.5
+        t = start + i * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
+        cols = j * G * bs + jax.lax.broadcasted_iota(jnp.int32, (1, G * bs), 1)
+        ok = chose & (cols <= t)
+
+        def head(h, carry):
+            s = jax.lax.dot_general(q_ref[0, h], keys, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+            s = jnp.where(ok, s, floor)
+            m_prev = max_ref[h]
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+            fix = jnp.exp(m_prev - m_new)
+            sum_ref[h] = sum_ref[h] * fix + p.sum(axis=1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * fix + jnp.dot(
+                p.astype(vals.dtype), vals, preferred_element_type=jnp.float32)
+            max_ref[h] = m_new
+            return carry
+
+        jax.lax.fori_loop(0, g, head, 0)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _done():
+        o_ref[0] = (acc_ref[...] / jnp.maximum(sum_ref[...], jnp.finfo(jnp.float32).tiny)
+                    ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "tq", "interpret"))
+def pallas_block_sparse_chunk_attention(q, k_pool, v_pool, row, start, picked,
+                                        scale: float, tq: int | None = None,
+                                        interpret: bool = False):
+    """The chunk kernel. ``q``: (H, g, T, D); ``k_pool``/``v_pool``: pool
+    leaves of a lane width a column (``pool_leaf_shape`` with ``head_dim``
+    128); ``row``: (blocks_per_slot,); ``start``: scalar; ``picked``: (H,
+    blocks_per_slot, T), 1 where query ``t`` attends the block. The grid
+    walks the live steps alone (a step is ``_SPARSE_CHUNK_STEP`` blocks, the
+    last one holds column ``start + T - 1``), each K/V head and query tile
+    with its running sums in VMEM. Returns (H, g, T, D)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    H, g, T, D = q.shape
+    num_blocks, _, rows, lanes = k_pool.shape
+    bs = rows * lanes // D
+    bps = row.shape[0]
+    G = _SPARSE_CHUNK_STEP
+    most = -(-bps // G)
+    if tq is None:
+        tq = _widest_divisor(T, _SPARSE_QUERY_TILE)
+    phys = jnp.clip(row.astype(jnp.int32), 0, num_blocks - 1)
+    phys = jnp.pad(phys, (0, most * G - bps), mode="edge")
+    picked = jnp.pad(picked.astype(jnp.int32), ((0, 0), (0, most * G - bps), (0, 0)))
+    start = jnp.asarray(start, jnp.int32)
+    steps = jnp.clip((start + T - 1) // (bs * G) + 1, 1, most)
+
+    def pages(b):
+        return pl.BlockSpec((None, None, rows, lanes),
+                            lambda h, i, j, phys, *_, b=b: (phys[j * G + b], h, 0, 0))
+
+    return pl.pallas_call(
+        functools.partial(_sparse_chunk_kernel, G=G, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(H, T // tq, steps),
+            in_specs=[pl.BlockSpec((1, g, tq, D), lambda h, i, j, *_: (h, 0, i, 0)),
+                      pl.BlockSpec((1, G, tq), lambda h, i, j, *_: (h, j, i)),
+                      *[pages(b) for b in range(G)], *[pages(b) for b in range(G)]],
+            out_specs=pl.BlockSpec((1, g, tq, D), lambda h, i, j, *_: (h, 0, i, 0)),
+            scratch_shapes=[pltpu.VMEM((g, tq, D), jnp.float32),
+                            pltpu.VMEM((g, tq, 1), jnp.float32),
+                            pltpu.VMEM((g, tq, 1), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((H, g, T, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name="block_sparse_chunk_attention",
+    )(phys, start.reshape(1), q, picked, *([k_pool] * G), *([v_pool] * G))
+
+
+def block_sparse_chunk_fits(pool_shape, head_dim: int, chunk: int) -> bool:
+    """Whether the chunk kernel's tiles lower for this pool layout: a column
+    a lane width, whole sublane tiles a block, and a query tile of whole
+    lanes."""
+    _, _, rows, lanes = pool_shape
+    return (lanes == head_dim == _LANES and rows % 16 == 0
+            and _widest_divisor(chunk, _SPARSE_QUERY_TILE) % _LANES == 0)
+
+
+def block_sparse_chunk_attention(q, k_pool, v_pool, row, start, picked,
+                                 head_dim: int, scale: float, body: str):
+    """One prefill chunk's attention of ONE slot over the blocks each query
+    chose. ``q``: (heads, group, T, D); ``row``: (blocks_per_slot,);
+    ``start``: the chunk's first column; ``picked``: (heads, blocks_per_slot,
+    T), 1 where query ``t`` of K/V head ``h`` attends the block (every live
+    one for a query before ``dense_len``). Query ``i`` attends columns ``<=
+    start + i`` of its blocks. ``body`` ``"paged_pallas"`` runs the kernel
+    where its tiles lower. Returns (heads, group, T, D)."""
+    with jax.named_scope("block_sparse_attention"):
+        if body == "paged_pallas" and block_sparse_chunk_fits(k_pool.shape, head_dim,
+                                                              q.shape[2]):
+            return pallas_block_sparse_chunk_attention(q, k_pool, v_pool, row, start,
+                                                       picked, scale)
+        return _chunk_walk_xla(q, k_pool, v_pool, row, start, picked, head_dim,
+                               scale).astype(q.dtype)
+
+
 def pallas_min_seq(head_dim: int) -> int:
     """Sequence length above which the Pallas kernels beat the XLA
     blockwise path, as a function of head_dim (VERDICT r4 #7 — the r4

@@ -25,11 +25,17 @@ XLA body (the CPU's, and the kernels' reference) and a kernel for one TPU:
   32-bit key bit by bit (32 counting passes over the row), and of the
   columns that tie with it the lowest are taken by 16 more passes over
   their column numbers: nothing is sorted and nothing approximated.
+
+Below the indexer, a second rule of the same kind: selection by BLOCKS of
+the pool (InfLLM-v2's), by compressed keys a paged leaf of their own holds,
+with the same exact selection over block scores (section "selection by
+blocks").
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -309,3 +315,267 @@ def select_columns(scores, last, k: int, body: str):
         cols = jnp.arange(scores.shape[1], dtype=jnp.int32)[None]
         return _select_rows(scores, cols, last.astype(jnp.int32)[:, None],
                             k).astype(jnp.int8)
+
+
+# -- selection by blocks ------------------------------------------------------
+#
+# A block-sparse attention (InfLLM-v2's) selects whole blocks of columns, and
+# a block is a page of the pool. It scores them by COMPRESSED keys: of each
+# K/V head, key ``i`` is the mean of the keys of columns ``stride * i ..
+# stride * i + kernel - 1`` and exists once the last of them is written.
+# A query ``t`` of a group ``g`` of query heads::
+#
+#     p_h[t, i] = softmax over the keys i that exist at t of (q_h,t . c_g,i * scale)
+#     B_g[t, b] = max over the keys i that start in block b of sum_{h in g} p_h[t, i]
+#     Sel_g(t)  = the first init_blocks blocks, the blocks that hold a column of
+#                 t - window + 1 .. t, and the blocks of largest B_g[t, .] among
+#                 the rest, until top_k blocks; the lower block wins a tie;
+#                 every block up to t's where t < dense_len
+#
+# The compressed keys are a paged leaf of their own, ``block / stride`` keys a
+# block, written through the slot's block table as the columns that complete
+# them are: key ``i`` lives in the block it starts in, and the last keys of a
+# block are complete only once the next block's first columns are written.
+
+
+class BlockSelection(NamedTuple):
+    """The sizes of a block-sparse attention's selection, in columns where
+    not said otherwise."""
+
+    kernel: int       # columns a compressed key is the mean of
+    stride: int       # columns between two compressed keys' first
+    block: int        # columns a block (a page of the pool)
+    top_k: int        # blocks a query attends, the forced ones among them
+    init_blocks: int  # leading blocks every query attends
+    window: int       # columns behind a query whose blocks it attends
+    dense_len: int    # a query before this column attends every column
+
+    @property
+    def keys_per_block(self) -> int:
+        return self.block // self.stride
+
+    @property
+    def table_width(self) -> int:
+        """Blocks a query can attend: ``top_k``, or every block up to
+        ``dense_len``'s."""
+        return max(self.top_k, -(-self.dense_len // self.block))
+
+
+def compressed_leaf_shape(num_blocks: int, heads: int, block_size: int, stride: int,
+                          width: int):
+    """The compressed keys' pool leaf: ``block_size / stride`` keys a block
+    and K/V head."""
+    return (num_blocks, heads, block_size // stride, width)
+
+
+def _pool_columns(pool, table, first, count: int, head_dim: int):
+    """Columns ``first .. first + count - 1`` of each row's K/V head(s):
+    ``pool`` a packed K/V leaf (``ops.attention.pool_leaf_shape``), ``table``
+    (rows, blocks_per_slot), ``first`` (rows,). Returns (rows, heads, count,
+    head_dim); columns before 0 or past the row's blocks hold anything."""
+    rows, bps = table.shape
+    bs = pool.shape[2] * pool.shape[3] // head_dim
+    n = (count - 2) // bs + 2  # blocks a run of `count` columns can touch
+    j0 = jnp.floor_divide(first, bs)
+    ids = jnp.take_along_axis(table, jnp.clip(j0[:, None] + jnp.arange(n), 0, bps - 1), 1)
+    blocks = pool[ids]  # (rows, n, heads, r, lanes); OOB ids clamp
+    blocks = blocks.reshape(*blocks.shape[:-2], bs, head_dim)
+    cols = jnp.moveaxis(blocks, 1, 2).reshape(rows, blocks.shape[2], n * bs, head_dim)
+    return jax.vmap(lambda c, at: jax.lax.dynamic_slice_in_dim(c, at, count, 1))(
+        cols, first - j0 * bs)
+
+
+def _mean_keys(window, starts, kernel: int):
+    """``window``: (rows, heads, W, D) consecutive columns; ``starts``: (rows,
+    M) each key's first column in it. Returns (rows, M, heads, D), the mean
+    of ``kernel`` columns from each start, float32."""
+    idx = starts[:, :, None] + jnp.arange(kernel)  # (rows, M, kernel)
+    got = jax.vmap(lambda w, i: jnp.take(w, i, axis=1, mode="clip"))(window, idx)
+    return jnp.moveaxis(got.astype(jnp.float32).mean(axis=3), 1, 2)
+
+
+def _put_keys(leaf, table, keys, numbers, ok, sel: BlockSelection):
+    """Write ``keys`` (rows, M, heads, D), compressed keys ``numbers`` (rows,
+    M) of each row, where ``ok``: into the block each starts in, through the
+    row's table; the others drop."""
+    num_blocks = leaf.shape[0]
+    bps = table.shape[1]
+    j = numbers * sel.stride // sel.block
+    ok = ok & (numbers >= 0) & (j < bps)
+    ids = jnp.take_along_axis(table, jnp.clip(j, 0, bps - 1), 1)
+    ids = jnp.where(ok, ids, num_blocks)
+    at = numbers % sel.keys_per_block
+    return leaf.at[ids.reshape(-1), :, at.reshape(-1)].set(
+        keys.reshape(-1, *keys.shape[2:]).astype(leaf.dtype), mode="drop")
+
+
+def write_chunk_keys(leaf, k_pool, row, start, valid, k_new, sel: BlockSelection,
+                     head_dim: int):
+    """Write the compressed keys that a prefill chunk's columns ``start ..
+    start + valid - 1`` complete. ``k_pool`` already holds the chunk's keys;
+    ``k_new``: (heads, T, D), those keys; the ``kernel - 1`` columns before
+    ``start`` are read back from the pool."""
+    T = k_new.shape[1]
+    K, s = sel.kernel, sel.stride
+    lo = start - (K - 1)
+    before = _pool_columns(k_pool, row[None], jnp.reshape(lo, (1,)), K - 1, head_dim)
+    window = jnp.concatenate([before, k_new[None].astype(before.dtype)], axis=2)
+    first = jnp.maximum(-(-lo // s), 0)  # the first key whose last column is start's or later
+    numbers = first + jnp.arange(T // s + 1)
+    keys = _mean_keys(window, (numbers * s - lo)[None], K)
+    ok = numbers * s + K - 1 < start + valid
+    return _put_keys(leaf, row[None], keys, numbers[None], ok[None], sel)
+
+
+def write_step_keys(leaf, k_pool, table, idx, active, sel: BlockSelection, head_dim: int):
+    """Write the compressed key that each active lane's new column ``idx``
+    completes, if it completes one. ``k_pool`` already holds the column."""
+    K, s = sel.kernel, sel.stride
+    lo = idx - (K - 1)
+    done = active & (lo >= 0) & (lo % s == 0)
+    window = _pool_columns(k_pool, table, lo, K, head_dim)
+    keys = _mean_keys(window, jnp.zeros((idx.shape[0], 1), jnp.int32), K)
+    return _put_keys(leaf, table, keys, (lo // s)[:, None], done[:, None], sel)
+
+
+def row_keys(leaf, table):
+    """Every compressed key of each row, in key order: (rows, heads,
+    blocks_per_slot * keys_per_block, D)."""
+    keys = leaf[table]  # (rows, bps, heads, per_block, D); OOB ids clamp
+    keys = jnp.moveaxis(keys, 2, 1)
+    return keys.reshape(*keys.shape[:2], -1, keys.shape[-1])
+
+
+def block_scores(q, keys, last, sel: BlockSelection, scale: float):
+    """``B_g[t, b]``. ``q``: (rows, heads, group, D), a query's heads by K/V
+    head; ``keys``: (rows, heads, n_keys, D) each row's compressed keys in
+    key order (``row_keys``), or (heads, n_keys, D) the one slot's that
+    every row reads; ``last``: (rows,) each query's column. Returns (rows,
+    heads, n_keys / keys_per_block) float32, 0 where a block has no key
+    yet."""
+    rows, heads, n_keys = q.shape[0], keys.shape[-3], keys.shape[-2]
+    with jax.named_scope("block_scores"):
+        s = jnp.einsum("rhgd,rhkd->rhgk" if keys.ndim == 4 else "rhgd,hkd->rhgk", q,
+                       keys.astype(q.dtype), preferred_element_type=jnp.float32) * scale
+        exists = (jnp.arange(n_keys) * sel.stride + sel.kernel - 1 <= last[:, None])
+        exists = exists[:, None, None]
+        top = jnp.max(jnp.where(exists, s, jnp.finfo(jnp.float32).min), -1, keepdims=True)
+        e = jnp.where(exists, jnp.exp(s - top), 0.0)
+        p = e / jnp.maximum(e.sum(-1, keepdims=True), jnp.finfo(jnp.float32).tiny)
+        P = p.sum(2)  # (rows, heads, n_keys)
+        return P.reshape(rows, heads, -1, sel.keys_per_block).max(-1)
+
+
+def select_blocks(scores, last, sel: BlockSelection, body: str):
+    """``Sel(t)`` as a mask (rows, blocks) int8 of 0 and 1. ``scores``: (rows,
+    blocks) float32 (``block_scores``); ``last``: (rows,) each query's
+    column. The forced blocks are given the largest score there is, so the
+    exact top-``k`` of ``select_columns`` (ties to the lower block) takes
+    them first."""
+    rows, nb = scores.shape
+    width = -(-nb // 128) * 128  # the selection kernel's rows are whole lanes
+    blocks = jnp.arange(width)
+    own = last // sel.block
+    local = (blocks[None] + 1) * sel.block - 1 >= last[:, None] - sel.window + 1
+    forced = (blocks[None] < sel.init_blocks) | local
+    keyed = jnp.where(forced, jnp.inf, jnp.pad(scores, ((0, 0), (0, width - nb))))
+    with jax.named_scope("select_blocks"):
+        mask = select_columns(keyed, own, sel.top_k, body)
+    dense = (last < sel.dense_len)[:, None] & (blocks[None] <= own[:, None])
+    return jnp.where(dense, jnp.int8(1), mask)[:, :nb]
+
+
+def chosen_blocks(mask, width: int):
+    """The selected blocks of each row in ascending order, ``(rows, width)``,
+    then the out-of-range block number in the places left."""
+    rows, nb = mask.shape
+    blocks = jnp.arange(nb, dtype=jnp.int32)
+    key = jnp.where(mask > 0, -blocks, -nb)  # the lowest block first
+    top, _ = jax.lax.top_k(key, min(width, nb))
+    top = jnp.pad(top, ((0, 0), (0, width - top.shape[1])), constant_values=-nb)
+    return jnp.where(top > -nb, -top, nb)
+
+
+def _block_scores_kernel(start_ref,   # scalar prefetch: (1,) int32
+                         q_ref,       # (1, group, tq, D)
+                         k_ref,       # (1, keys_per_block, nbp, D)
+                         o_ref,       # (1, tq, nbp)
+                         p_ref,       # scratch (keys_per_block, tq, nbp) float32
+                         *, sel: BlockSelection, scale: float):
+    i = pl.program_id(1)
+    group, tq, _ = q_ref.shape[1:]
+    per_block, nbp = k_ref.shape[1], k_ref.shape[2]
+    t = start_ref[0] + i * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
+    first = jax.lax.broadcasted_iota(jnp.int32, (1, nbp), 1) * sel.block
+    ends = [first + r * sel.stride + sel.kernel - 1 for r in range(per_block)]
+
+    @pl.when(start_ref[0] + (i + 1) * tq - 1 < sel.dense_len)
+    def _dense():  # every query of the tile attends every column: nothing to score
+        o_ref[0] = jnp.zeros((tq, nbp), jnp.float32)
+
+    @pl.when(start_ref[0] + (i + 1) * tq - 1 >= sel.dense_len)
+    def _score():
+        p_ref[...] = jnp.zeros(p_ref.shape, jnp.float32)
+
+        def head(h, carry):
+            qh = q_ref[0, h]
+            s = [jnp.where(ends[r] <= t, jax.lax.dot_general(
+                qh, k_ref[0, r], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale, -jnp.inf)
+                for r in range(per_block)]
+            top = s[0].max(axis=1, keepdims=True)
+            for r in range(1, per_block):
+                top = jnp.maximum(top, s[r].max(axis=1, keepdims=True))
+            top = jnp.where(top == -jnp.inf, 0.0, top)
+            e = [jnp.exp(x - top) for x in s]
+            total = e[0].sum(axis=1, keepdims=True)
+            for r in range(1, per_block):
+                total = total + e[r].sum(axis=1, keepdims=True)
+            inv = 1.0 / jnp.maximum(total, jnp.finfo(jnp.float32).tiny)
+            for r in range(per_block):
+                p_ref[r] += e[r] * inv
+            return carry
+
+        jax.lax.fori_loop(0, group, head, 0)
+        best = p_ref[0]
+        for r in range(1, per_block):
+            best = jnp.maximum(best, p_ref[r])
+        o_ref[0] = best
+
+
+@functools.partial(jax.jit, static_argnames=("sel", "scale", "tq", "interpret"))
+def pallas_block_scores(q, keys, start, sel: BlockSelection, scale: float,
+                        tq: int | None = None, interpret: bool = False):
+    """``B_g[t, b]`` of one chunk of ONE slot. ``q``: (heads, group, T, D), a
+    query's heads by K/V head; ``keys``: (heads, n_keys, D) the slot's
+    compressed keys in key order (``row_keys``); ``start``: the chunk's first
+    column. Returns (heads, T, blocks) float32, the blocks padded to whole
+    lanes; a tile whose queries all attend every column is not scored and
+    reads 0. A grid step is one K/V head and a tile of queries: the slot's
+    keys stay in VMEM, laid out a plane for each key of a block, so that a
+    block's score is the largest of its planes' and no lane is reshaped."""
+    heads, group, T, D = q.shape
+    per_block = sel.keys_per_block
+    nb = keys.shape[1] // per_block
+    nbp = -(-nb // 128) * 128
+    if tq is None:
+        tq = max(d for d in range(8, min(T, 256) + 1, 8) if T % d == 0)
+    planes = jnp.swapaxes(keys.reshape(heads, nb, per_block, D), 1, 2)
+    planes = jnp.pad(planes, ((0, 0), (0, 0), (0, nbp - nb), (0, 0))).astype(q.dtype)
+    out = pl.pallas_call(
+        functools.partial(_block_scores_kernel, sel=sel, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(heads, T // tq),
+            in_specs=[pl.BlockSpec((1, group, tq, D), lambda h, i, *_: (h, 0, i, 0)),
+                      pl.BlockSpec((1, per_block, nbp, D), lambda h, i, *_: (h, 0, 0, 0))],
+            out_specs=pl.BlockSpec((1, tq, nbp), lambda h, i, *_: (h, i, 0)),
+            scratch_shapes=[pltpu.VMEM((per_block, tq, nbp), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((heads, T, nbp), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=_SPARSE_VMEM),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name="block_scores",
+    )(jnp.reshape(start, (1,)).astype(jnp.int32), q, planes)
+    return out

@@ -593,10 +593,11 @@ class InferenceEngine:
         leaf's heads are the model's K/V heads, which grouped attention
         has fewer of than query heads; a block is ``block_size`` columns
         a head. Of a latent pool, the latent's leaf (an indexer's key beside
-        it is a narrower leaf of the same layout)."""
+        it is a narrower leaf of the same layout); of a pool with compressed
+        keys beside its K/V, a key's leaf."""
         found = leaves_of_kind(self.pool.cache, KV)
         leaf = next((leaf for path, leaf in found
-                     if leaf_name(path) == "cached_latent"), found[0][1])
+                     if leaf_name(path) in ("cached_latent", "cached_key")), found[0][1])
         _, _, rows, lanes = leaf.shape
         return leaf, rows * lanes // self.pool.block_size
 

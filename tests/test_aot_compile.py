@@ -551,6 +551,104 @@ def test_both_programs_of_the_sparse_latent_cell_compile_for_v5e(one_chip, monke
         assert sizes[name].temp_size_in_bytes / 1e9 < float(most) + 0.0005, name
 
 
+def test_sparse_linear_kernels_compile_for_v5e(one_chip):
+    """`minicpm-sala`'s kernels at the published widths: the lightning chunk
+    form over a chunk of 2,048 (32 heads of 128, sub-chunks of 256); the
+    compressed keys' block scores of a chunk
+    (2 K/V heads of 16 query heads, 1,568 blocks a slot); the chunk's
+    attention over its chosen pages of 64 columns."""
+    from elephas_tpu.ops import attention, lightning, sparse_index
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    jax.jit(lightning.pallas_lightning_chunk).lower(
+        *[shape((32, 2048, 128))] * 3, shape((32,)), shape((32, 128, 128)),
+        shape((), i32)).compile()
+    sel = sparse_index.BlockSelection(32, 16, 64, 64, 1, 2048, 8192)
+    jax.jit(lambda q, k, s: sparse_index.pallas_block_scores(q, k, s, sel, 128 ** -0.5)).lower(
+        shape((2, 16, 2048, 128), bf16), shape((2, 1568 * 4, 128), bf16),
+        shape((), i32)).compile()
+    pool = shape((16 * 1568, 2, 64, 128), bf16)
+    jax.jit(lambda q, kp, vp, row, s, picked: attention.pallas_block_sparse_chunk_attention(
+        q, kp, vp, row, s, picked, 128 ** -0.5)).lower(
+        shape((2, 16, 2048, 128), bf16), pool, pool, shape((1568,), i32), shape((), i32),
+        shape((2, 1568, 2048), jnp.int8)).compile()
+
+
+def test_both_programs_of_the_sparse_linear_cell_compile_for_v5e(one_chip, monkeypatch):
+    """`minicpm-sala.doc-mix-96k` whole, at the published widths and the
+    cell's serving sizes (16 slots of 1,568 blocks of 64, chunks of 2,048),
+    through `benchmark/aot_compile.py::serving` as the harness builds the
+    engine, with every kernel steered on: both programs fit the chip beside
+    their temporaries, the pool and the state rows come back in place, and
+    the configuration file's `memory_reckoning` quotes what this compile
+    reports. The pool is built on the host (1.8 GB of zeros)."""
+    import importlib.util
+    import json
+    import sys
+
+    from elephas_tpu.ops import attention, lightning
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                         "benchmark")
+    monkeypatch.setattr(sys, "path", list(sys.path))  # the script adds its own
+    spec = importlib.util.spec_from_file_location(
+        "bench_aot_compile", os.path.join(bench, "aot_compile.py"))
+    aot = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(aot)
+    # the described chip is not the default backend: steer the choice here
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(lightning, "_on_tpu", lambda: True)
+    programs = {}
+    monkeypatch.setattr(aot, "report", lambda name, compiled: programs.update(
+        {name.split()[-1]: compiled}))
+
+    class Described:
+        devices = [next(iter(one_chip.device_set))]
+
+    aot.serving(Described, ["minicpm-sala.doc-mix-96k"])
+    chunk, decode = programs["jit__chunk_prefill_impl"], programs["jit__paged_decode_impl"]
+
+    def kernels(program):  # the Pallas calls' lines: an XLA body's scope is not one
+        return [line for line in program.as_text().splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line]
+
+    for kernel in ("lightning_chunk", "block_scores", "select_columns",
+                   "block_sparse_chunk_attention"):
+        assert any(kernel in line for line in kernels(chunk)), kernel
+    # the decode step's lightning layers and its attention over the chosen
+    # pages are XLA's; the selection is the one kernel there
+    assert any("select_columns" in line for line in kernels(decode))
+    assert not any("lightning" in line for line in kernels(decode))
+    from conftest import assert_report_is_whole
+    from elephas_tpu.obs.programs import ProgramReport
+
+    for name, program in programs.items():  # which part issued each instruction
+        report = ProgramReport.from_compiled(program)
+        assert report.program == name
+        assert_report_is_whole(report)
+        parts = set(report.parts())
+        for scope in ("lightning", "sparse_attention", "select_blocks", "rope"):
+            assert any(scope in p.split("/") for p in parts), (name, scope)
+    sizes = {name: program.memory_analysis() for name, program in programs.items()}
+    for name, m in sizes.items():
+        print(f"minicpm-sala {name}, 16 slots, v5e:", m)
+        # weights 3.42 GB, the pool 1.70 with its compressed keys, state rows 0.10
+        assert 5.2e9 < m.argument_size_in_bytes < 5.25e9
+        assert m.alias_size_in_bytes >= 1.69e9 + 0.10e9  # no leaf of the pool copied
+    arguments = sizes["jit__chunk_prefill_impl"].argument_size_in_bytes
+    with open(os.path.join(bench, "configs", "minicpm-sala.json")) as f:
+        reckoning = json.load(f)["memory_reckoning"]
+    assert f"{arguments / 1e9:.2f} GB of arguments" in reckoning
+    stated = re.search(r"\+ ([0-9.]+) GB of temporaries in the chunk program.* and "
+                       r"([0-9.]+) GB in the decode program", reckoning)
+    for name, most in zip(("jit__chunk_prefill_impl", "jit__paged_decode_impl"),
+                          stated.groups()):
+        assert sizes[name].temp_size_in_bytes / 1e9 < float(most) + 0.0005, name
+
+
 # -- which model part issued each instruction (obs.programs.ProgramReport) -----
 
 
