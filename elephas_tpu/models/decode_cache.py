@@ -272,10 +272,12 @@ def attend_block_sparse(q, k, v, cached_key, cached_value, cached_compressed,
     head_dim), ``k``/``v``: (rows, heads, T, head_dim); a decode step under
     ``active``, one slot's chunk without it, as ``attend_paged`` has them.
 
-    Returns ``(out, (live, selected, scored))``: (rows, q_heads, T,
-    head_dim), and over the apply's real queries and K/V heads the columns
-    each could see, the columns it attended and the blocks it scored (none
-    for a query that attends every column)."""
+    Returns ``(out, (live, selected, scored, gathered))``: (rows, q_heads,
+    T, head_dim), and over the apply's real queries and K/V heads the
+    columns each could see, the columns it attended, the blocks it scored
+    (none for a query that attends every column) and the chosen pages the
+    chunk kernel fetched for it one by one (none on a decode step, or where
+    the chunk walks)."""
     from elephas_tpu.ops.attention import (
         _write_windows,
         block_sparse_chunk_attention,
@@ -315,6 +317,7 @@ def attend_block_sparse(q, k, v, cached_key, cached_value, cached_compressed,
                                             scale).reshape(rows, q_heads, 1, D)
         cache_index.value = jnp.where(active, idx + 1, idx)
         real = jnp.repeat(active, heads)
+        gathered = jnp.zeros((), jnp.int32)
     else:
         if rows != 1:
             raise ValueError(f"a prefill chunk over the paged pool is one slot's; got {rows} rows")
@@ -341,13 +344,13 @@ def attend_block_sparse(q, k, v, cached_key, cached_value, cached_compressed,
         last = jnp.repeat(at, heads)
         mask = select_blocks(scores[..., :nb].reshape(T * heads, nb), last, sel, paged.body)
         picked = jnp.moveaxis(mask.reshape(T, heads, nb), 0, 2)  # (heads, nb, T)
-        out = block_sparse_chunk_attention(jnp.moveaxis(qt, 0, 2), k_pool, v_pool, row,
-                                           start, picked, D, scale, paged.body)
-        out = out.reshape(q_heads, T, D)[None]
+        out, gathered = block_sparse_chunk_attention(qt, k_pool, v_pool, row, start, picked,
+                                                     D, scale, paged.body, sel, valid)
+        out = jnp.moveaxis(out.reshape(T, q_heads, D), 0, 1)[None]
         cache_index.value = idx + T
         real = jnp.repeat(jnp.arange(T) < valid, heads)
     cached_key.value, cached_value.value, cached_compressed.value = k_pool, v_pool, comp
-    return out, _block_counts(mask, last, real, sel)
+    return out, _block_counts(mask, last, real, sel) + (gathered.astype(jnp.float32),)
 
 
 def _block_counts(mask, last, real, sel):

@@ -42,10 +42,10 @@ for its positions; a sparse layer's holds ``cached_key`` /
 layer from the slot's state row and leaves the state after ``valid``
 tokens; a decode step steps the active lanes' rows alone. A sparse layer
 attends the pages it selected (``decode_cache.attend_block_sparse``) and
-sows ``sparse_columns_live``, ``sparse_columns_selected`` and
-``sparse_blocks_scored`` into ``counters``. Given ``valid``, the apply
-returns the logits of position ``valid - 1`` alone, ``(batch, 1,
-vocab)``.
+sows ``sparse_columns_live``, ``sparse_columns_selected``,
+``sparse_blocks_scored`` and ``sparse_pages_gathered`` into ``counters``.
+Given ``valid``, the apply returns the logits of position ``valid - 1``
+alone, ``(batch, 1, vocab)``.
 """
 
 from __future__ import annotations
@@ -223,12 +223,13 @@ class SparseAttention(nn.Module):
             if paged is None:
                 raise NotImplementedError("a block-sparse layer decodes over the paged "
                                           "pool alone")
-            out, (live, selected, scored) = attend_block_sparse(
+            out, (live, selected, scored, gathered) = attend_block_sparse(
                 q, k, v, cached_key, cached_value, compressed, index, active, paged,
                 sel, scale)
             self.sow("counters", "sparse_columns_live", live)
             self.sow("counters", "sparse_columns_selected", selected)
             self.sow("counters", "sparse_blocks_scored", scored)
+            self.sow("counters", "sparse_pages_gathered", gathered)
         else:
             out = _full_block_sparse(q, k, v, sel, scale)
         out = jnp.swapaxes(out, 1, 2).reshape(b, T, Hq * D)
@@ -265,7 +266,11 @@ class SALALayer(nn.Module):
         with jax.named_scope("mlp"):
             y = RMSNorm(self.eps, name="mlp_norm")(x).astype(self.dtype)
             ff = gated_feed_forward(y, self.d_ff, self.dtype)
-            return x + (self.residual * ff.astype(jnp.float32)).astype(x.dtype)
+            # the layer's output, whole: XLA would otherwise rebuild the residual
+            # stream in each later layer's fusions from every branch output
+            # before it, and keep all of them to the program's end
+            return jax.lax.optimization_barrier(
+                x + (self.residual * ff.astype(jnp.float32)).astype(x.dtype))
 
 
 class MiniCPMSALA(nn.Module):

@@ -691,17 +691,24 @@ def paged_chunk_attention(q, k_new, v_new, k_pool, v_pool, row, start,
 #
 # A block-sparse attention (``ops.sparse_index``: selection by blocks) picks,
 # for each query and K/V head, whole blocks of the pool. A decode step reads
-# a lane's chosen pages alone, through a table of them. A chunk walks the
-# slot's live blocks a step of ``_SPARSE_CHUNK_STEP`` at a time, each query
-# scoring the blocks it chose (every one up to its own column, before
-# ``dense_len``) with an online softmax: on one TPU a kernel keeps a tile's
-# scores and running sums in VMEM; elsewhere the XLA body does the same
-# arithmetic a step at a time.
+# a lane's chosen pages alone, through a table of them. A chunk's XLA body
+# walks the slot's live blocks a step of ``_SPARSE_CHUNK_STEP`` at a time,
+# each query scoring the blocks it chose (every one up to its own column,
+# before ``dense_len``) with an online softmax. On one TPU a kernel keeps a
+# tile's running sums in VMEM and walks only the tile's band: every live
+# step where the tile has a query before ``dense_len``, else the blocks its
+# queries are forced to attend (the init blocks and those of the window),
+# which a tile of queries shares. Each query then fetches the pages it chose
+# by score outside the band with copies of its own and scores them alone.
 
 # Blocks a step of the chunk's walk covers.
 _SPARSE_CHUNK_STEP = 8
 # Queries a tile of the chunk kernel.
 _SPARSE_QUERY_TILE = 256
+# Words a tile of a 1-D array in SMEM holds: a block of one is whole tiles.
+_SMEM_TILE = 1024
+# Blocks a word of the kernel's copy of the selection holds, a bit each.
+_WORD = 32
 
 
 def block_sparse_decode_attention(q, k_pool, v_pool, table, chosen, idx,
@@ -771,116 +778,338 @@ def _chunk_walk_xla(q, k_pool, v_pool, row, start, picked, head_dim: int,
     return acc / jnp.maximum(l, jnp.finfo(jnp.float32).tiny)[..., None]
 
 
-def _sparse_chunk_kernel(phys_ref, start_ref,  # scalar prefetch
-                         q_ref,                # (1, g, tq, D)
-                         m_ref,                # (1, G, tq) int32: a block of the step chosen
-                         *refs, G: int, scale: float):
-    """Grid step (K/V head ``h``, query tile ``i``, step ``j``): the tile's
-    queries of the group's heads against the step's ``G`` blocks, where the
-    step holds a column no later than the tile's last query."""
-    from jax.experimental import pallas as pl
-
-    k_refs, v_refs = refs[:G], refs[G:2 * G]
-    o_ref, acc_ref, max_ref, sum_ref = refs[2 * G:]
-    i, j = pl.program_id(1), pl.program_id(2)
-    g, tq = q_ref.shape[1], q_ref.shape[2]
-    bs = k_refs[0].shape[0]
-    floor = jnp.finfo(jnp.float32).min
-    start = start_ref[0]
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
-        max_ref[...] = jnp.full(max_ref.shape, floor, jnp.float32)
-        sum_ref[...] = jnp.zeros(sum_ref.shape, jnp.float32)
-
-    @pl.when(j * G * bs <= start + (i + 1) * tq - 1)
-    def _step():
-        keys = jnp.concatenate([ref[...] for ref in k_refs], axis=0)  # (G * bs, D)
-        vals = jnp.concatenate([ref[...] for ref in v_refs], axis=0)
-        # a block's choice spread over its columns: (tq, G) through a 0/1 matrix
-        chose = jnp.transpose(m_ref[0].astype(jnp.float32))
-        spread = (jax.lax.broadcasted_iota(jnp.int32, (G, G * bs), 1) // bs ==
-                  jax.lax.broadcasted_iota(jnp.int32, (G, G * bs), 0)).astype(jnp.float32)
-        chose = jnp.dot(chose, spread, preferred_element_type=jnp.float32) > 0.5
-        t = start + i * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
-        cols = j * G * bs + jax.lax.broadcasted_iota(jnp.int32, (1, G * bs), 1)
-        ok = chose & (cols <= t)
-
-        def head(h, carry):
-            s = jax.lax.dot_general(q_ref[0, h], keys, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale
-            s = jnp.where(ok, s, floor)
-            m_prev = max_ref[h]
-            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-            p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
-            fix = jnp.exp(m_prev - m_new)
-            sum_ref[h] = sum_ref[h] * fix + p.sum(axis=1, keepdims=True)
-            acc_ref[h] = acc_ref[h] * fix + jnp.dot(
-                p.astype(vals.dtype), vals, preferred_element_type=jnp.float32)
-            max_ref[h] = m_new
-            return carry
-
-        jax.lax.fori_loop(0, g, head, 0)
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _done():
-        o_ref[0] = (acc_ref[...] / jnp.maximum(sum_ref[...], jnp.finfo(jnp.float32).tiny)
-                    ).astype(o_ref.dtype)
+def _words_of(picked):
+    """``picked`` (H, blocks, T) as words of 32 blocks' bits: (H, blocks /
+    32, T) int32, bit ``b % 32`` of word ``b // 32`` set where query ``t``
+    attends block ``b``."""
+    H, nb, T = picked.shape
+    words = -(-nb // _WORD)
+    bits = jnp.pad(picked, ((0, 0), (0, words * _WORD - nb), (0, 0))).reshape(H, words, _WORD, T)
+    weight = jnp.left_shift(1, jnp.arange(_WORD, dtype=jnp.int32))[:, None]
+    packed = jnp.where(bits > 0, weight, 0).sum(2, dtype=jnp.int32)
+    return jnp.pad(packed, ((0, 0), (0, -words % 8), (0, 0)))  # whole sublane tiles
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "tq", "interpret"))
-def pallas_block_sparse_chunk_attention(q, k_pool, v_pool, row, start, picked,
-                                        scale: float, tq: int | None = None,
-                                        interpret: bool = False):
-    """The chunk kernel. ``q``: (H, g, T, D); ``k_pool``/``v_pool``: pool
-    leaves of a lane width a column (``pool_leaf_shape`` with ``head_dim``
-    128); ``row``: (blocks_per_slot,); ``start``: scalar; ``picked``: (H,
-    blocks_per_slot, T), 1 where query ``t`` attends the block. The grid
-    walks the live steps alone (a step is ``_SPARSE_CHUNK_STEP`` blocks, the
-    last one holds column ``start + T - 1``), each K/V head and query tile
-    with its running sums in VMEM. Returns (H, g, T, D)."""
+def _nth_set(words, n: int):
+    """The first ``n`` set bits of each column of ``words`` (H, words, T)
+    in order, as bit numbers: (H, n, T) int32, 0 past the column's count;
+    and the count (H, T). Each word's set bits are counted, and the n-th is
+    found inside its word: no sort and no scan."""
+    nw = words.shape[1]
+    held = jax.lax.population_count(words)
+    j = jnp.arange(nw)
+    before = (held[:, None] * (j[None, :] < j[:, None])[None, :, :, None]).sum(2)
+    k = jnp.arange(n)[None, :, None]
+    word = ((before + held)[:, None] <= k[..., None, :]).sum(2)  # (H, n, T): the k-th's word
+    here = word[:, :, None] == j[:, None]  # (H, n, nw, T), one-hot: sums, not gathers
+    got = jnp.where(here, words[:, None], 0).sum(2)
+    k = k - jnp.where(here, before[:, None], 0).sum(2)  # its place among the word's set bits
+    place = jnp.zeros_like(k)  # the most bits below which k or fewer are set
+    for step in (16, 8, 4, 2, 1):
+        wider = place + step
+        below = jax.lax.population_count(got & ((1 << wider) - 1))
+        place = jnp.where(below <= k, wider, place)
+    return jnp.where(word < nw, word * _WORD + place, 0), held.sum(1)
+
+
+def _bits_between(lo, hi, nw: int):
+    """(nw, T) int32 words with the bits of blocks ``lo[t] <= b < hi[t]``."""
+    first = jnp.arange(nw, dtype=jnp.int32)[:, None] * _WORD
+    a = jnp.clip(lo[None] - first, 0, _WORD)
+    b = jnp.clip(hi[None] - first, 0, _WORD)
+
+    def below(n):  # bits 0 .. n - 1
+        return jnp.where(n >= _WORD, -1, (1 << jnp.minimum(n, _WORD - 1)) - 1)
+
+    return below(b) & ~below(a)
+
+
+def block_sparse_chunk_plan(picked, start, valid, sel, tq: int, bs: int):
+    """What the chunk kernel walks and what each query fetches, for tiles of
+    ``tq`` queries and blocks of ``bs`` columns. A tile whose first query
+    selects (``sel``) walks a BAND: its init blocks, then whole steps of the
+    walk from the one that holds its first query's window's first column to
+    the one that holds its last query's column; every block a tile with a
+    query before ``dense_len`` can attend is in its band. A selecting query
+    fetches the pages it chose outside the band itself: only blocks it
+    chose by score, so no more than ``sparse_index.scored_blocks(sel)``.
+
+    Returns ``tiles`` (T / tq * 3,) int32, each tile's init blocks and the
+    first and last block of its band (its queries fetch only where the band
+    starts past block 0); ``words``, ``picked`` as bits (``_words_of``);
+    ``lists`` (H * T * R,) int32, a record of ``R`` a query: how many pages
+    it fetches (none at or past ``valid``), then their blocks in order,
+    block 0 in the places left, a tile's records whole SMEM tiles; and
+    ``fetched``, the chosen pages of all records."""
+    from elephas_tpu.ops.sparse_index import scored_blocks
+
+    H, nb, T = picked.shape
+    G = _SPARSE_CHUNK_STEP
+    nt = T // tq
+    first = start + jnp.arange(nt) * tq  # each tile's first query's column
+    hi = jnp.minimum((first + tq - 1) // bs, nb - 1)
+    width = max(1, scored_blocks(sel))
+    R = width + 1
+    if _SMEM_TILE % tq == 0:  # a tile's records fill whole tiles of 1-D SMEM
+        R = -(-R * tq // _SMEM_TILE) * _SMEM_TILE // tq
+    words = _words_of(picked)
+    selects = first >= sel.dense_len
+    lo = jnp.maximum(first - sel.window + 1, 0) // bs
+    lo = jnp.where(selects, jnp.minimum(lo - lo % G, hi - hi % G), 0)
+    init = jnp.minimum(sel.init_blocks, lo)
+
+    def records():
+        outside = _bits_between(jnp.repeat(init, tq), jnp.repeat(lo, tq), words.shape[1])
+        nth, count = _nth_set(words & jnp.where(jnp.arange(T) < valid, outside, 0), width)
+        rec = jnp.concatenate([count[:, None], nth], 1)  # (H, 1 + width, T)
+        return jnp.pad(jnp.swapaxes(rec, 1, 2), ((0, 0), (0, 0), (0, R - 1 - width)))
+
+    # a chunk with no query past dense_len walks: no record is built
+    lists = jax.lax.cond(selects[-1], records, lambda: jnp.zeros((H, T, R), jnp.int32))
+    tiles = jnp.stack([init, lo, hi], -1).reshape(-1).astype(jnp.int32)
+    return tiles, words, lists.reshape(-1), lists[..., 0].sum()
+
+
+def _sparse_chunk_kernel(phys_ref, start_ref, tiles_ref,  # scalar prefetch
+                         q_ref,                           # (tq, g * D): the heads side by side
+                         words_hbm,                       # (H, blocks / 32, T): picked's bits
+                         lists_hbm,                       # (H * T * R,): each query's record
+                         k_hbm, v_hbm,                    # the pools, left in HBM
+                         o_ref,                           # (tq, g * D): the heads side by side
+                         words_ref,                       # (blocks / 32, tq): the tile's
+                         lists_ref,                       # SMEM (tq * R,): the tile's
+                         band_k, band_v,                  # (2, G, bs, D): a step's pages
+                         own_k, own_v,                    # (2, W, bs, D): a query's pages
+                         acc_ref, max_ref, sum_ref,       # (g, tq, D|1) float32
+                         sem,                             # DMAs in flight: (5, 2)
+                         *, G: int, W: int, scale: float):
+    """Grid step (K/V head ``h``, query tile ``i``): the tile's queries of
+    the group's heads, first against its band ``G`` blocks a step, each
+    query under its bits of ``picked`` and the diagonal, then query by
+    query against the pages it fetched, all in one online softmax. The
+    kernel moves every page itself: while a step (a query) is scored, the
+    next one's pages are on their way into the other half of the buffers.
+    Every place of a half is filled, and those past the band's live blocks
+    or the query's own pages are weighted 0."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    H, g, T, D = q.shape
+    h, i = pl.program_id(0), pl.program_id(1)
+    tq, D = q_ref.shape[0], band_k.shape[3]
+    g = q_ref.shape[1] // D
+    bs = band_k.shape[2]
+    R = lists_ref.shape[0] // tq
+    floor = jnp.finfo(jnp.float32).min
+    start = start_ref[0]
+    init, lo, hi = (tiles_ref[i * 3 + n] for n in range(3))
+    fetches = lo > 0  # only a band past block 0 leaves chosen pages outside it
+    init_steps = (init + G - 1) // G
+    steps = init_steps + (hi - lo + G) // G
+
+    def band_step(s):  # (its first block, its blocks)
+        first = jnp.where(s < init_steps, s * G, lo + (s - init_steps) * G)
+        return first, jnp.minimum(G, jnp.where(s < init_steps, init, hi + 1) - first)
+
+    def pair(to):  # the K and V buffers: 0 the band's, 1 a query's
+        return ((k_hbm, (band_k, own_k)[to]), (v_hbm, (band_v, own_v)[to]))
+
+    def fetch(blocks, to, slot):
+        """Start the copies of head ``h``'s pages of the slot's blocks
+        ``blocks(b)``, one for every place of half ``slot`` of ``pair(to)``:
+        every place is filled, so all of them are waited for at once."""
+        def page(b, carry):
+            phys = phys_ref[blocks(b)]
+            for leaf, (pool, buf) in enumerate(pair(to)):
+                pltpu.make_async_copy(pool.at[phys, h], buf.at[slot, b],
+                                      sem.at[2 * to + leaf, slot]).start()
+            return carry
+
+        jax.lax.fori_loop(0, pair(to)[0][1].shape[1], page, 0)
+
+    def arrived(to, slot):
+        for leaf, (pool, buf) in enumerate(pair(to)):
+            pltpu.make_async_copy(pool.at[pl.ds(0, buf.shape[1]), h], buf.at[slot],
+                                  sem.at[2 * to + leaf, slot]).wait()
+
+    def band_fetch(s, slot):
+        first = band_step(s)[0]
+        fetch(lambda b: first + b, 0, slot)
+
+    def own_fetch(r, slot):  # past its count a record names block 0
+        fetch(lambda n: lists_ref[r * R + 1 + n], 1, slot)
+
+    bits = pltpu.make_async_copy(words_hbm.at[h, :, pl.ds(pl.multiple_of(i * tq, tq), tq)],
+                                 words_ref, sem.at[4, 0])
+    bits.start()
+    records = pltpu.make_async_copy(
+        lists_hbm.at[pl.ds((h * pl.num_programs(1) + i) * lists_ref.shape[0], lists_ref.shape[0])],
+        lists_ref, sem.at[4, 1])
+    records.start()
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+    max_ref[...] = jnp.full(max_ref.shape, floor, jnp.float32)
+    sum_ref[...] = jnp.zeros(sum_ref.shape, jnp.float32)
+    band_fetch(0, 0)
+    bits.wait()
+    records.wait()
+
+    @pl.when(fetches & (lists_ref[0] > 0))
+    def _first_query():
+        own_fetch(0, 0)
+
+    # a block's choice spread over its columns: (tq, G) through a 0/1 matrix
+    spread = (jax.lax.broadcasted_iota(jnp.int32, (G, G * bs), 1) // bs ==
+              jax.lax.broadcasted_iota(jnp.int32, (G, G * bs), 0)).astype(jnp.float32)
+    t = start + i * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
+
+    def walk(s, carry):
+        slot = jax.lax.rem(s, 2)
+
+        @pl.when(s + 1 < steps)
+        def _ahead():
+            band_fetch(s + 1, 1 - slot)
+
+        arrived(0, slot)
+        first, live = band_step(s)
+        # a step's G blocks lie in one word: their bits, (G, tq), then (tq, G)
+        word = words_ref[pl.ds(first // _WORD, 1), :]
+        shift = first % _WORD + jax.lax.broadcasted_iota(jnp.int32, (G, 1), 0)
+        bits = jax.lax.shift_right_logical(word, shift) & 1
+        chose = jnp.dot(jnp.transpose(bits.astype(jnp.float32)), spread,
+                        preferred_element_type=jnp.float32) > 0.5
+        cols = first * bs + jax.lax.broadcasted_iota(jnp.int32, (1, G * bs), 1)
+        ok = chose & (cols <= t) & (cols < (first + live) * bs)
+        keys = band_k[slot].reshape(G * bs, D)
+        vals = band_v[slot].reshape(G * bs, D)
+
+        def head(n, carry):
+            qh = q_ref[:, pl.ds(pl.multiple_of(n * D, D), D)]
+            s = jax.lax.dot_general(qh, keys, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+            s = jnp.where(ok, s, floor)
+            m_prev = max_ref[n]
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+            fix = jnp.exp(m_prev - m_new)
+            sum_ref[n] = sum_ref[n] * fix + p.sum(axis=1, keepdims=True)
+            acc_ref[n] = acc_ref[n] * fix + jnp.dot(
+                p.astype(vals.dtype), vals, preferred_element_type=jnp.float32)
+            max_ref[n] = m_new
+            return carry
+
+        return jax.lax.fori_loop(0, g, head, carry)
+
+    jax.lax.fori_loop(0, steps, walk, 0)
+
+    @pl.when(fetches)
+    def _gather():
+        cols = jax.lax.broadcasted_iota(jnp.int32, (1, W * bs), 1)
+
+        def query(r, carry):
+            slot = jax.lax.rem(r, 2)
+
+            @pl.when((r + 1 < tq) & (lists_ref[jnp.minimum(r + 1, tq - 1) * R] > 0))
+            def _ahead():
+                own_fetch(r + 1, 1 - slot)
+
+            n = lists_ref[r * R]
+
+            @pl.when(n > 0)
+            def _score():  # the query's heads against its pages: a row of the tile
+                arrived(1, slot)
+                row = pl.ds(r, 1)
+                # the query's heads: its row of the 16 (a bfloat16 tile) it lies in
+                rows = q_ref[pl.ds(pl.multiple_of(r - r % 16, 16), 16), :]
+                pick = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 0) == r % 16
+                qr = jnp.where(pick, rows.astype(jnp.float32), 0.0).sum(
+                    axis=0, keepdims=True).reshape(g, D).astype(own_k.dtype)
+                s = jax.lax.dot_general(qr, own_k[slot].reshape(W * bs, D),
+                                        (((1,), (1,)), ((), ())),
+                                        preferred_element_type=jnp.float32) * scale
+                ok = cols < n * bs
+                s = jnp.where(ok, s, floor)
+                m_prev = max_ref[:, row, :].reshape(g, 1)
+                m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+                p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+                fix = jnp.exp(m_prev - m_new)
+                sum_ref[:, row, :] = (sum_ref[:, row, :].reshape(g, 1) * fix
+                                      + p.sum(axis=1, keepdims=True)).reshape(g, 1, 1)
+                acc_ref[:, row, :] = (acc_ref[:, row, :].reshape(g, D) * fix + jnp.dot(
+                    p.astype(own_v.dtype), own_v[slot].reshape(W * bs, D),
+                    preferred_element_type=jnp.float32)).reshape(g, 1, D)
+                max_ref[:, row, :] = m_new.reshape(g, 1, 1)
+
+            return carry
+
+        jax.lax.fori_loop(0, tq, query, 0)
+
+    def put(n, carry):  # head n's result, into its lanes of the queries' rows
+        o_ref[:, pl.ds(pl.multiple_of(n * D, D), D)] = (
+            acc_ref[n] / jnp.maximum(sum_ref[n], jnp.finfo(jnp.float32).tiny)).astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, g, put, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "sel", "tq", "interpret"))
+def pallas_block_sparse_chunk_attention(q, k_pool, v_pool, row, start, picked,
+                                        scale: float, sel, valid, tq: int | None = None,
+                                        interpret: bool = False):
+    """The chunk kernel. ``q``: (T, H, g, D), a query's heads by K/V head
+    (each query's row of the layer's projection); ``k_pool``/``v_pool``: pool
+    leaves of a lane width a column (``pool_leaf_shape`` with ``head_dim``
+    128); ``row``: (blocks_per_slot,); ``start``: scalar; ``picked``: (H,
+    blocks_per_slot, T), 1 where query ``t`` attends the block; ``sel``:
+    the selection ``picked`` was made by (``sparse_index.BlockSelection``),
+    whose forced blocks make each tile's band (``block_sparse_chunk_plan``);
+    ``valid``: the chunk's real queries (the others fetch nothing and mean
+    nothing). A grid step is a K/V head and a query tile, its running sums
+    in VMEM; the pools stay in HBM and the kernel copies the pages it
+    reads. Returns (T, H * g * D), a query's heads side by side as the
+    layer's projection reads them, and the chosen pages the queries
+    fetched (a query's copies fill its places past its count with block 0,
+    weighted 0 and not counted)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    T, H, g, D = q.shape
     num_blocks, _, rows, lanes = k_pool.shape
     bs = rows * lanes // D
-    bps = row.shape[0]
     G = _SPARSE_CHUNK_STEP
-    most = -(-bps // G)
     if tq is None:
         tq = _widest_divisor(T, _SPARSE_QUERY_TILE)
-    phys = jnp.clip(row.astype(jnp.int32), 0, num_blocks - 1)
-    phys = jnp.pad(phys, (0, most * G - bps), mode="edge")
-    picked = jnp.pad(picked.astype(jnp.int32), ((0, 0), (0, most * G - bps), (0, 0)))
     start = jnp.asarray(start, jnp.int32)
-    steps = jnp.clip((start + T - 1) // (bs * G) + 1, 1, most)
-
-    def pages(b):
-        return pl.BlockSpec((None, None, rows, lanes),
-                            lambda h, i, j, phys, *_, b=b: (phys[j * G + b], h, 0, 0))
-
-    return pl.pallas_call(
-        functools.partial(_sparse_chunk_kernel, G=G, scale=scale),
+    tiles, words, lists, fetched = block_sparse_chunk_plan(picked, start, valid, sel, tq, bs)
+    W = lists.shape[0] // (H * T) - 1  # a record is a count and W blocks
+    phys = jnp.pad(jnp.clip(row.astype(jnp.int32), 0, num_blocks - 1), (0, G), mode="edge")
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    out = pl.pallas_call(
+        functools.partial(_sparse_chunk_kernel, G=G, W=W, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(H, T // tq, steps),
-            in_specs=[pl.BlockSpec((1, g, tq, D), lambda h, i, j, *_: (h, 0, i, 0)),
-                      pl.BlockSpec((1, G, tq), lambda h, i, j, *_: (h, j, i)),
-                      *[pages(b) for b in range(G)], *[pages(b) for b in range(G)]],
-            out_specs=pl.BlockSpec((1, g, tq, D), lambda h, i, j, *_: (h, 0, i, 0)),
-            scratch_shapes=[pltpu.VMEM((g, tq, D), jnp.float32),
+            num_scalar_prefetch=3,
+            grid=(H, T // tq),
+            in_specs=[pl.BlockSpec((tq, g * D), lambda h, i, *_: (i, h)),
+                      hbm,
+                      hbm, hbm, hbm],
+            out_specs=pl.BlockSpec((tq, g * D), lambda h, i, *_: (i, h)),
+            scratch_shapes=[pltpu.VMEM((words.shape[1], tq), jnp.int32),
+                            pltpu.SMEM((lists.shape[0] // (H * T // tq),), jnp.int32),
+                            pltpu.VMEM((2, G, bs, D), k_pool.dtype),
+                            pltpu.VMEM((2, G, bs, D), v_pool.dtype),
+                            pltpu.VMEM((2, W, bs, D), k_pool.dtype),
+                            pltpu.VMEM((2, W, bs, D), v_pool.dtype),
+                            pltpu.VMEM((g, tq, D), jnp.float32),
                             pltpu.VMEM((g, tq, 1), jnp.float32),
-                            pltpu.VMEM((g, tq, 1), jnp.float32)],
+                            pltpu.VMEM((g, tq, 1), jnp.float32),
+                            pltpu.SemaphoreType.DMA((5, 2))],
         ),
-        out_shape=jax.ShapeDtypeStruct((H, g, T, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((T, H * g * D), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=64 << 20),
         interpret=pltpu.InterpretParams() if interpret else False,
         name="block_sparse_chunk_attention",
-    )(phys, start.reshape(1), q, picked, *([k_pool] * G), *([v_pool] * G))
+    )(phys, start.reshape(1), tiles, q.reshape(T, H * g * D), words, lists, k_pool, v_pool)
+    return out, fetched
 
 
 def block_sparse_chunk_fits(pool_shape, head_dim: int, chunk: int) -> bool:
@@ -893,21 +1122,26 @@ def block_sparse_chunk_fits(pool_shape, head_dim: int, chunk: int) -> bool:
 
 
 def block_sparse_chunk_attention(q, k_pool, v_pool, row, start, picked,
-                                 head_dim: int, scale: float, body: str):
+                                 head_dim: int, scale: float, body: str, sel, valid):
     """One prefill chunk's attention of ONE slot over the blocks each query
-    chose. ``q``: (heads, group, T, D); ``row``: (blocks_per_slot,);
+    chose. ``q``: (T, heads, group, D); ``row``: (blocks_per_slot,);
     ``start``: the chunk's first column; ``picked``: (heads, blocks_per_slot,
     T), 1 where query ``t`` of K/V head ``h`` attends the block (every live
-    one for a query before ``dense_len``). Query ``i`` attends columns ``<=
-    start + i`` of its blocks. ``body`` ``"paged_pallas"`` runs the kernel
-    where its tiles lower. Returns (heads, group, T, D)."""
+    one for a query before ``dense_len``), as ``sel`` selects. Query ``i``
+    attends columns ``<= start + i`` of its blocks. ``body``
+    ``"paged_pallas"`` runs the kernel where its tiles lower; ``valid``: the
+    chunk's real queries. Returns (T, heads * group * D) and the chosen
+    pages the kernel's queries fetched one by one (none on the XLA body,
+    which walks)."""
     with jax.named_scope("block_sparse_attention"):
         if body == "paged_pallas" and block_sparse_chunk_fits(k_pool.shape, head_dim,
-                                                              q.shape[2]):
+                                                              q.shape[0]):
             return pallas_block_sparse_chunk_attention(q, k_pool, v_pool, row, start,
-                                                       picked, scale)
-        return _chunk_walk_xla(q, k_pool, v_pool, row, start, picked, head_dim,
-                               scale).astype(q.dtype)
+                                                       picked, scale, sel, valid)
+        out = _chunk_walk_xla(jnp.moveaxis(q, 0, 2), k_pool, v_pool, row, start, picked,
+                              head_dim, scale)
+        return (jnp.moveaxis(out, 2, 0).reshape(q.shape[0], -1).astype(q.dtype),
+                jnp.zeros((), jnp.int32))
 
 
 def pallas_min_seq(head_dim: int) -> int:

@@ -485,6 +485,18 @@ def select_blocks(scores, last, sel: BlockSelection, body: str):
     return jnp.where(dense, jnp.int8(1), mask)[:, :nb]
 
 
+def scored_blocks(sel: BlockSelection) -> int:
+    """The most blocks ``select_blocks`` gives a query at or past
+    ``dense_len`` by score: ``top_k`` less the forced ones, its init blocks
+    and the fewest blocks that hold a window of ``sel.window`` columns.
+    Those two lie apart only where ``dense_len`` is a window past the init
+    blocks, which this holds ``sel`` to."""
+    if sel.dense_len < sel.init_blocks * sel.block + sel.window:
+        raise ValueError(f"dense_len {sel.dense_len} is less than a window of {sel.window} "
+                         f"past {sel.init_blocks} init blocks of {sel.block}")
+    return sel.top_k - sel.init_blocks - -(-sel.window // sel.block)
+
+
 def chosen_blocks(mask, width: int):
     """The selected blocks of each row in ascending order, ``(rows, width)``,
     then the out-of-range block number in the places left."""

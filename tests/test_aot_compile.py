@@ -570,11 +570,65 @@ def test_sparse_linear_kernels_compile_for_v5e(one_chip):
     jax.jit(lambda q, k, s: sparse_index.pallas_block_scores(q, k, s, sel, 128 ** -0.5)).lower(
         shape((2, 16, 2048, 128), bf16), shape((2, 1568 * 4, 128), bf16),
         shape((), i32)).compile()
+    jax.jit(lambda q, kp, vp, row, s, picked, valid: attention.pallas_block_sparse_chunk_attention(
+        q, kp, vp, row, s, picked, 128 ** -0.5, sel, valid)).lower(
+        *_sparse_chunk_args(shape)).compile()
+
+
+def _sparse_chunk_args(shape):
+    """`minicpm-sala`'s chunk attention's arguments but its scale and
+    selection: a chunk of 2,048 queries, 16 heads on each of 2 K/V heads, 16
+    slots of 1,568 pages of 64."""
+    bf16, i32 = jnp.bfloat16, jnp.int32
     pool = shape((16 * 1568, 2, 64, 128), bf16)
-    jax.jit(lambda q, kp, vp, row, s, picked: attention.pallas_block_sparse_chunk_attention(
-        q, kp, vp, row, s, picked, 128 ** -0.5)).lower(
-        shape((2, 16, 2048, 128), bf16), pool, pool, shape((1568,), i32), shape((), i32),
-        shape((2, 1568, 2048), jnp.int8)).compile()
+    return (shape((2048, 2, 16, 128), bf16), pool, pool, shape((1568,), i32), shape((), i32),
+            shape((2, 1568, 2048), jnp.int8), shape((), i32))
+
+
+def _equations(jaxpr) -> int:
+    """Equations of a jaxpr and of every jaxpr inside its equations."""
+    from jax.extend import core
+
+    count = 0
+    for eqn in jaxpr.eqns:
+        count += 1
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                if isinstance(sub, core.ClosedJaxpr):
+                    count += _equations(sub.jaxpr)
+                elif isinstance(sub, core.Jaxpr):
+                    count += _equations(sub)
+    return count
+
+
+def test_the_sparse_chunk_kernel_stays_rolled(one_chip):
+    """The chunk attention's one kernel at the cell's widths is a body of
+    loops, not of copies: what its trace and lowering cost grows with its
+    equations and operands, and a body unrolled over pages, queries or
+    heads would be many times the walk's (114 equations, 21 operands, when
+    the kernel walked every live step)."""
+    from elephas_tpu.ops import attention, sparse_index
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    sel = sparse_index.BlockSelection(32, 16, 64, 64, 1, 2048, 8192)
+    jaxpr = jax.make_jaxpr(lambda q, kp, vp, row, s, picked, valid: (
+        attention.pallas_block_sparse_chunk_attention(q, kp, vp, row, s, picked, 128 ** -0.5,
+                                                      sel, valid)))(*_sparse_chunk_args(shape))
+
+    def kernels(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for value in eqn.params.values():
+                if hasattr(value, "jaxpr"):
+                    yield from kernels(value.jaxpr)
+
+    (kernel,) = [e for e in kernels(jaxpr.jaxpr)
+                 if e.params["name"] == "block_sparse_chunk_attention"]
+    assert _equations(kernel.params["jaxpr"]) <= 4 * 114
+    assert len(kernel.invars) + len(kernel.outvars) <= 21
 
 
 def test_both_programs_of_the_sparse_linear_cell_compile_for_v5e(one_chip, monkeypatch):

@@ -458,6 +458,7 @@ def test_the_counters_ride_the_lanes_fetch_and_the_chunks_span(run, served):
             _counts(columns, sel)
         dense = [c for c in columns if c < sel.dense_len]
         assert e.args["sparse_columns_selected"] >= 2 * sum(c + 1 for c in dense)
+        assert e.args["sparse_pages_gathered"] == 0  # the XLA body walks every chunk
 
 
 def test_the_pool_holds_matrix_states_and_compressed_keys(run, served, family):
@@ -524,6 +525,7 @@ def test_the_chunk_attention_kernel_is_the_xla_body():
     vp = jnp.asarray(rng.normal(size=(nbk, H, B, D)), jnp.float32)
     row = jnp.asarray(rng.permutation(nbk)[:bps], jnp.int32)
     q = jnp.asarray(rng.normal(size=(H, g, T, D)), jnp.float32)
+    sel = sparse_index.BlockSelection(32, 16, B, 8, 1, 128, bps * B)  # no query selects
     with jax.default_matmul_precision("highest"):
         for start in (0, 640, 1280):
             last = start + np.arange(T)
@@ -532,10 +534,140 @@ def test_the_chunk_attention_kernel_is_the_xla_body():
             picked[:, 0] = True
             want = attention._chunk_walk_xla(q, kp, vp, row, jnp.int32(start),
                                              jnp.asarray(picked), D, 0.1)
-            got = jax.block_until_ready(attention.pallas_block_sparse_chunk_attention(
-                q, kp, vp, row, jnp.int32(start), jnp.asarray(picked), 0.1, tq=128,
-                interpret=True))
+            got, fetched = attention.pallas_block_sparse_chunk_attention(
+                jnp.moveaxis(q, 2, 0), kp, vp, row, jnp.int32(start), jnp.asarray(picked),
+                0.1, sel, jnp.int32(T), tq=128, interpret=True)
+            got = jnp.moveaxis(jax.block_until_ready(got).reshape(T, H, g, D), 0, 2)
             assert np.abs(np.asarray(got - want)).max() < 1e-5
+            assert int(fetched) == 0  # before dense_len every tile walks
+
+
+# A selection at interpret-mode sizes: blocks of 16, 12 a query, a window of
+# 64 columns, every column before 256; chunks of 256 in tiles of 128.
+_GATHER_SEL = sparse_index.BlockSelection(4, 2, 16, 12, 1, 64, 256)
+
+
+def _gather_case(start, rng, sel=_GATHER_SEL):
+    """(q, pools, row, picked) of one chunk of 256 queries from ``start``: a
+    selection by random scores through `select_blocks`, as a layer makes it."""
+    H, g, T, D, bps, nbk = 2, 2, 256, 128, 64, 150
+    kp = jnp.asarray(rng.normal(size=(nbk, H, sel.block, D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(nbk, H, sel.block, D)), jnp.float32)
+    row = jnp.asarray(rng.permutation(nbk)[:bps], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(H, g, T, D)), jnp.float32)
+    last = start + np.arange(T)
+    mask = sparse_index.select_blocks(jnp.asarray(rng.random((T * H, bps)), jnp.float32),
+                                      jnp.repeat(jnp.asarray(last), H), sel, "paged_xla")
+    picked = np.array(jnp.moveaxis(mask.reshape(T, H, bps), 0, 2))
+    return q, kp, vp, row, picked
+
+
+def _attend_f32(q, kp, vp, row, start, picked, valid, scale=0.1):
+    """Each query's heads over the columns of its picked blocks up to its own,
+    in float64 numpy: the plain reference of the chunk attention."""
+    q, kp, vp, row = (np.asarray(x, np.float64) for x in (q, kp, vp, row))
+    H, g, T, D = q.shape
+    bs = kp.shape[2]
+    keys = kp[row.astype(int)].transpose(1, 0, 2, 3).reshape(H, -1, D)  # (H, columns, D)
+    vals = vp[row.astype(int)].transpose(1, 0, 2, 3).reshape(H, -1, D)
+    out = np.zeros((H, g, valid, D))
+    for h in range(H):
+        for t in range(valid):
+            cols = np.arange(keys.shape[1])
+            ok = picked[h, cols // bs, t].astype(bool) & (cols <= start + t)
+            s = q[h, :, t] @ keys[h, ok].T * scale
+            p = np.exp(s - s.max(-1, keepdims=True))
+            out[h, :, t] = (p / p.sum(-1, keepdims=True)) @ vals[h, ok]
+    return out
+
+
+def _band_of(start, t, sel=_GATHER_SEL, tq=128):
+    """(init blocks, first block of the band) of query ``t``'s tile."""
+    first = start + (t // tq) * tq
+    if first < sel.dense_len:
+        return 0, 0
+    lo = max(first - sel.window + 1, 0) // sel.block
+    hi = (first + tq - 1) // sel.block
+    lo = min(lo - lo % 8, hi - hi % 8)
+    return min(sel.init_blocks, lo), lo
+
+
+@pytest.mark.parametrize("start,valid,dense_len", [
+    (0, 256, 256), (128, 256, 256), (640, 256, 256), (768, 200, 256), (128, 256, 80)],
+    ids=["before-dense_len", "straddles", "far-past", "ragged", "window-past-init"])
+def test_the_gathered_chunk_attention_is_the_walk_and_the_reference(start, valid, dense_len):
+    """Past `dense_len` a tile walks its band and each query fetches the
+    pages it chose outside it: the same attention as the XLA walk and the
+    plain reference, and the pages fetched are each query's chosen blocks
+    outside its tile's band, none at or past `valid`, and no more than it
+    chose by score. The last case puts `dense_len` a window past the init
+    block, the nearest the forced blocks can lie and stay apart."""
+    sel = _GATHER_SEL._replace(dense_len=dense_len)
+    rng = np.random.default_rng(start)
+    q, kp, vp, row, picked = _gather_case(start, rng, sel)
+    H, g, T, D = q.shape
+    with jax.default_matmul_precision("highest"):
+        want = attention._chunk_walk_xla(q, kp, vp, row, jnp.int32(start),
+                                         jnp.asarray(picked), D, 0.1)
+        got, fetched = attention.pallas_block_sparse_chunk_attention(
+            jnp.moveaxis(q, 2, 0), kp, vp, row, jnp.int32(start), jnp.asarray(picked), 0.1,
+            sel, jnp.int32(valid), tq=128, interpret=True)
+    got = np.asarray(jnp.moveaxis(jax.block_until_ready(got).reshape(T, H, g, D), 0, 2))
+    assert np.abs(got - np.asarray(want))[:, :, :valid].max() < 1e-5
+    assert np.abs(got[:, :, :valid] - _attend_f32(q, kp, vp, row, start, picked, valid)).max() < 1e-5
+    outside = sum(int(picked[h, init:lo, t].sum()) for h in range(H) for t in range(valid)
+                  for init, lo in [_band_of(start, t, sel)])
+    assert int(fetched) == outside
+    assert (int(fetched) > 0) == (start + T - 128 >= sel.dense_len)
+    _, _, lists, _ = attention.block_sparse_chunk_plan(jnp.asarray(picked), jnp.int32(start),
+                                                       valid, sel, 128, sel.block)
+    assert np.asarray(lists).reshape(H, T, -1)[..., 0].max() <= sparse_index.scored_blocks(sel)
+
+
+def test_a_selection_keeps_its_window_apart_from_its_init_blocks():
+    """A query past `dense_len` chooses by score at most `top_k` less its
+    forced blocks, which holds only where its window cannot reach the init
+    blocks: a selection where it can is refused."""
+    assert sparse_index.scored_blocks(_GATHER_SEL) == 12 - 1 - 4
+    assert sparse_index.scored_blocks(sparse_index.BlockSelection(
+        32, 16, 64, 64, 1, 2048, 8192)) == 31  # minicpm-sala's published selection
+    with pytest.raises(ValueError, match="dense_len"):
+        sparse_index.scored_blocks(_GATHER_SEL._replace(dense_len=79))
+
+
+def test_queries_of_a_tile_fetch_their_own_pages():
+    """Two queries of one tile that chose disjoint pages outside the band,
+    and one whose scored pages all lie inside it (it fetches none): each
+    attends its own choice."""
+    sel, start = _GATHER_SEL, 640
+    rng = np.random.default_rng(3)
+    q, kp, vp, row, picked = _gather_case(start, rng)
+    H, g, T, D = q.shape
+    init, lo = _band_of(start, 200)
+    width = sparse_index.scored_blocks(sel)
+    for t, scored in ((200, range(init, init + width)), (201, range(lo - width, lo)),
+                      (202, range(lo, lo + width))):
+        own = (start + t) // sel.block
+        forced = set(range(sel.init_blocks)) | {
+            b for b in range(own + 1) if (b + 1) * sel.block - 1 >= start + t - sel.window + 1}
+        picked[:, :, t] = 0
+        for b in forced | set(scored):
+            picked[:, b, t] = 1
+    assert not set(range(init, init + width)) & set(range(lo - width, lo))
+    with jax.default_matmul_precision("highest"):
+        got, fetched = attention.pallas_block_sparse_chunk_attention(
+            jnp.moveaxis(q, 2, 0), kp, vp, row, jnp.int32(start), jnp.asarray(picked), 0.1,
+            sel, jnp.int32(T), tq=128, interpret=True)
+    got = np.asarray(jnp.moveaxis(jax.block_until_ready(got).reshape(T, H, g, D), 0, 2))
+    want = _attend_f32(q, kp, vp, row, start, picked, T)
+    assert np.abs(got[:, :, 200:203] - want[:, :, 200:203]).max() < 1e-5
+    assert np.abs(got - want).max() < 1e-5
+    _, _, lists, _ = attention.block_sparse_chunk_plan(jnp.asarray(picked), jnp.int32(start),
+                                                       T, sel, 128, sel.block)
+    records = np.asarray(lists).reshape(H, T, -1)  # a query's count, then its blocks
+    assert list(records[0, 200, :width + 1]) == [width, *range(init, init + width)]
+    assert list(records[0, 201, :width + 1]) == [width, *range(lo - width, lo)]
+    assert records[0, 202, 0] == 0
 
 
 # -- (g) the other hybrids lower as they did ------------------------------------------
