@@ -18,7 +18,10 @@ the held experts' part, ``(0, n_routed_experts)`` being the uncut layer;
 the engine refuses it speculation and ``shard_serving``), and
 ``minicpm_sala_lm`` (lightning linear-attention layers with a matrix state a
 slot beside block-sparse attention that selects whole pages of the pool;
-per-slot state, so refused as ``jamba_lm`` is).
+per-slot state, so refused as ``jamba_lm`` is), and ``granite_hybrid_lm``
+(Mamba-2 mixers whose matrix state decays by a step each token sets, beside
+grouped attention, a routed layer told which experts it holds and a shared
+expert on every layer; per-slot state, so refused as ``jamba_lm`` is).
 """
 
 from __future__ import annotations
@@ -57,7 +60,7 @@ def registered_models():
 
 # Import for side effect: populate the registry.
 from elephas_tpu.models import (  # noqa: E402,F401
-    mlp, cnn, resnet, lstm, transformer, jamba, latent_moe, minicpm_sala,
+    mlp, cnn, resnet, lstm, transformer, jamba, latent_moe, minicpm_sala, granite_hybrid,
 )
 from elephas_tpu.models.mlp import MLP  # noqa: E402,F401
 from elephas_tpu.models.cnn import SimpleCNN  # noqa: E402,F401
@@ -66,6 +69,7 @@ from elephas_tpu.models.lstm import LSTMClassifier  # noqa: E402,F401
 from elephas_tpu.models.jamba import JambaLM  # noqa: E402,F401
 from elephas_tpu.models.latent_moe import LatentMoELM  # noqa: E402,F401
 from elephas_tpu.models.minicpm_sala import MiniCPMSALA  # noqa: E402,F401
+from elephas_tpu.models.granite_hybrid import GraniteHybridLM  # noqa: E402,F401
 from elephas_tpu.models.transformer import (  # noqa: E402,F401
     TransformerLM,
     generate,

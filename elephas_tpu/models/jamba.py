@@ -157,15 +157,17 @@ class MambaMixer(nn.Module):
                         name="out_proj")(gated)
 
 
-def grouped_causal_attention(q, k, v, mask=None):
+def grouped_causal_attention(q, k, v, mask=None, scale=None):
     """q: (b, heads, T, hd); k, v: (b, kv_heads, L, hd), ``heads`` a
     multiple of ``kv_heads``; ``mask`` broadcastable to (b, 1, T, L), the
-    causal triangle where it is None. No K/V head is copied."""
+    causal triangle where it is None; ``scale`` the scores', ``hd ** -0.5``
+    where it is None. No K/V head is copied."""
     b, heads, T, hd = q.shape
     kv_heads, L = k.shape[1], k.shape[2]
     qg = q.reshape(b, kv_heads, heads // kv_heads, T, hd)
     scores = jnp.einsum("bkgqd,bkld->bkgql", qg, k,
-                        preferred_element_type=jnp.float32) * (1.0 / np.sqrt(hd))
+                        preferred_element_type=jnp.float32) * (
+                            1.0 / np.sqrt(hd) if scale is None else scale)
     if mask is None:
         mask = jnp.tril(jnp.ones((T, L), bool))[None, None]
     scores = jnp.where(mask[:, :, None], scores, jnp.finfo(jnp.float32).min)
@@ -178,6 +180,7 @@ class GroupedAttention(nn.Module):
     num_heads: int
     num_kv_heads: int
     head_dim: int
+    scale: Optional[float] = None  # the scores'; head_dim ** -0.5 where None
     dtype: Any = jnp.float32
     decode: bool = False
 
@@ -195,7 +198,7 @@ class GroupedAttention(nn.Module):
         if self.decode:
             out = self._cached(q, k, v, active, paged)
         else:
-            out = grouped_causal_attention(q, k, v)
+            out = grouped_causal_attention(q, k, v, scale=self.scale)
         out = jnp.transpose(out, (0, 2, 1, 3))
         return nn.DenseGeneral(d_model, axis=(-2, -1), use_bias=False, dtype=self.dtype,
                                name="out")(out)
@@ -216,12 +219,12 @@ class GroupedAttention(nn.Module):
         cache_index = self.variable("cache", "cache_index",
                                     lambda: jnp.array(0, jnp.int32))
         if init_pass:
-            return grouped_causal_attention(q, k, v)
+            return grouped_causal_attention(q, k, v, scale=self.scale)
         idx = cache_index.value
         k, v = k.astype(self.dtype), v.astype(self.dtype)
         if paged is not None:
             return attend_paged(q, k, v, cached_key, cached_value, cache_index,
-                                active, paged)
+                                active, paged, scale=self.scale)
         scalar = idx.ndim == 0
         idx = jnp.broadcast_to(idx, (b,))
         write = jax.vmap(lambda cache, new, i: jax.lax.dynamic_update_slice(
@@ -231,7 +234,7 @@ class GroupedAttention(nn.Module):
         moved = idx + T if active is None else jnp.where(active, idx + T, idx)
         cache_index.value = moved[0] if scalar else moved
         return grouped_causal_attention(
-            q, ck, cv, cache_attention_mask(ck.shape[2], T, idx))
+            q, ck, cv, cache_attention_mask(ck.shape[2], T, idx), scale=self.scale)
 
 
 class HybridLayer(nn.Module):

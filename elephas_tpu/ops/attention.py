@@ -408,6 +408,14 @@ def _expand_values(out, kv_b, nope: int):
                       preferred_element_type=jnp.float32).astype(out.dtype)
 
 
+def _kernel_scaled(q, scale):
+    """Queries for the K/V kernels, which scale their scores by ``head_dim
+    ** -0.5``: multiplied so that the scores come out at ``scale``."""
+    if scale is None:
+        return q
+    return (q.astype(jnp.float32) * (scale * q.shape[-1] ** 0.5)).astype(q.dtype)
+
+
 @functools.partial(jax.jit, static_argnames=("body", "scale", "window"))
 def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, table, idx,
                            active, body: str, scale=None, kv_b=None,
@@ -471,7 +479,8 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, table, idx,
                                      _nope_width(q, kv_b, width))[:, :, 0]
                 return out, k_pool, None
             return pallas_paged_decode_attention(
-                q, k_new, v_new, k_pool, v_pool, table, idx, active
+                _kernel_scaled(q, scale), k_new, v_new, k_pool, v_pool, table, idx,
+                active
             )
     with jax.named_scope("kv_write"):
         k_pool = _write_windows(k_pool, table, idx, k_new[:, :, None], active,
@@ -677,8 +686,8 @@ def paged_chunk_attention(q, k_new, v_new, k_pool, v_pool, row, start,
                     q, kv_b, k_pool, row, start, scale, valid,
                     selected=selected, window=window)
             else:
-                out = pallas_paged_chunk_attention(q, k_pool, v_pool, row,
-                                                   start)
+                out = pallas_paged_chunk_attention(_kernel_scaled(q, scale), k_pool,
+                                                   v_pool, row, start)
         else:
             out = _paged_attend_xla(
                 q[None], k_pool, v_pool, row[None],

@@ -919,3 +919,103 @@ def test_configure_compile_cache_respects_the_environment(monkeypatch):
         assert os.path.exists(expected) == existed
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_ssd_kernels_compile_for_v5e(one_chip):
+    """`granite-4.0-h-small`'s SSD kernels at the published widths (128 heads
+    of 64, a state of 128, two heads to a row of lanes): the chunk form over
+    a chunk of 512, two sub-chunks of 256; the step over 64 lanes, the state
+    in place."""
+    from elephas_tpu.ops import ssd
+
+    def arg(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    H, P, N, T, S = 128, 64, 128, 512, 64
+    G, _, W = ssd.state_shape(H, P, N)
+    assert ssd._pallas_shapes(H, P, N) and (G, W) == (64, 128)
+    chunk = jax.jit(lambda x, a, b, c, s0, v: ssd.pallas_ssd_chunk(x, a, b, c, s0, v, P)).lower(
+        arg(T, H * P), arg(T, H), arg(T, N), arg(T, N), arg(G, N, W),
+        arg(dtype=jnp.int32)).compile()
+    assert "tpu_custom_call" in chunk.as_text()
+    assert chunk.memory_analysis().temp_size_in_bytes < 64 << 20
+    step = jax.jit(lambda x, d, b, c, s, a: ssd.pallas_ssd_step(x, d, b, c, s, a, P),
+                   donate_argnums=(4,)).lower(
+        arg(S, H * P), arg(S, H * P), arg(S, N), arg(S, N), arg(S, G, N, W),
+        arg(S, dtype=jnp.bool_)).compile()
+    assert "tpu_custom_call" in step.as_text()
+    # the state comes back in place: nothing of its 256 MB is copied
+    assert step.memory_analysis().alias_size_in_bytes >= S * G * N * W * 4
+    assert step.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_both_programs_of_the_granite_cell_compile_for_v5e(one_chip, monkeypatch):
+    """`granite-4.0-h-small.gen-batch-2k` whole, at the published widths and
+    the cell's serving sizes (64 slots of 48 blocks of 64, chunks of 512),
+    through `benchmark/aot_compile.py::serving` as the harness builds the
+    engine, with every kernel steered on: both programs fit the chip beside
+    their temporaries, the pool and the state rows come back in place, every
+    part of the mixer has its scope, and the configuration file's
+    `memory_reckoning` quotes what this compile reports. The pool and the
+    state rows are built on the host (3.3 GB of zeros)."""
+    import importlib.util
+    import json
+    import sys
+
+    from elephas_tpu.ops import attention, routed_experts, ssd
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                         "benchmark")
+    monkeypatch.setattr(sys, "path", list(sys.path))  # the script adds its own
+    spec = importlib.util.spec_from_file_location(
+        "bench_aot_compile", os.path.join(bench, "aot_compile.py"))
+    aot = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(aot)
+    # the described chip is not the default backend: steer the choice here
+    for ops in (attention, routed_experts, ssd):
+        monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    programs = {}
+    monkeypatch.setattr(aot, "report", lambda name, compiled: programs.update(
+        {name.split()[-1]: compiled}))
+
+    class Described:
+        devices = [next(iter(one_chip.device_set))]
+
+    aot.serving(Described, ["granite-4.0-h-small.gen-batch-2k"])
+    chunk, decode = programs["jit__chunk_prefill_impl"], programs["jit__paged_decode_impl"]
+
+    def kernels(program):
+        return [line for line in program.as_text().splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line]
+
+    # 8 K/V heads of a chunk of 512 take more VMEM than the K/V chunk kernel
+    # has: the chunk's attention is XLA's
+    for kernel in ("ssd_chunk", "grouped_matmul"):
+        assert any(kernel in line for line in kernels(chunk)), kernel
+    for kernel in ("ssd_step", "paged_decode_attention"):
+        assert any(kernel in line for line in kernels(decode)), kernel
+    from conftest import assert_report_is_whole
+    from elephas_tpu.obs.programs import ProgramReport
+
+    for name, program in programs.items():  # which part issued each instruction
+        report = ProgramReport.from_compiled(program)
+        assert_report_is_whole(report)
+        parts = set(report.parts())
+        for scope in ("in_proj", "conv", "ssd", "gate_norm", "out_proj", "attention",
+                      "route", "shared"):
+            assert any(scope in p.split("/") for p in parts), (name, scope)
+    sizes = {name: program.memory_analysis() for name, program in programs.items()}
+    for name, m in sizes.items():
+        print(f"granite-4.0-h-small {name}, 64 slots, v5e:", m)
+        # weights 5.91 GB, the state rows 2.45 GB, the pool 0.81 GB
+        assert 9.1e9 < m.argument_size_in_bytes < 9.3e9
+        assert m.alias_size_in_bytes >= 2.4e9 + 0.8e9  # no leaf of the pool copied
+    arguments = sizes["jit__chunk_prefill_impl"].argument_size_in_bytes
+    with open(os.path.join(bench, "configs", "granite-4.0-h-small.json")) as f:
+        reckoning = json.load(f)["memory_reckoning"]
+    assert f"{arguments / 1e9:.2f} GB of arguments" in reckoning
+    stated = re.search(r"\+ ([0-9.]+) GB of temporaries in the chunk program.* and "
+                       r"([0-9.]+) GB in the decode program", reckoning)
+    for name, most in zip(("jit__chunk_prefill_impl", "jit__paged_decode_impl"),
+                          stated.groups()):
+        assert sizes[name].temp_size_in_bytes / 1e9 < float(most) + 0.0005, name
